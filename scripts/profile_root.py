@@ -5,10 +5,9 @@ import sys
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      os.path.join(os.path.dirname(__file__), "..",
-                                   ".jax_cache"))
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+from volsync_tpu.compile_cache import configure as _configure_cache  # noqa: E402
+
+_configure_cache()
 
 import jax
 import jax.numpy as jnp
@@ -60,7 +59,7 @@ def pages(d, s):
 
 
 # Root loop with a REAL chunk table (decoded from a warm run) but fed
-# salted digests so the tunnel cannot memoize. nb/max_nb structure is
+# salted digests so no two timed calls share arguments. nb/max_nb structure is
 # identical to the in-program loop.
 warm = seg.chunk_hash_segment(
     base, N, min_size=p.min_size, avg_size=p.avg_size, max_size=p.max_size,
@@ -89,23 +88,8 @@ flat0 = jnp.arange(8 * npp, dtype=jnp.uint32)  # synthetic digest table
 
 @jax.jit
 def root_only(fl, s):
-    # explicit word-major index: keep this row honest even when the
-    # VOLSYNC_PAGEMAJOR gate is set in the environment
     st = seg._root_digests_loop(
-        fl ^ s.astype(jnp.uint32), npp, page0, nleaves, lens_d, live,
-        word_index=lambda j, p: j * npp + p)
-    return st.astype(jnp.uint32).sum()
-
-
-@jax.jit
-def root_pagemajor(fl, s):
-    """Same loop over a PAGE-major digest table (word j of page p at
-    p*8 + j): each lane's 65-word gather reads contiguous memory. If
-    this is much faster than the word-major layout, restructuring the
-    SHA kernel's output layout pays."""
-    st = seg._root_digests_loop(
-        fl ^ s.astype(jnp.uint32), npp, page0, nleaves, lens_d, live,
-        word_index=lambda j, p: p * 8 + j)
+        fl ^ s.astype(jnp.uint32), npp, page0, nleaves, lens_d, live)
     return st.astype(jnp.uint32).sum()
 
 
@@ -114,4 +98,3 @@ print(f"== {SEG_MIB} MiB, backend={jax.default_backend()}, "
 timeit("full fused", full, base)
 timeit("pages only", pages, base)
 timeit("root only (word-major)", root_only, flat0)
-timeit("root only (page-major)", root_pagemajor, flat0)

@@ -6,10 +6,9 @@ import sys
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      os.path.join(os.path.dirname(__file__), "..",
-                                   ".jax_cache"))
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+from volsync_tpu.compile_cache import configure as _configure_cache  # noqa: E402
+
+_configure_cache()
 
 import jax
 import jax.numpy as jnp

@@ -197,7 +197,7 @@ def test_multihost_init_single_process():
 def test_multihost_require_fails_hard(monkeypatch):
     """VOLSYNC_DISTRIBUTED=1 is an explicit operator request: a failed
     jax.distributed auto-init must abort, not silently run single-host
-    while pod peers block at the coordinator barrier (ADVICE r3)."""
+    while pod peers block at the coordinator barrier."""
     import jax
 
     from volsync_tpu.parallel import multihost

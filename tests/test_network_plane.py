@@ -1,7 +1,7 @@
 """The network data plane: mover-jax gRPC service, cross-process rsync,
 and the asymmetric key split.
 
-Covers VERDICT r2 item 5's done-conditions: an rsync e2e across TWO OS
+Covers the done-conditions of a review record since deleted: an rsync e2e across TWO OS
 processes via a real network address, and a gRPC client getting
 (boundaries, digests) for a streamed buffer, identical to local chunking.
 """

@@ -282,7 +282,7 @@ def test_lock_stale_holder_is_removed():
 def test_snapshot_written_after_packs_are_durable(tmp_path, rng):
     """Crash-safety invariant: by the time a snapshot object appears in
     the store, every pack/index object it references must already be
-    there (ADVICE r1: flush-before-save_snapshot ordering)."""
+    there (flush-before-save_snapshot ordering)."""
     store = MemObjectStore()
     orig_put = store.put
     seen_at_snapshot = {}

@@ -201,7 +201,7 @@ def main(argv=None) -> int:
     dispatch them before the boot so the linter runs in CI containers
     with no cluster state, the flight recorder is readable from a
     half-broken process, ``session status`` works on a host whose
-    accelerator tunnel is wedged, and repair/scrub can run against a
+    accelerator is held by a stuck process, and repair/scrub can run against a
     store whose operator stack is exactly what crashed."""
     argv = argv if argv is not None else sys.argv[1:]
     if argv and argv[0] in ("lint", "trace", "session", "repair",

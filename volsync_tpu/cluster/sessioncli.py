@@ -1,7 +1,6 @@
 """``volsync session`` — supervised accelerator session verbs.
 
-Replaces scripts/chip_recovery_playbook.sh and the probe/recovery half
-of scripts/tunnel_watch.sh with the cluster/sessions.py supervisor:
+The operator-facing verbs of the cluster/sessions.py supervisor:
 
 - ``volsync session run [opts] -- CMD...`` — run CMD as the next
   serialized verify-then-measure job: probe first, kill at the hard
@@ -34,7 +33,7 @@ from volsync_tpu.objstore.faultstore import FaultSchedule, parse_spec
 DEFAULT_STATUS = "/tmp/volsync_session_status.json"
 
 #: EX_TEMPFAIL — the backend is unhealthy / the result was refused;
-#: retry after recovery (tunnel_watch.sh keys off this)
+#: retry after recovery (watch loops key off this)
 EXIT_UNHEALTHY = 75
 
 

@@ -136,12 +136,6 @@ def root_unroll() -> int:
     return env_int("VOLSYNC_ROOT_UNROLL", 4, minimum=1)
 
 
-def no_pallas() -> bool:
-    """VOLSYNC_NO_PALLAS=1 forces the XLA scan everywhere — the
-    operational kill-switch for toolchains without Mosaic support."""
-    return env_bool("VOLSYNC_NO_PALLAS")
-
-
 def donate_device_inputs() -> Optional[bool]:
     """VOLSYNC_DONATE tri-state: None when unset — callers fall back to
     the backend-aware default (donate staged segment buffers into the

@@ -206,6 +206,11 @@ def main(argv=None) -> int:
         format="%(asctime)s %(name)s %(levelname)s %(message)s")
     args = build_parser().parse_args(argv)
     cfg = resolve_config(args)
+    # before the first device program: a cold mover otherwise pays
+    # every compile again
+    from volsync_tpu.compile_cache import configure as configure_cache
+
+    log.info("jax compile cache: %s", configure_cache())
     if cfg["distributed"]:
         from volsync_tpu.parallel.multihost import init_distributed
 

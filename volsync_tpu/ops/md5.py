@@ -229,11 +229,9 @@ def md5_contiguous_blocks_device(data: jax.Array, *,
     from volsync_tpu.ops.sha256 import use_pallas_leaves
 
     if not use_pallas_leaves():
-        # Shares sha256's predicate (CPU backend OR the
-        # VOLSYNC_NO_PALLAS kill-switch): the operational escape hatch
-        # for a broken Mosaic toolchain must cover the MD5 delta path
-        # too, not just the leaf hashers.
-        xt = jnp.transpose(w, (1, 0))  # XLA transpose is fine here
+        # Shares sha256's predicate: off the TPU the XLA transpose
+        # stands in for the Mosaic kernel.
+        xt = jnp.transpose(w, (1, 0))
         Bp = B
     else:
         from volsync_tpu.ops.segment import _pallas_transpose

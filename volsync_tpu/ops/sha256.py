@@ -424,13 +424,7 @@ def _sha256_rows_pallas(wb: jax.Array, rows0: jax.Array) -> jax.Array:
 
 def use_pallas_leaves() -> bool:
     """The Pallas path runs on real TPU backends; tests/dry-runs on CPU
-    use the XLA scan (identical digests, golden-tested on both).
-    VOLSYNC_NO_PALLAS=1 forces the XLA scan everywhere (operational
-    kill-switch for toolchains without Mosaic support)."""
-    from volsync_tpu import envflags
-
-    if envflags.no_pallas():
-        return False
+    use the XLA scan (identical digests, golden-tested on both)."""
     return jax.default_backend() == "tpu"
 
 

@@ -26,26 +26,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.6 jax: experimental namespace, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-
-
-def _axis_size(name) -> int:
-    """Static size of a mapped axis inside a shard_map body. Pre-0.6 jax
-    has no lax.axis_size; psum of a Python int is folded statically."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
 
 from volsync_tpu.ops.gearcdc import DEFAULT_PARAMS, GearParams, _mix_u32
 from volsync_tpu.ops.sha256 import sha256_blocks
@@ -138,7 +120,7 @@ def make_chunk_hash_step(mesh, *, block_len: int = 64 * 1024,
     bloom_size = 1 << bloom_log2
 
     def local_step(data):  # data: [Wl, Sl] — this shard's slice
-        n_seq = _axis_size(SEQ_AXIS)
+        n_seq = jax.lax.axis_size(SEQ_AXIS)
         seq_i = jax.lax.axis_index(SEQ_AXIS)
 
         # Sequence-parallel halo: my left context is the previous shard's

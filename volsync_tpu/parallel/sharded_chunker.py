@@ -297,7 +297,7 @@ def _build_fused_fn(mesh, params: GearParams, shard_len: int,
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from volsync_tpu.parallel.engine import _axis_size, shard_map
+    from jax import shard_map
 
     from volsync_tpu.ops.gearcdc import gear_at_aligned
     from volsync_tpu.ops.segment import (
@@ -328,11 +328,9 @@ def _build_fused_fn(mesh, params: GearParams, shard_len: int,
         valid_len = valid_len.astype(jnp.int32)
 
         # --- per-shard page digests (no halo: pages don't cross seams)
-        # Always word-major here: the cross-shard word_index below
-        # assumes the per-shard kernel layout regardless of the
-        # single-chip VOLSYNC_PAGEMAJOR gate.
-        flat_local = _page_digests_flat(row, npps,
-                                        pagemajor=False)  # [8 * npps]
+        # Word-major per shard: the cross-shard word_index below
+        # assumes the per-shard kernel layout.
+        flat_local = _page_digests_flat(row, npps)  # [8 * npps]
         flat_g = jax.lax.all_gather(flat_local, SEQ, axis=0)  # [S, 8*npps]
         flat_g = flat_g.reshape(S * 8 * npps)
 
@@ -415,7 +413,7 @@ def _build_cand_fn(mesh, params: GearParams, shard_len: int, cap: int):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from volsync_tpu.parallel.engine import _axis_size, shard_map
+    from jax import shard_map
 
     from volsync_tpu.parallel.engine import _gear_doubling
 
@@ -424,7 +422,7 @@ def _build_cand_fn(mesh, params: GearParams, shard_len: int, cap: int):
     mask_l = np.uint32(params.mask_l)
 
     def local(data, valid_len):  # data: [1, Ls] this shard's slice
-        n = _axis_size(SEQ)
+        n = jax.lax.axis_size(SEQ)
         i = jax.lax.axis_index(SEQ)
         row = data[0]
         # Left halo: previous shard's 31-byte tail, shifted right around
@@ -465,7 +463,7 @@ def _build_cand_aligned_fn(mesh, params: GearParams, shard_len: int,
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from volsync_tpu.parallel.engine import _axis_size, shard_map
+    from jax import shard_map
 
     from volsync_tpu.ops.gearcdc import gear_at_aligned
 
@@ -501,14 +499,14 @@ def _build_leaf_fn(mesh, shard_len: int, cap: int):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from volsync_tpu.parallel.engine import _axis_size, shard_map
+    from jax import shard_map
 
     from volsync_tpu.ops.sha256 import sha256_chunks_device
 
     assert shard_len >= _LEAF, "shards must cover at least one leaf"
 
     def local(data, starts, lengths):  # [1, Ls], [1, cap], [1, cap]
-        n = _axis_size(SEQ)
+        n = jax.lax.axis_size(SEQ)
         row = data[0]
         # Right halo: my leaves may run up to LEAF-1 bytes past my slice;
         # fetch the next shard's head (ring: the last shard's wrap-around

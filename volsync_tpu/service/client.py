@@ -35,7 +35,10 @@ from volsync_tpu.service.server import (
 )
 from volsync_tpu.service.tenants import TENANT_METADATA_KEY
 
-_SEND_CHUNK = 4 * 1024 * 1024
+#: Request frame payload. gRPC refuses a message over 4 MiB (its
+#: default receive cap) and the frame adds a few bytes of protobuf
+#: header, so a full 4 MiB payload can never be sent: stay well under.
+_SEND_CHUNK = 2 * 1024 * 1024
 
 
 class ShedError(ThrottleError):

@@ -271,6 +271,10 @@ class MoverJaxServer:
     # -- lifecycle -----------------------------------------------------------
 
     def start(self) -> "MoverJaxServer":
+        # before the first request compiles a device program
+        from volsync_tpu.compile_cache import configure as configure_cache
+
+        configure_cache()
         self._server.start()
         log.info("mover-jax serving on %s:%d", self.host, self.port)
         return self
@@ -451,7 +455,10 @@ class MoverJaxServer:
         def collect(handle) -> pb.ChunkBatch:
             nonlocal base, head, plen
             fut, eof = handle
-            out, _ = fut.result(timeout=600)
+            # no wall-clock bound: a first-use compile of a new
+            # (S, P) bucket takes minutes (see SegmentMicroBatcher.wait)
+            out, _ = (self._batcher.wait(fut) if self._batcher is not None
+                      else fut.result())
             batch = pb.ChunkBatch(final=eof)
             consumed = 0
             for start, length, digest in out:
