@@ -66,11 +66,15 @@ from volsync_tpu.envflags import (
 )
 
 # Persistent compilation cache: later processes reuse compiled
-# executables instead of paying the first compile again. Placed before
-# jax is imported anywhere in this process; children inherit it.
-_configure_cache()
+# executables instead of paying the first compile again. main() places
+# it (configure() imports jax, so the modes' JAX_PLATFORMS go first);
+# children inherit it.
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
+
+#: modes that run on the host and pin the CPU backend
+_HOST_MODES = ("pipeline", "restore", "copies-smoke", "ec", "syncplan",
+               "index")
 
 # Wall-clock budgets (seconds). Consistency invariant: probe worst case
 # (sum(PROBE_TIMEOUTS)+backoffs, ~330s) + the device measurement
@@ -1634,12 +1638,14 @@ def _run_measurement_child(extra_env: dict, timeout_s: int) -> Optional[dict]:
 
 
 def main():
-    if len(sys.argv) > 1 and sys.argv[1] == "pipeline":
-        # Standalone stage-breakdown mode; host-side only, so pin the
-        # backend to CPU before anything imports jax. ``--faults SEED``
-        # arms the deterministic fault-injection wrapper so the number
-        # is goodput under a seeded fault schedule.
+    if len(sys.argv) > 1 and sys.argv[1] in _HOST_MODES:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    _configure_cache()
+    if len(sys.argv) > 1 and sys.argv[1] == "pipeline":
+        # Standalone stage-breakdown mode; host-side only (_HOST_MODES
+        # pins the backend to the CPU). ``--faults SEED`` arms the
+        # deterministic fault-injection wrapper so the number is
+        # goodput under a seeded fault schedule.
         fault_seed = None
         if "--faults" in sys.argv[2:]:
             i = sys.argv.index("--faults")
@@ -1654,7 +1660,6 @@ def main():
     if len(sys.argv) > 1 and sys.argv[1] == "restore":
         # Restore data plane: serial vs pipelined vs storm; host-side
         # (the verify kernel runs on the CPU backend).
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         smoke = "--smoke" in sys.argv[2:]
         storm = 4
         if "--storm" in sys.argv[2:]:
@@ -1671,7 +1676,6 @@ def main():
     if len(sys.argv) > 1 and sys.argv[1] == "copies-smoke":
         # Zero-copy contract gate: both data planes at smoke scale,
         # site sanction + copy_ratio threshold asserted; host-side.
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         res = copies_smoke()
         _emit(res)
         return 0 if res["ok"] else 1
@@ -1679,17 +1683,14 @@ def main():
         # Erasure-coding data plane: device vs NumPy GF(2^8) kernels,
         # reconstruct-vs-mirror latency, measured storage overhead;
         # host-side (the RS matmul runs on the CPU backend).
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         _emit(ec_bench(smoke="--smoke" in sys.argv[2:]))
         return 0
     if len(sys.argv) > 1 and sys.argv[1] == "syncplan":
         # Protocol-planner replay: host + CPU device kernels only.
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         _emit(syncplan_bench(smoke="--smoke" in sys.argv[2:]))
         return 0
     if len(sys.argv) > 1 and sys.argv[1] == "index":
         # Metadata-plane microbench; host-side only (numpy, no device).
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         kw: dict = {}
         argv = sys.argv[2:]
         spec = {"--entries": "entries", "--queries": "queries",

@@ -5,7 +5,11 @@ flight recorder + trigger auto-dumps (shed / breaker-open / injected
 fault / deadline), the closed-loop service acceptance (client ->
 admission -> scheduler -> device batch spans nest under one trace with
 tenant + stream id tags and the stage breakdown covering the measured
-p50), the `volsync trace` CLI, and the tracing-disabled overhead gate.
+p50), the `volsync trace` CLI, and the tracing-disabled overhead gate; self
+time and counters, the dispatch thread's spans in the ring under the
+submitter's trace, the ring's compact events and its eviction count,
+the names the segment program carries onto the device, and the
+program-load totals.
 """
 
 import glob
@@ -23,6 +27,8 @@ from volsync_tpu.obs import (
     begin_span,
     carry_context,
     chrome_trace,
+    count,
+    counter_totals,
     dump_trace,
     format_trace_header,
     new_trace,
@@ -31,6 +37,7 @@ from volsync_tpu.obs import (
     reset_spans,
     reset_trace,
     span,
+    span_self_totals,
     span_totals,
     stage_seconds_by_tenant,
     trace_context,
@@ -366,9 +373,15 @@ def test_trace_cli_dump_and_summary(tmp_path):
                for e in doc["traceEvents"])
     assert str(out_file) in lines[0]
 
+    count("ops.lanes", 3)
     lines.clear()
     assert cli_run(["trace", "summary"], {}, out=lines.append) == 0
     assert any("engine.read" in ln and "ok" in ln for ln in lines)
+    assert lines[0].split()[-1] == "self"
+    (row,) = [ln for ln in lines if ln.startswith("engine.read")]
+    assert float(row.split()[-1]) == pytest.approx(
+        span_self_totals()["engine.read"][1], abs=1e-4)
+    assert any(ln.split() == ["ops.lanes", "counter", "3"] for ln in lines)
 
     # dump to stdout when --out is omitted
     lines.clear()
@@ -390,6 +403,297 @@ def test_chrome_trace_shape_and_dump_trace(tmp_path):
     assert json.loads(Path(path).read_text())["traceEvents"]
     # no path + no dump dir -> None, no file side effects
     assert dump_trace() is None
+
+
+# -- self time and counters -----------------------------------------------
+
+def _sleep_span(name, seconds, inner=()):
+    with span(name):
+        time.sleep(seconds)
+        for child in inner:
+            child()
+
+
+@pytest.mark.parametrize("shape", ["nested", "siblings", "handle",
+                                   "other_thread"])
+def test_self_time(shape):
+    """A span()'s self seconds are its duration less the span()s that
+    closed inside it on the same thread; a begin_span() handle and a
+    span on another thread are nobody's child."""
+    if shape == "nested":
+        _sleep_span("svc.stream", 0.02, [
+            lambda: _sleep_span("svc.batch", 0.02, [
+                lambda: _sleep_span("repo.seal", 0.03)])])
+        children = {"svc.stream": ["svc.batch"], "svc.batch": ["repo.seal"],
+                    "repo.seal": []}
+    elif shape == "siblings":
+        _sleep_span("svc.stream", 0.02, [
+            lambda: _sleep_span("svc.batch", 0.02),
+            lambda: _sleep_span("repo.seal", 0.03)])
+        children = {"svc.stream": ["svc.batch", "repo.seal"],
+                    "svc.batch": [], "repo.seal": []}
+    elif shape == "handle":
+        def wait():
+            h = begin_span("svc.queue_wait", ctx=None)
+            time.sleep(0.03)
+            h.finish()
+
+        _sleep_span("svc.stream", 0.02, [wait])
+        children = {"svc.stream": [], "svc.queue_wait": []}
+    else:
+        def elsewhere():
+            with ThreadPoolExecutor(1) as pool:
+                pool.submit(_sleep_span, "repo.seal", 0.03).result()
+
+        _sleep_span("svc.stream", 0.02, [elsewhere])
+        children = {"svc.stream": [], "repo.seal": []}
+    totals, own = span_totals(), span_self_totals()
+    assert set(own) == set(totals) == set(children)
+    for name, kids in children.items():
+        assert own[name][0] == totals[name][0] == 1
+        want = totals[name][1] - sum(totals[k][1] for k in kids)
+        assert own[name][1] == pytest.approx(want, abs=1e-9)
+        assert own[name][1] >= 0.019
+
+
+def test_self_time_of_a_failing_child_still_leaves_the_parent():
+    with pytest.raises(ValueError):
+        with span("svc.stream"):
+            with span("svc.batch"):
+                time.sleep(0.02)
+                raise ValueError("boom")
+    totals, own = span_totals(), span_self_totals()
+    assert own["svc.stream"][1] == pytest.approx(
+        totals["svc.stream"][1] - totals["svc.batch"][1], abs=1e-9)
+    # the stack is empty again: the next span is nobody's child
+    with span("repo.seal"):
+        pass
+    assert span_self_totals()["repo.seal"][1] == \
+        span_totals()["repo.seal"][1]
+
+
+def test_counters_and_their_reset():
+    assert counter_totals() == {}
+    count("ops.dispatches")
+    count("ops.lanes", 3)
+    count("ops.lanes", 2)
+    assert counter_totals() == {"ops.dispatches": 1, "ops.lanes": 5}
+    with span("engine.read"):
+        pass
+    reset_spans()  # the benchmark's one call at window start zeroes both
+    assert counter_totals() == {} and span_self_totals() == {}
+
+
+# -- the dispatch thread, on the submitter's trace ------------------------
+
+_DISPATCH_SPANS = ("ops.queue_wait", "ops.batch_dispatch", "ops.stage",
+                   "ops.launch", "ops.fetch", "ops.decode")
+
+
+@pytest.mark.parametrize("sampled", [True, False])
+def test_batched_dispatch_enters_the_ring_under_the_submitters_trace(
+        sampled):
+    """A sampled submit puts the wait for the batcher, the dispatch and
+    its four stages in the ring with the submitter's trace id — on the
+    dispatch thread, which holds no context of its own; an unsampled
+    one puts nothing there, and the registry and the counters record
+    both."""
+    from volsync_tpu.ops.batcher import SegmentMicroBatcher
+    from volsync_tpu.ops.gearcdc import GearParams
+
+    params = GearParams(min_size=4096, avg_size=32768, max_size=65536,
+                        seed=0x5EED_CDC1, align=4096)
+    data = os.urandom(30_000)
+    mb = SegmentMicroBatcher(params, max_batch=2, window_ms=1.0,
+                             pipeline_depth=1)
+    try:
+        with trace_context(sampled=sampled) as root:
+            with span("engine.device"):
+                chunks, consumed = mb.submit(data, len(data), True)
+    finally:
+        mb.stop()
+    assert consumed == len(data) and chunks
+    totals = span_totals()
+    for name in _DISPATCH_SPANS:
+        # ops.stage twice: the rows are filled, and later released
+        assert totals[name][0] == (2 if name == "ops.stage" else 1), name
+    stages = sum(totals[n][1] for n in _DISPATCH_SPANS[2:])
+    assert stages <= totals["ops.batch_dispatch"][1]
+    assert span_self_totals()["ops.batch_dispatch"][1] == pytest.approx(
+        totals["ops.batch_dispatch"][1] - stages, abs=1e-9)
+    assert counter_totals() == {
+        "ops.dispatches": 1, "ops.lanes": 1, "ops.lanes_padded": 1,
+        "ops.bytes_valid": len(data), "ops.bytes_padded": 65536}
+
+    spans = {e["name"]: e for e in trace_events() if e["ph"] == "X"}
+    if not sampled:
+        assert spans == {}
+        return
+    assert set(_DISPATCH_SPANS) <= set(spans)
+    waiter = spans["engine.device"]
+    for name in _DISPATCH_SPANS:
+        assert spans[name]["args"]["trace_id"] == root.trace_id, name
+    for name in ("ops.queue_wait", "ops.batch_dispatch"):
+        assert spans[name]["args"]["parent_span_id"] == \
+            waiter["args"]["span_id"]
+    for name in _DISPATCH_SPANS[2:]:
+        assert spans[name]["args"]["parent_span_id"] == \
+            spans["ops.batch_dispatch"]["args"]["span_id"]
+        assert spans[name]["args"]["bucket"] == 65536
+        assert spans[name]["tid"] == spans["ops.batch_dispatch"]["tid"]
+    assert spans["ops.batch_dispatch"]["tid"] != waiter["tid"]
+    assert spans["ops.batch_dispatch"]["args"]["lanes"] == 1
+
+
+def test_single_lane_dispatch_is_counted_like_a_batched_one():
+    """The way to the device without the batcher: ops.stage around the
+    pad and the upload, the five counters from the same function."""
+    import numpy as np
+
+    from volsync_tpu.engine.chunker import DeviceChunkHasher, _buffer_bucket
+    from volsync_tpu.ops.gearcdc import GearParams
+
+    params = GearParams(min_size=4096, avg_size=32768, max_size=65536,
+                        seed=0x5EED_CDC1, align=4096)
+    data = np.frombuffer(os.urandom(30_000), np.uint8)
+    assert DeviceChunkHasher(params).process(data, eof=True)
+    totals = span_totals()
+    for name in ("ops.stage", "engine.fused_dispatch", "engine.fused_fetch"):
+        assert totals[name][0] == 1, name
+    assert counter_totals() == {
+        "ops.dispatches": 1, "ops.lanes": 1, "ops.lanes_padded": 1,
+        "ops.bytes_valid": 30_000,
+        "ops.bytes_padded": _buffer_bucket(30_000)}
+
+
+# -- the ring: compact events, rendered on export, evictions counted ------
+
+def test_ring_events_render_to_chrome_keys_and_evictions_are_counted(
+        monkeypatch):
+    from volsync_tpu.obs.copyledger import record_copy
+
+    monkeypatch.setenv("VOLSYNC_TRACE_RING", "16")
+    reset_trace()
+    with trace_context(tenant="t9", stream_id="s9") as root:
+        with span("repo.seal", blob=7):
+            record_copy("repo.seal", 10)
+    record_trigger("shed", tenant="t9")
+    by_ph = {}
+    for e in trace_events():
+        by_ph.setdefault((e["ph"], e["cat"]), e)
+    x = by_ph[("X", "span")]
+    assert set(x) == {"name", "cat", "ph", "ts", "dur", "pid", "tid", "args"}
+    assert x["args"] == {
+        "trace_id": root.trace_id, "span_id": x["args"]["span_id"],
+        "parent_span_id": root.span_id, "outcome": "ok", "tenant": "t9",
+        "stream_id": "s9", "blob": 7}
+    i = by_ph[("i", "copy")]
+    assert set(i) == {"name", "cat", "ph", "s", "ts", "pid", "tid", "args"}
+    assert i["s"] == "t" and i["args"]["trace_id"] == root.trace_id
+    assert x["ts"] <= i["ts"] <= x["ts"] + x["dur"]
+    g = by_ph[("i", "trigger")]
+    assert g["s"] == "g" and g["args"] == {"tenant": "t9"}
+    assert json.loads(json.dumps(chrome_trace()))["traceEvents"]
+    assert "obs.ring_dropped" not in counter_totals()
+
+    with trace_context(sampled=True):
+        for _ in range(20):
+            with span("engine.read"):
+                pass
+    assert len(trace_events()) == 16
+    assert counter_totals()["obs.ring_dropped"] == 3 + 20 - 16
+
+
+def test_default_ring_holds_a_traced_window():
+    from volsync_tpu import envflags
+
+    assert envflags.trace_ring_size() == 65536
+
+
+# -- names on the device --------------------------------------------------
+
+def test_segment_program_names_its_stages_and_kernels(monkeypatch):
+    """The six jax.named_scope stages are in the lowered program's
+    locations and the Pallas kernels carry their names (lowered for
+    the TPU, as tests/test_chip_compile.py steers it: the CPU arm has
+    no kernel)."""
+    import jax
+    import jax.numpy as jnp
+
+    from volsync_tpu.ops import segment
+    from volsync_tpu.ops.gearcdc import GearParams
+
+    p = GearParams(min_size=4096, avg_size=32768, max_size=65536,
+                   seed=0x5EED_CDC1, align=4096)
+    seg = 1 << 20
+    cand_cap, chunk_cap = segment.segment_caps(seg, p)
+    kw = dict(min_size=p.min_size, avg_size=p.avg_size, max_size=p.max_size,
+              seed=p.seed, mask_s=p.mask_s, mask_l=p.mask_l, align=p.align,
+              cand_cap=cand_cap, chunk_cap=chunk_cap)
+    monkeypatch.setattr(segment, "use_pallas_leaves", lambda: True)
+    scopes = ("gear_candidates", "compact", "boundary_walk", "page_sha",
+              "tail_sha", "merkle_roots")
+    batched = jax.jit(segment._chunk_hash_segments_impl,
+                      static_argnames=segment._SEGMENTS_STATIC).trace(
+        jax.ShapeDtypeStruct((2 * seg,), jnp.uint8),
+        jax.ShapeDtypeStruct((2,), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.bool_), **kw)
+    single = segment.chunk_hash_segment.trace(
+        jax.ShapeDtypeStruct((seg,), jnp.uint8), 1000, eof=True, **kw)
+    for traced in (batched, single):
+        text = traced.lower(lowering_platforms=("tpu",)).as_text(
+            debug_info=True)
+        for scope in scopes:
+            assert f"/{scope}/" in text, scope
+        for kernel in ("transpose_tiles", "sha256_pages"):
+            assert f'kernel_name = "{kernel}"' in text, kernel
+
+
+# -- program loads --------------------------------------------------------
+
+def test_load_totals_counts_a_compile():
+    import jax
+    import jax.numpy as jnp
+
+    from volsync_tpu import compile_cache
+
+    compile_cache._listen()
+    compile_cache._listen()  # registered once: one compile counts once
+    x = jnp.arange(8)
+    before = compile_cache.load_totals()
+    salt = time.perf_counter_ns() % 1000 + 2  # a program not yet loaded
+    inner = jax.jit(lambda v: jnp.sort(v * salt))  # traced inside outer
+    t0 = time.perf_counter()
+    jax.jit(lambda v: inner(v) + inner(v + 1))(x).block_until_ready()
+    wall = time.perf_counter() - t0
+    after = compile_cache.load_totals()
+    assert after["compiles"] + after["cache_hits"] == \
+        before["compiles"] + before["cache_hits"] + 1
+    for key in ("trace_s", "lower_s", "backend_s"):
+        assert after[key] > before[key], key
+    # the inner jit's trace lies inside the outer's and counts in both
+    # sums; load_s is the union: time that passed
+    seconds = sum(after[k] - before[k]
+                  for k in ("trace_s", "lower_s", "backend_s"))
+    assert 0 < after["load_s"] - before["load_s"] <= min(seconds, wall)
+    reset_spans()  # set-up is before the window: not zeroed with spans
+    assert compile_cache.load_totals() == after
+
+
+@pytest.mark.parametrize("spans, want", [
+    ([(0, 1), (2, 3)], [[0, 1], [2, 3]]),
+    ([(0, 3), (1, 2)], [[0, 3]]),                 # nested
+    ([(1, 2), (0, 3)], [[0, 3]]),                 # the outer fires last
+    ([(0, 2), (1, 3), (5, 6), (3, 5)], [[0, 6]]),  # two threads, a bridge
+    ([(4, 5), (0, 1), (2, 3), (0.5, 2.5)], [[0, 3], [4, 5]]),
+])
+def test_load_regions_are_merged_into_their_union(monkeypatch, spans, want):
+    from volsync_tpu import compile_cache
+
+    monkeypatch.setattr(compile_cache, "_covered", [])
+    for start, end in spans:
+        compile_cache._cover(float(start), float(end))
+    assert compile_cache._covered == want
 
 
 # -- disabled-path overhead gate ------------------------------------------

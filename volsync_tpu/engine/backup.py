@@ -10,6 +10,7 @@ mtime_ns, restic's heuristic) skips re-reading stable data.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import stat as stat_mod
@@ -22,6 +23,7 @@ from volsync_tpu.engine.chunker import (
     params_from_config,
     stream_chunk_batches,
 )
+from volsync_tpu.obs import span
 from volsync_tpu.repo import blobid
 from volsync_tpu.repo.repository import (
     BLOB_DATA,
@@ -144,47 +146,62 @@ class TreeBackup:
 
         Holds a shared repository lock so a concurrent prune (exclusive)
         can never sweep this backup's freshly written packs.
-        """
-        with self.repo.lock(exclusive=False):
-            # Re-read the index now that the lock is held: entries loaded
-            # before it could reference packs a prune swept in between,
-            # and dedup'ing against those would produce a snapshot whose
-            # blobs no longer exist (restic reloads after locking too).
-            self.repo.load_index()
-            return self._run_locked(root, hostname=hostname, tags=tags,
-                                    parent=parent)
 
-    def _run_locked(self, root, *, hostname, tags, parent):
-        root = Path(root)
-        stats = BackupStats()
+        The coordinating thread's spans come one after another and add
+        up to the operation: ``backup.prepare`` (lock, index, snapshots,
+        parent files), ``backup.walk``, ``backup.hash`` (the wall of the
+        per-file phase), ``backup.tree``, then the repository's
+        ``repo.flush`` and ``repo.save_snapshot``.
+        """
+        with contextlib.ExitStack() as held:
+            with span("backup.prepare"):
+                held.enter_context(self.repo.lock(exclusive=False))
+                # Re-read the index now that the lock is held: entries
+                # loaded before it could reference packs a prune swept
+                # in between, and dedup'ing against those would produce
+                # a snapshot whose blobs no longer exist (restic reloads
+                # after locking too).
+                self.repo.load_index()
+                parent, parent_files = self._parent_files(parent)
+            return self._run_locked(Path(root), hostname, tags, parent,
+                                    parent_files)
+
+    def _parent_files(self, parent: Optional[str]) -> tuple:
+        """(parent snapshot id, its files by path): the newest snapshot
+        where the caller names none."""
         snaps = self.repo.list_snapshots()
         if parent is None and snaps:
             parent = snaps[-1][0]
         parent_files = {}
-        parent_manifest = None
         if parent:
             parent_manifest = dict(snaps).get(parent)
             if parent_manifest:
                 parent_files = _load_parent_files(
                     self.repo, parent_manifest["tree"])
+        return parent, parent_files
+
+    def _run_locked(self, root: Path, hostname, tags, parent, parent_files):
+        stats = BackupStats()
         if self.skip_if_empty and not any(root.iterdir()):
             return None, stats
         # Single-threaded walk (stats + unchanged-file dedup decisions),
         # concurrent per-file hashing, deterministic tree assembly.
         jobs: list[tuple[Path, str, object]] = []
         inode_first: dict = {}  # (st_dev, st_ino) -> rel of first sight
-        skeleton = self._walk_dir(root, "", parent_files, stats, jobs,
-                                  inode_first)
+        with span("backup.walk"):
+            skeleton = self._walk_dir(root, "", parent_files, stats, jobs,
+                                      inode_first)
         contents: dict = {}
-        if jobs:
+        with span("backup.hash", files=len(jobs)):
             if self.workers > 1 and len(jobs) > 1:
                 from concurrent.futures import ThreadPoolExecutor
 
                 from volsync_tpu.obs import carry_context
 
-                # carry_context: worker-thread spans (plan.decide when
-                # protocol="auto", repo store spans) keep the caller's
-                # tenant/trace context instead of starting orphaned.
+                # carry_context: worker-thread spans (backup.file,
+                # plan.decide when protocol="auto", repo store spans)
+                # keep the caller's tenant/trace context instead of
+                # starting orphaned.
                 with ThreadPoolExecutor(self.workers) as pool:
                     for rel, resolved in pool.map(
                             carry_context(
@@ -195,7 +212,8 @@ class TreeBackup:
                 for j in jobs:
                     rel, resolved = self._hash_file(*j, stats)
                     contents[rel] = resolved
-        tree_id = self._assemble_tree(skeleton, contents, stats)
+        with span("backup.tree"):
+            tree_id = self._assemble_tree(skeleton, contents, stats)
         manifest = {
             "hostname": hostname,
             "paths": [str(root)],
@@ -382,8 +400,19 @@ class TreeBackup:
         actually hashed and mtime_ns a post-read lstat — the entry must
         describe the content that was stored, not the walk-time stat.
         Per-blob stats are updated by the repository under its lock;
-        everything else was counted in the walk."""
-        if st.st_size <= self.params.min_size or self._wants_full(st.st_size):
+        everything else was counted in the walk.
+
+        One root span a file, ``backup.file``: its self time is the
+        per-file host path that no inner span names (read, ``blob_id``,
+        the repository's bookkeeping, slicing)."""
+        on_host = (st.st_size <= self.params.min_size
+                   or self._wants_full(st.st_size))
+        with span("backup.file", path="host" if on_host else "device"):
+            return self._hash_file_body(path, rel, st, stats, on_host)
+
+    def _hash_file_body(self, path: Path, rel: str, st, stats: BackupStats,
+                        on_host: bool) -> tuple[str, tuple]:
+        if on_host:
             data = path.read_bytes()
             digest = blobid.blob_id(data)
             self.repo.add_blob(BLOB_DATA, digest, data, stats)

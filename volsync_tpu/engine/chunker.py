@@ -175,12 +175,16 @@ class DeviceChunkHasher:
                 return PendingSegment(chunks, None, None)
 
         padded = _buffer_bucket(length)
-        if have < padded:
-            record_copy("device.pad", length)
-            buffer = np.pad(buffer, (0, padded - have))
-        elif have > padded:
-            buffer = buffer[:padded]
-        return self.begin_device(jnp.asarray(buffer), length, eof=eof)
+        # the single-lane way to the device, timed and counted as the
+        # batched one is (ops/segment.py _hash_bucket, count_dispatch)
+        with span("ops.stage", lanes=1, bucket=padded):
+            if have < padded:
+                record_copy("device.pad", length)
+                buffer = np.pad(buffer, (0, padded - have))
+            elif have > padded:
+                buffer = buffer[:padded]
+            dev = jnp.asarray(buffer)
+        return self.begin_device(dev, length, eof=eof)
 
     def begin_device(self, dev, length: int, *,
                      eof: bool = True) -> "PendingSegment":

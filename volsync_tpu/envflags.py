@@ -412,12 +412,6 @@ def repack_packs_per_cycle() -> int:
 
 # -- observability (obs/tracing.py) --------------------------------------
 
-def trace_dir() -> Optional[str]:
-    """VOLSYNC_TRACE_DIR: where device_trace writes JAX profiler traces;
-    None (the default) disables tracing."""
-    return env_str("VOLSYNC_TRACE_DIR")
-
-
 def trace_sample() -> float:
     """VOLSYNC_TRACE_SAMPLE: fraction of new root traces whose spans are
     recorded into the flight recorder (1.0 = every trace, 0 = flight
@@ -427,8 +421,11 @@ def trace_sample() -> float:
 
 def trace_ring_size() -> int:
     """VOLSYNC_TRACE_RING: span events retained in the in-process
-    flight-recorder ring buffer (oldest evicted first)."""
-    return env_int("VOLSYNC_TRACE_RING", 4096, minimum=16)
+    flight-recorder ring buffer (oldest evicted first, and counted in
+    the ``obs.ring_dropped`` counter). The default holds a traced
+    benchmark window whole: one event a sealed blob, a ledgered copy, a
+    file and a dispatch stage is some tens of thousands in 30 s."""
+    return env_int("VOLSYNC_TRACE_RING", 65536, minimum=16)
 
 
 def trace_dump_dir() -> Optional[str]:

@@ -17,6 +17,7 @@ from datetime import datetime, timedelta
 
 from volsync_tpu.engine import TreeBackup, restore_snapshot
 from volsync_tpu.objstore import open_store
+from volsync_tpu.obs import span
 from volsync_tpu.repo.repository import (
     RepoError,
     RepoLockedError,
@@ -151,10 +152,9 @@ def _dispatch(ctx, env: dict, direction: str) -> int:
         if not any(data.iterdir()):
             log.info("source is empty, skipping backup (entry.sh:44-50)")
             return 0
-        repo = _open_or_init(env)
+        with span("repo.open"):
+            repo = _open_or_init(env)
         t0 = time.perf_counter()
-        from volsync_tpu.obs import device_trace, span
-
         from volsync_tpu.movers.base import normalize_protocol
 
         # SYNC_PROTOCOL=auto delegates per-file full-vs-cdc storage to
@@ -164,7 +164,7 @@ def _dispatch(ctx, env: dict, direction: str) -> int:
         proto = normalize_protocol(env.get("SYNC_PROTOCOL"), default="cdc")
         if proto == "delta":
             proto = "cdc"
-        with device_trace("restic-backup"), span("mover.restic.backup"):
+        with span("mover.restic.backup"):
             snap_id, stats = TreeBackup(
                 repo, hasher=_select_hasher(env, repo),
                 protocol=proto).run(
@@ -188,7 +188,8 @@ def _dispatch(ctx, env: dict, direction: str) -> int:
         return 0
 
     if direction == "prune":
-        repo = _open_or_init(env)
+        with span("repo.open"):
+            repo = _open_or_init(env)
         log.info("prune: %s", repo.prune())
         return 0
 

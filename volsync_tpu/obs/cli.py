@@ -6,8 +6,10 @@ Verbs:
   recorder as Chrome-trace-event JSON (load the file in Perfetto /
   chrome://tracing). Without ``--out`` the JSON prints to stdout.
 - ``volsync trace summary`` — the span registry as a table, split by
-  outcome, so a REPL/operator session can see where time went without
-  leaving the terminal.
+  outcome, with each stage's self seconds (its time less the spans that
+  closed inside it on the same thread) on its first row, and the
+  counters below, so a REPL/operator session can see where time went
+  without leaving the terminal.
 
 Like ``volsync lint``, the verb dispatches before the operator runtime
 boots: reading the recorder must work in a half-broken process (that is
@@ -35,7 +37,8 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv, out=print) -> int:
-    from volsync_tpu.obs import chrome_trace, dump_trace, span_totals
+    from volsync_tpu.obs import (chrome_trace, counter_totals, dump_trace,
+                                 span_self_totals, span_totals)
 
     args = build_parser().parse_args(list(argv))
     if args.verb == "dump":
@@ -49,7 +52,15 @@ def main(argv, out=print) -> int:
     if not totals:
         out("no spans recorded")
         return 0
-    out(f"{'stage':<32} {'outcome':<8} {'count':>8} {'seconds':>12}")
+    # self time is kept a stage, not an outcome: printed on the stage's
+    # first row
+    own = {stage: f"{secs:.4f}"
+           for stage, (_, secs) in span_self_totals().items()}
+    out(f"{'stage':<32} {'outcome':<8} {'count':>8} {'seconds':>12} "
+        f"{'self':>12}")
     for (stage, outcome), (count, secs) in sorted(totals.items()):
-        out(f"{stage:<32} {outcome:<8} {count:>8} {secs:>12.4f}")
+        out(f"{stage:<32} {outcome:<8} {count:>8} {secs:>12.4f} "
+            f"{own.pop(stage, ''):>12}")
+    for name, n in sorted(counter_totals().items()):
+        out(f"{name:<32} {'counter':<8} {n:>8}")
     return 0

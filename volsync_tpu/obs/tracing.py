@@ -9,7 +9,13 @@ Three layers:
   ride the same /metrics endpoint as the sync metrics. Spans are
   hierarchical when a :class:`TraceContext` is active: each span becomes
   the parent of spans opened inside it, and tenant-tagged contexts also
-  feed ``volsync_svc_stage_seconds{tenant,stage}``.
+  feed ``volsync_svc_stage_seconds{tenant,stage}``. Every ``span()``
+  also has a **self time**: its duration less the ``span()``s that
+  closed inside it on the same thread (:func:`span_self_totals`), so
+  what no inner span covers is a number and not a guess.
+- **Counters** — ``count("ops.lanes", n)`` beside the spans, for what
+  is a quantity and not a time (:func:`counter_totals`); zeroed with
+  the spans by :func:`reset_spans`.
 - **Flight recorder** — when the active context is sampled
   (``VOLSYNC_TRACE_SAMPLE``), finished spans land in a bounded
   in-process ring buffer exported as Chrome-trace-event JSON
@@ -18,10 +24,6 @@ Three layers:
   shed / breaker-open / injected-fault / deadline events in the ring
   and auto-dumps an annotated trace file when ``VOLSYNC_TRACE_DUMP``
   is set (throttled per reason).
-- **Device profiling** — ``device_trace()`` wraps a region with the JAX
-  profiler (TensorBoard/xprof format) when ``VOLSYNC_TRACE_DIR`` is set,
-  capturing XLA op timelines of the hot path on real hardware. Off by
-  default: profiling is opt-in and free when disabled.
 
 Context propagation: the current :class:`TraceContext` lives in a
 ``contextvars.ContextVar``. It does NOT cross thread boundaries by
@@ -59,21 +61,33 @@ _BUCKETS = (0.001, 0.005, 0.02, 0.05, 0.1, 0.25, 0.5, 1, 2, 5, 15, 60,
             float("inf"))
 
 _lock = lockcheck.make_lock("obs.spans")
-_totals: dict[str, list] = defaultdict(lambda: [0, 0.0])  # name -> [n, secs]
+# name -> [n, secs, self secs]
+_totals: dict[str, list] = defaultdict(lambda: [0, 0.0, 0.0])
 # (name, outcome) -> [n, secs]; outcome is "ok" or "error"
 _outcomes: dict[tuple, list] = defaultdict(lambda: [0, 0.0])
 _tenant_stage: dict[tuple, float] = defaultdict(float)  # (tenant, stage)->s
+_counters: dict[str, int] = defaultdict(int)
 _histogram: Optional[Histogram] = None
 
-# Flight-recorder state. Events are stored ready-made in Chrome trace
-# event format so export is a snapshot + json.dump. Timestamps are
-# microseconds since this module's perf_counter epoch.
+# The open span()s of each thread, innermost last: one [child seconds]
+# cell a frame. begin_span() handles never enter it (they may end on
+# another thread): they are waits, all self time and nobody's child.
+_open = threading.local()
+
+# Flight-recorder state. An event is a compact tuple
+# (cat, name, t, dur, tid, ctx, span_id, outcome, attrs) with t and dur
+# in perf_counter seconds (dur None for an instant); trace_events() and
+# chrome_trace() render the Chrome trace event dicts, with timestamps
+# in microseconds since this module's perf_counter epoch.
 _EPOCH = time.perf_counter()
 _PID = os.getpid()
 _ring: deque = deque(maxlen=envflags.trace_ring_size())
 _thread_names: dict[int, str] = {}
 _trigger_last: dict[str, float] = {}  # reason -> perf_counter of last dump
 _dump_seq = [0]
+#: counter of events the full ring pushed out (0: the ring holds all
+#: that was recorded since reset_spans())
+RING_DROPPED = "obs.ring_dropped"
 
 
 # -- trace context --------------------------------------------------------
@@ -91,7 +105,10 @@ class TraceContext:
     sampled: bool = True
 
     def child(self, span_id: str) -> "TraceContext":
-        return dataclasses.replace(self, span_id=span_id)
+        # built directly: every sampled span() makes one, and
+        # dataclasses.replace costs three times as much
+        return TraceContext(self.trace_id, span_id, self.tenant,
+                            self.stream_id, self.sampled)
 
     def evolve(self, **changes) -> "TraceContext":
         return dataclasses.replace(self, **changes)
@@ -102,8 +119,16 @@ _CTX: contextvars.ContextVar[Optional[TraceContext]] = \
 _CURRENT = object()  # sentinel: "use whatever context is active"
 
 
+# Span and trace ids are labels for correlation, not secrets: a private
+# generator seeded from the OS once. os.urandom() a span is a system
+# call that gives up the interpreter lock, and on a thread that feeds
+# the device in a process whose other threads all want that lock, each
+# one can cost a switch interval (5 ms) to get back.
+_ids = random.Random()
+
+
 def new_id() -> str:
-    return os.urandom(8).hex()
+    return f"{_ids.getrandbits(64):016x}"
 
 
 def _sample_decision() -> bool:
@@ -229,6 +254,20 @@ def _hist_child(stage: str, outcome: str):
     return child
 
 
+# the same for volsync_svc_stage_seconds{tenant,stage}: every span of a
+# served stream finishes under a tenant
+_tenant_children: dict = {}
+
+
+def _tenant_child(tenant: str, stage: str):
+    child = _tenant_children.get((tenant, stage))
+    if child is None:
+        child = _tenant_children[(tenant, stage)] = \
+            GLOBAL_METRICS.svc_stage_seconds.labels(tenant=tenant,
+                                                    stage=stage)
+    return child
+
+
 class _SpanHandle:
     """An open span. ``finish()`` is idempotent so error paths may
     finish eagerly and a ``finally`` can still call it."""
@@ -244,41 +283,45 @@ class _SpanHandle:
         self._done = False
         self.t0 = time.perf_counter()
 
-    def finish(self, outcome: str = "ok"):
+    def finish(self, outcome: str = "ok", child_seconds: float = 0.0):
+        """Record the span; returns its duration (None when already
+        finished). ``child_seconds`` is what span() saw close inside
+        it on this thread — a handle finished by hand has none."""
         if self._done:
-            return
+            return None
         self._done = True
         dt = time.perf_counter() - self.t0
         ctx = self.ctx
+        event = None
+        if ctx is not None and ctx.sampled:
+            event = ("span", self.name, self.t0, dt, threading.get_ident(),
+                     ctx, self.span_id, outcome, self.attrs)
         with _lock:
             acc = _totals[self.name]
             acc[0] += 1
             acc[1] += dt
+            acc[2] += dt - child_seconds
             oacc = _outcomes[(self.name, outcome)]
             oacc[0] += 1
             oacc[1] += dt
             if ctx is not None and ctx.tenant:
                 _tenant_stage[(ctx.tenant, self.name)] += dt
-            if ctx is not None and ctx.sampled:
-                tid = threading.get_ident()
-                if tid not in _thread_names:
-                    _thread_names[tid] = threading.current_thread().name
-                args = {"trace_id": ctx.trace_id, "span_id": self.span_id,
-                        "parent_span_id": ctx.span_id, "outcome": outcome}
-                if ctx.tenant:
-                    args["tenant"] = ctx.tenant
-                if ctx.stream_id:
-                    args["stream_id"] = ctx.stream_id
-                if self.attrs:
-                    args.update(self.attrs)
-                _ring.append({
-                    "name": self.name, "cat": "span", "ph": "X",
-                    "ts": (self.t0 - _EPOCH) * 1e6, "dur": dt * 1e6,
-                    "pid": _PID, "tid": tid, "args": args})
+            if event is not None:
+                _ring_append(event)
         _hist_child(self.name, outcome).observe(dt)
         if ctx is not None and ctx.tenant:
-            GLOBAL_METRICS.svc_stage_seconds.labels(
-                tenant=ctx.tenant, stage=self.name).inc(dt)
+            _tenant_child(ctx.tenant, self.name).inc(dt)
+        return dt
+
+
+def _ring_append(event: tuple) -> None:
+    """Caller holds ``_lock``."""
+    tid = event[4]
+    if tid not in _thread_names:
+        _thread_names[tid] = threading.current_thread().name
+    if len(_ring) == _ring.maxlen:
+        _counters[RING_DROPPED] += 1
+    _ring.append(event)
 
 
 def begin_span(name: str, ctx=_CURRENT, **attrs) -> _SpanHandle:
@@ -297,23 +340,38 @@ def begin_span(name: str, ctx=_CURRENT, **attrs) -> _SpanHandle:
 def span(name: str, **attrs):
     """Time a named stage; feeds the span registry + the histogram,
     and — when a sampled TraceContext is active — the flight recorder,
-    with spans opened inside nesting under this one."""
+    with spans opened inside nesting under this one. Its self time is
+    its duration less the span()s that closed inside it on this
+    thread."""
     h = begin_span(name, **attrs)
     token = None
     if h.ctx is not None and h.ctx.sampled:
         token = _CTX.set(h.ctx.child(h.span_id))
     try:
+        stack = _open.stack
+    except AttributeError:
+        stack = _open.stack = []
+    frame = [0.0]
+    stack.append(frame)
+    outcome = "ok"
+    try:
         yield h
     except BaseException:
-        if token is not None:
-            _CTX.reset(token)
-            token = None
-        h.finish("error")
+        outcome = "error"
         raise
-    else:
+    finally:
+        stack.pop()
         if token is not None:
             _CTX.reset(token)
-        h.finish("ok")
+        dt = h.finish(outcome, frame[0])
+        if stack and dt is not None:
+            stack[-1][0] += dt
+
+
+def count(name: str, n: int = 1) -> None:
+    """Add ``n`` to a named counter (literal dotted names, as spans)."""
+    with _lock:
+        _counters[name] += n
 
 
 def span_totals(by_outcome: bool = False) -> dict:
@@ -326,6 +384,20 @@ def span_totals(by_outcome: bool = False) -> dict:
         return {k: (v[0], v[1]) for k, v in _totals.items()}
 
 
+def span_self_totals() -> dict:
+    """``{stage: (count, self seconds)}``: each span's duration less
+    the span()s that closed inside it on the same thread. A handle
+    from begin_span() is a wait: all of it is self time."""
+    with _lock:
+        return {k: (v[0], v[2]) for k, v in _totals.items()}
+
+
+def counter_totals() -> dict:
+    """``{name: count}`` since the last reset_spans()."""
+    with _lock:
+        return dict(_counters)
+
+
 def stage_seconds_by_tenant() -> dict:
     """``{(tenant, stage): seconds}`` for spans finished under a
     tenant-tagged context — the in-process mirror of
@@ -335,14 +407,17 @@ def stage_seconds_by_tenant() -> dict:
 
 
 def reset_spans():
-    """Zero the span registry AND the Prometheus children it populated
-    (volsync_stage_duration_seconds / volsync_svc_stage_seconds) so
-    stage timings cannot bleed across tests/bench rounds."""
+    """Zero the span registry, the counters AND the Prometheus children
+    the spans populated (volsync_stage_duration_seconds /
+    volsync_svc_stage_seconds) so stage timings cannot bleed across
+    tests/bench rounds."""
     with _lock:
         _totals.clear()
         _outcomes.clear()
         _tenant_stage.clear()
+        _counters.clear()
         _hist_children.clear()
+        _tenant_children.clear()
         hist = _histogram
     if hist is not None:
         hist.clear()
@@ -362,22 +437,41 @@ def trace_instant(name: str, **args) -> None:
     ctx = _CTX.get()
     if ctx is None or not ctx.sampled:
         return
-    tid = threading.get_ident()
+    event = ("copy", name, time.perf_counter(), None, threading.get_ident(),
+             ctx, None, None, args)
     with _lock:
-        if tid not in _thread_names:
-            _thread_names[tid] = threading.current_thread().name
-        _ring.append({
-            "name": name, "cat": "copy", "ph": "i", "s": "t",
-            "ts": (time.perf_counter() - _EPOCH) * 1e6,
-            "pid": _PID, "tid": tid,
-            "args": {**args, "trace_id": ctx.trace_id,
-                     "parent_span_id": ctx.span_id}})
+        _ring_append(event)
+
+
+def _chrome_event(event: tuple) -> dict:
+    """One ring tuple as a Chrome trace event."""
+    cat, name, t, dur, tid, ctx, span_id, outcome, attrs = event
+    out = {"name": name, "cat": cat, "ts": (t - _EPOCH) * 1e6,
+           "pid": _PID, "tid": tid}
+    if cat == "span":
+        args = {"trace_id": ctx.trace_id, "span_id": span_id,
+                "parent_span_id": ctx.span_id, "outcome": outcome}
+        if ctx.tenant:
+            args["tenant"] = ctx.tenant
+        if ctx.stream_id:
+            args["stream_id"] = ctx.stream_id
+        if attrs:
+            args.update(attrs)
+        out.update(ph="X", dur=dur * 1e6, args=args)
+    elif cat == "copy":
+        out.update(ph="i", s="t",
+                   args={**attrs, "trace_id": ctx.trace_id,
+                         "parent_span_id": ctx.span_id})
+    else:  # trigger
+        out.update(ph="i", s="g", args=dict(attrs))
+    return out
 
 
 def trace_events() -> list:
     """Snapshot of the ring buffer (Chrome trace events, oldest first)."""
     with _lock:
-        return list(_ring)
+        events = list(_ring)
+    return [_chrome_event(e) for e in events]
 
 
 def chrome_trace(trigger: Optional[str] = None,
@@ -391,7 +485,8 @@ def chrome_trace(trigger: Optional[str] = None,
     meta = [{"name": "thread_name", "ph": "M", "pid": _PID, "tid": tid,
              "args": {"name": tname}}
             for tid, tname in sorted(threads.items())]
-    doc = {"traceEvents": meta + events, "displayTimeUnit": "ms"}
+    doc = {"traceEvents": meta + [_chrome_event(e) for e in events],
+           "displayTimeUnit": "ms"}
     if trigger is not None:
         # "reason" is the trigger's own key; annotations cannot shadow it
         doc["trigger"] = {**(annotations or {}), "reason": trigger}
@@ -429,12 +524,9 @@ def record_trigger(reason: str, /, **annotations) -> Optional[str]:
     failure modes from observability."""
     now = time.perf_counter()
     with _lock:
-        tid = threading.get_ident()
-        if tid not in _thread_names:
-            _thread_names[tid] = threading.current_thread().name
-        _ring.append({"name": "trigger." + reason, "cat": "trigger",
-                      "ph": "i", "s": "g", "ts": (now - _EPOCH) * 1e6,
-                      "pid": _PID, "tid": tid, "args": dict(annotations)})
+        _ring_append(("trigger", "trigger." + reason, now, None,
+                      threading.get_ident(), None, None, None,
+                      dict(annotations)))
     if envflags.trace_dump_dir() is None:
         return None
     interval = envflags.trace_trigger_interval()
@@ -459,24 +551,3 @@ def reset_trace():
         _ring = deque(maxlen=envflags.trace_ring_size())
         _thread_names.clear()
         _trigger_last.clear()
-
-
-# -- device profiling -----------------------------------------------------
-
-@contextlib.contextmanager
-def device_trace(label: str = "volsync"):
-    """JAX profiler trace of the wrapped region when VOLSYNC_TRACE_DIR is
-    set (TensorBoard 'profile' plugin / xprof reads the output); no-op
-    otherwise."""
-    trace_dir = envflags.trace_dir()
-    if not trace_dir:
-        yield
-        return
-    import jax
-
-    out = os.path.join(trace_dir, label)
-    jax.profiler.start_trace(out)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()

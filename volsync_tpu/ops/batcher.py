@@ -18,7 +18,7 @@ from concurrent.futures import wait as futures_wait
 
 from volsync_tpu import envflags
 from volsync_tpu.analysis import lockcheck
-from volsync_tpu.obs import span
+from volsync_tpu.obs import begin_span, current_context, span, use_context
 from volsync_tpu.ops.gearcdc import GearParams
 
 
@@ -26,6 +26,48 @@ class BatcherStopped(RuntimeError):
     """submit() after stop(), or work stranded by shutdown. Typed so
     the service layer can map it to a clean UNAVAILABLE instead of
     pattern-matching a RuntimeError message."""
+
+
+#: other lanes' trace ids kept on an ``ops.batch_dispatch`` ring event
+_LANE_TRACES_KEPT = 8
+
+
+def _dispatch_context(batch):
+    """(context, attrs) for one batch's ``ops.batch_dispatch``: the
+    first SAMPLED lane's context, so that the dispatch and its stages
+    enter the flight recorder beside a request that waited for them
+    (and are booked to that lane's tenant, where it has one), with the
+    other sampled lanes' trace ids (bounded) as an attribute.
+    (None, {}) when no lane is sampled — the span is then context-free
+    and costs what it did."""
+    sampled = [it.ctx for it in batch
+               if it.ctx is not None and it.ctx.sampled]
+    if not sampled:
+        return None, {}
+    ctx = sampled[0]
+    others = sorted({c.trace_id for c in sampled[1:]} - {ctx.trace_id})
+    attrs = ({"lane_traces": others[:_LANE_TRACES_KEPT]} if others else {})
+    return ctx, attrs
+
+
+class _Item:
+    """One submitted segment: its lane, the caller's future, the
+    caller's trace context and the open ``ops.queue_wait`` span (submit
+    to the dispatch thread taking the batch: collector window, slot
+    wait, hand-over)."""
+
+    __slots__ = ("data", "length", "eof", "future", "ctx", "qspan")
+
+    def __init__(self, data, length, eof):
+        self.data, self.length, self.eof = data, length, eof
+        self.future: Future = Future()
+        self.ctx = current_context()
+        self.qspan = begin_span("ops.queue_wait", ctx=self.ctx)
+
+    def fail(self, exc: BaseException) -> None:
+        self.qspan.finish("error")
+        if not self.future.done():
+            self.future.set_exception(exc)
 
 
 class SegmentMicroBatcher:
@@ -99,9 +141,9 @@ class SegmentMicroBatcher:
         trip."""
         if self._stop.is_set():
             raise BatcherStopped("microbatcher stopped")
-        f: Future = Future()
-        self._q.put((data, length, eof, f))
-        return f
+        item = _Item(data, length, eof)
+        self._q.put(item)
+        return item.future
 
     def _run(self):
         import time as time_mod
@@ -144,9 +186,8 @@ class SegmentMicroBatcher:
                     break
             if not acquired:
                 exc = BatcherStopped("microbatcher stopped")
-                for _, _, _, f in batch:
-                    if not f.done():
-                        f.set_exception(exc)
+                for item in batch:
+                    item.fail(exc)
                 return
             self._dq.put(batch)
 
@@ -154,19 +195,23 @@ class SegmentMicroBatcher:
         while True:
             batch = self._dq.get()
             try:
+                for item in batch:
+                    item.qspan.finish("ok")
                 # One span per coalesced device dispatch. A batch mixes
-                # segments from many streams/traces, so this span is
-                # context-free; per-stream attribution happens in the
-                # scheduler's svc.batch span around each future.
-                with span("ops.batch_dispatch", lanes=len(batch)):
+                # segments from many streams/traces: the span runs under
+                # the first sampled lane's context (_dispatch_context);
+                # per-stream attribution happens in the scheduler's
+                # svc.batch span around each future.
+                ctx, attrs = _dispatch_context(batch)
+                with use_context(ctx), \
+                        span("ops.batch_dispatch", lanes=len(batch), **attrs):
                     results = self._hasher.hash_segments(
-                        [(d, n, e) for d, n, e, _ in batch])
-                for (_, _, _, f), r in zip(batch, results):
-                    f.set_result(r)
+                        [(it.data, it.length, it.eof) for it in batch])
+                for item, r in zip(batch, results):
+                    item.future.set_result(r)
             except Exception as exc:  # noqa: BLE001 — per-caller delivery
-                for _, _, _, f in batch:
-                    if not f.done():
-                        f.set_exception(exc)
+                for item in batch:
+                    item.fail(exc)
             finally:
                 self._inflight.release()
 
@@ -194,11 +239,10 @@ class SegmentMicroBatcher:
         # leftovers still queued.
         while True:
             try:
-                _, _, _, f = self._q.get_nowait()
+                item = self._q.get_nowait()
             except queue.Empty:
                 break
-            if not f.done():
-                f.set_exception(BatcherStopped("microbatcher stopped"))
+            item.fail(BatcherStopped("microbatcher stopped"))
 
 
 _SHARED: dict = {}

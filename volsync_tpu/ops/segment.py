@@ -48,7 +48,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from volsync_tpu.obs import record_copy
+from volsync_tpu.obs import count as obs_count
+from volsync_tpu.obs import record_copy, span
 from volsync_tpu.ops.gearcdc import GearParams, gear_at_aligned
 from volsync_tpu.ops.sha256 import (
     _H0,
@@ -86,6 +87,20 @@ def segment_caps(padded_len: int, params: GearParams) -> tuple[int, int]:
     chunk_cap = _pow2ceil(padded_len // params.min_size + 2, 16)
     cand_cap = max(4096, _pow2ceil(4 * padded_len // params.avg_size, 4096))
     return cand_cap, chunk_cap
+
+
+def count_dispatch(lanes: int, lanes_padded: int, bytes_valid: int,
+                   bytes_padded: int) -> None:
+    """One run of a segment program, counted the same way on both ways
+    to the device (the batched program and the single-lane one):
+    ``ops.lanes`` / ``ops.dispatches`` is the occupancy of a run,
+    ``ops.bytes_valid`` / ``ops.bytes_padded`` the useful share of the
+    bytes handed to the device."""
+    obs_count("ops.dispatches")
+    obs_count("ops.lanes", lanes)
+    obs_count("ops.lanes_padded", lanes_padded)
+    obs_count("ops.bytes_valid", bytes_valid)
+    obs_count("ops.bytes_padded", bytes_padded)
 
 
 def _compact_candidates(mask: jax.Array, cand_cap: int, R: int,
@@ -244,6 +259,7 @@ def _pallas_transpose(x: jax.Array) -> jax.Array:
         out_specs=pl.BlockSpec((256, 256), lambda i, j: (j, i),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((C, R), jnp.uint32),
+        name="transpose_tiles",
     )(x)
 
 
@@ -300,6 +316,7 @@ def _page_digests_flat(data: jax.Array, n_pages_pad: int) -> jax.Array:
         out_shape=jax.ShapeDtypeStruct((8, n_pages_pad // 128, 128),
                                        jnp.uint32),
         scratch_shapes=[pltpu.VMEM((8, _LANE_SUB, 128), jnp.uint32)],
+        name="sha256_pages",
     )(x)
     return out.reshape(-1)  # [8 * n_pages_pad], word-major
 
@@ -421,25 +438,33 @@ def chunk_hash_segment(data: jax.Array, valid_len, *, min_size: int,
     n_pages_pad = _n_pages_pad(F)
     valid_len = jnp.asarray(valid_len, jnp.int32)
 
+    # Each stage under a jax.named_scope (metadata only: the program
+    # computes the same) so a device trace names the stage an op
+    # belongs to; the same six names as the batched program.
     # --- candidates (aligned gear evaluation, as cdc_candidates_aligned)
-    h = gear_at_aligned(data, seed, align)
-    pos_all = (jnp.arange(R, dtype=jnp.int32) * align + (align - 1))
-    ok = pos_all < valid_len
-    is_s = ((h & np.uint32(mask_s)) == 0) & ok
-    is_l = ((h & np.uint32(mask_l)) == 0) & ok
-    pos_s = _compact_candidates(is_s, cand_cap, R, align)
-    pos_l = _compact_candidates(is_l, cand_cap, R, align)
-    ns = jnp.sum(is_s).astype(jnp.int32)
-    nl = jnp.sum(is_l).astype(jnp.int32)
+    with jax.named_scope("gear_candidates"):
+        h = gear_at_aligned(data, seed, align)
+        pos_all = (jnp.arange(R, dtype=jnp.int32) * align + (align - 1))
+        ok = pos_all < valid_len
+        is_s = ((h & np.uint32(mask_s)) == 0) & ok
+        is_l = ((h & np.uint32(mask_l)) == 0) & ok
+    with jax.named_scope("compact"):
+        pos_s = _compact_candidates(is_s, cand_cap, R, align)
+        pos_l = _compact_candidates(is_l, cand_cap, R, align)
+        ns = jnp.sum(is_s).astype(jnp.int32)
+        nl = jnp.sum(is_l).astype(jnp.int32)
 
     # --- FastCDC boundary walk (on device)
-    starts, lens, count, consumed = _select_boundaries_device(
-        pos_s, jnp.minimum(ns, cand_cap), pos_l, jnp.minimum(nl, cand_cap),
-        valid_len, min_size=min_size, avg_size=avg_size, max_size=max_size,
-        chunk_cap=chunk_cap, eof=eof, align=align, n_rows=R)
+    with jax.named_scope("boundary_walk"):
+        starts, lens, count, consumed = _select_boundaries_device(
+            pos_s, jnp.minimum(ns, cand_cap), pos_l,
+            jnp.minimum(nl, cand_cap), valid_len, min_size=min_size,
+            avg_size=avg_size, max_size=max_size, chunk_cap=chunk_cap,
+            eof=eof, align=align, n_rows=R)
 
     # --- page digests (all full leaves are pages; no gather)
-    flat = _page_digests_flat(data, n_pages_pad)
+    with jax.named_scope("page_sha"):
+        flat = _page_digests_flat(data, n_pages_pad)
 
     # --- the ONE possibly-partial leaf: the final chunk's tail page.
     # Interior cuts land on the page grid (align == LEAF_SIZE and
@@ -452,16 +477,19 @@ def chunk_hash_segment(data: jax.Array, valid_len, *, min_size: int,
     has_tail = (count > 0) & (end % LEAF_SIZE != 0)
     tail_page = jnp.maximum(end - 1, 0) // LEAF_SIZE
     tail_len = end - tail_page * LEAF_SIZE
-    tail_dig = sha256_chunks_device(
-        data, (tail_page * LEAF_SIZE)[None],
-        jnp.where(has_tail, tail_len, 0)[None], max_len=LEAF_SIZE)
-    flat = _apply_tail_overrides(flat, n_pages_pad, tail_page[None],
-                                 tail_dig, has_tail[None])
+    with jax.named_scope("tail_sha"):
+        tail_dig = sha256_chunks_device(
+            data, (tail_page * LEAF_SIZE)[None],
+            jnp.where(has_tail, tail_len, 0)[None], max_len=LEAF_SIZE)
+        flat = _apply_tail_overrides(flat, n_pages_pad, tail_page[None],
+                                     tail_dig, has_tail[None])
 
     # --- roots
-    nleaves = jnp.where(live, (lens + (LEAF_SIZE - 1)) // LEAF_SIZE, 0)
-    page0 = starts // LEAF_SIZE
-    roots = _root_digests_loop(flat, n_pages_pad, page0, nleaves, lens, live)
+    with jax.named_scope("merkle_roots"):
+        nleaves = jnp.where(live, (lens + (LEAF_SIZE - 1)) // LEAF_SIZE, 0)
+        page0 = starts // LEAF_SIZE
+        roots = _root_digests_loop(flat, n_pages_pad, page0, nleaves, lens,
+                                   live)
 
     header = jnp.stack([count.astype(jnp.uint32),
                         consumed.astype(jnp.uint32),
@@ -520,21 +548,26 @@ def _chunk_hash_segments_impl(data: jax.Array, valid_len: jax.Array,
     npp = _n_pages_pad(S * F)
     eof = jnp.asarray(eof, jnp.bool_)
 
+    # Each stage under a jax.named_scope (metadata only: the program
+    # computes the same) so a device trace names the stage an op
+    # belongs to, whatever id the compiler gives the op.
     # --- candidates: gear is page-local, so the flat evaluation equals
     # the per-segment one; masks reshape back to [S, R].
-    h = gear_at_aligned(data, seed, align).reshape(S, R)
-    pos_all = jnp.arange(R, dtype=jnp.int32) * align + (align - 1)
-    ok = pos_all[None, :] < valid_len[:, None]
-    is_s = ((h & np.uint32(mask_s)) == 0) & ok
-    is_l = ((h & np.uint32(mask_l)) == 0) & ok
+    with jax.named_scope("gear_candidates"):
+        h = gear_at_aligned(data, seed, align).reshape(S, R)
+        pos_all = jnp.arange(R, dtype=jnp.int32) * align + (align - 1)
+        ok = pos_all[None, :] < valid_len[:, None]
+        is_s = ((h & np.uint32(mask_s)) == 0) & ok
+        is_l = ((h & np.uint32(mask_l)) == 0) & ok
 
     def compact(row):
         return _compact_candidates(row, cand_cap, R, align)
 
-    pos_s = jax.vmap(compact)(is_s)
-    pos_l = jax.vmap(compact)(is_l)
-    ns = jnp.sum(is_s, axis=1).astype(jnp.int32)
-    nl = jnp.sum(is_l, axis=1).astype(jnp.int32)
+    with jax.named_scope("compact"):
+        pos_s = jax.vmap(compact)(is_s)
+        pos_l = jax.vmap(compact)(is_l)
+        ns = jnp.sum(is_s, axis=1).astype(jnp.int32)
+        nl = jnp.sum(is_l, axis=1).astype(jnp.int32)
 
     # --- FastCDC walk per lane (vmapped masked while_loop)
     def walk(ps, n_s, plx, n_l, vl, e):
@@ -543,11 +576,13 @@ def _chunk_hash_segments_impl(data: jax.Array, valid_len: jax.Array,
             vl, min_size=min_size, avg_size=avg_size, max_size=max_size,
             chunk_cap=chunk_cap, eof=e, align=align, n_rows=R)
 
-    starts, lens, count, consumed = jax.vmap(walk)(pos_s, ns, pos_l, nl,
-                                                   valid_len, eof)
+    with jax.named_scope("boundary_walk"):
+        starts, lens, count, consumed = jax.vmap(walk)(
+            pos_s, ns, pos_l, nl, valid_len, eof)
 
     # --- page digests: ONE kernel batch over every page of every lane
-    digests = _page_digests_flat(data, npp)
+    with jax.named_scope("page_sha"):
+        digests = _page_digests_flat(data, npp)
 
     # --- per-lane tail override (each lane has at most one partial leaf)
     live = (jnp.arange(chunk_cap, dtype=jnp.int32)[None, :]
@@ -561,20 +596,22 @@ def _chunk_hash_segments_impl(data: jax.Array, valid_len: jax.Array,
     tail_page_local = jnp.maximum(end - 1, 0) // LEAF_SIZE
     tail_page = jnp.arange(S, dtype=jnp.int32) * F + tail_page_local
     tail_len = end - tail_page_local * LEAF_SIZE
-    tail_dig = sha256_chunks_device(
-        data, jnp.clip(tail_page * LEAF_SIZE, 0, S * P - 1),
-        jnp.where(has_tail, tail_len, 0), max_len=LEAF_SIZE)  # [S, 8]
-    digests = _apply_tail_overrides(digests, npp, tail_page, tail_dig[:S],
-                                    has_tail)
+    with jax.named_scope("tail_sha"):
+        tail_dig = sha256_chunks_device(
+            data, jnp.clip(tail_page * LEAF_SIZE, 0, S * P - 1),
+            jnp.where(has_tail, tail_len, 0), max_len=LEAF_SIZE)  # [S, 8]
+        digests = _apply_tail_overrides(digests, npp, tail_page,
+                                        tail_dig[:S], has_tail)
 
     # --- roots: one flat S*chunk_cap-lane loop over the shared digest
     # table (page0 offset per lane's segment)
-    nleaves = jnp.where(live, (lens + (LEAF_SIZE - 1)) // LEAF_SIZE, 0)
-    page0 = (starts // LEAF_SIZE
-             + (jnp.arange(S, dtype=jnp.int32) * F)[:, None])
-    roots = _root_digests_loop(
-        digests, npp, page0.reshape(-1), nleaves.reshape(-1),
-        lens.reshape(-1), live.reshape(-1))  # [S*chunk_cap, 8]
+    with jax.named_scope("merkle_roots"):
+        nleaves = jnp.where(live, (lens + (LEAF_SIZE - 1)) // LEAF_SIZE, 0)
+        page0 = (starts // LEAF_SIZE
+                 + (jnp.arange(S, dtype=jnp.int32) * F)[:, None])
+        roots = _root_digests_loop(
+            digests, npp, page0.reshape(-1), nleaves.reshape(-1),
+            lens.reshape(-1), live.reshape(-1))  # [S*chunk_cap, 8]
 
     header = jnp.stack([count.astype(jnp.uint32),
                         consumed.astype(jnp.uint32),
@@ -736,6 +773,7 @@ class FusedSegmentHasher:
         cand_cap = cand_cap or cc
         chunk_cap = chunk_cap or kc
         fn = self.segment_device_fn or chunk_hash_segment
+        count_dispatch(1, 1, length, P)
         return fn(dev, length, min_size=p.min_size, avg_size=p.avg_size,
                   max_size=p.max_size, seed=p.seed, mask_s=p.mask_s,
                   mask_l=p.mask_l, align=p.align, eof=eof,
@@ -813,38 +851,56 @@ class BatchedSegmentHasher:
         p = self.params
         cand_cap, chunk_cap = segment_caps(P, p)
         S = _pow2ceil(len(items), 1)
-        rows = np.zeros((S, P), dtype=np.uint8)
-        lens = np.zeros((S,), dtype=np.int32)
-        eofs = np.zeros((S,), dtype=bool)
-        staged = 0
-        for i, (buf, n, eof) in enumerate(items):
-            arr = np.frombuffer(buf, dtype=np.uint8, count=len(buf))
-            rows[i, : arr.shape[0]] = arr
-            staged += arr.shape[0]
-            lens[i] = n
-            eofs[i] = eof
-        record_copy("device.stage", staged)
+        # The dispatch thread's four costs, one span each, with no
+        # synchronisation the path did not have: the program is
+        # launched asynchronously and ops.fetch is where it is waited
+        # for.
+        at = {"lanes": len(items), "bucket": P}
+        with span("ops.stage", part="fill", **at):
+            rows = np.zeros((S, P), dtype=np.uint8)
+            lens = np.zeros((S,), dtype=np.int32)
+            eofs = np.zeros((S,), dtype=bool)
+            staged = 0
+            for i, (buf, n, eof) in enumerate(items):
+                arr = np.frombuffer(buf, dtype=np.uint8, count=len(buf))
+                rows[i, : arr.shape[0]] = arr
+                staged += arr.shape[0]
+                lens[i] = n
+                eofs[i] = eof
+            record_copy("device.stage", staged)
+        count_dispatch(len(items), S, int(lens.sum()), S * P)
         fn = (chunk_hash_segments_donated if _use_donation()
               else chunk_hash_segments)
-        packed = np.asarray(fn(
-            jnp.asarray(rows.reshape(-1)), jnp.asarray(lens),
-            jnp.asarray(eofs),
-            min_size=p.min_size, avg_size=p.avg_size, max_size=p.max_size,
-            seed=p.seed, mask_s=p.mask_s, mask_l=p.mask_l, align=p.align,
-            cand_cap=cand_cap, chunk_cap=chunk_cap))
+        with span("ops.launch", **at):
+            handle = fn(
+                jnp.asarray(rows.reshape(-1)), jnp.asarray(lens),
+                jnp.asarray(eofs),
+                min_size=p.min_size, avg_size=p.avg_size,
+                max_size=p.max_size, seed=p.seed, mask_s=p.mask_s,
+                mask_l=p.mask_l, align=p.align,
+                cand_cap=cand_cap, chunk_cap=chunk_cap)
+        with span("ops.fetch", **at):
+            packed = np.asarray(handle)
         out = []
-        for i, (buf, n, eof) in enumerate(items):
-            chunks, consumed, grown = decode_with_overflow_check(
-                packed[i], int(lens[i]), cand_cap, chunk_cap)
-            if grown is not None:
-                # adversarial lane: retry alone with doubled capacities
-                dev = jnp.asarray(rows[i])  # lint: ignore[VL502] rare overflow retry: one adversarial lane re-dispatched alone
-                inflight = self._single.dispatch(
-                    dev, int(lens[i]), eof=bool(eofs[i]),
-                    cand_cap=grown[0], chunk_cap=grown[1])
-                chunks, consumed = self._single.finish(
-                    dev, int(lens[i]), inflight, eof=bool(eofs[i]))
-            out.append((chunks, consumed))
+        with span("ops.decode", **at):
+            for i, (buf, n, eof) in enumerate(items):
+                chunks, consumed, grown = decode_with_overflow_check(
+                    packed[i], int(lens[i]), cand_cap, chunk_cap)
+                if grown is not None:
+                    # adversarial lane: retry alone, doubled capacities
+                    with span("ops.overflow_retry", bucket=P):
+                        dev = jnp.asarray(rows[i])  # lint: ignore[VL502] rare overflow retry: one adversarial lane re-dispatched alone
+                        inflight = self._single.dispatch(
+                            dev, int(lens[i]), eof=bool(eofs[i]),
+                            cand_cap=grown[0], chunk_cap=grown[1])
+                        chunks, consumed = self._single.finish(
+                            dev, int(lens[i]), inflight, eof=bool(eofs[i]))
+                out.append((chunks, consumed))
+        # The rows go back to the allocator under the stage's name too:
+        # what staging costs is mapping S x P fresh bytes and unmapping
+        # them again, a dispatch later.
+        with span("ops.stage", part="release", **at):
+            del rows
         return out
 
 

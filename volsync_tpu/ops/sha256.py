@@ -418,6 +418,7 @@ def _sha256_rows_pallas(wb: jax.Array, rows0: jax.Array) -> jax.Array:
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((8, B // 128, 128), jnp.uint32),
         scratch_shapes=[pltpu.VMEM((8, _LANE_SUB, 128), jnp.uint32)],
+        name="sha256_leaves",
     )(x)
     return jnp.transpose(out, (1, 2, 0)).reshape(B, 8)
 

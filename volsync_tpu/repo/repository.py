@@ -1431,12 +1431,14 @@ class Repository:
         pipelined mode it joins every in-flight upload BEFORE the index
         delta referencing those packs is written, and re-raises the
         first upload failure (whose pack was never registered)."""
-        with self._lock:  # lint: ignore[VL101] — reviewed: flush IS
-            # the durability barrier; the index-delta put must happen
-            # under repo.state so no new blob lands between the join
-            # and the delta write. Pool workers never take this lock.
-            self._flush_data()
-            self._persist_pending()
+        with span("repo.flush"):
+            with self._lock:  # lint: ignore[VL101] — reviewed: flush IS
+                # the durability barrier; the index-delta put must
+                # happen under repo.state so no new blob lands between
+                # the join and the delta write. Pool workers never take
+                # this lock.
+                self._flush_data()
+                self._persist_pending()
 
     # -- read path ----------------------------------------------------------
 
@@ -1499,15 +1501,16 @@ class Repository:
 
     def save_snapshot(self, manifest: dict) -> str:
         manifest.setdefault("time", datetime.now(timezone.utc).isoformat())
-        payload = self.box.seal(json.dumps(manifest).encode())
-        snap_id = hashlib.sha256(payload).hexdigest()
-        self._guard_publish("snapshot publish")
-        self.store.put(f"snapshots/{snap_id}", payload)
-        try:
+        with span("repo.save_snapshot"):
+            payload = self.box.seal(json.dumps(manifest).encode())
+            snap_id = hashlib.sha256(payload).hexdigest()
             self._guard_publish("snapshot publish")
-        except StaleWriterError:
-            self.store.delete(f"snapshots/{snap_id}")  # fenced mid-put
-            raise
+            self.store.put(f"snapshots/{snap_id}", payload)
+            try:
+                self._guard_publish("snapshot publish")
+            except StaleWriterError:
+                self.store.delete(f"snapshots/{snap_id}")  # fenced mid-put
+                raise
         return snap_id
 
     def list_snapshots(self) -> list[tuple[str, dict]]:
