@@ -1103,7 +1103,7 @@ def restore_bench(total_mib: int = 24, get_latency_s: float = 0.04,
             "min_size": 128 * 1024, "avg_size": 256 * 1024,
             "max_size": 512 * 1024, "seed": 7, "align": 4096})
         repo.PACK_TARGET = 1024 * 1024
-        snap, _ = TreeBackup(repo, workers=1).run(src)
+        snap, _ = TreeBackup(repo).run(src)
         assert snap
         npacks = len(list(mem.list("data/")))
 

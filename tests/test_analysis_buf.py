@@ -316,7 +316,7 @@ def test_runtime_copies_subset_of_static(tmp_path):
         "min_size": 32 * 1024, "avg_size": 64 * 1024,
         "max_size": 128 * 1024, "seed": 7})
     repo.pipelined = True
-    TreeBackup(repo, workers=2).run(src)
+    TreeBackup(repo).run(src)
     dst = tmp_path / "dst"
     restore_snapshot(Repository.open(fs), dst)
     for i in range(4):

@@ -370,7 +370,7 @@ def test_runtime_faults_subset_of_static(tmp_path):
     repo = Repository.init(top, chunker={
         "min_size": 16 * 1024, "avg_size": 32 * 1024,
         "max_size": 64 * 1024, "seed": 11})
-    TreeBackup(repo, workers=2).run(src)
+    TreeBackup(repo).run(src)
     dst = tmp_path / "dst"
     restore_snapshot(Repository.open(top), dst)
     for i in range(3):

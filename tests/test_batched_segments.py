@@ -140,10 +140,11 @@ def test_batched_hasher_driver(rng):
 
 @pytest.mark.slow
 def test_treebackup_with_shared_batcher(tmp_path, monkeypatch):
-    """VOLSYNC_BATCH_SEGMENTS=1: TreeBackup's concurrent file workers
-    coalesce segments through the shared microbatcher and the snapshot
-    is bit-identical to the unbatched run."""
-    import os
+    """VOLSYNC_BATCH_SEGMENTS=1: two backups at once on two threads
+    (as the fleet's replicas run them) coalesce segments through the
+    shared microbatcher, and each snapshot is bit-identical to the
+    unbatched run."""
+    from concurrent.futures import ThreadPoolExecutor
 
     from volsync_tpu.engine import TreeBackup, restore_snapshot
     from volsync_tpu.objstore import MemObjectStore
@@ -161,7 +162,7 @@ def test_treebackup_with_shared_batcher(tmp_path, monkeypatch):
 
     # unbatched reference run
     repo_a = Repository.init(MemObjectStore(), chunker=chunker_cfg)
-    snap_a, stats_a = TreeBackup(repo_a, workers=4).run(src)
+    snap_a, stats_a = TreeBackup(repo_a).run(src)
 
     # batched run through a fresh shared batcher
     monkeypatch.setenv("VOLSYNC_BATCH_SEGMENTS", "1")
@@ -183,16 +184,19 @@ def test_treebackup_with_shared_batcher(tmp_path, monkeypatch):
     monkeypatch.setattr(batcher_mod.SegmentMicroBatcher, "__init__",
                         spy_init)
     repo_b = Repository.init(MemObjectStore(), chunker=chunker_cfg)
+    repo_c = Repository.init(MemObjectStore(), chunker=chunker_cfg)
     try:
-        snap_b, stats_b = TreeBackup(repo_b, workers=4).run(src)
+        with ThreadPoolExecutor(2) as pool:
+            (snap_b, stats_b), (snap_c, stats_c) = pool.map(
+                lambda repo: TreeBackup(repo).run(src), [repo_b, repo_c])
     finally:
         # don't leak the worker thread into the rest of the session
         for b in batcher_mod._SHARED.values():
             b.stop()
 
     # identical content: same blob universe, restore matches
-    assert repo_a.blob_ids() == repo_b.blob_ids()
-    assert stats_a.blobs_new == stats_b.blobs_new
+    assert repo_a.blob_ids() == repo_b.blob_ids() == repo_c.blob_ids()
+    assert stats_a.blobs_new == stats_b.blobs_new == stats_c.blobs_new
     dst = tmp_path / "dst"
     dst.mkdir()
     restore_snapshot(repo_b, dst)
@@ -276,7 +280,7 @@ def test_treebackup_batched_plus_device_verified_restore(tmp_path,
     monkeypatch.setattr(batcher_mod, "_SHARED", {})
     repo = Repository.init(MemObjectStore(), chunker=chunker_cfg)
     try:
-        snap, _ = TreeBackup(repo, workers=3).run(src)
+        snap, _ = TreeBackup(repo).run(src)
         dst = tmp_path / "dst"
         restore_snapshot(repo, dst)
     finally:

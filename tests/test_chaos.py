@@ -121,7 +121,7 @@ def test_chaos_backup_restore(tmp_path, name, seed, specs):
     # workers=1: serial chunking makes the pack keyspace identical
     # run-to-run, so each schedule's firing pattern is a fixed property
     # of its seed — a soak run is a replay, not a lottery.
-    snap, _stats = TreeBackup(repo, workers=1).run(src)
+    snap, _stats = TreeBackup(repo).run(src)
     assert snap
 
     # restore THROUGH the chaos stack too — reads retry the same way
@@ -185,7 +185,7 @@ def test_chaos_same_seed_same_fault_sequence(tmp_path):
     Repository.init(fs, chunker=CHUNKER)
     repo = Repository.open(top)
     repo.PACK_TARGET = 64 * 1024
-    TreeBackup(repo, workers=1).run(src)
+    TreeBackup(repo).run(src)
     restore_snapshot(Repository.open(top), tmp_path / "dst")
     assert faults.injected, "schedule never fired — replay proves nothing"
 
@@ -227,7 +227,7 @@ def test_chaos_concurrent_backups_share_one_repository(tmp_path):
 
     def worker(t):
         try:
-            snap, _ = TreeBackup(repo, workers=1).run(
+            snap, _ = TreeBackup(repo).run(
                 trees[t], hostname=f"host{t}")
             results[t] = snap
         except Exception as e:  # surfaced via the errors assert below
@@ -278,13 +278,13 @@ def test_chaos_crash_midupload_then_recover(tmp_path):
     # the pipelined uploader may wrap the crash in UploadError — match
     # on the injected-crash message rather than the concrete type
     with pytest.raises(Exception, match="injected crash|store is dead"):
-        TreeBackup(repo, workers=1).run(src)
+        TreeBackup(repo).run(src)
     assert faults.crashed
 
     fresh = Repository.open(fs)
     assert fresh.list_snapshots() == []
     assert fresh.check(read_data=True) == []
-    snap, _ = TreeBackup(fresh, workers=2).run(src)
+    snap, _ = TreeBackup(fresh).run(src)
     assert snap
     _assert_consistent_and_restorable(fs, src, tmp_path / "dst")
 
@@ -327,10 +327,10 @@ def _seed_garbage(fs, tmp_path):
         (pre / f"g{i}.bin").write_bytes(rng.bytes(150_000 + 11 * i))
     repo = Repository.open(fs)
     repo.PACK_TARGET = 64 * 1024
-    doomed, _ = TreeBackup(repo, workers=1).run(pre)
+    doomed, _ = TreeBackup(repo).run(pre)
     for i in range(2):  # rewrite HALF the files: packs go partially live
         (pre / f"g{i}.bin").write_bytes(rng.bytes(150_000 + 11 * i))
-    kept, _ = TreeBackup(repo, workers=1).run(pre)
+    kept, _ = TreeBackup(repo).run(pre)
     repo.delete_snapshot(doomed)
     return pre, kept
 
@@ -434,7 +434,7 @@ def test_chaos_multiwriter_prune(tmp_path, monkeypatch, name, seed, cfg):
             # room instead of the 0-second default
             repo.default_lock_wait = 10.0
             barrier.wait(timeout=60)
-            snap, _ = TreeBackup(repo, workers=1).run(
+            snap, _ = TreeBackup(repo).run(
                 trees[t], hostname=f"writer{t}")
             snaps[t] = snap
         except Exception as e:  # surfaced via the errors assert below

@@ -87,7 +87,7 @@ def test_backup_crash_then_retry_restores(tmp_path, src_tree):
     dying = DyingStore(fs, die_after_packs=0)
     repo_a = Repository.open(dying)
     with pytest.raises(Exception, match="simulated mover crash"):
-        TreeBackup(repo_a, workers=2).run(src_tree)
+        TreeBackup(repo_a).run(src_tree)
     assert dying.dead
 
     # A FRESH open (the restarted mover pod) sees a consistent repo:
@@ -98,7 +98,7 @@ def test_backup_crash_then_retry_restores(tmp_path, src_tree):
     assert repo_b.check(read_data=True) == []
 
     # The retried backup completes and restores bit-exactly.
-    snap, _stats = TreeBackup(repo_b, workers=2).run(src_tree)
+    snap, _stats = TreeBackup(repo_b).run(src_tree)
     dst = tmp_path / "dst"
     repo_c = Repository.open(fs)
     restore_snapshot(repo_c, dst)
@@ -137,7 +137,7 @@ def test_pipelined_crash_before_flush_no_dangling_index(tmp_path, src_tree):
     assert fresh.list_snapshots() == []
     assert fresh.check(read_data=True) == []
     # and a clean retry fully restores
-    snap, _ = TreeBackup(fresh, workers=2).run(src_tree)
+    snap, _ = TreeBackup(fresh).run(src_tree)
     dst = tmp_path / "dst"
     restore_snapshot(Repository.open(fs), dst)
     for f in sorted(p.name for p in src_tree.iterdir()):
@@ -175,13 +175,13 @@ def test_prune_sweeps_crash_orphans(tmp_path, src_tree):
 
     dying = DyingStore(fs, die_after_packs=0)
     with pytest.raises(Exception, match="simulated mover crash"):
-        TreeBackup(Repository.open(dying), workers=2).run(src_tree)
+        TreeBackup(Repository.open(dying)).run(src_tree)
 
     orphan_packs = set(fs.list("data/"))
     assert orphan_packs, "the crash left at least one orphan pack"
 
     repo = Repository.open(fs)
-    snap, _ = TreeBackup(repo, workers=2).run(src_tree)
+    snap, _ = TreeBackup(repo).run(src_tree)
     before = set(fs.list("data/"))
 
     repo2 = Repository.open(fs)
@@ -228,7 +228,7 @@ def test_injected_crash_at_op_n_recovers(tmp_path, src_tree, prefix, at):
     repo.PACK_TARGET = 64 * 1024  # several packs from the tree
     # the pipelined uploader may wrap the crash in UploadError
     with pytest.raises(Exception, match="injected crash|store is dead"):
-        TreeBackup(repo, workers=2).run(src_tree)
+        TreeBackup(repo).run(src_tree)
     assert faults.crashed
 
     # the restarted mover pod: fresh open over the healthy store
@@ -241,7 +241,7 @@ def test_injected_crash_at_op_n_recovers(tmp_path, src_tree, prefix, at):
     for p in packs:
         assert fs.exists(f"data/{p[:2]}/{p}"), p
 
-    snap, _ = TreeBackup(fresh, workers=2).run(src_tree)
+    snap, _ = TreeBackup(fresh).run(src_tree)
     assert snap
     dst = tmp_path / "dst"
     restore_snapshot(Repository.open(fs), dst)
@@ -292,12 +292,12 @@ def test_prune_crash_between_steps_keeps_snapshots_restorable(
 
     repo = Repository.open(fs)
     repo.PACK_TARGET = 64 * 1024
-    snap1, _ = TreeBackup(repo, workers=2).run(src_tree)
+    snap1, _ = TreeBackup(repo).run(src_tree)
     # rewrite one file wholesale: its old chunks become dead the moment
     # snap1 is forgotten, making several packs partially live
     rng = np.random.RandomState(11)
     (src_tree / "f2.bin").write_bytes(rng.bytes(280_000))
-    snap2, _ = TreeBackup(repo, workers=2).run(src_tree)
+    snap2, _ = TreeBackup(repo).run(src_tree)
     assert snap1 and snap2 and snap1 != snap2
     expect = {p.name: p.read_bytes() for p in src_tree.iterdir()}
     repo.delete_snapshot(snap1)
@@ -361,10 +361,10 @@ def test_two_phase_prune_crash_at_manifest_boundaries(
 
     repo = Repository.open(fs)
     repo.PACK_TARGET = 64 * 1024
-    snap1, _ = TreeBackup(repo, workers=2).run(src_tree)
+    snap1, _ = TreeBackup(repo).run(src_tree)
     rng = np.random.RandomState(11)
     (src_tree / "f2.bin").write_bytes(rng.bytes(280_000))
-    snap2, _ = TreeBackup(repo, workers=2).run(src_tree)
+    snap2, _ = TreeBackup(repo).run(src_tree)
     assert snap1 and snap2 and snap1 != snap2
     expect = {p.name: p.read_bytes() for p in src_tree.iterdir()}
     repo.delete_snapshot(snap1)

@@ -64,7 +64,7 @@ def _src_tree(tmp_path, *, seed=5, files=5):
 def _backup(store, src):
     repo = Repository.init(store, chunker=CHUNKER)
     repo.PACK_TARGET = 64 * 1024  # several packs from a small tree
-    snap, _ = TreeBackup(repo, workers=1).run(src)
+    snap, _ = TreeBackup(repo).run(src)
     assert snap
     return snap
 
@@ -258,7 +258,7 @@ def _fragmented_estate(tmp_path, *, root=None):
         (src / f"f{i}.bin").write_bytes(rng.bytes(110_000 + 13 * i))
     repo = Repository.open(store)
     repo.PACK_TARGET = 64 * 1024
-    TreeBackup(repo, workers=1).run(src)
+    TreeBackup(repo).run(src)
     Repository.open(store).forget(last=1)
     return store, src
 
@@ -411,7 +411,7 @@ def test_chaos_ec_storm(tmp_path, monkeypatch, name, seed, make_specs):
     def backup_more():
         repo = Repository.open(FsObjectStore(str(root)))
         repo.PACK_TARGET = 64 * 1024
-        TreeBackup(repo, workers=1).run(src2)
+        TreeBackup(repo).run(src2)
 
     svc = ScrubService(top, interval_seconds=0.02)
     gc = ContinuousGC(FsObjectStore(str(root)), interval_seconds=0.05)

@@ -31,7 +31,7 @@ def test_hardlinks_roundtrip(tmp_path, rng):
     (src / "solo.bin").write_bytes(rng.bytes(60_000))
 
     repo = _mkrepo()
-    snap, stats = TreeBackup(repo, workers=2).run(src)
+    snap, stats = TreeBackup(repo).run(src)
     # linked copies are not re-hashed (one content walk for the inode)
     assert stats.bytes_scanned == 150_000 + 60_000
 
@@ -64,10 +64,10 @@ def test_hardlink_first_path_removed_between_backups(tmp_path, rng):
     os.link(src / "a.bin", src / "b.bin")
 
     repo = _mkrepo()
-    TreeBackup(repo, workers=1).run(src)
+    TreeBackup(repo).run(src)
 
     os.unlink(src / "a.bin")  # b.bin survives, mtime untouched
-    snap2, _ = TreeBackup(repo, workers=1).run(src)
+    snap2, _ = TreeBackup(repo).run(src)
 
     dst = tmp_path / "dst"
     restore_snapshot(repo, dst)
@@ -89,7 +89,7 @@ def test_sparse_restore_materializes_holes(tmp_path, rng):
         f.write(tail)
 
     repo = _mkrepo()
-    TreeBackup(repo, workers=1).run(src)
+    TreeBackup(repo).run(src)
     dst = tmp_path / "dst"
     restore_snapshot(repo, dst)
 
@@ -112,7 +112,7 @@ def test_sparse_disabled_writes_dense(tmp_path, rng, monkeypatch):
     data = bytes(8 << 20)  # all zeros
     (src / "z.bin").write_bytes(data)
     repo = _mkrepo()
-    TreeBackup(repo, workers=1).run(src)
+    TreeBackup(repo).run(src)
 
     monkeypatch.setenv("VOLSYNC_SPARSE", "0")
     dst = tmp_path / "dense"
@@ -133,7 +133,7 @@ def test_diverged_hardlink_restore_over_linked_dest(tmp_path, rng):
     (src / "a.bin").write_bytes(payload)
     os.link(src / "a.bin", src / "b.bin")
     repo = _mkrepo()
-    TreeBackup(repo, workers=1).run(src)
+    TreeBackup(repo).run(src)
     dst = tmp_path / "dst"
     restore_snapshot(repo, dst)
     assert (dst / "a.bin").stat().st_ino == (dst / "b.bin").stat().st_ino
@@ -142,7 +142,7 @@ def test_diverged_hardlink_restore_over_linked_dest(tmp_path, rng):
     os.unlink(src / "b.bin")
     other = rng.bytes(90_000)
     (src / "b.bin").write_bytes(other)
-    TreeBackup(repo, workers=4).run(src)
+    TreeBackup(repo).run(src)
 
     restore_snapshot(repo, dst)
     assert (dst / "a.bin").read_bytes() == payload
@@ -165,7 +165,7 @@ def test_xattrs_roundtrip(tmp_path, rng):
     os.setxattr(d, "user.dtag", b"dir-attr")
 
     repo = _mkrepo()
-    TreeBackup(repo, workers=1).run(src)
+    TreeBackup(repo).run(src)
     dst = tmp_path / "dst"
     restore_snapshot(repo, dst)
 
@@ -202,7 +202,7 @@ def test_owner_and_specials_roundtrip(tmp_path, rng):
     s.close()
 
     repo = _mkrepo()
-    TreeBackup(repo, workers=1).run(src)
+    TreeBackup(repo).run(src)
     dst = tmp_path / "dst"
     restore_snapshot(repo, dst)
 
@@ -235,7 +235,7 @@ def test_special_replaced_by_file_between_snapshots(tmp_path, rng):
     src.mkdir()
     os.mkfifo(src / "x")
     repo = _mkrepo()
-    TreeBackup(repo, workers=1).run(src)
+    TreeBackup(repo).run(src)
     dst = tmp_path / "dst"
     restore_snapshot(repo, dst)
     assert stat_mod.S_ISFIFO((dst / "x").lstat().st_mode)
@@ -243,7 +243,7 @@ def test_special_replaced_by_file_between_snapshots(tmp_path, rng):
     os.unlink(src / "x")
     payload = rng.bytes(20_000)
     (src / "x").write_bytes(payload)
-    TreeBackup(repo, workers=1).run(src)
+    TreeBackup(repo).run(src)
     restore_snapshot(repo, dst)
     assert (dst / "x").read_bytes() == payload
 

@@ -357,9 +357,9 @@ def _garbage_repo(tmp_path):
         (src / f"f{i}.bin").write_bytes(rng.bytes(120_000 + i))
     repo = Repository.open(fs)
     repo.PACK_TARGET = 64 * 1024
-    doomed, _ = TreeBackup(repo, workers=1).run(src)
+    doomed, _ = TreeBackup(repo).run(src)
     (src / "f0.bin").write_bytes(rng.bytes(120_000))
-    kept, _ = TreeBackup(repo, workers=1).run(src)
+    kept, _ = TreeBackup(repo).run(src)
     repo.delete_snapshot(doomed)
     return fs, kept
 

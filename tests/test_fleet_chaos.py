@@ -91,10 +91,10 @@ def _seed_garbage(fs, tmp_path):
         (pre / f"g{i}.bin").write_bytes(rng.bytes(150_000 + 11 * i))
     repo = Repository.open(fs)
     repo.PACK_TARGET = 64 * 1024
-    doomed, _ = TreeBackup(repo, workers=1).run(pre)
+    doomed, _ = TreeBackup(repo).run(pre)
     for i in range(2):
         (pre / f"g{i}.bin").write_bytes(rng.bytes(150_000 + 11 * i))
-    kept, _ = TreeBackup(repo, workers=1).run(pre)
+    kept, _ = TreeBackup(repo).run(pre)
     repo.delete_snapshot(doomed)
     return pre, kept
 
@@ -243,7 +243,7 @@ def test_chaos_fleet(tmp_path, monkeypatch, name, seed, cfg):
             "takeover never fenced the dead replica's writer"
         # the zombie wakes up and tries to publish: refused, typed
         with pytest.raises(StaleWriterError):
-            TreeBackup(zombie, workers=1).run(trees[0],
+            TreeBackup(zombie).run(trees[0],
                                               hostname="zombie-late")
         assert (METRICS.repo_fenced_publishes_total._value.get()
                 > fenced_before)

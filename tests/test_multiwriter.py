@@ -286,7 +286,7 @@ def test_backup_started_mid_prune_completes(tmp_path):
     with pruner.lock(mode="prune"):
         writer = Repository.open(fs)
         writer.PACK_TARGET = 64 * 1024
-        snap, _ = TreeBackup(writer, workers=1).run(
+        snap, _ = TreeBackup(writer).run(
             _write_tree(tmp_path, "src", seed=3))
         assert snap
         rival = Repository.open(fs)
@@ -308,10 +308,10 @@ def test_backup_lands_while_victims_await_sweep(tmp_path):
     seed = Repository.open(fs)
     seed.PACK_TARGET = 64 * 1024
     src = _write_tree(tmp_path, "src", seed=5)
-    doomed, _ = TreeBackup(seed, workers=1).run(src)
+    doomed, _ = TreeBackup(seed).run(src)
     rng = np.random.RandomState(9)
     (src / "f0.bin").write_bytes(rng.bytes(60_000))
-    kept, _ = TreeBackup(seed, workers=1).run(src)
+    kept, _ = TreeBackup(seed).run(src)
     seed.delete_snapshot(doomed)
 
     marker = Repository.open(fs)
@@ -323,7 +323,7 @@ def test_backup_lands_while_victims_await_sweep(tmp_path):
     # from its dedup so nothing extends a victim's life
     writer = Repository.open(fs)
     writer.PACK_TARGET = 64 * 1024
-    snap2, _ = TreeBackup(writer, workers=1).run(
+    snap2, _ = TreeBackup(writer).run(
         _write_tree(tmp_path, "other", seed=6))
     assert snap2
     check = Repository.open(fs)
@@ -383,10 +383,10 @@ def test_pipelined_restore_tolerates_concurrent_prune(tmp_path):
     # NEXT TO live blobs, forcing the mark phase to rewrite + park the
     # mixed pack (a pure-garbage pack would park without any overlap)
     src = _write_tree(tmp_path, "src", seed=21, files=6, size=15_000)
-    doomed, _ = TreeBackup(seed, workers=1).run(src)
+    doomed, _ = TreeBackup(seed).run(src)
     (src / "f0.bin").unlink()  # first-packed file: shares its pack
     #                            with still-live neighbours
-    kept, _ = TreeBackup(seed, workers=1).run(src)
+    kept, _ = TreeBackup(seed).run(src)
     seed.delete_snapshot(doomed)  # f0's blobs are now garbage
 
     report = {}
@@ -427,7 +427,7 @@ def _damaged_repo(tmp_path):
     repo = Repository.open(fs)
     repo.PACK_TARGET = 64 * 1024
     src = _write_tree(tmp_path, "src", seed=11)
-    snap, _ = TreeBackup(repo, workers=1).run(src)
+    snap, _ = TreeBackup(repo).run(src)
     orphan = "ab" + os.urandom(31).hex()
     fs.put(f"data/{orphan[:2]}/{orphan}", os.urandom(512))
     old = (datetime.now(timezone.utc)
@@ -503,7 +503,7 @@ def test_repair_reports_reachable_loss_and_refuses_gc(tmp_path):
     Repository.init(fs, chunker=CHUNKER)
     repo = Repository.open(fs)
     repo.PACK_TARGET = 64 * 1024
-    TreeBackup(repo, workers=1).run(_write_tree(tmp_path, "src", seed=13))
+    TreeBackup(repo).run(_write_tree(tmp_path, "src", seed=13))
     pack = sorted(p for p in repo._index.live_packs() if p)[0]
     fs.delete(f"data/{pack[:2]}/{pack}")
 
@@ -562,7 +562,7 @@ def test_repair_concurrent_with_live_fenced_writers(tmp_path):
             repo.PACK_TARGET = 64 * 1024
             repo.default_lock_wait = 10.0
             barrier.wait(timeout=60)
-            snap, _ = TreeBackup(repo, workers=1).run(
+            snap, _ = TreeBackup(repo).run(
                 trees[t], hostname=f"live{t}")
             snaps[t] = snap
         except Exception as e:  # surfaced via the errors assert below

@@ -334,10 +334,10 @@ def test_lock_contenders_back_out_and_one_proceeds():
 
 
 @pytest.mark.slow
-def test_parallel_backup_bit_identical_and_consistent(tmp_path, rng):
-    """Worker-pool hashing must produce the identical snapshot id as the
-    serial path (tree assembly is order-independent), dedup concurrent
-    identical files exactly once, and keep stats consistent."""
+def test_backup_bit_identical_and_consistent(tmp_path, rng):
+    """Two backups of one volume into two repositories produce the
+    identical tree, store identical files exactly once, and keep stats
+    consistent."""
     import shutil
 
     from volsync_tpu.engine.backup import TreeBackup
@@ -354,10 +354,10 @@ def test_parallel_backup_bit_identical_and_consistent(tmp_path, rng):
     (src / "small.txt").write_bytes(b"tiny")
     (src / "empty").write_bytes(b"")
 
-    def snap(workers):
-        root = tmp_path / f"repo-w{workers}"
+    def snap(run):
+        root = tmp_path / f"repo-{run}"
         repo = Repository.init(FsObjectStore(root))
-        sid, stats = TreeBackup(repo, workers=workers).run(src)
+        sid, stats = TreeBackup(repo).run(src)
         assert repo.check() == []
         tree = dict(repo.list_snapshots())[sid]["tree"]
         return tree, stats, root
@@ -366,7 +366,8 @@ def test_parallel_backup_bit_identical_and_consistent(tmp_path, rng):
     tree1, stats1, _ = snap(1)
     tree4, stats4, root4 = snap(4)
     assert tree1 == tree4
-    # identical content stored once, regardless of worker interleaving
+    # identical content stored once
+    assert stats1.blobs_new == stats4.blobs_new
     assert stats4.blobs_new + stats4.blobs_dedup \
         == stats1.blobs_new + stats1.blobs_dedup
     assert stats4.bytes_scanned == stats1.bytes_scanned == 6 * 700_000 + 4

@@ -70,7 +70,7 @@ def _seed_repo(fs_root, src):
     fs = FsObjectStore(str(fs_root))
     repo = Repository.init(fs, chunker=CHUNKER)
     repo.PACK_TARGET = 64 * 1024  # several packs from a small tree
-    snap, _ = TreeBackup(repo, workers=1).run(src)
+    snap, _ = TreeBackup(repo).run(src)
     assert snap
     return len([k for k in fs.list("data/")])
 

@@ -71,7 +71,7 @@ def _corpus(tmp_path) -> Path:
 def _backup(store, src, pack_target=64 * 1024):
     repo = Repository.init(store, chunker=CHUNKER)
     repo.PACK_TARGET = pack_target
-    snap, _ = TreeBackup(repo, workers=1).run(src)
+    snap, _ = TreeBackup(repo).run(src)
     assert snap
     return snap
 

@@ -322,7 +322,7 @@ def test_parallel_backup_restore_through_s3(tmp_path, rng):
         for i in range(10):
             (src / f"f{i}.bin").write_bytes(rng.bytes(120_000))
         repo = Repository.init(store, password="s3cret")
-        sid, stats = TreeBackup(repo, workers=6).run(src)
+        sid, stats = TreeBackup(repo).run(src)
         assert stats.files == 10
         snaps = dict(repo.list_snapshots())
         dest = tmp_path / "out"

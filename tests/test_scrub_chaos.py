@@ -55,7 +55,7 @@ def _src_tree(tmp_path, *, seed=5, files=5):
 def _backup(store, src):
     repo = Repository.init(store, chunker=CHUNKER)
     repo.PACK_TARGET = 64 * 1024  # several packs from a small tree
-    snap, _ = TreeBackup(repo, workers=1).run(src)
+    snap, _ = TreeBackup(repo).run(src)
     assert snap
     return snap
 
@@ -365,7 +365,7 @@ def test_scrub_chaos_durable_rot_under_live_traffic(tmp_path,
     def backup_more():
         repo = Repository.open(FsObjectStore(str(root)))
         repo.PACK_TARGET = 64 * 1024
-        TreeBackup(repo, workers=1).run(src2)
+        TreeBackup(repo).run(src2)
 
     svc = ScrubService(fs, interval_seconds=0.02)
     gc = ContinuousGC(FsObjectStore(str(root)), interval_seconds=0.05)

@@ -87,8 +87,7 @@ def _load_parent_files(repo: Repository, parent_tree: str,
 
 class TreeBackup:
     def __init__(self, repo: Repository, *, skip_if_empty: bool = True,
-                 hasher=None, workers: Optional[int] = None,
-                 protocol: str = "cdc"):
+                 hasher=None, protocol: str = "cdc"):
         """``hasher`` swaps the chunk+hash engine: single-chip
         DeviceChunkHasher (default) or the mesh-sharded
         parallel.sharded_chunker.MeshChunkHasher — both produce
@@ -104,14 +103,14 @@ class TreeBackup:
         produce valid interchangeable snapshots; they differ only in
         blob granularity, i.e. dedup ratio vs scan cost.
 
-        ``workers`` hashes that many FILES concurrently (default 4, env
-        VOLSYNC_BACKUP_WORKERS). Files are independent streams, so their
-        per-segment result round-trips overlap while the device
-        serializes their kernels — the same concurrency the reference
-        gets from parallel mover pods (MaxConcurrentReconciles), here
-        inside one backup. Snapshot bits are identical for any worker
-        count: tree assembly is deterministic and the repository dedups
-        concurrent identical blobs under its lock.
+        Files are hashed ONE AT A TIME, in walk order, on the thread
+        that called ``run()``, and not by a pool of file workers: only
+        one thread runs Python at a time, and on the v5e's host the
+        hand-overs of the interpreter lock between four workers cost
+        more than their device round trips overlapped (half the rate
+        of one thread on both benchmark volumes; PERF.md sections 5 and
+        6, PR 27). What overlaps a file's round trips is what runs in C
+        behind it: the seal and upload pools, the batcher's threads.
         """
         self.repo = repo
         want = params_from_config(repo.chunker_params)
@@ -125,14 +124,6 @@ class TreeBackup:
                 f"hasher params {self.params} != repository chunker "
                 f"params {want}")
         self.skip_if_empty = skip_if_empty
-        if workers is None:
-            workers = envflags.backup_workers()
-        # A hasher that doesn't declare thread-safety (the mesh-sharded
-        # engine: collective enqueue order must match across devices)
-        # forces serial file hashing regardless of the knob.
-        if not getattr(self.hasher, "thread_safe", False):
-            workers = 1
-        self.workers = max(1, workers)
         if protocol not in ("cdc", "full", "auto"):
             raise ValueError(f"unknown backup protocol {protocol!r}")
         self.protocol = protocol
@@ -184,8 +175,8 @@ class TreeBackup:
         stats = BackupStats()
         if self.skip_if_empty and not any(root.iterdir()):
             return None, stats
-        # Single-threaded walk (stats + unchanged-file dedup decisions),
-        # concurrent per-file hashing, deterministic tree assembly.
+        # Walk (stats + unchanged-file dedup decisions), per-file
+        # hashing in walk order, deterministic tree assembly.
         jobs: list[tuple[Path, str, object]] = []
         inode_first: dict = {}  # (st_dev, st_ino) -> rel of first sight
         with span("backup.walk"):
@@ -193,25 +184,9 @@ class TreeBackup:
                                       inode_first)
         contents: dict = {}
         with span("backup.hash", files=len(jobs)):
-            if self.workers > 1 and len(jobs) > 1:
-                from concurrent.futures import ThreadPoolExecutor
-
-                from volsync_tpu.obs import carry_context
-
-                # carry_context: worker-thread spans (backup.file,
-                # plan.decide when protocol="auto", repo store spans)
-                # keep the caller's tenant/trace context instead of
-                # starting orphaned.
-                with ThreadPoolExecutor(self.workers) as pool:
-                    for rel, resolved in pool.map(
-                            carry_context(
-                                lambda j: self._hash_file(*j, stats)),
-                            jobs):
-                        contents[rel] = resolved
-            else:
-                for j in jobs:
-                    rel, resolved = self._hash_file(*j, stats)
-                    contents[rel] = resolved
+            for job in jobs:
+                rel, resolved = self._hash_file(*job, stats)
+                contents[rel] = resolved
         with span("backup.tree"):
             tree_id = self._assemble_tree(skeleton, contents, stats)
         manifest = {
@@ -235,12 +210,11 @@ class TreeBackup:
     def _walk_dir(self, dirpath: Path, rel: str, parent_files: dict,
                   stats: BackupStats, jobs: list,
                   inode_first: dict) -> dict:
-        """Single-threaded walk -> a skeleton tree. File entries that
-        need hashing carry content=None and append a job; unchanged
-        files resolve to the parent's content list immediately. All
-        stats counted here (except per-blob counts, which the
-        repository updates under its own lock) so worker threads never
-        touch the shared counters.
+        """Walk -> a skeleton tree. File entries that need hashing
+        carry content=None and append a job; unchanged files resolve to
+        the parent's content list immediately. All stats are counted
+        here, except per-blob counts, which the repository updates
+        under its own lock.
 
         Iterative (one child-iterator frame per open directory):
         pushing a frame and resuming the parent's iterator afterwards
@@ -352,8 +326,8 @@ class TreeBackup:
     def _assemble_tree(self, skeleton: dict, contents: dict,
                        stats: BackupStats) -> str:
         """Deterministic bottom-up tree-blob construction from the walk
-        skeleton + hashed file contents (independent of hashing order,
-        so snapshots are bit-identical for any worker count). Iterative
+        skeleton + hashed file contents (independent of hashing
+        order). Iterative
         post-order — children's tree blobs are written before the
         parent serializes references to them, at any depth."""
         done: dict = {}  # id(skeleton node) -> tree id
@@ -379,7 +353,7 @@ class TreeBackup:
                         content, size, mtime_ns = contents[rel]
                         # Metadata observed AT read time, not walk
                         # time: a file rewritten between the walk's
-                        # lstat and the worker's read must not pair new
+                        # lstat and the file's read must not pair new
                         # content with stale size/mtime (restore's
                         # unchanged-skip heuristic keys on them).
                         e["content"] = content
@@ -395,7 +369,7 @@ class TreeBackup:
 
     def _hash_file(self, path: Path, rel: str, st,
                    stats: BackupStats) -> tuple[str, tuple]:
-        """Worker body: chunk+hash one file, store its blobs. Returns
+        """Chunk+hash one file, store its blobs. Returns
         (rel, (content, size, mtime_ns)) where size is the byte count
         actually hashed and mtime_ns a post-read lstat — the entry must
         describe the content that was stored, not the walk-time stat.

@@ -91,9 +91,9 @@ class DeviceChunkHasher:
     shift-invariant path.
     """
 
-    #: Safe to drive from concurrent threads: no per-call mutable state
-    #: (the fused hasher is stateless; jit caches are global/locked).
-    thread_safe = True
+    # Safe to drive from concurrent threads (the service's handlers
+    # share one): no per-call mutable state; the fused hasher is
+    # stateless and jit caches are global and locked.
 
     #: Owners that manage their own batching (MoverJaxServer) set this
     #: False so the process-wide VOLSYNC_BATCH_SEGMENTS hook cannot

@@ -147,11 +147,14 @@ def donate_device_inputs() -> Optional[bool]:
     return env_bool("VOLSYNC_DONATE")
 
 
-# -- engine worker knobs (engine/backup.py, engine/restore.py) -----------
+# -- engine worker knobs (engine/restore.py) ----------------------------
 
 def backup_workers() -> int:
-    """Concurrent per-file hashing workers for TreeBackup."""
-    return env_int("VOLSYNC_BACKUP_WORKERS", 4, minimum=1)
+    """Files one TreeBackup streams through the device at once: one
+    (engine/backup.py hashes file after file; PERF.md section 6, PR 27).
+    A function and not a flag: benchmark/warm.py plans the lanes of the
+    batched segment programs from it."""
+    return 1
 
 
 def restore_workers() -> int:

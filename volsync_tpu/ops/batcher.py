@@ -1,7 +1,7 @@
 """Cross-stream segment microbatching (shared by service + local engine).
 
 Concurrent producers — gRPC ChunkHash handlers (service/server.py) or
-TreeBackup's per-file workers (engine/backup.py) — submit segments
+the backups of one process (engine/backup.py) — submit segments
 that coalesce into ONE batched device dispatch
 (ops/segment.chunk_hash_segments): the service/engine-side form of
 BASELINE configs[5]'s cross-PVC batching. A lone producer pays at most

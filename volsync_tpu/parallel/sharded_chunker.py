@@ -61,11 +61,11 @@ class MeshChunkHasher:
     programs regardless of workload shape.
     """
 
-    #: NOT safe for concurrent process() calls: sharded dispatches issue
-    #: mesh collectives whose per-device enqueue order must match across
-    #: the ring, and the compiled-fn caches race. TreeBackup serializes
-    #: file hashing when this hasher is injected.
-    thread_safe = False
+    # NOT safe for concurrent process() calls: sharded dispatches issue
+    # mesh collectives whose per-device enqueue order must match across
+    # the ring, and the compiled-fn caches race. A TreeBackup hashes one
+    # file at a time, so one backup never makes two; two backups at once
+    # may not share one of these.
 
     def __init__(self, params: GearParams, mesh=None):
         import jax

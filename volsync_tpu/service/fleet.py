@@ -410,7 +410,7 @@ class Replica:
         ticket = self.server.admission.admit_stream(tenant)
         try:
             with span("fleet.backup"):
-                snap, _stats = TreeBackup(self.repo, workers=1).run(
+                snap, _stats = TreeBackup(self.repo).run(
                     tree, hostname=hostname or self.replica_id)
             return snap
         finally:
