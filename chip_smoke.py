@@ -625,7 +625,7 @@ def phase_kernel_proof(seed: int) -> None:
 def phase_mesh(work: Path, seed: int, sizes: Sizes, chips: int) -> None:
     import jax
 
-    from volsync_tpu.movers.restic import entry
+    from volsync_tpu.obs import counter_totals
 
     check(jax.device_count() == chips,
           f"jax.device_count() is {jax.device_count()}, not {chips}")
@@ -642,22 +642,25 @@ def phase_mesh(work: Path, seed: int, sizes: Sizes, chips: int) -> None:
         out["bytes"] = sizes.mesh_file
 
     with phase("mesh-backup") as out:
+        before = counter_totals()
         run_mover("backup", work / "repo-mesh", src,
                   {"VOLSYNC_ENGINE": "mesh"})
         out["bytes"] = sizes.mesh_file
-        hashers = list(entry._MESH_HASHERS.values())
-        check(len(hashers) == 1, "the mesh engine was not selected")
-        mesh_devs = list(hashers[0].mesh.devices.flat)
-        check(len(mesh_devs) == chips,
-              f"seq mesh spans {len(mesh_devs)} device(s), not {chips}")
-        probe = np.frombuffer(rng.bytes(8 * MiB), np.uint8)
-        data, shard_len = hashers[0]._upload(probe, len(probe))
-        holders = {s.device for s in data.addressable_shards}
-        check(holders == set(jax.devices()),
-              f"uploaded segment has shards on {len(holders)} device(s)")
-        out.update({"mesh_devices": len(mesh_devs),
-                    "shard_devices": len(holders),
-                    "shard_len": int(shard_len)})
+        # the program's own counters say what ran: segments staged onto
+        # the mesh, and the devices that held a shard of each
+        counts = {k: v - before.get(k, 0)
+                  for k, v in counter_totals().items()}
+        staged = counts.get("mesh.dispatches", 0)
+        check(staged > 0, "the mesh engine was not selected")
+        check(counts["mesh.shards"] == chips * staged,
+              f"a staged segment has shards on "
+              f"{counts['mesh.shards'] / staged:g} device(s), not {chips}")
+        out.update({"mesh_dispatches": staged,
+                    "shard_devices": counts["mesh.shards"] // staged,
+                    "staged_useful_share": round(
+                        counts["mesh.bytes_valid"]
+                        / (counts["mesh.bytes_valid"]
+                           + counts["mesh.bytes_padded"]), 4)})
 
     with phase("single-chip-backup") as out:
         run_mover("backup", work / "repo-single", src)

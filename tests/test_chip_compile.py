@@ -205,3 +205,25 @@ def test_mesh_fused_fn_four_devices(tpu_arms, topo):
     text = c.as_text()
     assert text.count("tpu_custom_call") >= 2
     assert "all-gather" in text
+
+
+def test_mesh_fused_segment_at_the_streams_shard(tpu_arms, topo):
+    """The same program at the shard a four-chip host's stream gives
+    each chip since the segment follows the shards: 4 x 32 MiB (what
+    one chip is dispatched without the mesh), not the last segment."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from volsync_tpu.parallel.sharded_chunker import (
+        SEQ, MeshChunkHasher, make_stream_mesh)
+
+    hasher = MeshChunkHasher(_params(), make_stream_mesh(topo.devices[:4]))
+    shard_len = 32 * MiB
+    assert hasher.shard_bucket(4 * shard_len) == shard_len
+    fn = hasher._fused_fn(shard_len, *hasher.fused_caps(shard_len), False)
+    c = fn.lower(
+        _sds((4, shard_len), jnp.uint8,
+             NamedSharding(hasher.mesh, PartitionSpec(SEQ, None))),
+        _sds((), jnp.int32,
+             NamedSharding(hasher.mesh, PartitionSpec()))).compile()
+    assert _kernels(c) >= 2 and "all-gather" in c.as_text()

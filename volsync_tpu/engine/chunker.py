@@ -102,8 +102,8 @@ class DeviceChunkHasher:
 
     #: ``begin()`` takes ``valid_len``: stream_chunk_batches hands it a
     #: view already padded to the device bucket (zeroed pad lane), so no
-    #: np.pad copy happens per segment. Hashers without the kwarg (mesh,
-    #: bench hosts) get the exact-length view instead.
+    #: np.pad copy happens per segment. Hashers without the kwarg
+    #: (bench hosts) get the exact-length view instead.
     accepts_prepadded = True
 
     def __init__(self, params: GearParams):
@@ -671,14 +671,15 @@ class _SegmentFill:
     the device a pre-padded view with no np.pad copy."""
 
     def __init__(self, reader: Callable[[int], bytes], piece_size: int,
-                 max_size: int):
+                 max_size: int, bucket=_buffer_bucket):
         self._read, self._readinto = _resolve_reader(reader)
         self._piece = piece_size
         self.head = max_size
         self.target = piece_size + max_size
+        self.bucket = bucket  # the hasher's pad target of a length
         # head + fill window + bucket slack for the device pad lane
         # (bucket(tail + fill) never reaches past this).
-        self.capacity = max_size + _buffer_bucket(self.target + max_size)
+        self.capacity = max_size + bucket(self.target + max_size)
         self._eof = False
         self._carry: Optional[memoryview] = None  # over-returned piece
 
@@ -801,6 +802,21 @@ class _SegmentReadahead:
                 bufpool.GLOBAL.release(item[0])
 
 
+def _segment_source(reader, params: GearParams, segment_size: int,
+                    hasher) -> _SegmentFill:
+    """The fill of a stream over ``hasher``. A hasher that shards a
+    segment over several chips says how large a segment it wants
+    (``stream_segment_size``: every chip gets what one chip is
+    dispatched) and how it pads one (``buffer_bucket``); the one-chip
+    engine has neither and gets ``segment_size`` and ``_buffer_bucket``.
+    A warm plan asks this for the same fill the stream will use."""
+    scale = getattr(hasher, "stream_segment_size", None)
+    if scale is not None:
+        segment_size = scale(segment_size)
+    return _SegmentFill(reader, segment_size, params.max_size,
+                        getattr(hasher, "buffer_bucket", _buffer_bucket))
+
+
 def stream_chunk_batches(reader: Callable[[int], bytes],
                          params: GearParams,
                          segment_size: int = 32 * 1024 * 1024,
@@ -850,7 +866,8 @@ def stream_chunk_batches(reader: Callable[[int], bytes],
     hasher = hasher or DeviceChunkHasher(params)
     if readahead is None:
         readahead = envflags.readahead_segments()
-    src = _SegmentFill(reader, segment_size, params.max_size)
+    src = _segment_source(reader, params, segment_size, hasher)
+    bucket = src.bucket
     ra: Optional[_SegmentReadahead] = None
     if readahead > 0:
         ra = src = _SegmentReadahead(src, readahead)
@@ -869,14 +886,14 @@ def stream_chunk_batches(reader: Callable[[int], bytes],
                 # Hand the device a view already padded to its bucket:
                 # zero the pad lane in place (a memset over recycled
                 # buffer slack, not a payload copy) — no np.pad.
-                plen = _buffer_bucket(length)
+                plen = bucket(length)
                 arr[fill: start + plen] = 0
                 return begin(arr[start: start + plen], eof=eof,
                              valid_len=length)
             if begin is not None:
                 return begin(arr[start:fill], eof=eof)
-            # Engines without split-phase support (e.g. the mesh
-            # hasher) still work, just without the overlap.
+            # Engines without split-phase support (bench hosts) still
+            # work, just without the overlap.
             return PendingSegment(
                 hasher.process(arr[start:fill], eof=eof), None, None)
 
@@ -904,7 +921,11 @@ def stream_chunk_batches(reader: Callable[[int], bytes],
                 record_copy("chunker.tail_carry", t)
             tail = None
             token = _dispatch(buf, start, fill, eof)
-            consumed = token.end
+            with span("engine.device"):
+                # a fused segment that is still in flight (the mesh,
+                # the one-chip engine without the batcher) learns its
+                # cuts from its one fetch: the stream waits here
+                consumed = token.end
             tail = memoryview(buf)[start + consumed: fill]
             if len(tail) == 0:
                 tail = None

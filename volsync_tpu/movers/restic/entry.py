@@ -111,6 +111,18 @@ RC_LOCKED = 4
 _MESH_HASHERS: dict = {}
 
 
+def mesh_hasher(params):
+    """The process's one mesh hasher for a chunker-param set: what a
+    ``VOLSYNC_ENGINE=mesh`` backup hashes with, and what a set-up that
+    loads its programs ahead of the first Job has to load them into."""
+    from volsync_tpu.parallel.sharded_chunker import MeshChunkHasher
+
+    hasher = _MESH_HASHERS.get(params)
+    if hasher is None:
+        hasher = _MESH_HASHERS[params] = MeshChunkHasher(params)
+    return hasher
+
+
 def _select_hasher(env: dict, repo: Repository):
     """VOLSYNC_ENGINE=mesh shards the scan over the device mesh
     (parallel/sharded_chunker.py); default is the single-chip engine.
@@ -119,13 +131,8 @@ def _select_hasher(env: dict, repo: Repository):
     if env.get("VOLSYNC_ENGINE", "").lower() != "mesh":
         return None
     from volsync_tpu.engine.chunker import params_from_config
-    from volsync_tpu.parallel.sharded_chunker import MeshChunkHasher
 
-    params = params_from_config(repo.chunker_params)
-    hasher = _MESH_HASHERS.get(params)
-    if hasher is None:
-        hasher = _MESH_HASHERS[params] = MeshChunkHasher(params)
-    return hasher
+    return mesh_hasher(params_from_config(repo.chunker_params))
 
 
 def restic_entrypoint(ctx) -> int:

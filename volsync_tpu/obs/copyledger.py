@@ -48,6 +48,8 @@ SANCTIONED_SITES = frozenset({
     "chunker.tail_carry",  # sub-min_size tail carried between segments
     "device.pad",          # host buffer staged into the padded device lane
     "device.stage",        # segment rows gathered for the batched kernel
+    "mesh.pad",            # unpadded segment copied out to the mesh's bucket
+    "mesh.stage",          # segment laid out over the seq mesh's chips
     "verify.stage",        # restore verify staging onto the device
     "objstore.assemble",   # iovec joined for a contiguous-transport backend
     "repo.buffered_read",  # blob read back while still in the write pipeline

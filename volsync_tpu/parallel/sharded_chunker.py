@@ -5,8 +5,11 @@
 ``TreeBackup`` — the real backup path — run sharded over a device mesh
 with no orchestration changes. The reference has *no* intra-volume
 parallelism at all (SURVEY.md §5 long-context note: rsync/restic stream
-single-threaded); sharding one volume's scan across chips is the TPU
-framework's core win.
+single-threaded). What the chip read of it is in PERF.md §5-6
+(``dedup-1t.backup-mesh4``): a first backup of one 2 GiB stream moves
+at 1.7 times the one-chip engine's rate on the same four-chip host,
+mostly because a segment goes from the pooled buffer to four chips at
+once with no staging copy; the host still holds the chips back.
 
 Per segment, two shard_map kernels over a 1-D ``seq`` ring of devices:
 
@@ -31,7 +34,8 @@ from __future__ import annotations
 
 import numpy as np
 
-from volsync_tpu.engine.chunker import _pow2ceil
+from volsync_tpu.engine.chunker import PendingSegment, _pow2ceil
+from volsync_tpu.obs import count, record_copy, span
 from volsync_tpu.ops.gearcdc import GearParams, _mix_u32, select_boundaries
 from volsync_tpu.repo import blobid
 
@@ -78,62 +82,130 @@ class MeshChunkHasher:
         self._fused_cache: dict = {}
         self._jax = jax
 
-    # -- public protocol (mirrors DeviceChunkHasher.process) ----------------
+    #: ``begin()`` takes ``valid_len``: stream_chunk_batches hands it a
+    #: view already padded to ``buffer_bucket`` (zeroed pad lane), so a
+    #: streamed segment goes to the chips with no np.pad copy.
+    accepts_prepadded = True
+
+    # -- what a stream (and a warm plan) needs to know of the layout --------
+
+    def shard_bucket(self, length: int) -> int:
+        """Bytes a shard holds of a ``length``-byte segment: a pow2
+        bucket, so that streaming reuses a handful of programs."""
+        S = self.n_shards
+        return _pow2ceil((length + S - 1) // S, max(_LEAF, 64 * 1024))
+
+    def buffer_bucket(self, length: int) -> int:
+        """Pad target of a segment (the mesh's ``_buffer_bucket``)."""
+        return self.n_shards * self.shard_bucket(length)
+
+    def stream_segment_size(self, segment_size: int) -> int:
+        """The ``segment_size`` a stream over this hasher fills at:
+        each shard gets what one chip is dispatched, and a full
+        segment (fill window + max_size + a carried tail under
+        max_size) just fits the shards' bucket instead of spilling
+        into the next power of two."""
+        return max(segment_size,
+                   self.buffer_bucket(self.n_shards * segment_size)
+                   - 2 * self.params.max_size)
+
+    def fused_caps(self, shard_len: int) -> tuple[int, int]:
+        """(cand_cap, chunk_cap) of the fused program at ``shard_len``.
+        cand_cap is per shard (compaction is local; the header's
+        candidate slot carries the WORST shard's true count)."""
+        from volsync_tpu.ops.segment import segment_caps
+
+        cand_cap, chunk_cap = segment_caps(self.n_shards * shard_len,
+                                           self.params)
+        return max(1024, cand_cap // self.n_shards), chunk_cap
+
+    def fused_programs(self) -> list[tuple[int, int, int, bool]]:
+        """(shard_len, cand_cap, chunk_cap, eof) of every fused program
+        built so far (what a warm plan has to have listed)."""
+        return sorted(self._fused_cache)
+
+    # -- public protocol (mirrors DeviceChunkHasher) ------------------------
 
     def process(self, buffer, *, eof: bool = True) -> list[tuple[int, int, str]]:
+        return self.begin(buffer, eof=eof).finish()
+
+    def begin(self, buffer, *, eof: bool = True,
+              valid_len: int | None = None) -> PendingSegment:
+        """Stage the segment onto the shards and launch its program,
+        leaving it IN FLIGHT (fused path; the split-phase paths walk
+        the boundaries on the host and are done when this returns).
+        ``buffer`` may already be padded to ``buffer_bucket(valid_len)``
+        with a zeroed pad lane."""
         if isinstance(buffer, (bytes, bytearray, memoryview)):
             buffer = np.frombuffer(buffer, dtype=np.uint8)
-        length = int(buffer.shape[0])
+        length = (int(buffer.shape[0]) if valid_len is None
+                  else int(valid_len))
         if length == 0:
-            return []
+            return PendingSegment([], None, None)
         p = self.params
         if length <= p.min_size:
             if not eof:
-                return []
-            return [(0, length, blobid.blob_id(buffer.tobytes()))]
+                return PendingSegment([], None, None)
+            return PendingSegment(
+                [(0, length, blobid.blob_id(buffer[:length]))], None, None)
 
         data, shard_len = self._upload(buffer, length)
         if p.align == _LEAF:
-            return self._process_fused(data, shard_len, length, eof)
+            return PendingSegment.fused_segment(
+                self, data, length, self.dispatch(data, length, eof=eof),
+                eof)
         idx_s, idx_l = self._candidates(data, shard_len, length)
         chunks = select_boundaries(idx_s, idx_l, length, p, eof=eof)
         if not chunks:
-            return []
+            return PendingSegment([], None, None)
         hexes = self._span_roots(data, shard_len, chunks)
-        return [(int(s), int(l), h) for (s, l), h in zip(chunks, hexes)]
+        return PendingSegment(
+            [(int(s), int(l), h) for (s, l), h in zip(chunks, hexes)],
+            None, None)
 
     # -- fused page-aligned path (one dispatch, one small fetch) ------------
 
-    def _process_fused(self, data, shard_len: int, length: int,
-                       eof: bool) -> list[tuple[int, int, str]]:
+    def dispatch(self, data, length: int, *, eof: bool,
+                 cand_cap: int | None = None, chunk_cap: int | None = None):
         """The ops/segment.py one-round-trip protocol, sharded: page
         digests and candidates compute per shard (pages never cross
         seams — shard_len % LEAF == 0 — so there is NO halo at all),
         the 32-bytes-per-4KiB digest stream all-gathers over the seq
         ring (1/128th of the data volume, riding ICI), and the FastCDC
         walk + root assembly run replicated on the gathered table. ONE
-        replicated ~20 KiB result comes back; capacity overflows are
-        reported in-band and retried with doubled tables, exactly like
-        the single-chip FusedSegmentHasher."""
-        from volsync_tpu.ops.segment import (
-            decode_with_overflow_check,
-            segment_caps,
-        )
+        replicated ~20 KiB result comes back. Returns the in-flight
+        handle and the capacities it was compiled at (the
+        FusedSegmentHasher.dispatch protocol)."""
+        shard_len = int(data.shape[1])
+        cc, kc = self.fused_caps(shard_len)
+        cand_cap = cand_cap or cc
+        chunk_cap = chunk_cap or kc
+        fn = self._fused_fn(shard_len, cand_cap, chunk_cap, eof)
+        with span("mesh.launch", shards=self.n_shards, shard_len=shard_len):
+            handle = fn(data, np.int32(length))
+        return handle, (cand_cap, chunk_cap)
 
-        padded = self.n_shards * shard_len
-        cand_cap, chunk_cap = segment_caps(padded, self.params)
-        # cand_cap is per shard in this path (compaction is local; the
-        # header's candidate slot carries the WORST shard's true count).
-        cand_cap = max(1024, cand_cap // self.n_shards)
-        while True:
-            fn = self._fused_fn(shard_len, cand_cap, chunk_cap, eof)
-            packed = np.asarray(fn(data, np.int32(length)))
+    def finish(self, data, length: int, inflight, *, eof: bool):
+        """Fetch + decode; capacity overflows are reported in-band and
+        re-dispatched with doubled tables, exactly like the single-chip
+        FusedSegmentHasher."""
+        from volsync_tpu.ops.segment import decode_with_overflow_check
+
+        handle, (cand_cap, chunk_cap) = inflight
+        with span("mesh.fetch"):
+            packed = np.asarray(handle)
+        with span("mesh.decode"):
             chunks, consumed, grown = decode_with_overflow_check(
                 packed, length, cand_cap, chunk_cap)
-            if grown is None:
-                assert not eof or consumed == length
-                return chunks
-            cand_cap, chunk_cap = grown
+        if grown is None:
+            assert not eof or consumed == length
+            return chunks, consumed
+        with span("mesh.overflow_retry", shard_len=int(data.shape[1])):
+            return self.finish(
+                data, length,
+                self.dispatch(data, length, eof=eof, cand_cap=grown[0],
+                              chunk_cap=grown[1]),
+                eof=eof)
 
     def _fused_fn(self, shard_len: int, cand_cap: int, chunk_cap: int,
                   eof: bool):
@@ -148,19 +220,35 @@ class MeshChunkHasher:
     # -- upload -------------------------------------------------------------
 
     def _upload(self, buffer: np.ndarray, length: int):
-        """Pad to S * pow2-bucketed shard length, lay out [S, Ls] with
-        shard i holding bytes [i*Ls, (i+1)*Ls)."""
+        """Lay the segment out [S, Ls] with shard i holding bytes
+        [i*Ls, (i+1)*Ls), Ls the shards' pow2 bucket, and put each
+        shard on its chip. A buffer that is not yet ``buffer_bucket``
+        long is padded here (one host copy, ledger site ``mesh.pad``);
+        what goes to the chips is ledgered as ``mesh.stage``."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         S = self.n_shards
-        shard_len = _pow2ceil((length + S - 1) // S, max(_LEAF, 64 * 1024))
+        shard_len = self.shard_bucket(length)
         padded = S * shard_len
-        if padded != length:
-            buffer = np.pad(buffer, (0, padded - length))
-        host = buffer.reshape(S, shard_len)
-        data = jax.device_put(
-            host, NamedSharding(self.mesh, P(SEQ, None)))
+        have = int(buffer.shape[0])
+        with span("mesh.stage", shards=S, shard_len=shard_len):
+            if have < padded:
+                record_copy("mesh.pad", length)
+                buffer = np.pad(buffer, (0, padded - have))
+            elif have > padded:
+                buffer = buffer[:padded]
+            data = jax.device_put(
+                buffer.reshape(S, shard_len),
+                NamedSharding(self.mesh, P(SEQ, None)))
+            record_copy("mesh.stage", padded)
+        # one segment staged = one dispatch (a capacity retry runs the
+        # program again on the same shards: span mesh.overflow_retry)
+        count("mesh.dispatches")
+        count("mesh.shards",
+              len({s.device for s in data.addressable_shards}))
+        count("mesh.bytes_valid", length)
+        count("mesh.bytes_padded", padded - length)
         return data, shard_len
 
     # -- kernel 1: CDC candidates -------------------------------------------
@@ -327,43 +415,54 @@ def _build_fused_fn(mesh, params: GearParams, shard_len: int,
         row = data[0]
         valid_len = valid_len.astype(jnp.int32)
 
+        # Each stage under a jax.named_scope (metadata only), the
+        # one-chip program's six names (ops/segment.py) plus the two
+        # gathers, so a device trace names the stage an op belongs to.
         # --- per-shard page digests (no halo: pages don't cross seams)
         # Word-major per shard: the cross-shard word_index below
         # assumes the per-shard kernel layout.
-        flat_local = _page_digests_flat(row, npps)  # [8 * npps]
-        flat_g = jax.lax.all_gather(flat_local, SEQ, axis=0)  # [S, 8*npps]
-        flat_g = flat_g.reshape(S * 8 * npps)
+        with jax.named_scope("page_sha"):
+            flat_local = _page_digests_flat(row, npps)  # [8 * npps]
+        with jax.named_scope("digest_all_gather"):
+            flat_g = jax.lax.all_gather(flat_local, SEQ, axis=0)
+            flat_g = flat_g.reshape(S * 8 * npps)  # [S, 8*npps] flat
 
         def word_index(j, page):  # word j of GLOBAL page p
             return (page // npp) * (8 * npps) + j * npps + page % npp
 
         # --- per-shard aligned candidates -> global sorted tables
-        h = gear_at_aligned(row, p.seed, align)  # [R]
-        pos = (i * shard_len
-               + jnp.arange(R, dtype=jnp.int32) * align + (align - 1))
-        ok = pos < valid_len
-        is_s = ((h & mask_s) == 0) & ok
-        is_l = ((h & mask_l) == 0) & ok
-        ridx_l = jnp.nonzero(is_l, size=cand_cap, fill_value=R)[0]
-        safe = jnp.clip(ridx_l, 0, R - 1)
-        lpos = jnp.where(ridx_l < R, pos[safe], sentinel)
-        lstrict = jnp.where(ridx_l < R, is_s[safe], False)
-        spos = jnp.where(lstrict, lpos, sentinel)
-        pos_l = jnp.sort(jax.lax.all_gather(lpos, SEQ, axis=0).reshape(-1))
-        pos_s = jnp.sort(jax.lax.all_gather(spos, SEQ, axis=0).reshape(-1))
-        nl = jax.lax.psum(jnp.sum(is_l).astype(jnp.int32), SEQ)
-        ns = jax.lax.psum(jnp.sum(is_s).astype(jnp.int32), SEQ)
-        worst = jax.lax.pmax(jnp.sum(is_l).astype(jnp.int32), SEQ)
+        with jax.named_scope("gear_candidates"):
+            h = gear_at_aligned(row, p.seed, align)  # [R]
+            pos = (i * shard_len
+                   + jnp.arange(R, dtype=jnp.int32) * align + (align - 1))
+            ok = pos < valid_len
+            is_s = ((h & mask_s) == 0) & ok
+            is_l = ((h & mask_l) == 0) & ok
+        with jax.named_scope("compact"):
+            ridx_l = jnp.nonzero(is_l, size=cand_cap, fill_value=R)[0]
+            safe = jnp.clip(ridx_l, 0, R - 1)
+            lpos = jnp.where(ridx_l < R, pos[safe], sentinel)
+            lstrict = jnp.where(ridx_l < R, is_s[safe], False)
+            spos = jnp.where(lstrict, lpos, sentinel)
+        with jax.named_scope("candidate_all_gather"):
+            pos_l = jnp.sort(
+                jax.lax.all_gather(lpos, SEQ, axis=0).reshape(-1))
+            pos_s = jnp.sort(
+                jax.lax.all_gather(spos, SEQ, axis=0).reshape(-1))
+            nl = jax.lax.psum(jnp.sum(is_l).astype(jnp.int32), SEQ)
+            ns = jax.lax.psum(jnp.sum(is_s).astype(jnp.int32), SEQ)
+            worst = jax.lax.pmax(jnp.sum(is_l).astype(jnp.int32), SEQ)
 
         # --- replicated FastCDC walk (global positions are multiples of
         # align too, so the successor-table fast form applies with the
         # GLOBAL row count S*R)
-        starts, lens, count, consumed = _select_boundaries_device(
-            pos_s, jnp.minimum(ns, S * cand_cap),
-            pos_l, jnp.minimum(nl, S * cand_cap),
-            valid_len, min_size=p.min_size, avg_size=p.avg_size,
-            max_size=p.max_size, chunk_cap=chunk_cap, eof=eof,
-            align=align, n_rows=S * R)
+        with jax.named_scope("boundary_walk"):
+            starts, lens, count, consumed = _select_boundaries_device(
+                pos_s, jnp.minimum(ns, S * cand_cap),
+                pos_l, jnp.minimum(nl, S * cand_cap),
+                valid_len, min_size=p.min_size, avg_size=p.avg_size,
+                max_size=p.max_size, chunk_cap=chunk_cap, eof=eof,
+                align=align, n_rows=S * R)
 
         # --- the ONE possibly-partial tail leaf: hashed by its owner
         # shard, psum-broadcast, spliced into the gathered table.
@@ -377,22 +476,24 @@ def _build_fused_fn(mesh, params: GearParams, shard_len: int,
         owner = tail_page // npp
         loc_off = (tail_page % npp) * _LEAF
         mine = has_tail & (owner == i)
-        t_dig = sha256_chunks_device(
-            row, loc_off[None], jnp.where(mine, tail_len, 0)[None],
-            max_len=_LEAF)[0]
-        t_dig = jax.lax.psum(
-            jnp.where(mine, t_dig, jnp.uint32(0)), SEQ)
-        ovr = jnp.where(has_tail,
-                        word_index(jnp.arange(8, dtype=jnp.int32),
-                                   tail_page),
-                        S * 8 * npps)  # OOB -> dropped
-        flat_g = flat_g.at[ovr].set(t_dig, mode="drop")
+        with jax.named_scope("tail_sha"):
+            t_dig = sha256_chunks_device(
+                row, loc_off[None], jnp.where(mine, tail_len, 0)[None],
+                max_len=_LEAF)[0]
+            t_dig = jax.lax.psum(
+                jnp.where(mine, t_dig, jnp.uint32(0)), SEQ)
+            ovr = jnp.where(has_tail,
+                            word_index(jnp.arange(8, dtype=jnp.int32),
+                                       tail_page),
+                            S * 8 * npps)  # OOB -> dropped
+            flat_g = flat_g.at[ovr].set(t_dig, mode="drop")
 
         # --- replicated roots + packed result
-        nleaves = jnp.where(live, (lens + (_LEAF - 1)) // _LEAF, 0)
-        page0 = starts // _LEAF
-        roots = _root_digests_loop(flat_g, S * npp, page0, nleaves, lens,
-                                   live, word_index=word_index)
+        with jax.named_scope("merkle_roots"):
+            nleaves = jnp.where(live, (lens + (_LEAF - 1)) // _LEAF, 0)
+            page0 = starts // _LEAF
+            roots = _root_digests_loop(flat_g, S * npp, page0, nleaves,
+                                       lens, live, word_index=word_index)
         header = jnp.stack([count.astype(jnp.uint32),
                             consumed.astype(jnp.uint32),
                             worst.astype(jnp.uint32),
@@ -406,7 +507,11 @@ def _build_fused_fn(mesh, params: GearParams, shard_len: int,
         out_specs=P(),
         check_vma=False,
     )
-    return jax.jit(sharded)
+
+    def mesh_fused_segment(data, valid_len):  # the program's trace name
+        return sharded(data, valid_len)
+
+    return jax.jit(mesh_fused_segment)
 
 
 def _build_cand_fn(mesh, params: GearParams, shard_len: int, cap: int):
