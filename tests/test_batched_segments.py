@@ -96,6 +96,43 @@ def test_batched_empty_and_all_zero_lanes():
     assert sum(l for _, l, _ in chunks2) == P.min_size - 1
 
 
+SMALL = 64 * 1024  # a lane short enough to compile three lane counts
+
+
+@pytest.mark.parametrize("lens,eofs", [
+    ([SMALL - 100], [True]),             # the tail on the bucket's last page
+    ([SMALL - 100], [False]),            # not eof: no tail, zero iterations
+    ([3 * 4096], [True]),                # eof on the page grid: no tail
+    ([5 * 4096 + 55, 0], [True, True]),  # one padding lane (valid_len 0)
+    ([5 * 4096 + 56, 2 * 4096 + 4095], [True, True]),
+    ([4096 + 63, SMALL - 1], [False, True]),
+    ([SMALL - 4095, 64, 0, 7 * 4096 + 119], [True, True, False, True]),
+    ([SMALL, 4096 + 120, 9 * 4096 + 1, 12345], [False, True, True, False]),
+], ids=["1-last-page", "1-not-eof", "1-on-grid", "2-with-empty",
+        "2-eof", "2-mixed", "4-with-empty", "4-mixed"])
+def test_batched_tail_leaves_per_lane(rng, lens, eofs):
+    """1, 2 and 4 lanes, eof mixed, each eof lane with its own partial
+    tail leaf (every SHA padding edge somewhere), a lane of valid_len 0,
+    and batches where no lane has a tail: cuts and ids against the host
+    walk + hashlib, lane for lane."""
+    from volsync_tpu.ops.gearcdc import chunk_buffer
+
+    cand_cap, chunk_cap = segment_caps(SMALL, P)
+    rows = np.zeros((len(lens), SMALL), dtype=np.uint8)
+    for i, n in enumerate(lens):
+        rows[i, :n] = np.frombuffer(rng.bytes(n), np.uint8)
+    out = np.asarray(chunk_hash_segments(
+        jnp.asarray(rows.reshape(-1)), jnp.asarray(lens, jnp.int32),
+        jnp.asarray(eofs), **_kw(cand_cap, chunk_cap)))
+    for i, (n, eof) in enumerate(zip(lens, eofs)):
+        chunks, consumed, _, _ = decode_segment(out[i], chunk_cap)
+        view = rows[i, :n].tobytes()
+        want = [(s, l, blobid.blob_id(view[s: s + l]))
+                for s, l in chunk_buffer(view, P, eof=eof)] if n else []
+        assert chunks == want, f"lane {i}"
+        assert consumed == sum(l for _, l, _ in want), f"lane {i}"
+
+
 def test_batched_duplicate_content_same_ids(rng):
     """Identical lanes produce identical chunk tables/ids — the dedup
     substrate for cross-PVC batches."""

@@ -120,6 +120,81 @@ def test_fused_capacity_retry(rng):
     assert [(s, l, d) for s, l, d in chunks] == ref
 
 
+# Every SHA-256 padding edge of a partial leaf: terminator and length
+# in one block or two, a word boundary or not, the longest tail.
+TAIL_EDGES = [1, 3, 4, 55, 56, 63, 64, 119, 120, 2000, 4093, 4095]
+
+
+@pytest.mark.parametrize("tail_len", [0] + TAIL_EDGES)
+def test_tail_leaf_digests_match_generic_hasher_and_hashlib(rng, tail_len):
+    """ops/segment._tail_leaf_digests (page-aligned start, under a page)
+    against its oracle sha256_chunks_device (arbitrary offsets) and
+    hashlib: the same page at three lanes, one of them the buffer's last
+    page, one lane with no tail (length 0: no compression, masked by the
+    callers)."""
+    import hashlib
+
+    import jax.numpy as jnp
+
+    from volsync_tpu.ops.segment import LEAF_SIZE, _tail_leaf_digests
+    from volsync_tpu.ops.sha256 import _H0, sha256_chunks_device
+
+    F = 6
+    data = np.frombuffer(rng.bytes(F * LEAF_SIZE), np.uint8)
+    pages = np.array([2, F - 1, 0, 4], np.int32)
+    lens = np.array([tail_len, tail_len, 0, max(tail_len - 1, 0)], np.int32)
+    got = np.asarray(_tail_leaf_digests(
+        jnp.asarray(data), jnp.asarray(pages), jnp.asarray(lens)))
+    want = np.asarray(sha256_chunks_device(
+        jnp.asarray(data), jnp.asarray(pages * LEAF_SIZE),
+        jnp.asarray(lens), max_len=LEAF_SIZE))
+    for lane, (pg, n) in enumerate(zip(pages, lens)):
+        if n == 0:  # no tail: the initial state, nothing hashed
+            assert (got[lane] == _H0).all(), lane
+            continue
+        assert (got[lane] == want[lane]).all(), lane
+        assert got[lane].astype(">u4").tobytes() == hashlib.sha256(
+            data[pg * LEAF_SIZE: pg * LEAF_SIZE + n].tobytes()).digest()
+
+
+@pytest.mark.parametrize(
+    "n", [5 * 4096 + t for t in [0] + TAIL_EDGES] + [65536 - 7],
+    ids=[f"tail{t}" for t in [0] + TAIL_EDGES] + ["last-page-of-bucket"])
+def test_fused_single_lane_tail_edges(rng, n):
+    """chunk_hash_segment end to end with the final chunk ending at
+    every padding edge into a page (0: on the grid, no tail at all),
+    and with the tail leaf on the very last page of the padded bucket
+    (the row gather's upper edge)."""
+    data = rng.randint(0, 256, size=(n,), dtype=np.uint8).tobytes()
+    assert run_engine(data, PARAMS) == host_reference(data, PARAMS)
+
+
+def test_span_roots_eight_spans_each_with_its_own_tail(rng):
+    """span_roots_device directly: 8 page-disjoint spans, every one with
+    a partial last leaf of another length (N lanes, N tails in one
+    stage), plus padding lanes; ids against hashlib's Merkle id."""
+    import jax.numpy as jnp
+
+    from volsync_tpu.ops.segment import span_roots_device
+
+    sizes = [1, 55, 4096 + 56, 63, 2 * 4096 + 64, 119, 4096 + 120, 4095]
+    pieces, starts = [], []
+    off = 0
+    for n in sizes:
+        starts.append(off)
+        pieces.append(rng.bytes(n) + bytes(-n % 4096))
+        off += len(pieces[-1])
+    buf = b"".join(pieces)
+    pad = 3  # inert lanes (lens < 0) among the live ones
+    roots = np.asarray(span_roots_device(
+        jnp.asarray(np.frombuffer(buf, np.uint8)),
+        jnp.asarray(starts + [0] * pad, jnp.int32),
+        jnp.asarray(sizes + [-1] * pad, jnp.int32)))
+    for lane, (s, n) in enumerate(zip(starts, sizes)):
+        assert roots[lane].astype(">u4").tobytes().hex() \
+            == blobid.blob_id(buf[s: s + n]), (lane, n)
+
+
 def test_decode_segment_shape():
     cc, kc = segment_caps(65536, PARAMS)
     packed = np.zeros((4 + kc * 10,), np.uint32)

@@ -500,8 +500,8 @@ def hash_spans(buffer, spans: list[tuple[int, int]]) -> list[str]:
     When every span start is 4 KiB-aligned (the mover's packer pads to
     the page grid), this is ONE fused dispatch + ONE [N, 8] fetch:
     all full leaves are pages of the buffer (contiguous hashing, no
-    gather) and only each span's short tail pays the gather path
-    (ops/segment.span_roots_device). Unaligned spans fall back to the
+    gather) and only each span's short tail goes through the tail
+    stage (ops/segment.span_roots_device). Unaligned spans fall back to the
     generic per-leaf gather batch.
     """
     if not spans:

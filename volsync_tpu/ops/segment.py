@@ -24,6 +24,9 @@ so the pipeline is built exclusively from:
   bit-identical to ``gearcdc._select_boundaries_py`` (golden-tested);
 - a Pallas tile-transpose (VMEM shuffles, ~HBM speed) feeding the
   Pallas SHA-256 lane kernel, digests kept in kernel layout;
+- a tail stage for the ONE partial leaf a lane (``_tail_leaf_digests``:
+  its page as one row, a while_loop to the longest live tail; the
+  arbitrary-offset hasher ``sha256_chunks_device`` is only its oracle);
 - a root stage that hashes "VMRK1" || le64(len) || leaf-digests
   (repo/blobid.py) with a while_loop over message blocks — a 17-word
   gather per block per chunk lane, nothing data-sized.
@@ -59,7 +62,7 @@ from volsync_tpu.ops.sha256 import (
     _sha256_leaf_kernel,
     _sha256_rows,
     pack_words,
-    sha256_chunks_device,
+    pack_words_rows,
     use_pallas_leaves,
 )
 
@@ -138,6 +141,80 @@ def _apply_tail_overrides(flat: jax.Array, n_pages_pad: int,
     ovr = jnp.where(has_tail[:, None], wi(j8, tail_pages[:, None]),
                     8 * n_pages_pad)  # OOB -> dropped
     return flat.at[ovr.reshape(-1)].set(tail_digs.reshape(-1), mode="drop")
+
+
+_TAIL_BLOCKS = LEAF_SIZE // 64 + 1  # blocks of the longest tail leaf, 4,095 B
+
+#: Fewest lanes the tail stage runs its compressions over. Measured on a
+#: v5e (scripts/profile_tail.py): ONE lane costs 62 us a compression
+#: (4.1 ms for a 65-block tail: the whole ~4 ms a dispatch the stage
+#: used to cost), 16 lanes 2.3 us for all sixteen; lanes are free, a
+#: one-lane compression is not, so a lone tail rides with inert lanes.
+_TAIL_MIN_LANES = 16
+
+
+def _tail_leaf_digests(data: jax.Array, tail_page: jax.Array,
+                       tail_len: jax.Array) -> jax.Array:
+    """SHA-256 of each lane's partial tail leaf: the first ``tail_len``
+    bytes of page ``tail_page``. THE tail stage of every segment program
+    (single, batched, span, mesh), so it has ONE home.
+
+    data: [P] uint8, P % LEAF_SIZE == 0; tail_page: [N] int32 page
+    index into ``data``; tail_len: [N] int32 in [0, LEAF_SIZE), 0 =
+    the lane has no tail: it takes no compression and its row is the
+    initial state (callers drop it through ``has_tail``). Returns
+    [N, 8] uint32, bit-exact vs hashlib.
+
+    A tail leaf always starts on a page boundary and is shorter than a
+    page: invariants of every caller, so none of the generality of
+    ``sha256_chunks_device`` (arbitrary byte offsets: a byte gather and
+    a scan of always 65 compressions over exactly the caller's lanes)
+    is paid here. Each page is taken as one contiguous row and packed
+    to words on a 2-D minor dim (see ``pack_words``: through [8N, 512],
+    so that one lane is not a 1-D stride), the FIPS padding is laid on
+    in words, and a while_loop over a block-major message runs to the
+    LARGEST live lane's block count, zero iterations for a segment with
+    no tail (every non-eof one), at no fewer than ``_TAIL_MIN_LANES``.
+    """
+    N = tail_page.shape[0]
+    F = data.shape[0] // LEAF_SIZE
+    u32 = jnp.uint32
+    rows = data.reshape(F, LEAF_SIZE)[jnp.clip(tail_page, 0, F - 1)]
+    words = pack_words_rows(rows.reshape(N * 8, LEAF_SIZE // 8))
+    lanes = max(N, _TAIL_MIN_LANES)
+    words = jnp.pad(words.reshape(N, LEAF_SIZE // 4),
+                    ((0, lanes - N), (0, 16)))  # [lanes, 65 * 16]
+    tail_len = jnp.pad(tail_len.astype(jnp.int32), (0, lanes - N))
+
+    # Word q holds bytes 4q..4q+3. Word tail_len // 4 keeps its first
+    # tail_len % 4 bytes and takes the 0x80 terminator after them;
+    # later words are zero but the last of block nb-1, the bit length
+    # (< 2^15: the high length word stays zero).
+    q = jnp.arange(_TAIL_BLOCKS * 16, dtype=jnp.int32)[None, :]
+    qterm = (tail_len >> 2)[:, None]
+    r8 = ((tail_len & 3).astype(u32) << u32(3))[:, None]
+    partial = (words & ~(u32(0xFFFFFFFF) >> r8)) | (u32(0x80000000) >> r8)
+    nb = jnp.where(tail_len > 0, (tail_len + 9 + 63) // 64, 0)  # [lanes]
+    msg = jnp.where(q < qterm, words,
+                    jnp.where(q == qterm, partial, u32(0)))
+    msg = jnp.where(q == (nb * 16 - 1)[:, None],
+                    (tail_len.astype(u32) << u32(3))[:, None], msg)
+    # Block-major, so an iteration indexes the major dim (half the time
+    # of a 16-word dynamic_slice of the minor dim at 16 lanes).
+    blocks = msg.reshape(lanes, _TAIL_BLOCKS, 16).transpose(1, 0, 2)
+    max_nb = jnp.max(nb)
+
+    def cond(c):
+        return c[0] < max_nb
+
+    def body(c):
+        n, state = c
+        new = _compress(state, blocks[n])
+        return n + 1, jnp.where((n < nb)[:, None], new, state)
+
+    state0 = jnp.broadcast_to(jnp.asarray(_H0), (lanes, 8))
+    _, state = jax.lax.while_loop(cond, body, (jnp.int32(0), state0))
+    return state[:N]
 
 
 def _select_boundaries_device(pos_s, ns, pos_l, nl, valid_len, *,
@@ -478,9 +555,8 @@ def chunk_hash_segment(data: jax.Array, valid_len, *, min_size: int,
     tail_page = jnp.maximum(end - 1, 0) // LEAF_SIZE
     tail_len = end - tail_page * LEAF_SIZE
     with jax.named_scope("tail_sha"):
-        tail_dig = sha256_chunks_device(
-            data, (tail_page * LEAF_SIZE)[None],
-            jnp.where(has_tail, tail_len, 0)[None], max_len=LEAF_SIZE)
+        tail_dig = _tail_leaf_digests(
+            data, tail_page[None], jnp.where(has_tail, tail_len, 0)[None])
         flat = _apply_tail_overrides(flat, n_pages_pad, tail_page[None],
                                      tail_dig, has_tail[None])
 
@@ -597,11 +673,10 @@ def _chunk_hash_segments_impl(data: jax.Array, valid_len: jax.Array,
     tail_page = jnp.arange(S, dtype=jnp.int32) * F + tail_page_local
     tail_len = end - tail_page_local * LEAF_SIZE
     with jax.named_scope("tail_sha"):
-        tail_dig = sha256_chunks_device(
-            data, jnp.clip(tail_page * LEAF_SIZE, 0, S * P - 1),
-            jnp.where(has_tail, tail_len, 0), max_len=LEAF_SIZE)  # [S, 8]
+        tail_dig = _tail_leaf_digests(
+            data, tail_page, jnp.where(has_tail, tail_len, 0))  # [S, 8]
         digests = _apply_tail_overrides(digests, npp, tail_page,
-                                        tail_dig[:S], has_tail)
+                                        tail_dig, has_tail)
 
     # --- roots: one flat S*chunk_cap-lane loop over the shared digest
     # table (page0 offset per lane's segment)
@@ -690,7 +765,8 @@ def span_roots_device(data: jax.Array, starts: jax.Array,
     ``rclone sync --checksum``): many whole files pack into one buffer
     at page-aligned offsets, so all full Merkle leaves are pages of the
     buffer (hashed contiguously, no gather) and only each span's final
-    partial leaf — at most one per span — pays the gather path. Returns
+    partial leaf — at most one per span — goes through the tail stage
+    (``_tail_leaf_digests``: N row gathers, one loop). Returns
     [N, 8] uint32 roots (garbage on padding lanes).
 
     Unlike chunk_hash_segment there is no boundary walk: the spans ARE
@@ -720,11 +796,11 @@ def span_roots_device(data: jax.Array, starts: jax.Array,
     has_tail = live & (lens_c % LEAF_SIZE != 0)
     tail_page = jnp.maximum(end - 1, 0) // LEAF_SIZE
     tail_len = end - tail_page * LEAF_SIZE
-    tail_dig = sha256_chunks_device(
-        data, jnp.clip(tail_page * LEAF_SIZE, 0, P - 1),
-        jnp.where(has_tail, tail_len, 0), max_len=LEAF_SIZE)  # [n_cap, 8]
-    flat = _apply_tail_overrides(flat, n_pages_pad, tail_page, tail_dig,
-                                 has_tail)
+    with jax.named_scope("tail_sha"):
+        tail_dig = _tail_leaf_digests(
+            data, tail_page, jnp.where(has_tail, tail_len, 0))  # [N, 8]
+        flat = _apply_tail_overrides(flat, n_pages_pad, tail_page,
+                                     tail_dig, has_tail)
 
     nleaves = jnp.where(live,
                         jnp.maximum((lens_c + LEAF_SIZE - 1) // LEAF_SIZE, 1),

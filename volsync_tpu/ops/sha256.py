@@ -25,8 +25,10 @@ Two packing paths:
   path, small metadata).
 - ``sha256_chunks_device``: given a device-resident byte buffer and chunk
   (start, length) vectors, builds padded message blocks *on device* with
-  gathers + masks — no host round-trip. This is the bulk data path used by
-  the chunk/hash engine.
+  gathers + masks — no host round-trip. The path for chunks at arbitrary
+  byte offsets; full 4 KiB leaves go through the page kernels below and
+  the fused programs' page-aligned tail leaf through
+  ``ops/segment._tail_leaf_digests``.
 """
 
 from __future__ import annotations
@@ -443,6 +445,16 @@ def sha256_chunks_device(data: jax.Array, starts: jax.Array,
     on device with gathers and index masks, so the bulk path never leaves
     HBM. Lanes may have length 0 (digest of empty string — masked out by
     callers as needed).
+
+    This is the hasher for chunks at ARBITRARY byte offsets (the
+    split-phase engine's leaves, the mesh's gather lanes): a byte gather
+    a lane and a scan of always ``max_len // 64 + 1`` compressions over
+    exactly B lanes, whatever the lengths. The page-aligned partial tail
+    leaf of the fused segment programs is NOT served here but by
+    ``ops/segment._tail_leaf_digests`` (row gather, a loop to the longest
+    live tail, never fewer than 16 lanes: on a v5e ONE lane costs 62 us
+    a compression, sixteen 2 us together), which keeps this one as its
+    test oracle.
     """
     assert max_len < (1 << 28), "bit length packed in uint32 lanes"
     B = starts.shape[0]

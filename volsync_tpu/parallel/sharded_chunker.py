@@ -392,12 +392,9 @@ def _build_fused_fn(mesh, params: GearParams, shard_len: int,
         _page_digests_flat,
         _root_digests_loop,
         _select_boundaries_device,
+        _tail_leaf_digests,
     )
-    from volsync_tpu.ops.sha256 import (
-        _LANE_TILE,
-        sha256_chunks_device,
-        use_pallas_leaves,
-    )
+    from volsync_tpu.ops.sha256 import _LANE_TILE, use_pallas_leaves
 
     p = params
     S = mesh.devices.size
@@ -474,12 +471,11 @@ def _build_fused_fn(mesh, params: GearParams, shard_len: int,
         tail_page = jnp.maximum(end - 1, 0) // _LEAF
         tail_len = end - tail_page * _LEAF
         owner = tail_page // npp
-        loc_off = (tail_page % npp) * _LEAF
         mine = has_tail & (owner == i)
         with jax.named_scope("tail_sha"):
-            t_dig = sha256_chunks_device(
-                row, loc_off[None], jnp.where(mine, tail_len, 0)[None],
-                max_len=_LEAF)[0]
+            t_dig = _tail_leaf_digests(
+                row, (tail_page % npp)[None],
+                jnp.where(mine, tail_len, 0)[None])[0]
             t_dig = jax.lax.psum(
                 jnp.where(mine, t_dig, jnp.uint32(0)), SEQ)
             ovr = jnp.where(has_tail,
