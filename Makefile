@@ -4,38 +4,36 @@
 
 .PHONY: lint lint-locks lint-buf lint-fx test chaos chaos-concurrent chaos-fleet \
 	chaos-restore chaos-scrub chaos-ec scrub-smoke static-check \
-	bench-index-smoke service-bench-smoke fleet-bench-smoke \
-	restore-bench-smoke copies-smoke syncplan-bench-smoke \
-	ec-bench-smoke trace-smoke session-smoke clean-lint
+	trace-smoke session-smoke clean-lint
 
-# Cached SARIF lint over the whole tree (package + scripts/ + bench.py):
+# Cached SARIF lint over the whole tree (package + scripts/):
 # all rule families, VL001-VL005 + VL105/VL106 + VL301 per-file + VL101-VL104
 # interprocedural + VL201-VL205 shape/dtype abstract interpretation +
 # VL401-VL404 static concurrency + VL501-VL505 buffer provenance +
 # VL601-VL605 fault paths, no baseline. Warm runs re-analyze zero
 # files; see docs/development.md.
 lint:
-	python -m volsync_tpu.analysis volsync_tpu/ scripts/ bench.py \
+	python -m volsync_tpu.analysis volsync_tpu/ scripts/ \
 	    --no-baseline --format sarif --out lint.sarif --cache .lint-cache
 
 # Just the static concurrency family (VL401-VL404), with the lock
 # acquisition-order graph exported for inspection.
 lint-locks:
-	python -m volsync_tpu.analysis volsync_tpu/ scripts/ bench.py \
+	python -m volsync_tpu.analysis volsync_tpu/ scripts/ \
 	    --no-baseline --select VL4 --dump-lock-graph lock-graph.json
 
 # Just the buffer-provenance family (VL501-VL505), with the provenance
 # graph (sanctioned sites, function summaries, arg->param flow edges)
 # exported for inspection.
 lint-buf:
-	python -m volsync_tpu.analysis volsync_tpu/ scripts/ bench.py \
+	python -m volsync_tpu.analysis volsync_tpu/ scripts/ \
 	    --no-baseline --select VL5 --dump-provenance provenance.json
 
 # Just the fault-path family (VL601-VL605), with the effect graph
 # (resolved laws, per-function effect/raise summaries, retry-policy
 # edges) exported for inspection.
 lint-fx:
-	python -m volsync_tpu.analysis volsync_tpu/ scripts/ bench.py \
+	python -m volsync_tpu.analysis volsync_tpu/ scripts/ \
 	    --no-baseline --select VL6 --dump-effects effects.json
 
 test:
@@ -118,61 +116,6 @@ chaos-ec:
 
 static-check:
 	scripts/static_check.sh
-
-# Small-scale metadata-plane bench (docs/performance.md): exercises the
-# batched/sharded/prefiltered index paths end to end and fails loudly
-# if any of them regress into errors. Scale-accurate numbers need the
-# full run: `python bench.py index` (1M entries).
-bench-index-smoke:
-	JAX_PLATFORMS=cpu python bench.py index --entries 50000 \
-	    --queries 20000
-
-# Closed-loop multi-tenant service bench on CPU at smoke scale
-# (docs/service.md): drives the admission + WDRR scheduling stack end
-# to end and asserts the JSON contract (per-tenant latencies, shed
-# accounting, provenance block) so the bench stays runnable.
-service-bench-smoke:
-	VOLSYNC_SVCBENCH_SMOKE=1 python scripts/service_bench.py
-
-# Fleet-mode service bench at smoke scale (docs/service.md): 2 replica
-# servers behind the FleetRouter with a mid-phase replica kill; the
-# script asserts the fleet JSON contract (per-replica breakdown, fleet
-# p50/p99 + goodput, failover accounting, kill event, provenance).
-fleet-bench-smoke:
-	VOLSYNC_SVCBENCH_SMOKE=1 VOLSYNC_SVCBENCH_REPLICAS=2 \
-	    VOLSYNC_SVCBENCH_KILL=1 python scripts/service_bench.py
-
-# Restore data plane bench at smoke scale (docs/performance.md,
-# "Restore data plane"): serial-vs-pipelined-vs-storm over a 40 ms
-# fake store; asserts its JSON contract stays runnable (speedup,
-# storm_fetch_ratio, cache hit ratio, per-stage spans, provenance).
-# Scale-accurate numbers need the full run: `python bench.py restore`.
-restore-bench-smoke:
-	python bench.py restore --smoke
-
-# Zero-copy contract gate (docs/performance.md, "Zero-copy data
-# movement"): backup + restore data planes at smoke scale; fails on a
-# ledgered copy site outside obs.SANCTIONED_SITES or a copy_ratio over
-# the committed COPY_RATIO_MAX threshold stamped in the artifact.
-copies-smoke:
-	python bench.py copies-smoke
-
-# Protocol-planner replay at smoke scale (docs/performance.md,
-# "Protocol planner"): three canned workloads (cold full, 1%-churn,
-# high-dedup) measured with the real engines — batched delta scan,
-# real TreeBackup dedup — then scored against the oracle; asserts the
-# planner matches the cheapest protocol per workload (regret <= 1.05)
-# and the bench JSON contract stays runnable.
-syncplan-bench-smoke:
-	python bench.py syncplan --smoke
-
-# Erasure-coding bench at smoke scale (docs/performance.md): device vs
-# NumPy GF(2^8) encode/decode throughput, reconstruct-vs-mirror-fetch
-# latency, and the measured storage overhead asserted at <= 1.5x.
-# Scale-accurate numbers need the full run: `python bench.py ec`
-# (committed artifact: BENCH_EC_r01.json).
-ec-bench-smoke:
-	python bench.py ec --smoke
 
 # Flight-recorder gate (docs/observability.md): a tiny pipelined backup
 # under a tenant-tagged trace must export a Perfetto-loadable

@@ -39,7 +39,6 @@ import sys
 import tempfile
 import threading
 import time
-import warnings
 from pathlib import Path
 
 import numpy as np
@@ -96,14 +95,12 @@ FULL = Sizes(
 
 class Observer:
     """Process-wide counters the phase lines are cut from: backend
-    compiles (count, seconds), persistent-cache hits, and any
-    'donated buffers were not usable' warning."""
+    compiles (count, seconds) and persistent-cache hits."""
 
     def __init__(self):
         self.compiles = 0
         self.compile_seconds = 0.0
         self.cache_hits = 0
-        self.donation_warnings: list[str] = []
         self._lock = threading.Lock()
 
     def install(self) -> None:
@@ -111,15 +108,6 @@ class Observer:
 
         mon.register_event_duration_secs_listener(self._on_duration)
         mon.register_event_listener(self._on_event)
-        prev = warnings.showwarning
-
-        def showwarning(message, category, filename, lineno, *a, **k):
-            if "donated buffers were not usable" in str(message):
-                with self._lock:
-                    self.donation_warnings.append(str(message)[:200])
-            prev(message, category, filename, lineno, *a, **k)
-
-        warnings.showwarning = showwarning
 
     def _on_duration(self, event: str, duration: float, **_kw) -> None:
         if event == "/jax/core/compile/backend_compile_duration":
@@ -183,7 +171,7 @@ class phase:
 def refuse_overrides(environ=None) -> None:
     """The smoke proves the defaults users get. Any VOLSYNC_* variable
     in the environment could steer a phase off the device path
-    (batching, donation, pipelines, verify, engine, workers...)."""
+    (batching, pipelines, verify, engine, workers...)."""
     environ = os.environ if environ is None else environ
     forced = sorted(k for k in environ if k.startswith("VOLSYNC_"))
     check(not forced, f"VOLSYNC_* overrides set: {forced}; unset them")
@@ -591,9 +579,7 @@ def phase_kernel_proof(seed: int) -> None:
         single = seg.chunk_hash_segment.lower(
             jax.ShapeDtypeStruct((P,), jnp.uint8), np.int32(P), eof=True,
             **kw).compile()
-        batched_fn = (seg.chunk_hash_segments_donated if seg._use_donation()
-                      else seg.chunk_hash_segments)
-        batched = batched_fn.lower(
+        batched = seg.chunk_hash_segments.lower(
             jax.ShapeDtypeStruct((2 * P,), jnp.uint8),
             jax.ShapeDtypeStruct((2,), jnp.int32),
             jax.ShapeDtypeStruct((2,), jnp.bool_), **kw).compile()
@@ -617,7 +603,6 @@ def phase_kernel_proof(seed: int) -> None:
         for s, n, d in chunks:
             check(d == blobid.blob_id(view[s: s + n]),
                   f"fused blob id at {s} != hashlib")
-        out["donating_variant"] = batched_fn is seg.chunk_hash_segments_donated
 
 
 # -- --chips 4: the mesh engine against the single-chip engine ---------------
@@ -701,7 +686,6 @@ def run(chips: int, seed: int, sizes: Sizes, work: Path) -> dict:
     print(json.dumps({"phase": "total", "compiles": c[0],
                       "compile_seconds": round(c[1], 3),
                       "compile_cache_hits": c[2],
-                      "donation_warnings": OBS.donation_warnings[:3],
                       "peak_device_bytes": peak_device_bytes()}),
           flush=True)
     return device

@@ -7,8 +7,8 @@ One script, three granularities of the same measurement — pick with
         with block_until_ready between dispatches, plus the end-to-end
         shipped protocol (fused program + result fetch) and the
         dispatch round-trip floor.
-  v2    fenced, salted stage split (tune_sha.py methodology:
-        scalar-fetch fence, per-iteration salts): full pipeline vs
+  v2    fenced, salted stage split (scalar-fetch fence,
+        per-iteration salts): full pipeline vs
         page digests vs gear+walk.
   v3    finest-grain gear-side isolation: gear only, +compaction,
         +successor tables, +FastCDC walk, full fused.
@@ -130,7 +130,7 @@ def run_base(seg_mib: int, iters: int) -> None:
 
 
 def _fence_timeit(name, fn, base, N, iters):
-    """Salted scalar-fetch fence (tune_sha.py methodology): the scalar
+    """Salted scalar-fetch fence: the scalar
     result forces execution; per-iteration salts keep every timed
     call's arguments distinct."""
     float(fn(base, jnp.uint8(0)))

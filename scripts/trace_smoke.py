@@ -32,8 +32,8 @@ def _run_tiny_pipeline() -> None:
     smoke.pipeline span every other span parents to."""
     import numpy as np
 
-    from bench import _HostSegmentHasher
-    from volsync_tpu.engine.chunker import stream_chunk_batches
+    from volsync_tpu.engine.chunker import (
+        DeviceChunkHasher, stream_chunk_batches)
     from volsync_tpu.objstore.store import MemObjectStore
     from volsync_tpu.obs import (
         reset_spans, reset_trace, span, trace_context)
@@ -60,7 +60,7 @@ def _run_tiny_pipeline() -> None:
         with span("smoke.pipeline"):
             for chunks in stream_chunk_batches(
                     reader, params, segment_size=512 * 1024,
-                    hasher=_HostSegmentHasher(chunk_size=128 * 1024),
+                    hasher=DeviceChunkHasher(params),
                     readahead=2):
                 repo.add_blobs(
                     "data", [(digest, chunk) for chunk, digest in chunks])

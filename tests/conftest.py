@@ -2,8 +2,8 @@
 
 Mirrors the reference's envtest strategy (SURVEY.md §4): everything below
 e2e runs without real hardware. Multi-chip sharding tests use the 8 virtual
-CPU devices; real-TPU behavior is covered by bench.py / the driver's
-compile checks.
+CPU devices; real-TPU behavior is covered by chip_smoke.py, the
+benchmark's cells (benchmark/) and tests/test_chip_compile.py.
 """
 
 import os
@@ -51,6 +51,32 @@ def _fresh_breakers():
     from volsync_tpu.resilience import reset_breakers
 
     reset_breakers()
+
+
+@pytest.fixture
+def batch_segments(monkeypatch):
+    """-> force(on): what follows runs with VOLSYNC_BATCH_SEGMENTS
+    forced on (the shared batcher and ``chunk_hash_segments``: the way
+    every cell of the benchmark reaches the one-chip engine) or off
+    (the single-lane ``chunk_hash_segment`` the suite pins above). The
+    batchers the test started are stopped when it ends."""
+    from volsync_tpu.ops import batcher
+
+    monkeypatch.setattr(batcher, "_SHARED", {})
+
+    def force(on: bool) -> None:
+        monkeypatch.setenv("VOLSYNC_BATCH_SEGMENTS", "1" if on else "0")
+
+    yield force
+    for b in batcher._SHARED.values():
+        b.stop()
+
+
+@pytest.fixture(params=[False, True], ids=["single-lane", "batcher"])
+def batched(request, batch_segments):
+    """Both ways to the one-chip engine, a case each."""
+    batch_segments(request.param)
+    return request.param
 
 
 @pytest.fixture

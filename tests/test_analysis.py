@@ -4,7 +4,7 @@ resolution
 over the committed mini-package in ``analysis_fixtures/``, baseline
 add/expire, suppression comments, SARIF emission, the incremental
 cache, and the tier-1 gate — `volsync lint` runs clean over the
-shipped package, ``scripts/`` and ``bench.py`` with NO baseline."""
+shipped package and ``scripts/`` with NO baseline."""
 
 import json
 from pathlib import Path
@@ -676,9 +676,9 @@ def test_volsync_cli_lint_verb(tmp_path):
 # -- the tier-1 gate --------------------------------------------------------
 
 def test_package_is_lint_clean():
-    """The whole shipped tree — the package, ``scripts/`` and
-    ``bench.py`` — passes every rule (per-file AND interprocedural)
-    with NO baseline: the repo's stated invariants (env reads via
+    """The whole shipped tree — the package and ``scripts/`` —
+    passes every rule (per-file AND interprocedural) with NO
+    baseline: the repo's stated invariants (env reads via
     envflags, gated imports, no silent swallows, tracer-safe kernels,
     lockcheck-routed locks, no blocking I/O under locks, named/joined
     threads, exception-safe acquires) hold right now, and this test
@@ -686,9 +686,9 @@ def test_package_is_lint_clean():
     pkg = Path(volsync_tpu.__file__).resolve().parent
     paths = [str(pkg)]
     repo_root = pkg.parent
-    for extra in (repo_root / "scripts", repo_root / "bench.py"):
-        if extra.exists():  # absent when only the package is installed
-            paths.append(str(extra))
+    scripts = repo_root / "scripts"
+    if scripts.exists():  # absent when only the package is installed
+        paths.append(str(scripts))
     findings, errors = run_lint(paths)
     assert errors == []
     assert findings == [], "\n" + "\n".join(f.render() for f in findings)

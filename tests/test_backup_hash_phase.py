@@ -51,8 +51,14 @@ def tree(tmp_path, rng):
     return root, files
 
 
-def run_backup(root, protocol="cdc", repo=None):
-    repo = repo or Repository.init(MemObjectStore(), chunker=CHUNKER)
+#: the page-aligned format, scaled down: the one whose segments may go
+#: through the shared batcher (conftest.py ``batched``)
+CHUNKER_4K = {"min_size": 4096, "avg_size": 32768, "max_size": 65536,
+              "seed": 7, "align": 4096}
+
+
+def run_backup(root, protocol="cdc", repo=None, chunker=CHUNKER):
+    repo = repo or Repository.init(MemObjectStore(), chunker=chunker)
     snap_id, stats = TreeBackup(repo, protocol=protocol).run(root)
     return repo, dict(repo.list_snapshots())[snap_id], stats
 
@@ -99,13 +105,22 @@ def test_entries_describe_the_files(tree, protocol):
 
 
 @pytest.mark.parametrize("protocol", ["cdc", "full"])
-def test_two_backups_of_one_tree_store_the_same_packs(tree, protocol):
-    """Nothing in the phase depends on timing: the same tree, the same
-    blobs, and the same packs (blobs in the same order at the same
-    offsets), so the store lists the same data keys."""
+def test_two_backups_of_one_tree_store_the_same_packs(
+        tree, protocol, batch_segments, batched):
+    """Nothing in the phase depends on timing, nor on the way to the
+    device (the first backup hashes single-lane, the second as the case
+    says): the same tree, the same blobs, and the same packs (blobs in
+    the same order at the same offsets), so the store lists the same
+    data keys."""
     root, _ = tree
-    repo1, first, stats1 = run_backup(root, protocol)
-    repo2, second, stats2 = run_backup(root, protocol)
+    batch_segments(False)
+    repo1, first, stats1 = run_backup(root, protocol, chunker=CHUNKER_4K)
+    batch_segments(batched)
+    obs.reset_spans()
+    repo2, second, stats2 = run_backup(root, protocol, chunker=CHUNKER_4K)
+    if protocol == "cdc":  # "full" stores files whole, hashed on the host
+        assert ("ops.batch_dispatch" in obs.span_totals()) == batched
+        assert ("engine.fused_dispatch" in obs.span_totals()) != batched
     assert first["tree"] == second["tree"]
     assert repo1.blob_ids() == repo2.blob_ids()
     assert sorted(repo1.store.list("data/")) \

@@ -81,8 +81,8 @@ def test_kernel_proof_fails_without_kernels():
 
 
 def test_refuses_volsync_overrides():
-    with pytest.raises(chip_smoke.SmokeFailure, match="VOLSYNC_DONATE"):
-        chip_smoke.refuse_overrides({"VOLSYNC_DONATE": "0", "HOME": "/x"})
+    with pytest.raises(chip_smoke.SmokeFailure, match="VOLSYNC_ENGINE"):
+        chip_smoke.refuse_overrides({"VOLSYNC_ENGINE": "mesh", "HOME": "/x"})
     chip_smoke.refuse_overrides({"HOME": "/x", "JAX_PLATFORMS": "cpu"})
 
 

@@ -6,6 +6,7 @@ storage provider, real runner executing the data-plane entrypoint, real
 repository — only the hardware is the test CPU mesh.
 """
 
+import json
 import time
 
 import pytest
@@ -28,6 +29,7 @@ from volsync_tpu.controller.manager import Manager
 from volsync_tpu.metrics import Metrics
 from volsync_tpu.movers.base import Catalog
 from volsync_tpu.movers import restic as restic_mover
+from volsync_tpu.obs import reset_spans
 
 
 @pytest.fixture
@@ -69,9 +71,52 @@ def wait(cluster, pred, timeout=30.0):
     assert cluster.wait_for(pred, timeout=timeout, poll=0.05), "timed out"
 
 
-def test_backup_then_restore_roundtrip(world, rng):
+def assert_stored_as_the_reference_cuts(tmp_path, files: dict) -> None:
+    """Every snapshot of the test's repository lists each file with the
+    ids of the benchmark's plain references (numpy gear CDC, hashlib
+    blob ids) over its bytes: the same whichever way the engine went."""
+    from benchmark.reference import blobid as ref_blobid
+    from benchmark.reference import gearcdc as ref_gearcdc
+    from volsync_tpu.engine.chunker import params_from_config
+    from volsync_tpu.objstore import FsObjectStore
+    from volsync_tpu.repo.repository import DEFAULT_CHUNKER, Repository
+
+    p = params_from_config(DEFAULT_CHUNKER)
+    chunker = {**DEFAULT_CHUNKER, "norm_level": p.norm_level}
+    want = {rel: [ref_blobid.blob_id(data[s: s + n]) for s, n in (
+                ref_gearcdc.cuts(data, chunker) if len(data) > p.min_size
+                else [(0, len(data))])]
+            for rel, data in files.items()}
+    repo = Repository.open(FsObjectStore(tmp_path / "repo"),
+                           password="hunter2")
+
+    def walk(tree_id, prefix=""):
+        for e in json.loads(repo.read_blob(tree_id))["entries"]:
+            if e["type"] == "dir":
+                yield from walk(e["subtree"], prefix + e["name"] + "/")
+            else:
+                yield prefix + e["name"], e["content"]
+
+    snaps = repo.list_snapshots()
+    assert snaps
+    for _, snap in snaps:
+        assert dict(walk(snap["tree"])) == want
+
+
+def assert_way_to_the_device(batched: bool) -> None:
+    """A file went to the device, and by the way the case names."""
+    from volsync_tpu.obs import span_totals
+
+    spans = span_totals()
+    assert ("ops.batch_dispatch" in spans) == batched
+    assert ("engine.fused_dispatch" in spans) != batched
+
+
+def test_backup_then_restore_roundtrip(world, rng, batched):
     cluster, tmp_path = world
-    files = {"a.txt": b"alpha" * 1000, "sub/b.bin": rng.bytes(300_000)}
+    reset_spans()
+    # sub/b.bin is above the default chunker's min_size: a device file
+    files = {"a.txt": b"alpha" * 1000, "sub/b.bin": rng.bytes(700_000)}
     make_volume(cluster, "app-data", files)
     repo_secret(cluster, tmp_path)
 
@@ -122,11 +167,15 @@ def test_backup_then_restore_roundtrip(world, rng):
     # cleanup happened: the mover Job was collected after the iteration
     wait(cluster, lambda: cluster.try_get("Job", "default",
                                           "volsync-src-backup") is None)
+    assert_way_to_the_device(batched)
+    assert_stored_as_the_reference_cuts(tmp_path, files)
 
 
-def test_second_manual_sync_is_incremental(world, rng):
+def test_second_manual_sync_is_incremental(world, rng, batched):
     cluster, tmp_path = world
-    vol = make_volume(cluster, "data2", {"f.bin": rng.bytes(200_000)})
+    reset_spans()
+    files = {"f.bin": rng.bytes(600_000)}  # above min_size: a device file
+    vol = make_volume(cluster, "data2", files)
     repo_secret(cluster, tmp_path)
     rs = ReplicationSource(
         metadata=ObjectMeta(name="inc", namespace="default"),
@@ -159,6 +208,9 @@ def test_second_manual_sync_is_incremental(world, rng):
     assert len(snaps) == 2
     # second snapshot deduped everything (parent skip or blob dedup)
     assert snaps[1][1]["stats"]["bytes_new"] == 0
+    assert snaps[0][1]["tree"] == snaps[1][1]["tree"]
+    assert_way_to_the_device(batched)
+    assert_stored_as_the_reference_cuts(tmp_path, files)
 
 
 def test_misconfigured_spec_surfaces_error(world):

@@ -1,11 +1,13 @@
-"""The fused mesh path against the plain reference.
+"""The fused paths against the plain reference.
 
 ``tests/test_sharded_chunker.py`` holds the mesh engine to the one-chip
-engine; here it is held to the benchmark's plain reference of the
+engine; here both are held to the benchmark's plain reference of the
 repository format (``benchmark/reference/gearcdc.py``: numpy alone,
 ``benchmark/reference/blobid.py``: hashlib alone), which shares no code
-with either, on 4 of the suite's 8 virtual CPU devices — the four-chip
-host of ``dedup-1t.backup-mesh4`` with the chunker scaled down.
+with either: the mesh on 4 of the suite's 8 virtual CPU devices — the
+four-chip host of ``dedup-1t.backup-mesh4`` with the chunker scaled
+down — and the one-chip engine both ways, the single-lane program the
+suite pins and the shared batcher the one-chip cells run.
 """
 
 import io
@@ -15,7 +17,8 @@ import pytest
 
 from benchmark.reference import blobid as ref_blobid
 from benchmark.reference import gearcdc as ref_gearcdc
-from volsync_tpu.engine.chunker import _segment_source, stream_chunks
+from volsync_tpu.engine.chunker import (DeviceChunkHasher, _buffer_bucket,
+                                        _segment_source, stream_chunks)
 from volsync_tpu.obs import (copies_by_site, counter_totals, reset_copies,
                              reset_spans, span_totals)
 from volsync_tpu.ops.gearcdc import GearParams
@@ -37,6 +40,15 @@ def hasher():
     return MeshChunkHasher(FUSED, make_stream_mesh(jax.devices()[:SHARDS]))
 
 
+@pytest.fixture(params=["mesh", "one-chip", "one-chip-batcher"])
+def engine(request, batch_segments):
+    """Every fused way to the cuts and ids of a segment."""
+    if request.param == "mesh":
+        return request.getfixturevalue("hasher")
+    batch_segments(request.param == "one-chip-batcher")
+    return DeviceChunkHasher(FUSED)
+
+
 def random_bytes(n: int, seed: int) -> bytes:
     return np.random.default_rng([seed, n]).bytes(n)
 
@@ -51,34 +63,35 @@ def reference(data: bytes) -> list[tuple[int, int, str]]:
     SHARDS * PAGE * 61 + PAGE,    # whole pages, not a multiple of shards
     SHARDS * 262144,              # fills the shards' bucket to the byte
 ])
-def test_one_segment_cuts_and_ids_are_the_references(hasher, length):
+def test_one_segment_cuts_and_ids_are_the_references(engine, length):
     data = random_bytes(length, 1)
-    assert length % (SHARDS * PAGE) or length == hasher.buffer_bucket(length)
-    assert hasher.process(data, eof=True) == reference(data)
+    bucket = getattr(engine, "buffer_bucket", _buffer_bucket)
+    assert length % (SHARDS * PAGE) or length == bucket(length)
+    assert engine.process(data, eof=True) == reference(data)
 
 
 @pytest.mark.parametrize("first", [600_000, 3 * 262144 + 17])
-def test_two_segments_eof_false_then_true(hasher, first):
+def test_two_segments_eof_false_then_true(engine, first):
     """The tail a non-eof segment withholds is re-fed with what follows,
     as a stream does it; the two passes together are the reference's
     cuts of the whole."""
     data = random_bytes(1_100_000, 2)
-    head = hasher.process(data[:first], eof=False)
+    head = engine.process(data[:first], eof=False)
     consumed = sum(n for _, n, _ in head)
     assert 0 < consumed < first and consumed % PAGE == 0
-    rest = hasher.process(data[consumed:], eof=True)
+    rest = engine.process(data[consumed:], eof=True)
     got = head + [(consumed + s, n, d) for s, n, d in rest]
     assert got == reference(data)
 
 
-def test_repeat_half_resynchronises(hasher):
+def test_repeat_half_resynchronises(engine):
     """A stream whose second half repeats its first (the deployment's
     shape): past the first cut after the seam the second half's chunks
     are the first half's, id for id, whichever shards they fell in."""
     half = 131 * PAGE  # the seam is on the page grid, not on a shard's
     uniq = random_bytes(half, 3)
     data = uniq + uniq
-    got = hasher.process(data, eof=True)
+    got = engine.process(data, eof=True)
     assert got == reference(data)
     first = [(s, n, d) for s, n, d in got if s + n <= half]
     second = {(s - half, n, d) for s, n, d in got if s >= half}
@@ -136,9 +149,57 @@ def test_stream_segment_follows_the_shards(hasher, nbytes):
         assert counts["mesh.bytes_valid"] / staged > 0.85
 
 
+@pytest.mark.parametrize("kind", ["mesh", "one-chip", "fake"])
+def test_stream_hands_a_hasher_its_view(hasher, kind, monkeypatch):
+    """The two arms of ``stream_chunk_batches``: a hasher with ``begin``
+    gets a view of exactly ``buffer_bucket(length)`` bytes whose tail
+    past ``valid_len`` is zero, whatever the pooled buffer held before;
+    an object with ``process`` alone (a test fake) gets the exact view."""
+    import hashlib
+
+    segment = 256 * 1024
+    seen = []
+
+    class Fake:
+        def process(self, buffer, *, eof):
+            seen.append((int(buffer.shape[0]), None, False))
+            step = FUSED.max_size
+            end = len(buffer) if eof else len(buffer) // step * step
+            return [(s, min(step, end - s),
+                     hashlib.sha256(buffer[s: s + step][: end - s]).hexdigest())
+                    for s in range(0, end, step)]
+
+    if kind == "fake":
+        h, bucket = Fake(), None
+    else:
+        h = hasher if kind == "mesh" else DeviceChunkHasher(FUSED)
+        bucket = getattr(h, "buffer_bucket", _buffer_bucket)
+        real = h.begin
+
+        def begin(buffer, *, eof, valid_len):
+            seen.append((int(buffer.shape[0]), valid_len,
+                         bool(buffer[valid_len:].any())))
+            return real(buffer, eof=eof, valid_len=valid_len)
+
+        monkeypatch.setattr(h, "begin", begin)
+    # a first stream of 0xFF leaves the pool's buffers dirty
+    for data in (b"\xff" * 2_300_000, random_bytes(1_000_003, 7)):
+        seen.clear()
+        got = b"".join(bytes(c) for c, _ in stream_chunks(
+            io.BytesIO(data).read, FUSED, segment_size=segment, hasher=h))
+        assert got == data  # and no view is left to keep a buffer parked
+        assert seen
+        for have, valid, dirty in seen:
+            if bucket is None:
+                assert valid is None  # process(): nothing but the data
+            else:
+                assert have == bucket(valid) and not dirty
+    if bucket is None:
+        assert any(have != _buffer_bucket(have) for have, _, _ in seen)
+
+
 def test_one_segment_source_is_unchanged_for_the_one_chip_engine():
-    from volsync_tpu.engine.chunker import (DeviceChunkHasher, _SegmentFill,
-                                            _buffer_bucket)
+    from volsync_tpu.engine.chunker import _SegmentFill
 
     segment = 32 * 1024 * 1024
     p = GearParams(align=4096)

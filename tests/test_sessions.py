@@ -391,10 +391,39 @@ def test_status_mirror_written_on_transitions(tmp_path):
     assert mirrored["epoch"] == sup.epoch
 
 
+def test_kill_marked_children_hits_only_the_marker():
+    """The session supervisor's force-release SIGKILLs exactly the
+    processes carrying the measurement-child environment marker and
+    nothing else. Uses a per-test sentinel marker so the sweep can
+    never touch a real session running elsewhere on the host."""
+    import os
+    import signal
+    import subprocess
+    import sys
+    import time
+
+    sentinel = f"VOLSYNC_SESSION_TEST_{os.getpid()}"
+    stale = subprocess.Popen(
+        [sys.executable, "-c", "import time; time.sleep(120)"],
+        env={**os.environ, "VOLSYNC_SESSION_SENTINEL": sentinel})
+    bystander = subprocess.Popen(
+        [sys.executable, "-c", "import time; time.sleep(120)"],
+        env=dict(os.environ))
+    try:
+        time.sleep(0.3)
+        killed = kill_marked_children(
+            f"VOLSYNC_SESSION_SENTINEL={sentinel}")
+        assert killed == 1
+        assert stale.wait(timeout=10) == -signal.SIGKILL
+        assert bystander.poll() is None  # untouched
+    finally:
+        for p in (stale, bystander):
+            if p.poll() is None:
+                p.kill()
+
+
 def test_kill_marked_children_ignores_unmatched_marker():
-    # the real targeted-kill behavior (marker hit, bystander spared) is
-    # asserted in tests/test_bench_harness.py; here: a sentinel marker
-    # that matches nothing must be a harmless no-op
+    # a sentinel marker that matches nothing must be a harmless no-op
     assert kill_marked_children("VOLSYNC_NO_SUCH_SENTINEL=1",
                                 log_fn=lambda _m: None) == 0
 

@@ -45,8 +45,6 @@ from volsync_tpu.obs import (
     use_context,
 )
 
-SCRIPTS = str(Path(__file__).resolve().parent.parent / "scripts")
-
 
 @pytest.fixture(autouse=True)
 def _clean_obs():
@@ -288,7 +286,7 @@ def test_trigger_throttling(monkeypatch, tmp_path):
 # -- the closed-loop service acceptance -----------------------------------
 
 def test_service_closed_loop_trace_acceptance():
-    """A closed-loop service_bench run: one stream's spans nest
+    """A closed-loop run (tests/closed_loop.py): one stream's spans nest
     client -> admission -> scheduler queue -> device batch under a
     single trace id, tagged with tenant + stream id, and the summed
     component breakdown accounts for >= 90% of the enclosing
@@ -299,9 +297,7 @@ def test_service_closed_loop_trace_acceptance():
     instead. The metric used to divide by the client-measured p50
     with no wait instrumentation, and flaked this gate whenever the
     CPU was saturated (bronze coverage 0.74)."""
-    if SCRIPTS not in sys.path:
-        sys.path.insert(0, SCRIPTS)
-    from service_bench import run_closed_loop
+    from closed_loop import run_closed_loop
     from volsync_tpu.ops.gearcdc import GearParams
 
     params = GearParams(min_size=64 * 1024, avg_size=128 * 1024,
@@ -322,9 +318,9 @@ def test_service_closed_loop_trace_acceptance():
         for stage in ("svc.stream", "svc.admit", "svc.batch"):
             assert tn["stages_s"].get(stage, 0) > 0, (name, tn["stages_s"])
         assert tn["stage_coverage"] >= 0.9, (name, tn)
-    # provenance self-describes where the time went (satellite 3)
-    prov_spans = res["provenance"]["trace"]["spans"]
-    assert "svc.batch" in prov_spans and "client.chunk_stream" in prov_spans
+    # the process-wide totals hold both ends of the stream
+    totals = span_totals()
+    assert "svc.batch" in totals and "client.chunk_stream" in totals
 
     # flight recorder: find one fully-nested stream
     evs = [e for e in trace_events() if e["ph"] == "X"]
@@ -702,8 +698,8 @@ def test_tracing_disabled_overhead_under_2pct(monkeypatch):
     """Acceptance: with sampling off and no active context (the
     pipeline smoke's disabled-tracing configuration) one span() costs
     < 2% of one segment-scale sha256 — the per-span workload unit of
-    `bench.py pipeline`, which opens one span per ~MiB-sized
-    hash/seal/upload stage. The two costs are measured separately
+    a backup, which opens one span per ~MiB-sized hash/seal/upload
+    stage. The two costs are measured separately
     (min-of-5 each) because the span cost (~µs) is far below the
     run-to-run noise of a combined wall-clock comparison."""
     monkeypatch.setenv("VOLSYNC_TRACE_SAMPLE", "0")

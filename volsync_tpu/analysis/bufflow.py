@@ -3,7 +3,7 @@
 The zero-copy data plane moves payload bytes as pooled buffers
 (engine/bufpool.py) and memoryviews; the copy ledger
 (obs/copyledger.py) accounts for the sanctioned host copies that
-remain, and the donation twins (ops/segment.py) hand staged device
+remain, and a ``donate_argnums`` jit twin hands its staged device
 rows to XLA for reuse.  VL106 guards that contract syntactically; this
 module proves it semantically: an abstract provenance lattice per
 value —

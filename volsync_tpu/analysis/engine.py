@@ -133,7 +133,7 @@ def relativize(path: Path) -> str:
     """Cwd-relative posix path when ``path`` lives under the cwd, else
     the path as-is.  The single relativization policy for cache keys,
     scope decisions and dump/SARIF artifacts: an absolute
-    ``/root/repo/bench.py`` must not inherit a ``repo`` scope dir, and
+    ``/root/repo/chip_smoke.py`` must not inherit a ``repo`` scope dir, and
     dump files must not leak absolute checkout paths."""
     try:
         return path.relative_to(Path.cwd()).as_posix()

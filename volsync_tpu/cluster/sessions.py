@@ -29,8 +29,7 @@ Four pieces:
 - **BenchQueue** — the serialized verify-then-measure queue: jobs run
   strictly one-at-a-time behind a verify probe, are killed at a
   per-job hard deadline, and every result carries the session
-  provenance (backend, session id, fencing epoch) that
-  ``bench.bench_provenance`` stamps into BENCH_*.json.
+  provenance (backend, session id, fencing epoch).
 - **FakeSessionBackend** — deterministic seeded fault schedules in the
   ``objstore/faultstore.py`` style (probe hang, keepalive drop,
   zombie-holds-device, crash mid-job) so the whole supervisor is
@@ -38,9 +37,8 @@ Four pieces:
   real thing: subprocess probes with hard timeouts and a
   stale-measurement-child sweep as ``force_release``.
 
-``scripts/bench_self.py`` is a thin wrapper over this module via the
-``volsync session run/status/recycle`` CLI verbs
-(cluster/sessioncli.py).
+The ``volsync session run/status/recycle`` CLI verbs
+(cluster/sessioncli.py) are its one client.
 """
 
 from __future__ import annotations
@@ -550,7 +548,7 @@ class BenchQueue:
             finally:
                 self._notify("job_finished", sid)
                 self.supervisor.resume_keepalive()
-                # never join a possibly-wedged worker (bench.py rule)
+                # never join a possibly-wedged worker
                 pool.shutdown(wait=False, cancel_futures=True)
             elapsed = self._clock() - t0
             if elapsed >= deadline:

@@ -3,7 +3,7 @@
 Every cold process pays the device programs' compiles again (tens of
 seconds per ``(S, P)`` bucket of the batched segment program on a
 v5e), so each launcher — the operator, the mover-jax service,
-``bench.py``, ``chip_smoke.py`` and the tuning scripts — calls
+``chip_smoke.py`` and the profiling scripts — calls
 ``configure()`` before its first use of JAX. Where the environment
 places the cache (``JAX_COMPILATION_CACHE_DIR``) that directory is used
 and no other is set; otherwise it lives at ``<checkout>/.jax_cache``:

@@ -33,6 +33,12 @@ from volsync_tpu.ops.gearcdc import (
     cdc_candidates_aligned_packed,
     select_boundaries,
 )
+from volsync_tpu.ops.gearcdc import _pow2ceil_int as _pow2ceil
+from volsync_tpu.ops.segment import (
+    LEAF_SIZE,
+    FusedSegmentHasher,
+    _buffer_bucket,
+)
 from volsync_tpu.ops.sha256 import (
     sha256_chunks_device,
     sha256_leaves_device,
@@ -48,24 +54,6 @@ def params_from_config(cfg: dict) -> GearParams:
     return GearParams(min_size=cfg["min_size"], avg_size=cfg["avg_size"],
                       max_size=cfg["max_size"], seed=cfg["seed"],
                       align=cfg.get("align", 1))
-
-
-def _pow2ceil(n: int, lo: int = 1) -> int:
-    v = lo
-    while v < n:
-        v *= 2
-    return v
-
-
-def _buffer_bucket(length: int) -> int:
-    """Pad target for input buffers. Shapes are static under jit, so an
-    unbounded variety of buffer lengths (every file tail is unique) would
-    mean a fresh multi-second XLA compile each — pad into a small fixed
-    set instead: pow2 up to 8 MiB, then multiples of 8 MiB."""
-    if length <= 8 * 1024 * 1024:
-        return _pow2ceil(length, 64 * 1024)
-    m = 8 * 1024 * 1024
-    return (length + m - 1) // m * m
 
 
 class DeviceChunkHasher:
@@ -89,6 +77,17 @@ class DeviceChunkHasher:
     64 <= align < 4096 keeps the split-phase pipeline (synchronous
     boundary walk, leaf hashing left in flight); align=1 the legacy
     shift-invariant path.
+
+    The hasher protocol, as ``stream_chunk_batches`` and ``TreeBackup``
+    use it (``parallel/sharded_chunker.MeshChunkHasher`` is the other
+    implementation): ``process(buffer, eof=)`` -> the cut list;
+    ``begin(buffer, eof=, valid_len=)`` -> a ``PendingSegment``, where
+    ``buffer`` may already be padded with zeros to the hasher's bucket
+    and ``valid_len`` says how much of it is data; optionally
+    ``buffer_bucket(length)`` (the pad target, else ``_buffer_bucket``)
+    and ``stream_segment_size(segment_size)`` (the fill a stream
+    should use, else the caller's). An object with ``process`` alone is
+    accepted as a test fake and gets exact-length views.
     """
 
     # Safe to drive from concurrent threads (the service's handlers
@@ -100,22 +99,11 @@ class DeviceChunkHasher:
     #: override their explicit per-request configuration.
     use_shared_batcher = True
 
-    #: ``begin()`` takes ``valid_len``: stream_chunk_batches hands it a
-    #: view already padded to the device bucket (zeroed pad lane), so no
-    #: np.pad copy happens per segment. Hashers without the kwarg
-    #: (bench hosts) get the exact-length view instead.
-    accepts_prepadded = True
-
     def __init__(self, params: GearParams):
         self.params = params
-        from volsync_tpu.ops.segment import LEAF_SIZE
-
-        if params.align == LEAF_SIZE:  # the page-aligned fused format
-            from volsync_tpu.ops.segment import FusedSegmentHasher
-
-            self.fused = FusedSegmentHasher(params)
-        else:
-            self.fused = None
+        # the page-aligned fused format, else split-phase or legacy
+        self.fused = (FusedSegmentHasher(params)
+                      if params.align == LEAF_SIZE else None)
 
     def process(self, buffer, *, eof: bool = True) -> list[tuple[int, int, str]]:
         """-> [(start, length, sha256-hex)] covering ``buffer`` (the tail
@@ -161,8 +149,7 @@ class DeviceChunkHasher:
             return PendingSegment(
                 [(0, length, blobid.blob_id(buffer[:length]))], None, None)
 
-        if (self.use_shared_batcher and self.fused is not None
-                and self.fused.segment_device_fn is None):
+        if self.use_shared_batcher and self.fused is not None:
             from volsync_tpu.ops.batcher import shared_batcher
 
             batcher = shared_batcher(p)
@@ -184,13 +171,6 @@ class DeviceChunkHasher:
             elif have > padded:
                 buffer = buffer[:padded]
             dev = jnp.asarray(buffer)
-        return self.begin_device(dev, length, eof=eof)
-
-    def begin_device(self, dev, length: int, *,
-                     eof: bool = True) -> "PendingSegment":
-        from volsync_tpu.obs import span
-
-        p = self.params
         if self.fused is not None:
             with span("engine.fused_dispatch"):
                 inflight = self.fused.dispatch(dev, length, eof=eof)
@@ -206,9 +186,7 @@ class DeviceChunkHasher:
             # Split-phase aligned path (64 <= align < 4096): leaf digests
             # stay in flight; chunks are known synchronously.
             plan = _leaf_plan(chunks)
-            dev_digests = _dispatch_leaves(
-                dev, plan[0], plan[1], plan[2],
-                leaf_fn=self.leaf_device_fn)
+            dev_digests = _dispatch_leaves(dev, plan[0], plan[1], plan[2])
             return PendingSegment.split_phase(chunks, (plan, dev_digests))
         # Legacy unaligned path: synchronous gather hashing.
         hexes = device_span_roots(dev, chunks, aligned=False)
@@ -216,25 +194,15 @@ class DeviceChunkHasher:
             [(int(s), int(l), h) for (s, l), h in zip(chunks, hexes)],
             None, None)
 
-    def process_device(self, dev, length: int, *,
-                       eof: bool = True) -> list[tuple[int, int, str]]:
-        """The device pipeline on an already-resident padded buffer —
-        what process() runs after upload, and what bench.py measures:
-        one fused dispatch (candidates -> on-device walk -> leaf digests
-        -> roots) plus its single result fetch."""
-        return self.begin_device(dev, length, eof=eof).finish()
-
     def _candidates(self, dev, length: int):
         p = self.params
         padded = int(dev.shape[0])
         if p.align > 1:
-            cand = self.cand_device_fn or (
-                lambda d, cap: cdc_candidates_aligned_packed(
-                    d, seed=p.seed, mask_s=p.mask_s, mask_l=p.mask_l,
-                    align=p.align, max_candidates=cap, valid_len=length))
             cap = 4096  # expected count: padded/avg_size << 4096
             while True:
-                packed = np.asarray(cand(dev, cap))
+                packed = np.asarray(cdc_candidates_aligned_packed(  # lint: ignore[VL501] the split-phase protocol's candidate fetch: one compacted table a segment, metadata not payload
+                    dev, seed=p.seed, mask_s=p.mask_s, mask_l=p.mask_l,
+                    align=p.align, max_candidates=cap, valid_len=length))
                 c = int(packed[-1])
                 if c <= cap:
                     break
@@ -258,12 +226,6 @@ class DeviceChunkHasher:
                 break
             cap = _pow2ceil(max(cs, cl), cap * 2)
         return np.asarray(idx_s)[:cs], np.asarray(idx_l)[:cl]
-
-    #: Override points for the two fused device dispatches (benchmarks
-    #: compose a content-salt into the same programs; None = the library
-    #: kernels sha256_leaves_device / cdc_candidates_aligned_packed).
-    leaf_device_fn = None
-    cand_device_fn = None
 
 
 def device_leaf_digests(dev, leaf_starts: list[int],
@@ -316,8 +278,7 @@ def _leaf_plan(chunks: list[tuple[int, int]]):
     return full_rows, short_starts, short_lengths, slot, spans
 
 
-def _dispatch_leaves(dev, full_rows, short_starts, short_lengths,
-                     leaf_fn=None):
+def _dispatch_leaves(dev, full_rows, short_starts, short_lengths):
     """Launch the single fused leaf dispatch; returns the in-flight
     [F + T, 8] device array (callers fetch it as late as possible)."""
     import jax.numpy as jnp
@@ -330,7 +291,7 @@ def _dispatch_leaves(dev, full_rows, short_starts, short_lengths,
     tl = np.zeros((lanes_t,), np.int32)
     ts[: len(short_starts)] = short_starts
     tl[: len(short_lengths)] = short_lengths
-    return (leaf_fn or sha256_leaves_device)(
+    return sha256_leaves_device(
         dev, jnp.asarray(rows), jnp.asarray(ts), jnp.asarray(tl),
         leaf_len=blobid.LEAF_SIZE), lanes_f
 
@@ -424,7 +385,7 @@ class PendingSegment:
 
 
 def device_span_roots(dev, chunks: list[tuple[int, int]], *,
-                      aligned: bool = False, leaf_fn=None) -> list[str]:
+                      aligned: bool = False) -> list[str]:
     """Merkle blob ids for (start, length) slices of the device buffer
     (repo/blobid.py): every 4 KiB leaf of every chunk hashes as one
     independent lane, then the tiny roots combine host-side.
@@ -437,7 +398,7 @@ def device_span_roots(dev, chunks: list[tuple[int, int]], *,
     if aligned:
         plan = _leaf_plan(chunks)
         dev_digests, lanes_f = _dispatch_leaves(
-            dev, plan[0], plan[1], plan[2], leaf_fn=leaf_fn)
+            dev, plan[0], plan[1], plan[2])
         return _assemble_roots(chunks, plan, np.asarray(dev_digests),
                                lanes_f)
     leaf_starts: list[int] = []
@@ -873,8 +834,6 @@ def stream_chunk_batches(reader: Callable[[int], bytes],
         ra = src = _SegmentReadahead(src, readahead)
     head = src.head
     begin = getattr(hasher, "begin", None)
-    prepadded = begin is not None and getattr(
-        hasher, "accepts_prepadded", False)
 
     def _dispatch(buf, start, fill, eof):
         length = fill - start
@@ -882,7 +841,7 @@ def stream_chunk_batches(reader: Callable[[int], bytes],
             if length == 0:
                 return PendingSegment([], None, None)
             arr = np.frombuffer(buf, np.uint8)
-            if prepadded:
+            if begin is not None:
                 # Hand the device a view already padded to its bucket:
                 # zero the pad lane in place (a memset over recycled
                 # buffer slack, not a payload copy) — no np.pad.
@@ -890,10 +849,7 @@ def stream_chunk_batches(reader: Callable[[int], bytes],
                 arr[fill: start + plen] = 0
                 return begin(arr[start: start + plen], eof=eof,
                              valid_len=length)
-            if begin is not None:
-                return begin(arr[start:fill], eof=eof)
-            # Engines without split-phase support (bench hosts) still
-            # work, just without the overlap.
+            # a test fake: process() alone, on the exact view
             return PendingSegment(
                 hasher.process(arr[start:fill], eof=eof), None, None)
 

@@ -136,17 +136,6 @@ def root_unroll() -> int:
     return env_int("VOLSYNC_ROOT_UNROLL", 4, minimum=1)
 
 
-def donate_device_inputs() -> Optional[bool]:
-    """VOLSYNC_DONATE tri-state: None when unset — callers fall back to
-    the backend-aware default (donate staged segment buffers into the
-    batched chunk-hash dispatch on TPU, where XLA reuses the donated
-    HBM; skip on CPU, where donation is ignored with a warning) — else
-    the forced bool."""
-    if os.environ.get("VOLSYNC_DONATE") is None:
-        return None
-    return env_bool("VOLSYNC_DONATE")
-
-
 # -- engine worker knobs (engine/restore.py) ----------------------------
 
 def backup_workers() -> int:
@@ -522,25 +511,6 @@ def session_job_deadline() -> float:
     serialized bench queue — a job is killed at this wall-clock bound,
     never allowed to hold the single-tenant device open-endedly."""
     return env_float("VOLSYNC_SESSION_JOB_DEADLINE_S", 1800.0, minimum=1.0)
-
-
-def session_id() -> Optional[str]:
-    """VOLSYNC_SESSION_ID: stamped into a job's environment by the
-    session queue so bench provenance can carry the supervised-session
-    identity; None when the process runs outside a session."""
-    return env_str("VOLSYNC_SESSION_ID")
-
-
-def session_epoch() -> int:
-    """VOLSYNC_SESSION_EPOCH: the fencing epoch stamped alongside
-    VOLSYNC_SESSION_ID (0 when unset)."""
-    return env_int("VOLSYNC_SESSION_EPOCH", 0)
-
-
-def session_backend() -> Optional[str]:
-    """VOLSYNC_SESSION_BACKEND: backend name stamped alongside
-    VOLSYNC_SESSION_ID."""
-    return env_str("VOLSYNC_SESSION_BACKEND")
 
 
 def session_status_path() -> Optional[str]:

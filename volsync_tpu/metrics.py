@@ -169,20 +169,12 @@ class Metrics:
         # won on price), "override" (VOLSYNC_SYNC_PROTO pinned it),
         # "probe" (forced exploration to seed an empty stat book),
         # "no_basis" (destination has no prior copy, delta impossible),
-        # "size_cap" (file too large for a whole-file blob) — plus the
-        # regret of the last replayed planning benchmark (chosen-protocol
-        # cost over oracle cost; 1.0 = planner matched the oracle).
+        # "size_cap" (file too large for a whole-file blob).
         # Label values are closed literal sets, so cardinality is fixed.
         self.svc_protocol_selected = Counter(
             "volsync_svc_protocol_selected_total",
             "Sync-protocol planner decisions, by protocol and reason",
             ["protocol", "reason"], registry=self.registry,
-        )
-        self.plan_regret = Gauge(
-            "volsync_plan_regret_ratio",
-            "Chosen-protocol cost over oracle cost for the last planner "
-            "replay (1.0 = optimal)",
-            registry=self.registry,
         )
         # Repository store locking (repo/repository.py): age of the
         # newest conflicting lock a waiter observed — a stale-holder

@@ -66,8 +66,7 @@ crash-at-op-N recovery scenarios use. Every injection is recorded in
 
 Arming: construct directly (tests), or set ``VOLSYNC_FAULT_SEED`` (+
 optional ``VOLSYNC_FAULT_SPEC``) and open stores through
-``open_store()`` / ``maybe_wrap()`` — the CLI and bench.py
-(``--faults SEED``) ride that path.
+``open_store()`` / ``maybe_wrap()`` — the CLI rides that path.
 
 Spec strings (``parse_spec``): semicolon-separated entries
 ``kind:key=value,...`` e.g. ::
@@ -483,8 +482,7 @@ def maybe_wrap(store, *, seed: Optional[int] = None,
                spec: Optional[str] = None):
     """Wrap ``store`` in a FaultStore when armed (explicitly or via
     VOLSYNC_FAULT_SEED / VOLSYNC_FAULT_SPEC); otherwise return it
-    unchanged. The arming path tests, bench.py --faults, and the CLI
-    all share."""
+    unchanged. The arming path tests and the CLI share."""
     if seed is None:
         seed = envflags.fault_seed()
     if seed is None:

@@ -39,10 +39,10 @@ _lock = lockcheck.make_lock("obs.copyledger")
 _by_site: defaultdict = defaultdict(int)
 _children: dict = {}  # site -> cached Prometheus label child
 
-# Every site allowed to call record_copy. The copies-smoke gate
-# (bench.py copies-smoke, wired into scripts/static_check.sh) fails on
-# a ledgered site outside this set — adding one is a reviewed change,
-# same as adding the record_copy call itself.
+# Every site allowed to call record_copy. `volsync lint` (VL505,
+# analysis/bufflow.py) fails on a ledgered site outside this set —
+# adding one is a reviewed change, same as adding the record_copy call
+# itself.
 SANCTIONED_SITES = frozenset({
     "chunker.ingest",      # read()-only source copied into the pooled segment
     "chunker.tail_carry",  # sub-min_size tail carried between segments

@@ -31,8 +31,7 @@ Design notes
   applies the SAME device matmul kernel with the inverse rows — encode
   and decode share one jitted primitive per coefficient matrix.
 - Bit-exactness is enforced by golden tests against the pure-NumPy
-  oracle (``rs_encode_np`` / ``rs_reconstruct_np``), which is also the
-  CPU baseline bench.py's ``ec`` mode reports against.
+  oracle (``rs_encode_np`` / ``rs_reconstruct_np``).
 """
 
 from __future__ import annotations

@@ -81,6 +81,17 @@ _DOMAIN_BYTE4 = b"VMRK1"[4]
 from volsync_tpu.ops.gearcdc import _pow2ceil_int as _pow2ceil
 
 
+def _buffer_bucket(length: int) -> int:
+    """Pad target for input buffers. Shapes are static under jit, so an
+    unbounded variety of buffer lengths (every file tail is unique) would
+    mean a fresh multi-second XLA compile each — pad into a small fixed
+    set instead: pow2 up to 8 MiB, then multiples of 8 MiB."""
+    if length <= 8 * 1024 * 1024:
+        return _pow2ceil(length, 64 * 1024)
+    m = 8 * 1024 * 1024
+    return (length + m - 1) // m * m
+
+
 def segment_caps(padded_len: int, params: GearParams) -> tuple[int, int]:
     """(cand_cap, chunk_cap) for a padded segment length.
 
@@ -701,37 +712,8 @@ def _chunk_hash_segments_impl(data: jax.Array, valid_len: jax.Array,
 _SEGMENTS_STATIC = ("min_size", "avg_size", "max_size", "seed", "mask_s",
                     "mask_l", "align", "cand_cap", "chunk_cap")
 
-#: normal variant — the staged [S*P] device buffer stays alive after
-#: the dispatch (callers that re-read it must use this)
 chunk_hash_segments = functools.partial(
     jax.jit, static_argnames=_SEGMENTS_STATIC)(_chunk_hash_segments_impl)
-
-#: buffer-donating variant: the staged input buffer is offered to XLA
-#: for reuse. The v5e compiler declines the offer ("Some donated
-#: buffers were not usable: uint8[...]" — no program output has the
-#: input's shape), so today this buys no HBM; it only makes the staged
-#: device array dead after the call. The overflow-retry path rebuilds
-#: lanes from the HOST rows, never the donated array. On CPU jax
-#: ignores donation (with a warning), which is why _use_donation
-#: defaults by backend.
-chunk_hash_segments_donated = functools.partial(
-    jax.jit, static_argnames=_SEGMENTS_STATIC,
-    donate_argnums=(0,))(_chunk_hash_segments_impl)
-
-
-@functools.lru_cache(maxsize=None)
-def _donation_default() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _use_donation() -> bool:
-    """VOLSYNC_DONATE forced value, else donate exactly on TPU."""
-    from volsync_tpu import envflags
-
-    forced = envflags.donate_device_inputs()
-    if forced is not None:
-        return forced
-    return _donation_default()
 
 
 @functools.partial(jax.jit, static_argnames=("n_pages_pad",))
@@ -837,10 +819,6 @@ class FusedSegmentHasher:
             "fused path requires the page-aligned cut format (align=4096)"
         self.params = params
 
-    #: Override point (benchmarks compose a content salt into the same
-    #: program); None = chunk_hash_segment on the library kernels.
-    segment_device_fn = None
-
     def dispatch(self, dev, length: int, *, eof: bool,
                  cand_cap: int | None = None, chunk_cap: int | None = None):
         p = self.params
@@ -848,12 +826,12 @@ class FusedSegmentHasher:
         cc, kc = segment_caps(P, p)
         cand_cap = cand_cap or cc
         chunk_cap = chunk_cap or kc
-        fn = self.segment_device_fn or chunk_hash_segment
         count_dispatch(1, 1, length, P)
-        return fn(dev, length, min_size=p.min_size, avg_size=p.avg_size,
-                  max_size=p.max_size, seed=p.seed, mask_s=p.mask_s,
-                  mask_l=p.mask_l, align=p.align, eof=eof,
-                  cand_cap=cand_cap, chunk_cap=chunk_cap), \
+        return chunk_hash_segment(
+            dev, length, min_size=p.min_size, avg_size=p.avg_size,
+            max_size=p.max_size, seed=p.seed, mask_s=p.mask_s,
+            mask_l=p.mask_l, align=p.align, eof=eof,
+            cand_cap=cand_cap, chunk_cap=chunk_cap), \
             (cand_cap, chunk_cap)
 
     def finish(self, dev, length: int, inflight, *, eof: bool):
@@ -890,8 +868,6 @@ class BatchedSegmentHasher:
         self._single = FusedSegmentHasher(params)
 
     def hash_segments(self, items) -> list:
-        from volsync_tpu.engine.chunker import _buffer_bucket
-
         if not items:
             return []
         # Lanes GROUP BY buffer bucket: padding every lane to the
@@ -945,10 +921,8 @@ class BatchedSegmentHasher:
                 eofs[i] = eof
             record_copy("device.stage", staged)
         count_dispatch(len(items), S, int(lens.sum()), S * P)
-        fn = (chunk_hash_segments_donated if _use_donation()
-              else chunk_hash_segments)
         with span("ops.launch", **at):
-            handle = fn(
+            handle = chunk_hash_segments(
                 jnp.asarray(rows.reshape(-1)), jnp.asarray(lens),
                 jnp.asarray(eofs),
                 min_size=p.min_size, avg_size=p.avg_size,

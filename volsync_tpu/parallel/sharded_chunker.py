@@ -82,11 +82,6 @@ class MeshChunkHasher:
         self._fused_cache: dict = {}
         self._jax = jax
 
-    #: ``begin()`` takes ``valid_len``: stream_chunk_batches hands it a
-    #: view already padded to ``buffer_bucket`` (zeroed pad lane), so a
-    #: streamed segment goes to the chips with no np.pad copy.
-    accepts_prepadded = True
-
     # -- what a stream (and a warm plan) needs to know of the layout --------
 
     def shard_bucket(self, length: int) -> int:

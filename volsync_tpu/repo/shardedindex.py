@@ -60,7 +60,7 @@ _M_FP = GLOBAL_METRICS.index_prefilter.labels(outcome="false_positive")
 # Batches at or below this many keys per shard take the scalar-probe
 # path: the vectorized probe's fixed numpy setup (~30us per touched
 # shard) only amortizes once partitions grow past a few dozen keys
-# (measured crossover ~32-48 keys/shard on CPU; see bench.py index).
+# (measured crossover ~32-48 keys/shard on CPU).
 _SMALL_BATCH_PER_SHARD = 32
 
 
