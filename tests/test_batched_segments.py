@@ -14,6 +14,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmark.reference import blobid as ref_blobid
+from benchmark.reference import gearcdc as ref_gearcdc
+from volsync_tpu.obs import (copies_by_site, counter_totals, reset_copies,
+                             reset_spans, span_totals)
 from volsync_tpu.ops.gearcdc import GearParams
 from volsync_tpu.ops.segment import (
     chunk_hash_segment,
@@ -394,3 +398,192 @@ def test_hash_bucket_splits_at_index_space_bound(monkeypatch, rng):
     assert got == want  # identical chunks/consumed per lane
     assert len(calls) >= 3  # genuinely split
     assert all(n <= 2 * 256 * 1024 for n in calls)
+
+
+# -- one lane that is already the bucket goes to the device uncopied ------
+
+CHUNKER = {"min_size": P.min_size, "avg_size": P.avg_size,
+           "max_size": P.max_size, "seed": P.seed,
+           "norm_level": P.norm_level, "align": P.align}
+BUCKET = SEG  # a lane this long is its bucket
+
+
+def _reference(data: bytes) -> list[tuple[int, int, str]]:
+    return [(s, n, ref_blobid.blob_id(data[s: s + n]))
+            for s, n in ref_gearcdc.cuts(data, CHUNKER)]
+
+
+def _padded(valid: int, seed: int) -> bytes:
+    """``valid`` random bytes zero-padded to the bucket."""
+    return np.random.default_rng([seed, valid]).bytes(valid) \
+        + bytes(BUCKET - valid)
+
+
+def _at_odd_offset(padded: bytes) -> np.ndarray:
+    """The view a stream sends: bucket bytes somewhere inside a larger
+    bytearray (``head - tail`` is on no grid)."""
+    whole = bytearray(13 + BUCKET + 7)
+    whole[13: 13 + BUCKET] = padded
+    return np.frombuffer(whole, np.uint8)[13: 13 + BUCKET]
+
+
+_LANE_KINDS = {
+    "ndarray": lambda padded: np.frombuffer(padded, np.uint8).copy(),
+    "odd-offset-view": _at_odd_offset,
+    "bytes": bytes,
+    "memoryview": lambda padded: memoryview(bytearray(padded)),
+}
+
+
+@pytest.fixture
+def counted():
+    """Spans, counters and the copy ledger from zero."""
+    reset_spans()
+    reset_copies()
+
+
+@pytest.mark.parametrize("valid,eof", [
+    (BUCKET - 5000, True),   # an eof segment with a partial tail leaf
+    (BUCKET - 5000, False),  # a stream's middle segment, pad after it
+    (BUCKET, True),          # the lane fills the bucket to the byte
+], ids=["eof-tail", "not-eof", "full"])
+@pytest.mark.parametrize("kind", list(_LANE_KINDS))
+def test_a_bucket_shaped_lane_goes_direct_and_reads_the_same(
+        counted, kind, valid, eof):
+    """Alone in its dispatch and as long as its bucket, a lane is handed
+    to the device as it is: the cuts and ids are those of the same bytes
+    sent one byte short of the bucket (the copy path pads that byte back
+    as a zero) and the plain reference's, no rows were filled and given
+    back to stage it, and the buffer reads afterwards as it did
+    before."""
+    from volsync_tpu.ops.segment import BatchedSegmentHasher
+
+    padded = _padded(valid, 11)
+    lane = _LANE_KINDS[kind](padded)
+    h = BatchedSegmentHasher(P)
+    (chunks, consumed), = h.hash_segments([(lane, valid, eof)])
+    assert counter_totals() == {
+        "ops.dispatches": 1, "ops.lanes": 1, "ops.lanes_padded": 1,
+        "ops.lanes_direct": 1, "ops.bytes_valid": valid,
+        "ops.bytes_padded": BUCKET}
+    # the bytes handed to the device are counted as the parent counted
+    # them (segment_hbm_roofline divides by them); no rows were made
+    assert copies_by_site() == {"device.stage": BUCKET}
+    assert span_totals()["ops.stage"][0] == 1  # nothing to release
+    assert bytes(lane) == padded
+
+    ref = _reference(padded[:valid])
+    if eof:
+        assert chunks == ref and consumed == valid
+    else:
+        assert chunks and chunks == ref[: len(chunks)]
+        assert consumed == sum(n for _, n, _ in chunks) < valid
+    if valid < BUCKET:
+        reset_spans()
+        reset_copies()
+        copied, = h.hash_segments([(padded[: BUCKET - 1], valid, eof)])
+        assert copied == (chunks, consumed)
+        assert "ops.lanes_direct" not in counter_totals()
+        assert copies_by_site()["device.stage"] == BUCKET - 1
+
+
+@pytest.mark.parametrize("lanes", [
+    lambda: [_padded(BUCKET - 5000, 21), _padded(BUCKET - 4096, 22)],
+    lambda: [_padded(BUCKET - 5000, 23)[: BUCKET - 1]],
+], ids=["two-lanes", "short-lane"])
+def test_every_other_batch_is_copied_as_before(counted, lanes):
+    """Two lanes, a lane shorter than its bucket: rows are filled as
+    they always were, and the direct lane's counter stays at nothing."""
+    from volsync_tpu.ops.segment import BatchedSegmentHasher
+
+    valid = BUCKET - 5000
+    bufs = lanes()
+    got = BatchedSegmentHasher(P).hash_segments(
+        [(buf, valid, True) for buf in bufs])
+    for buf, (chunks, consumed) in zip(bufs, got):
+        assert chunks == _reference(bytes(buf)[:valid])
+        assert consumed == valid
+    counts = counter_totals()
+    assert "ops.lanes_direct" not in counts
+    assert counts["ops.lanes"] == len(bufs) and counts["ops.dispatches"] == 1
+    assert copies_by_site()["device.stage"] == sum(map(len, bufs))
+    assert span_totals()["ops.stage"][0] == 2  # fill, release
+
+
+def test_a_strided_lane_is_refused_as_before(counted):
+    """A buffer that is not contiguous never was a lane (np.frombuffer
+    refuses it): being bucket-long and alone does not make it one."""
+    from volsync_tpu.ops.segment import BatchedSegmentHasher
+
+    wide = np.zeros((BUCKET, 2), np.uint8)
+    with pytest.raises(ValueError, match="contiguous"):
+        BatchedSegmentHasher(P).hash_segments(
+            [(wide[:, 0], BUCKET - 5000, True)])
+    assert counter_totals() == {} and copies_by_site() == {}
+
+
+@pytest.mark.parametrize("caps,grown", [
+    ((4096, 4), "chunk table"),    # 4 chunks of a ~8-chunk segment
+    ((2, 512), "candidate table"),  # 2 candidates of dozens
+])
+def test_overflow_retry_resends_a_direct_lane(counted, monkeypatch, caps,
+                                              grown):
+    """The lane that overflowed the compiled tables is sent again alone
+    from the rows of its batch: on the direct path those are the
+    caller's buffer."""
+    from volsync_tpu.ops import segment as seg
+
+    monkeypatch.setattr(seg, "segment_caps", lambda padded, params: caps)
+    valid = BUCKET - 5000
+    padded = _padded(valid, 31)
+    lane = _at_odd_offset(padded)
+    (chunks, consumed), = seg.BatchedSegmentHasher(P).hash_segments(
+        [(lane, valid, True)])
+    assert chunks == _reference(padded[:valid]), grown
+    assert consumed == valid
+    assert span_totals()["ops.overflow_retry"][0] == 1
+    counts = counter_totals()
+    assert counts["ops.lanes_direct"] == 1
+    assert counts["ops.dispatches"] >= 2  # the batch, then the lane alone
+    assert bytes(lane) == padded
+
+
+def test_a_lone_stream_sends_every_segment_direct(counted, batch_segments,
+                                                  monkeypatch):
+    """``stream_chunk_batches`` over the shared batcher (the backup
+    cells' way to the device): every segment is a pooled view padded in
+    place to its bucket, so every lane is direct; the chunks are the
+    reference's, and once the consumer lets go of them every pooled
+    buffer is free again: neither the batcher nor the runtime keeps a
+    view of one."""
+    import io
+
+    import jax
+
+    from volsync_tpu.engine import bufpool
+    from volsync_tpu.engine.chunker import stream_chunk_batches
+
+    batch_segments(True)
+    pool = bufpool.BufferPool()
+    monkeypatch.setattr(bufpool, "GLOBAL", pool)
+    data = np.random.default_rng(41).bytes(5 * BUCKET + 12_345)
+    got, at = [], 0
+    for batch in stream_chunk_batches(io.BytesIO(data).read, P,
+                                      segment_size=BUCKET, readahead=0):
+        for view, digest in batch:
+            got.append((at, len(view), digest))
+            assert bytes(view) == data[at: at + len(view)]
+            at += len(view)
+        del batch, view
+    assert got == _reference(data)
+    counts = counter_totals()
+    assert counts["ops.lanes"] >= 5
+    assert counts["ops.lanes_direct"] == counts["ops.lanes"] \
+        == counts["ops.dispatches"]
+    assert copies_by_site()["device.stage"] == counts["ops.bytes_padded"]
+    # (the runtime may keep its reference to the last host buffer it
+    # read until it is next called: one more call lets it go)
+    jax.device_put(np.zeros(8, np.uint8)).block_until_ready()
+    pool.release(pool.acquire(4096))  # an acquire re-probes what is parked
+    assert pool._parked == []
+    assert pool._free_bytes > 4096  # and the segments' buffers came back

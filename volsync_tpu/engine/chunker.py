@@ -119,11 +119,14 @@ class DeviceChunkHasher:
         runs synchronously here and only the leaf digests stay in
         flight.
 
-        ``buffer`` may be bytes/bytearray/memoryview or a uint8 ndarray;
-        it is never copied on the host here unless it must be padded to
-        a device bucket. Callers that already hold a bucket-padded view
+        ``buffer`` may be bytes/bytearray/memoryview or a uint8 ndarray.
+        Callers that already hold a bucket-padded view
         (stream_chunk_batches' pooled segments) pass the padded view
-        plus ``valid_len`` — the zero-pad np.pad copy then disappears.
+        plus ``valid_len``: it then reaches the device with no host copy
+        on either way (no np.pad here; through the batcher, alone in its
+        dispatch, no staging copy either: ops/segment.py _hash_bucket).
+        A buffer shorter than its bucket is copied once into zeroed
+        padding (np.pad here, the batch's rows there).
 
         When batching is enabled (ops/batcher._batching_enabled:
         VOLSYNC_BATCH_SEGMENTS=1, or unset on a TPU backend — the
@@ -156,8 +159,11 @@ class DeviceChunkHasher:
             if batcher is not None:
                 # consumed == the last chunk's end by the walk's
                 # semantics, which is exactly what PendingSegment.end
-                # derives from the chunk list. The ndarray passes
-                # through uncopied (submit blocks, so it stays alive).
+                # derives from the chunk list. The ndarray is handed
+                # over as it is; whether it is copied is the batch's to
+                # say (_hash_bucket: not when it is bucket-shaped and
+                # alone). submit blocks until the result is fetched, so
+                # it stays alive and unchanged for as long as it is read.
                 chunks, _consumed = batcher.submit(buffer, length, eof)
                 return PendingSegment(chunks, None, None)
 

@@ -47,7 +47,7 @@ SANCTIONED_SITES = frozenset({
     "chunker.ingest",      # read()-only source copied into the pooled segment
     "chunker.tail_carry",  # sub-min_size tail carried between segments
     "device.pad",          # host buffer staged into the padded device lane
-    "device.stage",        # segment rows gathered for the batched kernel
+    "device.stage",        # lanes' bytes staged for the batched kernel
     "mesh.pad",            # unpadded segment copied out to the mesh's bucket
     "mesh.stage",          # segment laid out over the seq mesh's chips
     "verify.stage",        # restore verify staging onto the device

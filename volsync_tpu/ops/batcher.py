@@ -138,7 +138,14 @@ class SegmentMicroBatcher:
         (chunks, consumed) for this segment. The service scheduler
         (service/scheduler.py) feeds the batcher through this so its
         deficit-round-robin thread never blocks on a device round
-        trip."""
+        trip.
+
+        ``data`` must not change until the future resolves: a lane that
+        is alone in its dispatch and already bucket-shaped is handed to
+        the device as it is (ops/segment.py _hash_bucket), and the
+        transfer reads the caller's memory. The result is fetched
+        before the future resolves, so the transfer has ended by
+        then; the batcher lets go of ``data`` as it resolves."""
         if self._stop.is_set():
             raise BatcherStopped("microbatcher stopped")
         item = _Item(data, length, eof)
@@ -208,6 +215,10 @@ class SegmentMicroBatcher:
                     results = self._hasher.hash_segments(
                         [(it.data, it.length, it.eof) for it in batch])
                 for item, r in zip(batch, results):
+                    # this thread holds its batch until the next one
+                    # arrives: drop the lane, or a pooled buffer stays
+                    # exported (engine/bufpool.py parks it) that long
+                    item.data = None
                     item.future.set_result(r)
             except Exception as exc:  # noqa: BLE001 — per-caller delivery
                 for item in batch:

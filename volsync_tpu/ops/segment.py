@@ -855,8 +855,12 @@ class BatchedSegmentHasher:
     of BASELINE configs[5]).
 
     ``hash_segments(items)`` takes ``[(bytes-like, valid_len, eof)]``,
-    pads every lane to one shared bucketed length, and returns
-    ``[(chunks, consumed)]`` per lane. Lanes whose true counts overflow
+    groups the lanes by bucketed length, copies each group into rows
+    zero-padded to its bucket, and returns ``[(chunks, consumed)]`` per
+    lane. A lane that is alone in its dispatch and already as long as
+    its bucket (a stream's pooled segment: engine/chunker.py
+    stream_chunk_batches pads it in place) is the row: it goes to the
+    device as it is, uncopied. Lanes whose true counts overflow
     the compiled capacities retry INDIVIDUALLY through the
     single-segment path (adversarial data only — the batch result for
     the other lanes is already in hand)."""
@@ -908,19 +912,32 @@ class BatchedSegmentHasher:
         # launched asynchronously and ops.fetch is where it is waited
         # for.
         at = {"lanes": len(items), "bucket": P}
-        with span("ops.stage", part="fill", **at):
-            rows = np.zeros((S, P), dtype=np.uint8)
+        # One lane that is already the bucket (S == 1, every one of its
+        # P bytes the caller's, pad included) is the [1, P] the program
+        # takes: the same shapes, so the same executable, and the same
+        # bytes a copy of it would hold.
+        direct = len(items) == 1 and len(items[0][0]) == P
+        with span("ops.stage", part="direct" if direct else "fill", **at):
+            lanes = [np.frombuffer(buf, dtype=np.uint8, count=len(buf))
+                     for buf, _, _ in items]
             lens = np.zeros((S,), dtype=np.int32)
             eofs = np.zeros((S,), dtype=bool)
-            staged = 0
-            for i, (buf, n, eof) in enumerate(items):
-                arr = np.frombuffer(buf, dtype=np.uint8, count=len(buf))
-                rows[i, : arr.shape[0]] = arr
-                staged += arr.shape[0]
+            for i, (_, n, eof) in enumerate(items):
                 lens[i] = n
                 eofs[i] = eof
-            record_copy("device.stage", staged)
+            if direct:
+                rows = lanes[0].reshape(1, P)
+            else:
+                rows = np.zeros((S, P), dtype=np.uint8)
+                for i, arr in enumerate(lanes):
+                    rows[i, : arr.shape[0]] = arr
+            # the lanes' bytes on their way to the device, copied into
+            # rows first or not (as mesh.stage counts them): what the
+            # segment programs' share of the HBM roofline is taken over
+            record_copy("device.stage", sum(arr.shape[0] for arr in lanes))
         count_dispatch(len(items), S, int(lens.sum()), S * P)
+        if direct:
+            obs_count("ops.lanes_direct")
         with span("ops.launch", **at):
             handle = chunk_hash_segments(
                 jnp.asarray(rows.reshape(-1)), jnp.asarray(lens),
@@ -946,9 +963,12 @@ class BatchedSegmentHasher:
                         chunks, consumed = self._single.finish(
                             dev, int(lens[i]), inflight, eof=bool(eofs[i]))
                 out.append((chunks, consumed))
-        # The rows go back to the allocator under the stage's name too:
-        # what staging costs is mapping S x P fresh bytes and unmapping
-        # them again, a dispatch later.
+        if direct:
+            return out  # the caller's buffer: nothing to give back
+        # Copied rows go back to the allocator under the stage's name
+        # too: at the sizes where a fresh S x P array is a mapping of
+        # its own, staging costs faulting those pages in while they are
+        # filled and unmapping them again, a dispatch later.
         with span("ops.stage", part="release", **at):
             del rows
         return out
