@@ -150,17 +150,21 @@ def test_pallas_transpose(tpu_arms, one_chip):
     assert _kernels(c) >= 1
 
 
-def test_span_roots_device(tpu_arms, one_chip):
+@pytest.mark.parametrize("P,N", [(32 * MiB, 128), (72 * MiB, 128),
+                                 (72 * MiB, 1024)])
+def test_span_roots_device(tpu_arms, one_chip, P, N):
     """The rclone checksum / restore-verify program at one 32 MiB
-    staging bucket."""
+    staging bucket, and at what a restore's 64 MiB verify batch
+    presents (``restic-dest-10g.restore``): the 72 MiB bucket, a batch
+    of large blobs and one of a thousand small files' blobs."""
     import jax.numpy as jnp
 
     from volsync_tpu.ops.segment import span_roots_device
 
     c = span_roots_device.lower(
-        _sds((32 * MiB,), jnp.uint8, one_chip),
-        _sds((128,), jnp.int32, one_chip),
-        _sds((128,), jnp.int32, one_chip)).compile()
+        _sds((P,), jnp.uint8, one_chip),
+        _sds((N,), jnp.int32, one_chip),
+        _sds((N,), jnp.int32, one_chip)).compile()
     assert _kernels(c) >= 2
 
 

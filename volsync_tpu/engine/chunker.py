@@ -24,7 +24,7 @@ import numpy as np
 
 from volsync_tpu import envflags
 from volsync_tpu.engine import bufpool
-from volsync_tpu.obs import record_copy, span
+from volsync_tpu.obs import count, record_copy, span
 from volsync_tpu.repo import blobid
 
 from volsync_tpu.ops.gearcdc import (
@@ -488,9 +488,13 @@ def hash_spans(buffer, spans: list[tuple[int, int]]) -> list[str]:
         # their id is a constant anyway.
         empty = lengths[: len(spans)] == 0
         lengths[: len(spans)][empty] = -1
-        roots = np.asarray(span_roots_device(  # lint: ignore[VL501] one batched 32 B/span root download — metadata, not payload
-            _upload_padded(buffer), jnp.asarray(starts),
-            jnp.asarray(lengths))).astype(">u4")
+        with span("verify.launch",
+                  bucket=_buffer_bucket(max(len(buffer), 1)), lanes=n_cap):
+            out = span_roots_device(
+                _upload_padded(buffer), jnp.asarray(starts),
+                jnp.asarray(lengths))
+        with span("verify.fetch"):
+            roots = np.asarray(out).astype(">u4")  # lint: ignore[VL501] one batched 32 B/span root download — metadata, not payload
         empty_id = blobid.blob_id(b"")
         return [empty_id if empty[i]
                 else roots[i].tobytes().hex()  # lint: ignore[VL106] 32 B span-root ids, metadata not payload
@@ -532,13 +536,16 @@ def verify_blob_batch(pairs: list) -> list:
     # page-aligned slot (the single sanctioned copy of this path —
     # replaces the old pieces-list + b"".join + np.pad double copy);
     # hash_spans then uploads it with no further host-side pad.
-    staging = np.zeros((_buffer_bucket(max(off, 1)),), np.uint8)
-    for (start, _), (_, data) in zip(spans, pairs):
-        n = len(data)
-        if n:
-            staging[start: start + n] = np.frombuffer(
-                data, np.uint8, count=n)
+    with span("verify.stage"):
+        staging = np.zeros((_buffer_bucket(max(off, 1)),), np.uint8)
+        for (start, _), (_, data) in zip(spans, pairs):
+            n = len(data)
+            if n:
+                staging[start: start + n] = np.frombuffer(
+                    data, np.uint8, count=n)
     record_copy("verify.stage", payload)
+    count("verify.bytes_valid", payload)
+    count("verify.bytes_padded", len(staging) - payload)
     got = hash_spans(staging, spans)
     return [bid for (bid, _), d in zip(pairs, got) if d != bid]
 

@@ -10,6 +10,7 @@ extra files in the target can optionally be deleted (--delete semantics).
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 from pathlib import Path
@@ -18,6 +19,7 @@ from typing import Optional
 import numpy as np
 
 from volsync_tpu import envflags
+from volsync_tpu.obs import span
 from volsync_tpu.repo.repository import Repository
 
 
@@ -80,31 +82,35 @@ class TreeRestore:
         jobs: list[tuple[dict, Path]] = []
         dirs: list[tuple[Path, dict]] = []
         links: list[tuple[dict, Path]] = []
-        self._walk_tree(manifest["tree"], dest, stats, jobs, dirs, links,
-                        delete_extra=delete_extra)
+        with span("restore.tree"):
+            self._walk_tree(manifest["tree"], dest, stats, jobs, dirs,
+                            links, delete_extra=delete_extra)
         if jobs:
             self._restore_files(jobs, stats)
-        # Hardlinks AFTER the file pool: the link's source path is only
-        # guaranteed to exist (with final content) once every file job
-        # has run. Metadata is shared with the source inode, already
-        # applied there.
-        for entry, target in links:
-            source = dest / entry["hardlink_to"]
-            if target.exists() and not target.is_symlink() \
-                    and os.path.samestat(target.lstat(), source.lstat()):
-                stats["skipped"] += 1
-                continue
-            if target.is_symlink() or target.exists():
-                _rmtree(target)
-            os.link(source, target)
-            stats["files"] += 1
-        # Directory metadata last, children-first: any earlier write
-        # inside a directory would overwrite its restored mtime.
-        for path, entry in reversed(dirs):
-            _apply_xattrs(path, entry)  # before chmod: a read-only
-            _apply_owner(path, entry)   # mode would block setxattr;
-            os.chmod(path, entry["mode"])  # chown clears suid -> last
-            os.utime(path, ns=(entry["mtime_ns"], entry["mtime_ns"]))
+        with span("restore.finalize"):
+            # Hardlinks AFTER the file pool: the link's source path is
+            # only guaranteed to exist (with final content) once every
+            # file job has run. Metadata is shared with the source
+            # inode, already applied there.
+            for entry, target in links:
+                source = dest / entry["hardlink_to"]
+                if target.exists() and not target.is_symlink() \
+                        and os.path.samestat(target.lstat(),
+                                             source.lstat()):
+                    stats["skipped"] += 1
+                    continue
+                if target.is_symlink() or target.exists():
+                    _rmtree(target)
+                os.link(source, target)
+                stats["files"] += 1
+            # Directory metadata last, children-first: any earlier
+            # write inside a directory would overwrite its restored
+            # mtime.
+            for path, entry in reversed(dirs):
+                _apply_xattrs(path, entry)  # before chmod: a read-only
+                _apply_owner(path, entry)   # mode would block setxattr;
+                os.chmod(path, entry["mode"])  # chown clears suid -> last
+                os.utime(path, ns=(entry["mtime_ns"], entry["mtime_ns"]))
         return stats
 
     def _walk_tree(self, tree_id: str, dirpath: Path, stats: dict,
@@ -457,10 +463,12 @@ def restore_snapshot(repo: Repository, dest, *,
     between select and walk could delete the chosen snapshot's packs and
     the restore would die mid-way with delete_extra damage already done.
     """
-    with repo.lock(exclusive=False):
-        repo.load_index()
-        selected = repo.select_snapshot(restore_as_of=restore_as_of,
-                                        previous=previous)
+    with contextlib.ExitStack() as held:
+        with span("restore.select"):
+            held.enter_context(repo.lock(exclusive=False))
+            repo.load_index()
+            selected = repo.select_snapshot(restore_as_of=restore_as_of,
+                                            previous=previous)
         if selected is None:
             return None
         snap_id, manifest = selected

@@ -68,7 +68,13 @@ from typing import Optional
 from volsync_tpu import envflags
 from volsync_tpu.metrics import GLOBAL as GLOBAL_METRICS
 from volsync_tpu.objstore.store import NoSuchKey
-from volsync_tpu.obs import current_context, record_trigger, span, use_context
+from volsync_tpu.obs import (
+    count,
+    current_context,
+    record_trigger,
+    span,
+    use_context,
+)
 from volsync_tpu.repo import crypto
 from volsync_tpu.repo.packcache import PackCache
 from volsync_tpu.repo.repository import (
@@ -113,6 +119,9 @@ def restore_files_pipelined(tr, jobs: list, stats: dict) -> None:
         cache = PackCache(repo.store, rescue=repo.ec_reconstruct)
     with span("restore.plan"):
         plans, placements, groups = _plan(tr, jobs, stats)
+    for plan in plans:
+        if plan.remaining == 0:  # an empty file: nothing to fetch
+            _finish_file(tr, plan, stats)
     if not plans:
         return
     try:
@@ -166,8 +175,6 @@ def _plan(tr, jobs: list, stats: dict):
             plan.remaining += 1
         plan.total = offset
         plans.append(plan)
-        if plan.remaining == 0:
-            _finish_file(tr, plan, stats)
     return plans, placements, groups
 
 
@@ -286,15 +293,23 @@ def _execute(tr, repo, cache: PackCache, plans, placements,
             # device verify caught wrong bytes: heal from the mirror
             # before giving up (module docstring, stage 3)
             repair_batch(bad)
-        with span("restore.write"):
-            for blob_id, data in batch:
+        count("restore.blobs", len(batch))
+        for blob_id, data in batch:
+            # one span a blob's placements; a file its last write
+            # completes is finished outside it (restore.finalize), so
+            # the two add up on this thread
+            done = []
+            with span("restore.write"):
                 for plan, offset in placements[blob_id]:
                     _write_at(tr, plan, offset, data)
                     plan.remaining -= 1
                     if plan.remaining == 0:
-                        _finish_file(tr, plan, stats)
-                _M_RESTORE_BYTES.inc(len(data)
-                                     * len(placements[blob_id]))
+                        done.append(plan)
+            for plan in done:
+                _finish_file(tr, plan, stats)
+            written = len(data) * len(placements[blob_id])
+            _M_RESTORE_BYTES.inc(written)
+            count("restore.bytes_restored", written)
         batch, batch_bytes = [], 0
 
     order = deque(groups.items())
@@ -308,31 +323,39 @@ def _execute(tr, repo, cache: PackCache, plans, placements,
                     pending.append(
                         (pack_id, members, pool.submit(fetch, pack_id)))
                 pack_id, members, fut = pending.popleft()
-                body = fut.result()
-                for blob_id, p_off, p_len, raw_len in members:
-                    if body is None:
-                        # buffered in an active write pipeline of this
-                        # process — no pack object to fetch yet
-                        data = repo.read_blob_raw(blob_id)
-                        if len(data) != raw_len:
-                            raise crypto.IntegrityError(
-                                f"restore: blob {blob_id} length "
-                                f"{len(data)} != indexed {raw_len}")
-                    else:
-                        src[blob_id] = (pack_id, p_off, p_len, raw_len)
-                        try:
-                            data = decode_member(body, blob_id, p_off,
-                                                 p_len, raw_len)
-                        except Exception:  # noqa: BLE001 — an
-                            # undecodable segment (torn seal, decompress
-                            # error, wrong length) is the same silent-
-                            # corruption class the verify stage catches;
-                            # try the mirror before dying
-                            mbody = healthy_body(pack_id)
-                            if mbody is None:
-                                raise
-                            data = decode_member(mbody, blob_id, p_off,
-                                                 p_len, raw_len)
+                with span("restore.fetch_wait"):
+                    body = fut.result()
+                # the pack's members decode under ONE span, then join
+                # the verify batch (same order, same flush rule)
+                decoded = []
+                with span("restore.decode"):
+                    for blob_id, p_off, p_len, raw_len in members:
+                        if body is None:
+                            # buffered in an active write pipeline of
+                            # this process — no pack object to fetch yet
+                            data = repo.read_blob_raw(blob_id)
+                            if len(data) != raw_len:
+                                raise crypto.IntegrityError(
+                                    f"restore: blob {blob_id} length "
+                                    f"{len(data)} != indexed {raw_len}")
+                        else:
+                            src[blob_id] = (pack_id, p_off, p_len, raw_len)
+                            try:
+                                data = decode_member(body, blob_id, p_off,
+                                                     p_len, raw_len)
+                            except Exception:  # noqa: BLE001 — an
+                                # undecodable segment (torn seal,
+                                # decompress error, wrong length) is the
+                                # same silent-corruption class the verify
+                                # stage catches; try the mirror before
+                                # dying
+                                mbody = healthy_body(pack_id)
+                                if mbody is None:
+                                    raise
+                                data = decode_member(mbody, blob_id,
+                                                     p_off, p_len, raw_len)
+                        decoded.append((blob_id, data))
+                for blob_id, data in decoded:
                     batch.append((blob_id, data))
                     batch_bytes += len(data)
                     if batch_bytes >= tr._VERIFY_BATCH:
@@ -369,9 +392,10 @@ def _write_at(tr, plan: _FilePlan, offset: int, data: bytes) -> None:
 def _finish_file(tr, plan: _FilePlan, stats: dict) -> None:
     """All content written: materialize trailing holes and stamp
     metadata exactly as the serial writer does."""
-    with open(plan.target, "r+b") as f:
-        f.truncate(plan.total)
-    tr._finalize_file(plan.entry, plan.target)
+    with span("restore.finalize"):
+        with open(plan.target, "r+b") as f:
+            f.truncate(plan.total)
+        tr._finalize_file(plan.entry, plan.target)
     stats["files"] += 1
     stats["bytes"] += plan.entry["size"]
 

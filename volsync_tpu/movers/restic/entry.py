@@ -201,8 +201,10 @@ def _dispatch(ctx, env: dict, direction: str) -> int:
         return 0
 
     if direction == "restore":
-        repo = Repository.open(open_store(env["RESTIC_REPOSITORY"], env=env),
-                               password=env.get("RESTIC_PASSWORD") or None)
+        with span("repo.open"):
+            repo = Repository.open(
+                open_store(env["RESTIC_REPOSITORY"], env=env),
+                password=env.get("RESTIC_PASSWORD") or None)
         repo.default_lock_wait = float(env.get("LOCK_WAIT_SECONDS", "120"))
         as_of = (datetime.fromisoformat(env["RESTORE_AS_OF"])
                  if env.get("RESTORE_AS_OF") else None)

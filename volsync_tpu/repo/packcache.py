@@ -39,7 +39,7 @@ from volsync_tpu import envflags
 from volsync_tpu.analysis import lockcheck
 from volsync_tpu.metrics import GLOBAL as GLOBAL_METRICS
 from volsync_tpu.objstore.store import NoSuchKey
-from volsync_tpu.obs import span
+from volsync_tpu.obs import count, span
 from volsync_tpu.repo.compactindex import as_key_rows
 from volsync_tpu.repo.shardedindex import BloomPrefilter
 
@@ -165,6 +165,8 @@ class PackCache:
                     _M_EVICTIONS.inc()
             self._inflight.pop(pack_id, None)
         _M_MISSES.inc()
+        count("restore.packs_fetched")
+        count("restore.bytes_fetched", len(body))
         flight.done.set()
         return body
 
