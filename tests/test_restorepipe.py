@@ -134,6 +134,71 @@ def test_golden_pipelined_equals_serial(tmp_path):
     _assert_trees_identical(src, d_pipe)
 
 
+def _restore(store, dest, *, pipeline):
+    repo = Repository.open(store)
+    with repo.lock(exclusive=False):
+        repo.load_index()
+        snap_id, manifest = repo.select_snapshot()
+        TreeRestore(repo, pipeline=pipeline)._run_locked(
+            snap_id, manifest, dest)
+    return repo, manifest
+
+
+@pytest.mark.parametrize("sparse", ["1", "0"], ids=["sparse", "dense"])
+def test_a_repeated_blob_is_scanned_once_and_lands_everywhere(
+        tmp_path, monkeypatch, sparse):
+    """A file whose two halves are the same blobs, holes among them:
+    each blob's runs are derived once and applied at every place it
+    lands, so the tree has the serial path's allocation, every
+    placement is counted, and the dense ones are exactly the placements
+    of blobs without an aligned zero page (all of them with sparse
+    writes off)."""
+    import json
+
+    from volsync_tpu.obs import counter_totals, reset_spans
+
+    monkeypatch.setenv("VOLSYNC_SPARSE", sparse)
+    rng = np.random.RandomState(17)
+    half = b"".join([
+        rng.bytes(64 * 1024), bytes(128 * 1024), rng.bytes(40 * 1024),
+        bytes(8 * 1024), rng.bytes(20 * 1024), bytes(5000),
+        rng.bytes(300_000 - 5000)])
+    src = tmp_path / "src"
+    src.mkdir()
+    (src / "twice.bin").write_bytes(half + half)
+    (src / "tail.bin").write_bytes(rng.bytes(10_000) + bytes(2_000))
+    store = MemObjectStore()
+    _backup(store, src)
+    d_serial, d_pipe = tmp_path / "serial", tmp_path / "pipe"
+    _restore(store, d_serial, pipeline=False)
+    reset_spans()
+    repo, manifest = _restore(store, d_pipe, pipeline=True)
+    _assert_trees_identical(d_serial, d_pipe, blocks=True)
+    _assert_trees_identical(src, d_pipe)
+
+    def has_zero_page(blob: bytes) -> bool:
+        return any(blob[i:i + 4096] == bytes(4096)
+                   for i in range(0, len(blob) - 4095, 4096))
+
+    content = [blob_id
+               for e in json.loads(
+                   repo.read_blob(manifest["tree"]))["entries"]
+               for blob_id in e.get("content", [])]
+    holed = {b for b in set(content) if has_zero_page(repo.read_blob(b))}
+    assert holed and len(set(content)) < len(content)  # the shape holds
+    if sparse == "1":
+        # and the holes are holes: the two 128 KiB ones at the least
+        assert d_pipe.joinpath("twice.bin").stat().st_blocks * 512 \
+            <= 2 * len(half) - 2 * 128 * 1024
+    counts = counter_totals()
+    assert counts["restore.blobs"] == len(set(content))
+    assert counts["restore.writes"] == len(content)
+    assert counts["restore.writes_dense"] == (
+        sum(b not in holed for b in content) if sparse == "1"
+        else len(content))
+    assert counts["restore.bytes_restored"] == 2 * len(half) + 12_000
+
+
 def test_skip_unchanged_and_delete_extra(tmp_path):
     src = _corpus(tmp_path)
     store = MemObjectStore()

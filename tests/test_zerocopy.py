@@ -128,6 +128,169 @@ def test_write_sparse_input_type_parity(tmp_path, case, data):
         assert got == golden, f"{case}: {name} diverged"
 
 
+def _historical_write_sparse(f, data) -> None:
+    """The writer up to PR 32, the oracle of the page scan: it found
+    the longest zero run from an int64 index of every non-zero byte
+    (eight bytes of index a data byte), and only then looked at pages."""
+    view = memoryview(data).cast("B")
+    n = len(view)
+    if n == 0:
+        f.write(view)
+        return
+    arr = np.frombuffer(view, np.uint8)
+    nz = np.flatnonzero(arr)
+    if nz.size == 0:
+        if n < 4096:  # no zero page exists -> the dense short-circuit
+            f.write(view)
+        else:
+            f.seek(n, os.SEEK_CUR)
+        return
+    gaps = np.diff(nz) - 1
+    longest = max(int(nz[0]), int(n - 1 - nz[-1]),
+                  int(gaps.max()) if gaps.size else 0)
+    if longest < 4096:
+        f.write(view)
+        return
+    full = n // 4096
+    zero_pages = np.logical_not(
+        arr[:full * 4096].reshape(full, 4096).any(axis=1))
+    bounds = np.flatnonzero(np.diff(zero_pages)) + 1
+    starts = np.concatenate(([0], bounds))
+    ends = np.concatenate((bounds, [full]))
+    for s, e in zip(starts, ends):
+        if zero_pages[s]:
+            f.seek((e - s) * 4096, os.SEEK_CUR)
+        else:
+            f.write(view[s * 4096:e * 4096])
+    if full * 4096 < n:
+        f.write(view[full * 4096:])
+
+
+class _RecordingFile:
+    """A file object that keeps what was asked of it: the calls, and
+    the byte extents written, adjacent ones merged (two writers that
+    cut one run of data differently cover the same extents)."""
+
+    def __init__(self):
+        self.pos = 0
+        self.calls = []
+        self.written = []
+
+    def write(self, b):
+        n = len(b)
+        self.calls.append(("write", n))
+        if n:
+            if self.written and self.written[-1][1] == self.pos:
+                self.written[-1][1] += n
+            else:
+                self.written.append([self.pos, self.pos + n])
+        self.pos += n
+        return n
+
+    def seek(self, off, whence=os.SEEK_SET):
+        assert whence == os.SEEK_CUR
+        self.calls.append(("seek", off))
+        self.pos += off
+        return self.pos
+
+
+def _solid(n: int, seed: int = 3) -> bytes:
+    """``n`` bytes none of which is zero: a zero run laid into them is
+    exactly as long as it was made."""
+    return np.random.RandomState(seed).randint(
+        1, 256, n, dtype=np.uint8).tobytes()
+
+
+def _with_zero_run(offset: int, run: int, total: int = 5 * 4096 + 13):
+    buf = bytearray(_solid(total))
+    buf[offset:offset + run] = bytes(run)
+    return bytes(buf)
+
+
+def _at_odd_address(data: bytes) -> memoryview:
+    """A view of ``data`` whose first byte sits at an odd address, as
+    a pack-slice view may: no wider word than a byte can be read there
+    without a copy."""
+    backing = bytearray(len(data) + 1)
+    start = 1 - np.frombuffer(backing, np.uint8).ctypes.data % 2
+    backing[start:start + len(data)] = data
+    return memoryview(backing)[start:start + len(data)]
+
+
+_EDGE_CASES = {
+    **{f"run{run}@{off}": _with_zero_run(off, run)
+       for run in (4095, 4096, 8190, 8191) for off in (0, 1, 4095)},
+    "one-zero-page": _with_zero_run(2 * 4096, 4096),
+    "zero-page-first": _with_zero_run(0, 4096, total=4 * 4096),
+    "zero-page-last": _with_zero_run(3 * 4096, 4096, total=4 * 4096),
+    "zero-page-then-solid-tail":
+        _with_zero_run(4 * 4096, 4096, total=5 * 4096 + 100),
+    "zero-page-then-zero-tail":
+        _with_zero_run(4 * 4096, 4096 + 100, total=5 * 4096 + 100),
+    "zero-pages-then-solid-tail": bytes(2 * 4096) + _solid(5),
+    "solid-then-zero-tail": _solid(8192) + bytes(100),
+    "short-solid-then-zeros": _solid(100) + bytes(3000),
+    "all-zero-1": bytes(1),
+    "all-zero-4095": bytes(4095),
+    "all-zero-4096": bytes(4096),
+    "all-zero-4097": bytes(4097),
+    "all-zero-1MiB": bytes(1 << 20),
+    "dense-10001": _solid(10_001),
+    "dense-3-pages-and-5": _solid(3 * 4096 + 5),
+    "random-64KiB-and-3": _data(65_539, seed=9),
+    "alternating-pages": b"".join(
+        bytes(4096) if i % 2 else _solid(4096, seed=i) for i in range(9)),
+    "empty": b"",
+}
+
+
+@pytest.mark.parametrize("case", list(_EDGE_CASES))
+def test_write_sparse_keeps_the_historical_outcome(tmp_path, case):
+    """On the edges of the hole rule the page scan lays down what the
+    historical byte index did: the same bytes, size and allocation on
+    a real file, and the same extents written and skipped, from
+    ``bytes``, ``bytearray`` and a view at an odd address alike."""
+    data = _EDGE_CASES[case]
+    p = tmp_path / "oracle"
+    with open(p, "wb") as f:
+        _historical_write_sparse(f, data)
+        f.truncate(len(data))
+    st = os.stat(p)
+    golden = (p.read_bytes(), st.st_size, st.st_blocks)
+    assert golden[0] == data
+    want = _RecordingFile()
+    _historical_write_sparse(want, data)
+    for name, convert in (("bytes", bytes), ("bytearray", bytearray),
+                          ("odd-address", _at_odd_address)):
+        assert _sparse_write(tmp_path, name, convert(data)) == golden, name
+        got = _RecordingFile()
+        _write_sparse(got, convert(data))
+        assert (got.written, got.pos) == (want.written, want.pos), name
+
+
+def test_write_sparse_reads_a_blob_once_by_the_page():
+    """The mechanism: over a blob without a zero page the scan leaves
+    one byte a page behind, never an index that grows with the data
+    (the historical writer peaked above sixteen times the blob), and
+    the blob goes down as ONE write of all of it, no seek."""
+    import tracemalloc
+
+    n = 8 << 20
+    blob = _data(n, seed=21)
+    f = _RecordingFile()
+    tracemalloc.start()
+    try:
+        _write_sparse(f, blob)
+        _, peak = tracemalloc.get_traced_memory()
+        tracemalloc.reset_peak()
+        _historical_write_sparse(_RecordingFile(), blob)
+        _, peak_before = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    assert f.calls == [("write", n)]
+    assert peak < n // 8 < 16 * n < peak_before
+
+
 # -- vectored seal ----------------------------------------------------------
 
 def _boxes():

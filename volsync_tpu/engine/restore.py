@@ -369,53 +369,66 @@ def _apply_xattrs(path, entry: dict) -> None:
             pass
 
 
-_ZERO_PAGE = bytes(4096)
+_PAGE = 4096
+
+
+def _sparse_runs(view) -> list:
+    """The ``(start, stop, hole)`` byte runs ``_write_sparse`` puts
+    ``view`` (a flat byte memoryview) down as: ONE pass over its
+    bytes, by the aligned 4 KiB page, one byte of temporary a page
+    (an OR over each page's row; ``uint8`` rows, so a pack-slice view
+    at any address takes the same way; 0.06-0.07 s/GiB on the chip's
+    host against the write's 0.26-0.70, scripts/profile_restore_write.py,
+    PR 33). A property of the bytes alone: the restore pipeline derives
+    it once a blob and applies it at every placement.
+
+    Hole semantics are pinned to the historical writer, which indexed
+    every non-zero byte to find the longest zero run
+    (tests/test_zerocopy.py keeps it as the oracle): a hole is an
+    aligned all-zero page, so data without one is ONE dense run, tail
+    included; wholly-zero data of a page or more is one hole over its
+    full length (partial tail included), shorter is written; a
+    partial tail after a data run joins it, after a hole it is
+    written even when zero."""
+    n = len(view)
+    full = n // _PAGE
+    body = full * _PAGE
+    arr = np.frombuffer(view, np.uint8)
+    nonzero = np.bitwise_or.reduce(
+        arr[:body].reshape(full, _PAGE), axis=1) != 0
+    if not nonzero.any() and not arr[body:].any():
+        return [(0, n, n >= _PAGE)]  # empty: one write of nothing
+    # where a run of pages ends and the next begins: pages, not bytes
+    cuts = [0, *(np.flatnonzero(np.diff(nonzero)) + 1).tolist(), full]
+    runs = [(s * _PAGE, e * _PAGE, not nonzero[s])
+            for s, e in zip(cuts, cuts[1:]) if s < e]
+    if body < n:
+        if runs and not runs[-1][2]:
+            runs[-1] = (runs[-1][0], n, False)
+        else:
+            runs.append((body, n, False))
+    return runs
+
+
+def _write_runs(f, view, runs) -> None:
+    """Put ``view`` down at ``f``'s position as ``_sparse_runs`` cut
+    it: a seek a hole, a write a data run."""
+    for start, stop, hole in runs:
+        if hole:
+            f.seek(stop - start, os.SEEK_CUR)
+        else:
+            f.write(view[start:stop])
 
 
 def _write_sparse(f, data) -> None:
     """rsync -S analogue: aligned runs of all-zero 4 KiB pages become
     seeks (holes) instead of writes — content identical, allocation
     not. Accepts any buffer (the zero-copy restore pipeline hands
-    pack-slice memoryviews straight through); the zero-run scan is
-    numpy so no ``bytes`` materialization happens here.
-
-    Hole semantics are pinned to the historical writer: data with no
-    4096-zero-byte RUN anywhere writes densely in one call; wholly-zero
-    data seeks its full length (including a partial tail); otherwise
-    page-ALIGNED all-zero pages seek and everything else (partial tail
-    included, even when zero) writes."""
+    pack-slice memoryviews straight through); no ``bytes``
+    materialization happens here. The trailing-hole ``truncate`` is
+    the caller's."""
     view = memoryview(data).cast("B")
-    n = len(view)
-    if n == 0:
-        f.write(view)
-        return
-    arr = np.frombuffer(view, np.uint8)
-    nz = np.flatnonzero(arr)
-    if nz.size == 0:
-        if n < 4096:  # no zero page exists -> the dense short-circuit
-            f.write(view)
-        else:
-            f.seek(n, os.SEEK_CUR)
-        return
-    gaps = np.diff(nz) - 1
-    longest = max(int(nz[0]), int(n - 1 - nz[-1]),
-                  int(gaps.max()) if gaps.size else 0)
-    if longest < 4096:
-        f.write(view)
-        return
-    full = n // 4096
-    zero_pages = np.logical_not(
-        arr[:full * 4096].reshape(full, 4096).any(axis=1))
-    bounds = np.flatnonzero(np.diff(zero_pages)) + 1
-    starts = np.concatenate(([0], bounds))
-    ends = np.concatenate((bounds, [full]))
-    for s, e in zip(starts, ends):
-        if zero_pages[s]:
-            f.seek((e - s) * 4096, os.SEEK_CUR)
-        else:
-            f.write(view[s * 4096:e * 4096])
-    if full * 4096 < n:
-        f.write(view[full * 4096:])
+    _write_runs(f, view, _sparse_runs(view))
 
 
 def _rmtree(path: Path):
