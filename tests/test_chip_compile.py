@@ -151,12 +151,13 @@ def test_pallas_transpose(tpu_arms, one_chip):
 
 
 @pytest.mark.parametrize("P,N", [(32 * MiB, 128), (72 * MiB, 128),
-                                 (72 * MiB, 1024)])
+                                 (72 * MiB, 1024), (72 * MiB, 512)])
 def test_span_roots_device(tpu_arms, one_chip, P, N):
     """The rclone checksum / restore-verify program at one 32 MiB
     staging bucket, and at what a restore's 64 MiB verify batch
     presents (``restic-dest-10g.restore``): the 72 MiB bucket, a batch
-    of large blobs and one of a thousand small files' blobs."""
+    of large blobs and one of a thousand small files' blobs; and the
+    ~444 whole files of a hash pass of ``rclone-smallfiles.sync``."""
     import jax.numpy as jnp
 
     from volsync_tpu.ops.segment import span_roots_device

@@ -251,7 +251,10 @@ def test_do_not_delete_snapshot_is_relinquished(world, rng):
         assert cluster.wait_for(lambda: (
             (c := cluster.try_get("ReplicationDestination", "default",
                                   "rst"))
-            and c.status and c.status.latest_image is not None),
+            and c.status and c.status.latest_image is not None
+            # the iteration has ended: an update of the spec below may
+            # not race the controller's last writes of this one
+            and c.status.last_manual_sync == "one"),
             timeout=60, poll=0.05)
         cr = cluster.get("ReplicationDestination", "default", "rst")
         protected = cr.status.latest_image.name

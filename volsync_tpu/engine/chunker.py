@@ -15,6 +15,7 @@ whole stream (see ops/gearcdc.py).
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import queue
 import threading
@@ -517,35 +518,53 @@ def _open_readahead(path, segment_size: int):
     return open(path, "rb")
 
 
+def stage_page_aligned(lengths: list, fill, *,
+                       filling=contextlib.nullcontext):
+    """The one stager of a ``hash_spans`` batch: item ``i`` of
+    ``lengths[i]`` bytes gets the next page-aligned slot of ONE zeroed
+    bucket-sized buffer (``_buffer_bucket``: ``hash_spans`` then uploads
+    it with no host-side pad) and ``fill(i, slot)`` puts its bytes
+    there, once: a copy for a blob in hand (``verify_blob_batch``), the
+    file's read itself for a file (``movers/rclone/sync.hash_files``,
+    whose ``filling()`` puts a span of its own around those reads,
+    inside ``verify.stage``). Returns (buffer, [(start, length)]).
+    Records span ``verify.stage``, ledger site ``verify.stage`` (the
+    valid bytes, what the device is credited with) and
+    ``verify.bytes_valid`` / ``verify.bytes_padded``."""
+    spans = []
+    off = payload = 0
+    for n in lengths:
+        spans.append((off, n))
+        payload += n
+        off += n + (-n % blobid.LEAF_SIZE)
+    with span("verify.stage"):
+        staging = np.zeros((_buffer_bucket(max(off, 1)),), np.uint8)
+        with filling():
+            for i, (start, n) in enumerate(spans):
+                if n:
+                    fill(i, staging[start: start + n])
+    record_copy("verify.stage", payload)
+    count("verify.bytes_valid", payload)
+    count("verify.bytes_padded", len(staging) - payload)
+    return staging, spans
+
+
 def verify_blob_batch(pairs: list) -> list:
     """Device-batch blob-id verification: ``pairs`` is
     [(expected-id-hex, plaintext bytes)]; returns the ids whose content
     re-derives to something else. One fused dispatch per call (blobs
-    pack page-aligned — hash_spans' fast path); decrypt/decompress
-    stay with the caller, only the per-byte hashing rides the device.
+    pack page-aligned through ``stage_page_aligned`` — hash_spans' fast
+    path); decrypt/decompress stay with the caller, only the per-byte
+    hashing rides the device.
     Shared by Repository.check's device path and TreeRestore."""
     if not pairs:
         return []
-    spans = []
-    off = payload = 0
-    for _, data in pairs:
-        spans.append((off, len(data)))
-        payload += len(data)
-        off += len(data) + (-len(data) % blobid.LEAF_SIZE)
-    # One zeroed bucket-sized staging buffer, one copy per blob into its
-    # page-aligned slot (the single sanctioned copy of this path —
-    # replaces the old pieces-list + b"".join + np.pad double copy);
-    # hash_spans then uploads it with no further host-side pad.
-    with span("verify.stage"):
-        staging = np.zeros((_buffer_bucket(max(off, 1)),), np.uint8)
-        for (start, _), (_, data) in zip(spans, pairs):
-            n = len(data)
-            if n:
-                staging[start: start + n] = np.frombuffer(
-                    data, np.uint8, count=n)
-    record_copy("verify.stage", payload)
-    count("verify.bytes_valid", payload)
-    count("verify.bytes_padded", len(staging) - payload)
+
+    def copy_blob(i, slot):
+        slot[:] = np.frombuffer(pairs[i][1], np.uint8, count=len(slot))
+
+    staging, spans = stage_page_aligned(
+        [len(data) for _, data in pairs], copy_blob)
     got = hash_spans(staging, spans)
     return [bid for (bid, _), d in zip(pairs, got) if d != bid]
 

@@ -11,28 +11,50 @@ the TPU hash pipeline:
     (engine/chunker.py hash_spans) — the per-byte work that rclone does
     on CPU cores is the batched-lane SHA-256 kernel here;
   - bucket layout is content-addressed: ``<prefix>/objects/<digest>``
-    holds file bytes, ``<prefix>/index.json`` maps relpath -> metadata
-    (type, size, mode, mtime_ns, digest / symlink target). The index is
-    the facl-dump analogue: modes and mtimes round-trip through it;
+    holds file bytes, ``<prefix>/index/manifest.json`` names the shards
+    under ``<prefix>/index/shards/`` that map relpath -> metadata (type,
+    size, mode, mtime_ns, owner, xattrs, digest / symlink target). The
+    index is the facl-dump analogue: metadata round-trips through it.
+    The layout is this mover's own: a stock ``rclone`` binary reads the
+    objects as files named by checksum, not as the tree;
   - transfers fan out over a thread pool (the --transfers 10 analogue;
     object-store puts/gets are IO-bound);
   - mirror semantics: objects no longer referenced by the new index are
     deleted (source direction), local files not in the index are deleted
     (destination direction); empty directories are preserved
-    (--create-empty-src-dirs).
+    (--create-empty-src-dirs);
+  - ``--checksum`` on both sides: every checksum compared was computed
+    on this run from the file's bytes, and a fetched file is hashed on
+    the device, under a temporary name, before it is left under its own
+    (a mismatch fails the sync: ``rclone.fetch_mismatch``).
+
+Spans and counters (``rclone.*``; docs/observability.md): the entry's
+thread records scan, hash, lease, list, transfer_wait, index_read,
+index_write, sweep, delete_local, place and apply_meta, which add up to
+the call's wall; the transfer pool's threads record one ``rclone.put`` /
+``rclone.get`` an object under the caller's trace.
 """
 
 from __future__ import annotations
 
+import hashlib
 import json
 import logging
 import os
+import shutil
 import stat as stat_mod
+import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from pathlib import Path
 
-from volsync_tpu.engine.chunker import hash_file_streaming, hash_spans
+from volsync_tpu.engine.chunker import (
+    hash_file_streaming,
+    hash_spans,
+    stage_page_aligned,
+)
 from volsync_tpu.engine.restore import _apply_owner, _apply_xattrs
+from volsync_tpu.obs import carry_context, count, span
 from volsync_tpu.objstore.store import (
     NoSuchKey,
     ObjectStore,
@@ -97,14 +119,10 @@ class _MirrorLease:
         self._stop = None
 
     def _stamp(self):
-        import time
-
         self.store.put(self.key, json.dumps(
             {"holder": self.holder, "time": time.time()}).encode())
 
     def _others_fresh(self) -> list:
-        import time
-
         fresh = []
         for key in list(self.store.list(_key(self.prefix, LOCKS))):
             if key == self.key:
@@ -120,14 +138,13 @@ class _MirrorLease:
         return fresh
 
     def __enter__(self):
-        import threading
-
-        self._stamp()
-        others = self._others_fresh()
-        if others:
-            self.store.delete(self.key)  # back off: only our own lock
-            raise BucketLockedError(
-                f"{self.prefix}: mirror held by {others}")
+        with span("rclone.lease"):
+            self._stamp()
+            others = self._others_fresh()
+            if others:
+                self.store.delete(self.key)  # back off: only our own lock
+                raise BucketLockedError(
+                    f"{self.prefix}: mirror held by {others}")
         stop = threading.Event()
         self._stop = stop
         restamp_policy = RetryPolicy.from_env(
@@ -150,7 +167,8 @@ class _MirrorLease:
     def __exit__(self, *exc):
         if self._stop is not None:
             self._stop.set()
-        self.store.delete(self.key)  # only ever our own lock object
+        with span("rclone.lease"):
+            self.store.delete(self.key)  # only ever our own lock object
 
 
 def _safe_rel(rel: str) -> bool:
@@ -198,6 +216,10 @@ def scan_tree(root: Path, *, collect_meta: bool = True) -> dict[str, dict]:
     # delete real data from the empty index).
     root_dev = root.stat().st_dev
     for dirpath, dirnames, filenames in os.walk(root):
+        # by name, not in the filesystem's order: what a hash pass
+        # batches together is then a function of the tree alone
+        dirnames.sort()
+        filenames.sort()
         d = Path(dirpath)
         rel_dir = d.relative_to(root).as_posix()
         if rel_dir != ".":
@@ -235,48 +257,61 @@ def scan_tree(root: Path, *, collect_meta: bool = True) -> dict[str, dict]:
 
 
 def hash_files(root: Path, rels: list[str]) -> dict[str, str]:
-    """Device digests for the given files. Small files pack into ~64 MiB
-    host buffers (one upload + one batched SHA-256 call per buffer —
-    engine/chunker.py hash_spans); large files hash segment-by-segment
-    with bounded memory (hash_file_streaming)."""
+    """Device digests for the given files. Small files are read straight
+    into the page-aligned slots of ~64 MiB staging buffers (the stager
+    of the restore's verify batches, engine/chunker.stage_page_aligned;
+    one upload + one batched SHA-256 call per buffer — hash_spans);
+    large files hash segment-by-segment with bounded memory
+    (hash_file_streaming)."""
     out: dict[str, str] = {}
-    batch: list[tuple[str, bytes]] = []
-    batch_bytes = 0
+    batch: list[tuple[str, int]] = []
+    batch_bytes = total = 0
+
+    def read_file(i, slot):
+        rel, n = batch[i]
+        view = memoryview(slot)
+        got = 0
+        with open(root / rel, "rb", buffering=0) as f:
+            while got < n:
+                k = f.readinto(view[got:])
+                if not k:
+                    break
+                got += k
+        if got != n:
+            raise SyncError(f"{rel}: changed while it was hashed "
+                            f"({got} of {n} bytes read)")
 
     def flush():
         nonlocal batch, batch_bytes
         if not batch:
             return
-        # Files pack at 4 KiB-aligned offsets (<=4095B zero fill each),
+        # Files sit at 4 KiB-aligned offsets (<=4095B zero fill each),
         # which puts every Merkle leaf on the buffer's page grid — the
         # hash_spans fused fast path (ops/segment.span_roots_device):
         # one dispatch + one [N, 8] fetch, no per-leaf gathers.
-        pieces: list[bytes] = []
-        spans = []
-        off = 0
-        for _, data in batch:
-            spans.append((off, len(data)))
-            pieces.append(data)
-            pad = -len(data) % 4096
-            if pad:
-                pieces.append(bytes(pad))
-            off += len(data) + pad
-        buf = b"".join(pieces)
-        for (rel, _), digest in zip(batch, hash_spans(buf, spans)):
+        staging, spans = stage_page_aligned(
+            [n for _, n in batch], read_file,
+            filling=lambda: span("rclone.read"))
+        for (rel, _), digest in zip(batch, hash_spans(staging, spans)):
             out[rel] = digest
+        count("rclone.hash_batches")
         batch, batch_bytes = [], 0
 
-    for rel in rels:
-        p = root / rel
-        if p.stat().st_size > _STREAM_THRESHOLD:
-            out[rel] = hash_file_streaming(p)
-            continue
-        data = p.read_bytes()
-        batch.append((rel, data))
-        batch_bytes += len(data)
-        if batch_bytes >= _BATCH_BYTES:
-            flush()
-    flush()
+    with span("rclone.hash"):
+        for rel in rels:
+            p = root / rel
+            n = p.stat().st_size
+            total += n
+            if n > _STREAM_THRESHOLD:
+                out[rel] = hash_file_streaming(p)
+                continue
+            batch.append((rel, n))
+            batch_bytes += n
+            if batch_bytes >= _BATCH_BYTES:
+                flush()
+        flush()
+    count("rclone.files_hashed", len(rels))
+    count("rclone.bytes_hashed", total)
     return out
 
 
@@ -284,8 +319,6 @@ def _shard_of(rel: str) -> str:
     """Index shard for a relpath: all entries of one DIRECTORY share a
     shard (a changed file dirties exactly its directory's shard), hashed
     into at most 256 buckets so huge flat trees still bound shard count."""
-    import hashlib
-
     d = rel.rsplit("/", 1)[0] if "/" in rel else ""
     return hashlib.sha256(d.encode()).hexdigest()[:2]
 
@@ -294,15 +327,13 @@ def write_index(store: ObjectStore, prefix: str,
                 entries: dict[str, dict]) -> dict:
     """Persist the index as per-directory shards + a small manifest.
 
-    BASELINE configs[3] (100 GiB, many small files) is metadata-heavy:
+    BASELINE configs[2] (100 GiB, many small files) is metadata-heavy:
     a monolithic index.json re-uploads every entry on every sync. Here
     a sync touches O(changed directories) index bytes: each shard's
     object name embeds its content hash, so unchanged shards are simply
     re-referenced by the new manifest and never re-serialized past the
     grouping pass. Returns {"shards": total, "written": uploaded}.
     """
-    import hashlib
-
     groups: dict[str, dict[str, dict]] = {}
     for rel, e in entries.items():
         groups.setdefault(_shard_of(rel), {})[rel] = e
@@ -389,7 +420,8 @@ def sync_up(root: Path, store: ObjectStore, prefix: str, *,
     unreferenced objects are deleted afterwards (mirror semantics).
     """
     root = Path(root)
-    entries = scan_tree(root)
+    with span("rclone.scan"):
+        entries = scan_tree(root)
     files = [r for r, e in entries.items() if e["type"] == "file"]
     digests = hash_files(root, files)
     for rel in files:
@@ -400,12 +432,20 @@ def sync_up(root: Path, store: ObjectStore, prefix: str, *,
                           transfers)
 
 
+def _put_object(store, key: str, src: Path) -> None:
+    with span("rclone.put"):
+        put_file(store, key, src)
+
+
 def _mirror_up(root, store, prefix, entries, files, digests,
                transfers) -> dict:
     wanted = set(digests.values())
-    have = {k.rsplit("/", 1)[-1] for k in store.list(_key(prefix, OBJECTS))}
+    with span("rclone.list"):
+        have = {k.rsplit("/", 1)[-1]
+                for k in store.list(_key(prefix, OBJECTS))}
     to_upload = wanted - have
-    uploaded = 0
+    uploaded_bytes = 0
+    put = carry_context(_put_object)
     with ThreadPoolExecutor(max_workers=transfers) as pool:
         futs = []
         seen: set[str] = set()
@@ -413,89 +453,66 @@ def _mirror_up(root, store, prefix, entries, files, digests,
             d = digests[rel]
             if d in to_upload and d not in seen:
                 seen.add(d)
+                uploaded_bytes += entries[rel]["size"]
                 futs.append(pool.submit(
-                    put_file, store, _key(prefix, OBJECTS, d), root / rel))
-        for f in futs:
-            f.result()
+                    put, store, _key(prefix, OBJECTS, d), root / rel))
+        with span("rclone.transfer_wait"):
+            for f in futs:
+                f.result()
+            pool.shutdown()
         uploaded = len(futs)
 
-    idx_stats = write_index(store, prefix, entries)
+    with span("rclone.index_write"):
+        idx_stats = write_index(store, prefix, entries)
 
     # mirror: drop objects the new index no longer references
+    with span("rclone.list"):
+        stored = list(store.list(_key(prefix, OBJECTS)))
     deleted = 0
-    for key in list(store.list(_key(prefix, OBJECTS))):
-        if key.rsplit("/", 1)[-1] not in wanted:
-            store.delete(key)
-            deleted += 1
+    with span("rclone.sweep"):
+        for key in stored:
+            if key.rsplit("/", 1)[-1] not in wanted:
+                store.delete(key)
+                deleted += 1
+    nbytes = sum(entries[rel]["size"] for rel in files)
+    count("rclone.files_uploaded", uploaded)
+    count("rclone.bytes_uploaded", uploaded_bytes)
+    count("rclone.files_skipped", len(files) - uploaded)
+    count("rclone.objects_deleted", deleted)
+    count("rclone.bytes_synced", nbytes)
     return {"files": len(files), "uploaded": uploaded,
             "deduped": len(files) - uploaded, "deleted_objects": deleted,
             "index_shards": idx_stats["shards"],
             "index_shards_written": idx_stats["written"],
-            "bytes": sum(e["size"] for e in entries.values()
-                         if e["type"] == "file")}
+            "bytes": nbytes}
 
 
-def sync_down(store: ObjectStore, prefix: str, root: Path, *,
-              transfers: int = DEFAULT_TRANSFERS) -> dict:
-    """Bucket -> volume mirror (DIRECTION=destination, active.sh:28-33).
+def _clear_path(p: Path) -> None:
+    """Remove whatever stands at ``p``. unlink, not rmtree, for a
+    symlink: rmtree silently refuses symlinks, and a surviving symlink
+    would make the next write follow it (possibly out of the volume)
+    instead of replacing it."""
+    if p.is_dir() and not p.is_symlink():
+        shutil.rmtree(p, ignore_errors=True)
+    elif p.is_symlink() or p.exists():
+        p.unlink()
 
-    Local files whose digest already matches are untouched (checksum
-    compare); metadata (mode, mtime) is re-applied from the index either
-    way — the setfacl --restore analogue. Extraneous local paths are
-    deleted.
-    """
-    root = Path(root)
-    root.mkdir(parents=True, exist_ok=True)
-    got = read_index(store, prefix)
-    if not got and not store.exists(_key(prefix, INDEX_MANIFEST)) \
-            and not store.exists(_key(prefix, INDEX_KEY)):
-        raise SyncError(
-            f"no index at {prefix!r}: nothing has been synced here")
-    entries = _validated_entries(got)
 
-    local = scan_tree(root, collect_meta=False)
-    local_files = [r for r, e in local.items() if e["type"] == "file"
-                   and r in entries and entries[r]["type"] == "file"
-                   and entries[r]["size"] == e["size"]]
-    local_digests = hash_files(root, local_files)
+def _fetch_name(rel: str) -> str:
+    """Where a fetched file waits, beside its own name, until its bytes
+    have been hashed to the checksum the index gives."""
+    head, _, name = rel.rpartition("/")
+    tmp = f".volsync.fetch.{os.getpid()}.{name}"
+    return f"{head}/{tmp}" if head else tmp
 
-    # delete extraneous paths first (files, then emptied dirs bottom-up)
-    deleted = 0
-    for rel in sorted(local, key=len, reverse=True):
-        if rel not in entries:
-            p = root / rel
-            if p.is_symlink() or p.is_file():
-                p.unlink()
-            elif p.is_dir():
-                import shutil
 
-                shutil.rmtree(p, ignore_errors=True)
-            deleted += 1
-
-    # directories (create-empty-src-dirs), shallow-first
-    for rel in sorted((r for r, e in entries.items() if e["type"] == "dir"),
-                      key=len):
-        p = root / rel
-        if p.is_symlink() or (p.exists() and not p.is_dir()):
-            p.unlink()
-        p.mkdir(parents=True, exist_ok=True)
-
-    skipped = 0
-
-    def materialize(rel: str, entry: dict):
-        p = root / rel
-        if p.is_symlink() or p.is_file():
-            # unlink, not rmtree: rmtree silently refuses symlinks, and a
-            # surviving symlink would make the write follow it (possibly
-            # out of the volume) instead of replacing it
-            p.unlink()
-        elif p.is_dir():
-            import shutil
-
-            shutil.rmtree(p, ignore_errors=True)
-        p.parent.mkdir(parents=True, exist_ok=True)
+def _fetch_object(store, prefix: str, root: Path, rel: str, entry: dict,
+                  tmp: str) -> None:
+    with span("rclone.get"):
+        dst = root / tmp
+        dst.parent.mkdir(parents=True, exist_ok=True)
         try:
-            n = get_file(store, _key(prefix, OBJECTS, entry["digest"]), p)
+            n = get_file(store, _key(prefix, OBJECTS, entry["digest"]), dst)
         except NoSuchKey:
             # e.g. a concurrent source-direction mirror swept an object
             # the index we read still references — retryable sync failure,
@@ -505,51 +522,128 @@ def sync_down(store: ObjectStore, prefix: str, root: Path, *,
         if n != entry["size"]:
             raise SyncError(f"{rel}: object size mismatch")
 
-    with ThreadPoolExecutor(max_workers=transfers) as pool:
-        futs = []
-        for rel, entry in entries.items():
-            if entry["type"] != "file":
-                continue
-            if local_digests.get(rel) == entry["digest"]:
-                skipped += 1
-                continue
-            futs.append(pool.submit(materialize, rel, entry))
-        for f in futs:
-            f.result()
-        fetched = len(futs)
 
-    for rel, entry in entries.items():
-        p = root / rel
-        if entry["type"] == "symlink":
-            if p.is_symlink() or p.exists():
-                if p.is_dir() and not p.is_symlink():
-                    import shutil
+def _fetch_verified(store, prefix: str, root: Path, wanted: dict,
+                    transfers: int) -> None:
+    """Fetch ``wanted`` ({relpath: index entry}) under temporary names
+    on the transfer pool, hash what arrived on the device (hash_files:
+    the same batches as the local pass), and leave a file under its own
+    name only if its bytes hash to the checksum the index gives
+    (``rclone sync --checksum`` checks the hash after the transfer). A
+    mismatch fails the sync; the files that matched are in place, no
+    temporary is left either way."""
+    tmp_of = {rel: _fetch_name(rel) for rel in wanted}
+    fetch = carry_context(_fetch_object)
+    try:
+        with ThreadPoolExecutor(max_workers=transfers) as pool:
+            futs = [pool.submit(fetch, store, prefix, root, rel, entry,
+                                tmp_of[rel])
+                    for rel, entry in wanted.items()]
+            with span("rclone.transfer_wait"):
+                for f in futs:
+                    f.result()
+                pool.shutdown()
+        got = hash_files(root, list(tmp_of.values()))
+        bad = []
+        with span("rclone.place"):
+            for rel, entry in wanted.items():
+                if got[tmp_of[rel]] != entry["digest"]:
+                    bad.append(rel)
+                    continue
+                _clear_path(root / rel)
+                os.replace(root / tmp_of.pop(rel), root / rel)
+        if bad:
+            count("rclone.fetch_mismatch", len(bad))
+            raise SyncError(
+                f"{len(bad)} fetched file(s) do not hash to the index's "
+                f"checksum (first: {bad[0]}): left as they were")
+    finally:  # what did not take its name: a mismatch, or a failure
+        for tmp in tmp_of.values():
+            (root / tmp).unlink(missing_ok=True)
 
-                    shutil.rmtree(p, ignore_errors=True)
-                else:
-                    p.unlink()
-            p.parent.mkdir(parents=True, exist_ok=True)
-            os.symlink(entry["target"], p)
-            _apply_xattrs(p, entry)
-            _apply_owner(p, entry)
-        elif entry["type"] == "file":
-            # xattrs before chmod (read-only modes block setxattr),
-            # chown before chmod (chown clears suid) — the engine
-            # restore's ordering; the index carries the facl-dump
-            # analogue (owner + ACL xattrs)
-            _apply_xattrs(p, entry)
-            _apply_owner(p, entry)
-            os.chmod(p, entry["mode"])
-            os.utime(p, ns=(entry["mtime_ns"], entry["mtime_ns"]))
-    # dir metadata last (child writes bump parent mtimes), deepest first
+
+def sync_down(store: ObjectStore, prefix: str, root: Path, *,
+              transfers: int = DEFAULT_TRANSFERS) -> dict:
+    """Bucket -> volume mirror (DIRECTION=destination, active.sh:28-33).
+
+    Local files whose digest already matches are untouched (checksum
+    compare, the digest computed on this run from the file's bytes); the
+    others are fetched and hashed before they take their name
+    (_fetch_verified); metadata (mode, mtime, owner, xattrs) is
+    re-applied from the index either way — the setfacl --restore
+    analogue. Extraneous local paths are deleted.
+    """
+    root = Path(root)
+    root.mkdir(parents=True, exist_ok=True)
+    with span("rclone.index_read"):
+        got = read_index(store, prefix)
+        if not got and not store.exists(_key(prefix, INDEX_MANIFEST)) \
+                and not store.exists(_key(prefix, INDEX_KEY)):
+            raise SyncError(
+                f"no index at {prefix!r}: nothing has been synced here")
+    entries = _validated_entries(got)
+
+    with span("rclone.scan"):
+        local = scan_tree(root, collect_meta=False)
+    local_files = [r for r, e in local.items() if e["type"] == "file"
+                   and r in entries and entries[r]["type"] == "file"
+                   and entries[r]["size"] == e["size"]]
+    local_digests = hash_files(root, local_files)
+
+    # delete extraneous paths first (files, then emptied dirs bottom-up)
+    deleted = 0
+    with span("rclone.delete_local"):
+        for rel in sorted(local, key=len, reverse=True):
+            if rel not in entries:
+                _clear_path(root / rel)
+                deleted += 1
+
+    # directories (create-empty-src-dirs), shallow-first
     for rel in sorted((r for r, e in entries.items() if e["type"] == "dir"),
-                      key=len, reverse=True):
-        entry = entries[rel]
-        _apply_xattrs(root / rel, entry)
-        _apply_owner(root / rel, entry)
-        os.chmod(root / rel, entry["mode"])
-        os.utime(root / rel, ns=(entry["mtime_ns"], entry["mtime_ns"]))
-    return {"files": sum(1 for e in entries.values() if e["type"] == "file"),
-            "fetched": fetched, "skipped": skipped, "deleted_local": deleted,
-            "bytes": sum(e.get("size", 0) for e in entries.values()
-                         if e["type"] == "file")}
+                      key=len):
+        p = root / rel
+        if p.is_symlink() or (p.exists() and not p.is_dir()):
+            p.unlink()
+        p.mkdir(parents=True, exist_ok=True)
+
+    files = {r: e for r, e in entries.items() if e["type"] == "file"}
+    wanted = {r: e for r, e in files.items()
+              if local_digests.get(r) != e["digest"]}
+    _fetch_verified(store, prefix, root, wanted, transfers)
+
+    with span("rclone.apply_meta"):
+        for rel, entry in entries.items():
+            p = root / rel
+            if entry["type"] == "symlink":
+                _clear_path(p)
+                p.parent.mkdir(parents=True, exist_ok=True)
+                os.symlink(entry["target"], p)
+                _apply_xattrs(p, entry)
+                _apply_owner(p, entry)
+            elif entry["type"] == "file":
+                # xattrs before chmod (read-only modes block setxattr),
+                # chown before chmod (chown clears suid) — the engine
+                # restore's ordering; the index carries the facl-dump
+                # analogue (owner + ACL xattrs)
+                _apply_xattrs(p, entry)
+                _apply_owner(p, entry)
+                os.chmod(p, entry["mode"])
+                os.utime(p, ns=(entry["mtime_ns"], entry["mtime_ns"]))
+    # dir metadata last (child writes bump parent mtimes), deepest first
+    with span("rclone.apply_meta"):
+        for rel in sorted((r for r, e in entries.items()
+                           if e["type"] == "dir"), key=len, reverse=True):
+            entry = entries[rel]
+            _apply_xattrs(root / rel, entry)
+            _apply_owner(root / rel, entry)
+            os.chmod(root / rel, entry["mode"])
+            os.utime(root / rel, ns=(entry["mtime_ns"], entry["mtime_ns"]))
+    nbytes = sum(e["size"] for e in files.values())
+    count("rclone.files_fetched", len(wanted))
+    count("rclone.bytes_fetched", sum(e["size"] for e in wanted.values()))
+    count("rclone.files_skipped", len(files) - len(wanted))
+    count("rclone.local_deleted", deleted)
+    count("rclone.bytes_synced", nbytes)
+    return {"files": len(files), "fetched": len(wanted),
+            "skipped": len(files) - len(wanted), "deleted_local": deleted,
+            "bytes": nbytes}
