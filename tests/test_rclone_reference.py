@@ -8,6 +8,7 @@ the cell does at its size, and the configuration's guarantees (a)-(d)
 are held one by one. CPU, small sizes, seeded."""
 
 import ast
+import base64
 import os
 import time
 from pathlib import Path
@@ -237,12 +238,25 @@ def test_a_missing_object_fails_the_sync_and_leaves_no_temporary(tmp_path):
 ITEMS = [0, 1, 4095, 4096, 4097, 65536, 70_001]
 
 
-@pytest.mark.parametrize("caller", ["verify_blob_batch", "hash_files"])
-def test_the_stagers_two_callers_give_the_reference_ids(tmp_path, caller):
+def _record_calls(monkeypatch, names, calls):
+    """Let ``os.<name>`` append (name, first argument) to ``calls``
+    before it does its work."""
+    for name in names:
+        def recorded(path, *args, _name=name, _fn=getattr(os, name), **kw):
+            calls.append((_name, path))
+            return _fn(path, *args, **kw)
+        monkeypatch.setattr(os, name, recorded)
+
+
+@pytest.mark.parametrize("caller", ["verify_blob_batch", "hash_files",
+                                    "hash_files_sizes_in_hand"])
+def test_the_stagers_two_callers_give_the_reference_ids(tmp_path, caller,
+                                                        monkeypatch):
     """One stager (``engine/chunker.stage_page_aligned``), two callers:
     the same items come out with the ids of ``reference/blobid.py``,
     and both record the site and the counters the roofline and the
-    useful share are read from."""
+    useful share are read from. A hash pass that is handed the sizes
+    its caller's scan took asks the kernel for none."""
     from volsync_tpu.engine.chunker import _buffer_bucket, verify_blob_batch
     from volsync_tpu.movers.rclone.sync import hash_files
 
@@ -262,7 +276,15 @@ def test_the_stagers_two_callers_give_the_reference_ids(tmp_path, caller):
         for i, b in enumerate(blobs):
             (tmp_path / f"f{i}").write_bytes(b)
         rels = [f"f{i}" for i in range(len(blobs))]
-        assert hash_files(tmp_path, rels) == dict(zip(rels, want))
+        in_hand = caller == "hash_files_sizes_in_hand"
+        stats = []
+        _record_calls(monkeypatch, ("stat", "lstat"), stats)
+        assert hash_files(tmp_path, rels, list(ITEMS) if in_hand else None) \
+            == dict(zip(rels, want))
+        monkeypatch.undo()
+        asked = [path for _, path in stats
+                 if str(path).startswith(str(tmp_path))]
+        assert len(asked) == (0 if in_hand else len(ITEMS))
         valid = sum(ITEMS)
         padded = _buffer_bucket(sum(n + -n % 4096 for n in ITEMS))
         assert span_totals()["rclone.read"][0] == 1
@@ -407,3 +429,177 @@ def test_the_cells_two_states_differ_as_its_file_says(tmp_path):
         assert states[0]["other"][rel] == in_b
         assert states[1]["other"][rel] == in_a
         assert states[0]["files"][rel] == states[1]["files"][rel]
+
+
+def _walk_oracle(root: Path) -> list[tuple[str, dict]]:
+    """``scan_tree``'s entries, in its order, by ``os.walk`` and
+    ``os.lstat`` alone: a directory, its files by name, its symlinked
+    directories, then its subdirectories by name, depth first."""
+    def meta(p):
+        st = os.lstat(p)
+        return {"uid": st.st_uid, "gid": st.st_gid, "xattrs": {
+            n: base64.b64encode(os.getxattr(
+                p, n, follow_symlinks=False)).decode()
+            for n in sorted(os.listxattr(p, follow_symlinks=False))}}
+
+    def kept(p):
+        st = os.lstat(p)
+        return {"mode": st.st_mode & 0o7777, "mtime_ns": st.st_mtime_ns}
+
+    out = []
+    for top, dirnames, filenames in os.walk(root):
+        dirnames.sort()
+        rel_top = os.path.relpath(top, root)
+        prefix = "" if rel_top == "." else rel_top + "/"
+        if prefix:
+            out.append((rel_top, {"type": "dir", **kept(top), **meta(top)}))
+        for name in sorted(filenames):
+            p = os.path.join(top, name)
+            if os.path.islink(p):
+                out.append((prefix + name, {
+                    "type": "symlink", "target": os.readlink(p), **meta(p)}))
+            elif os.path.isfile(p):
+                out.append((prefix + name, {
+                    "type": "file", "size": os.lstat(p).st_size,
+                    **kept(p), **meta(p)}))
+        for name in list(dirnames):
+            p = os.path.join(top, name)
+            if os.path.islink(p):
+                dirnames.remove(name)
+                out.append((prefix + name, {
+                    "type": "symlink", "target": os.readlink(p), **meta(p)}))
+    return out
+
+
+@pytest.mark.parametrize("collect_meta", [True, False])
+def test_the_scan_is_the_plain_walk_in_its_order(tmp_path, collect_meta):
+    """What a hash pass batches together, and so the programs a warm-up
+    meets, is a function of ``scan_tree``'s order: it is the plain
+    walk's, entry for entry, on nested directories, a symlink to a
+    file, one to a directory, a dangling one, an empty directory and a
+    FIFO (which no mover carries)."""
+    from volsync_tpu.movers.rclone.sync import scan_tree
+
+    vol = tmp_path / "v"
+    for rel in ("b/z", "b/a/deep/f", "b/a/e", "a.d/x", "a", "c", "b.x",
+                "b/.hidden", "B/y"):
+        (vol / rel).parent.mkdir(parents=True, exist_ok=True)
+        (vol / rel).write_bytes(rel.encode() * 7)
+    (vol / "hollow").mkdir()
+    (vol / "b" / "void").mkdir()
+    os.symlink("a", vol / "to_file")
+    os.symlink("b/a", vol / "a_dir_link")
+    os.symlink("../b", vol / "b" / "loop")
+    os.symlink("nowhere", vol / "b" / "dangling")
+    os.mkfifo(vol / "b" / "fifo")
+    os.chmod(vol / "c", 0o4750)
+    os.utime(vol / "b" / "z", ns=(1, 1_234_567_891_234_567_891))
+    try:
+        os.setxattr(vol / "a", "user.tag", b"\x00v")
+    except OSError:
+        pass  # a filesystem without user.*: both sides then read none
+    want = _walk_oracle(vol)
+    if not collect_meta:
+        want = [(rel, {k: v for k, v in e.items() if k != "xattrs"})
+                for rel, e in want]
+    got = scan_tree(vol, collect_meta=collect_meta)
+    assert list(got.items()) == want
+    assert list(got)[:8] == ["a", "b.x", "c", "to_file", "a_dir_link",
+                             "B", "B/y", "a.d"]
+    kinds = {rel: e["type"] for rel, e in want}
+    assert kinds["a_dir_link"] == kinds["b/loop"] == "symlink"
+    assert kinds["b/dangling"] == kinds["to_file"] == "symlink"
+    assert kinds["hollow"] == kinds["b/void"] == "dir"
+    assert "b/fifo" not in kinds and "b/a/deep/f" in kinds
+    assert not any(rel.startswith("a_dir_link/") for rel in kinds)
+
+
+def _drift_mode(f: Path):
+    os.chmod(f, 0o600)
+
+
+def _drift_mtime(f: Path):
+    os.utime(f, ns=(5, 5))
+
+
+def _drift_xattr(f: Path):
+    os.setxattr(f, "user.drift", b"1")
+
+
+def _drift_xattr_value(f: Path):
+    os.setxattr(f, "user.keep", b"other")
+
+
+def _drift_content(f: Path):
+    was = os.stat(f)
+    f.write_bytes(bytes(reversed(f.read_bytes())))
+    os.utime(f, ns=(was.st_atime_ns, was.st_mtime_ns))
+
+
+#: drift -> (what it does to one file of the destination, the calls on
+#: regular files that put it back)
+DRIFTS = {
+    "none": (lambda f: None, []),
+    "mode": (_drift_mode, ["chmod"]),
+    "mtime": (_drift_mtime, ["utime"]),
+    # one name too many: the whole set is applied, as the restore does
+    "xattr": (_drift_xattr, ["removexattr", "setxattr"]),
+    "xattr_value": (_drift_xattr_value, ["setxattr"]),
+    # fetched: a new inode gets every call, in the restore's order
+    "content": (_drift_content, ["setxattr", "chown", "chmod", "utime"]),
+}
+
+
+@pytest.mark.parametrize("drift", sorted(DRIFTS))
+def test_metadata_is_settled_by_the_calls_that_change_something(
+        tmp_path, monkeypatch, drift):
+    """The destination's metadata pass works from the scan's record: on
+    a destination that already is the mirror no file gets a ``chown``,
+    ``chmod`` or ``utime`` (``rclone.meta_kept`` == files); after a
+    drift of one file the next sync makes exactly the calls that put it
+    back, on that file, and the trees compare clean. Symlinks and
+    directories get every call every time, as before."""
+    vol, bucket, dest = tmp_path / "v", tmp_path / "bucket", tmp_path / "d"
+    how, want_calls = DRIFTS[drift]
+    rng = np.random.default_rng(SEED)
+    names = ["f0", "sub/f1", "sub/f2", "sub/deeper/f3", "f4"]
+    for i, rel in enumerate(names):
+        (vol / rel).parent.mkdir(parents=True, exist_ok=True)
+        (vol / rel).write_bytes(rng.bytes(3000 + 5000 * i))
+    os.chmod(vol / "f0", 0o640)
+    os.symlink("f0", vol / "link")
+    try:  # the file that drifts carries an xattr where it can
+        os.setxattr(vol / "sub" / "f2", "user.keep", b"v")
+    except OSError:
+        if drift.startswith("xattr"):
+            pytest.skip("the filesystem takes no user.* xattr")
+        want_calls = [name for name in want_calls if name != "setxattr"]
+    assert _sync("source", bucket, vol) == 0
+    assert _sync("destination", bucket, dest) == 0
+    victim = dest / "sub" / "f2"
+    how(victim)
+    calls = []
+    _record_calls(monkeypatch, ("chown", "chmod", "utime", "setxattr",
+                                "removexattr"), calls)
+    reset_spans()
+    assert _sync("destination", bucket, dest) == 0
+    monkeypatch.undo()
+    # whether a path is a file is asked now, not while the sync ran: a
+    # fetched file's temporary name is gone, its own name is the file
+    on_files = [(name, str(path)) for name, path in calls
+                if os.path.isfile(path) and not os.path.islink(path)]
+    assert on_files == [(name, str(victim)) for name in want_calls]
+    on_dirs = {str(path) for name, path in calls if os.path.isdir(path)
+               and not os.path.islink(path) and name == "utime"}
+    assert on_dirs == {str(dest / "sub"), str(dest / "sub" / "deeper")}
+    c = counter_totals()
+    assert c["rclone.meta_files"] == len(names)
+    assert c["rclone.meta_kept"] == len(names) - bool(want_calls)
+    assert c["rclone.files_fetched"] == (1 if drift == "content" else 0)
+    assert c["rclone.files_hashed"] == len(names) + c["rclone.files_fetched"]
+    assert _clean(treecmp.compare(vol, dest))
+    assert os.listxattr(victim) == os.listxattr(vol / "sub" / "f2")
+    st, src = os.stat(victim), os.stat(vol / "sub" / "f2")
+    assert (st.st_mode, st.st_mtime_ns, st.st_uid, st.st_gid) == (
+        src.st_mode, src.st_mtime_ns, src.st_uid, src.st_gid)
+    assert victim.read_bytes() == (vol / "sub" / "f2").read_bytes()

@@ -26,7 +26,13 @@ the TPU hash pipeline:
   - ``--checksum`` on both sides: every checksum compared was computed
     on this run from the file's bytes, and a fetched file is hashed on
     the device, under a temporary name, before it is left under its own
-    (a mismatch fails the sync: ``rclone.fetch_mismatch``).
+    (a mismatch fails the sync: ``rclone.fetch_mismatch``);
+  - a pass asks the kernel about a file once: the scan's record of a
+    path (its relative name as a string, the ``lstat`` it took) is what
+    the hash pass sizes its slots from and reads by, and what the
+    destination's metadata pass compares the index with, so that a file
+    left in place gets only the calls that change something
+    (``rclone.meta_kept`` of ``rclone.meta_files`` got none).
 
 Spans and counters (``rclone.*``; docs/observability.md): the entry's
 thread records scan, hash, lease, list, transfer_wait, index_read,
@@ -37,9 +43,11 @@ the call's wall; the transfer pool's threads record one ``rclone.put`` /
 
 from __future__ import annotations
 
+import base64
 import hashlib
 import json
 import logging
+import operator
 import os
 import shutil
 import stat as stat_mod
@@ -171,6 +179,9 @@ class _MirrorLease:
             self.store.delete(self.key)  # only ever our own lock object
 
 
+_by_name = operator.attrgetter("name")
+
+
 def _safe_rel(rel: str) -> bool:
     """Remote index relpaths are untrusted input: reject anything that
     could escape the volume root (absolute paths, '..', empty segments) —
@@ -188,95 +199,122 @@ def _validated_entries(entries: dict) -> dict:
     return entries
 
 
-def _owner_xattrs(st, p) -> dict:
-    """uid/gid + xattrs for the metadata index — the reference rclone
-    mover's `getfacl -R` dump analogue (active.sh:24), which records
-    owner and ACLs; ACLs travel inside system.posix_acl_* xattrs.
-    ``xattrs`` is ALWAYS present (possibly {}) in this index format:
-    removing the last xattr at the source must strip it at the
-    destination too (pre-format indexes are recognized by the absent
-    uid key and left alone)."""
-    from volsync_tpu.engine.backup import _read_xattrs
-
-    return {"uid": st.st_uid, "gid": st.st_gid,
-            "xattrs": _read_xattrs(p)}
-
-
-def scan_tree(root: Path, *, collect_meta: bool = True) -> dict[str, dict]:
+def scan_tree(root, *, collect_meta: bool = True) -> dict[str, dict]:
     """Walk a volume -> {relpath: entry} with file metadata (no digests
     yet). Sockets/devices are skipped, as the reference movers do.
-    ``collect_meta=False`` skips the owner/xattr syscalls — for scans
-    used only for membership/type/size (sync_down's local inventory)."""
-    meta = _owner_xattrs if collect_meta else (lambda st, p: {})
+
+    One ``lstat`` an entry, the one ``DirEntry`` takes, and every later
+    stage of the pass works from what it said (size, mode, mtime_ns,
+    uid, gid) and from the relative name as a string: no ``Path`` is
+    built a file. uid/gid + xattrs are the reference rclone mover's
+    `getfacl -R` dump analogue (active.sh:24), which records owner and
+    ACLs; ACLs travel inside system.posix_acl_* xattrs.
+    ``collect_meta=False`` skips the xattr calls — for sync_down's local
+    inventory, which never reaches an index. With it, ``xattrs`` is
+    ALWAYS present (possibly {}) in this index format: removing the last
+    xattr at the source must strip it at the destination too
+    (pre-format indexes are recognized by the absent uid key and left
+    alone).
+
+    Order: a directory's entry, its files by name, its symlinked
+    directories by name, then its subdirectories by name, depth first —
+    what a hash pass batches together is then a function of the tree
+    alone, not of the filesystem's order. An explicit stack: depth is
+    bounded by memory, not by the interpreter's recursion limit."""
+    from volsync_tpu.engine.backup import _read_xattrs
+
+    def meta(st, path) -> dict:
+        owner = {"uid": st.st_uid, "gid": st.st_gid}
+        if collect_meta:
+            owner["xattrs"] = _read_xattrs(path)
+        return owner
+
+    def link(e, st) -> dict:
+        return {"type": "symlink", "target": os.readlink(e.path),
+                **meta(st, e.path)}
+
     entries: dict[str, dict] = {}
-    root = Path(root)
+    root = os.fspath(root)
     # --one-file-system (active.sh:19). stat(), not lstat(): a
     # symlinked volume root must anchor at the walked filesystem or the
     # whole inventory reads as foreign (and a later mirror pass would
     # delete real data from the empty index).
-    root_dev = root.stat().st_dev
-    for dirpath, dirnames, filenames in os.walk(root):
-        # by name, not in the filesystem's order: what a hash pass
-        # batches together is then a function of the tree alone
-        dirnames.sort()
-        filenames.sort()
-        d = Path(dirpath)
-        rel_dir = d.relative_to(root).as_posix()
-        if rel_dir != ".":
-            st = d.lstat()
+    root_dev = os.stat(root).st_dev
+    stack = [(root, "", None)]  # path, relative name, its lstat
+    while stack:
+        path, rel_dir, st = stack.pop()
+        try:
+            with os.scandir(path) as it:
+                listed = sorted(it, key=_by_name)
+        except OSError:
+            continue  # unreadable: left out, as os.walk leaves it out
+        if st is not None:
             if st.st_dev != root_dev:
-                # mount point: record as an empty dir, don't descend
-                dirnames.clear()
-                filenames = []
+                listed = []  # mount point: an empty dir, don't descend
             entries[rel_dir] = {"type": "dir", "mode": st.st_mode & 0o7777,
                                 "mtime_ns": st.st_mtime_ns,
-                                **meta(st, d)}
-        for name in filenames:
-            p = d / name
-            st = p.lstat()
+                                **meta(st, path)}
+        prefix = rel_dir + "/" if rel_dir else ""
+        dirs = []
+        for e in listed:
+            try:
+                if e.is_dir():  # through a symlink too: sorted out below
+                    dirs.append(e)
+                    continue
+            except OSError:
+                pass
+            st = e.stat(follow_symlinks=False)
             if st.st_dev != root_dev:
                 continue  # foreign device (bind-mounted file)
-            rel = p.relative_to(root).as_posix()
             if stat_mod.S_ISLNK(st.st_mode):
-                entries[rel] = {"type": "symlink",
-                                "target": os.readlink(p), **meta(st, p)}
+                entries[prefix + e.name] = link(e, st)
             elif stat_mod.S_ISREG(st.st_mode):
-                entries[rel] = {"type": "file", "size": st.st_size,
-                                "mode": st.st_mode & 0o7777,
-                                "mtime_ns": st.st_mtime_ns,
-                                **meta(st, p)}
-        # symlinked dirs: record as symlink, don't descend
-        for name in list(dirnames):
-            p = d / name
-            if p.is_symlink():
-                dirnames.remove(name)
-                entries[p.relative_to(root).as_posix()] = {
-                    "type": "symlink", "target": os.readlink(p),
-                    **meta(p.lstat(), p)}
+                entries[prefix + e.name] = {
+                    "type": "file", "size": st.st_size,
+                    "mode": st.st_mode & 0o7777,
+                    "mtime_ns": st.st_mtime_ns, **meta(st, e.path)}
+        below = []
+        for e in dirs:
+            st = e.stat(follow_symlinks=False)
+            if e.is_symlink():  # record as symlink, don't descend
+                entries[prefix + e.name] = link(e, st)
+            else:
+                below.append((e.path, prefix + e.name, st))
+        stack.extend(reversed(below))  # popped by name, depth first
     return entries
 
 
-def hash_files(root: Path, rels: list[str]) -> dict[str, str]:
+def hash_files(root, rels: list[str],
+               sizes: list[int] | None = None) -> dict[str, str]:
     """Device digests for the given files. Small files are read straight
     into the page-aligned slots of ~64 MiB staging buffers (the stager
     of the restore's verify batches, engine/chunker.stage_page_aligned;
     one upload + one batched SHA-256 call per buffer — hash_spans);
     large files hash segment-by-segment with bounded memory
-    (hash_file_streaming)."""
+    (hash_file_streaming).
+
+    ``sizes`` are the files' lengths as the caller's scan (or the GET)
+    gave them, one a name; without them each file is stat-ed here. A
+    file is read by one open, one ``readv`` into its slot and one
+    close: shorter than its size, it changed under the pass and fails
+    it; longer, it is cut at the size the index records for it."""
     out: dict[str, str] = {}
     batch: list[tuple[str, int]] = []
     batch_bytes = total = 0
+    base = os.path.join(os.fspath(root), "")
 
     def read_file(i, slot):
         rel, n = batch[i]
-        view = memoryview(slot)
-        got = 0
-        with open(root / rel, "rb", buffering=0) as f:
-            while got < n:
-                k = f.readinto(view[got:])
+        fd = os.open(base + rel, os.O_RDONLY)
+        try:
+            got = os.readv(fd, [slot])
+            while 0 < got < n:  # a short read: go on to the file's end
+                k = os.readv(fd, [slot[got:]])
                 if not k:
                     break
                 got += k
+        finally:
+            os.close(fd)
         if got != n:
             raise SyncError(f"{rel}: changed while it was hashed "
                             f"({got} of {n} bytes read)")
@@ -298,12 +336,12 @@ def hash_files(root: Path, rels: list[str]) -> dict[str, str]:
         batch, batch_bytes = [], 0
 
     with span("rclone.hash"):
-        for rel in rels:
-            p = root / rel
-            n = p.stat().st_size
+        if sizes is None:
+            sizes = [os.stat(base + rel).st_size for rel in rels]
+        for rel, n in zip(rels, sizes):
             total += n
             if n > _STREAM_THRESHOLD:
-                out[rel] = hash_file_streaming(p)
+                out[rel] = hash_file_streaming(base + rel)
                 continue
             batch.append((rel, n))
             batch_bytes += n
@@ -423,7 +461,7 @@ def sync_up(root: Path, store: ObjectStore, prefix: str, *,
     with span("rclone.scan"):
         entries = scan_tree(root)
     files = [r for r, e in entries.items() if e["type"] == "file"]
-    digests = hash_files(root, files)
+    digests = hash_files(root, files, [entries[r]["size"] for r in files])
     for rel in files:
         entries[rel]["digest"] = digests[rel]
 
@@ -543,7 +581,8 @@ def _fetch_verified(store, prefix: str, root: Path, wanted: dict,
                 for f in futs:
                     f.result()
                 pool.shutdown()
-        got = hash_files(root, list(tmp_of.values()))
+        got = hash_files(root, list(tmp_of.values()),
+                         [entry["size"] for entry in wanted.values()])
         bad = []
         with span("rclone.place"):
             for rel, entry in wanted.items():
@@ -562,6 +601,52 @@ def _fetch_verified(store, prefix: str, root: Path, wanted: dict,
             (root / tmp).unlink(missing_ok=True)
 
 
+def _xattrs_differ(path: str, want: dict) -> bool:
+    """One ``listxattr``; values are read only where the names are the
+    wanted ones and there are any."""
+    try:
+        have = os.listxattr(path, follow_symlinks=False)
+    except OSError:
+        return False  # no xattrs here: _apply_xattrs would stop too
+    if set(have) != set(want):
+        return True
+    try:
+        return any(os.getxattr(path, n, follow_symlinks=False)
+                   != base64.b64decode(v) for n, v in want.items())
+    except OSError:
+        return True
+
+
+def _settle_meta(path: str, entry: dict, have: dict | None) -> bool:
+    """Bring a file's metadata to the index entry's. ``have`` is the
+    scan's record of this very inode (None for a file this run fetched,
+    which gets every call): then only the calls that change something
+    are made, attribute by attribute, as ``rclone sync`` sets a modtime
+    only where it differs. Returns whether none was.
+
+    xattrs before chmod (read-only modes block setxattr), chown before
+    chmod (chown clears suid, so a chown brings its chmod) — the engine
+    restore's ordering; the index carries the facl-dump analogue (owner
+    + ACL xattrs). Absent keys are a pre-format index: left alone."""
+    if have is None:
+        xattrs = owner = mode = times = True
+    else:
+        xattrs = "xattrs" in entry and _xattrs_differ(path, entry["xattrs"])
+        owner = "uid" in entry and (
+            (entry["uid"], entry["gid"]) != (have["uid"], have["gid"]))
+        mode = owner or entry["mode"] != have["mode"]
+        times = entry["mtime_ns"] != have["mtime_ns"]
+    if xattrs:
+        _apply_xattrs(path, entry)
+    if owner:
+        _apply_owner(path, entry)
+    if mode:
+        os.chmod(path, entry["mode"])
+    if times:
+        os.utime(path, ns=(entry["mtime_ns"], entry["mtime_ns"]))
+    return not (xattrs or mode or times)
+
+
 def sync_down(store: ObjectStore, prefix: str, root: Path, *,
               transfers: int = DEFAULT_TRANSFERS) -> dict:
     """Bucket -> volume mirror (DIRECTION=destination, active.sh:28-33).
@@ -569,9 +654,12 @@ def sync_down(store: ObjectStore, prefix: str, root: Path, *,
     Local files whose digest already matches are untouched (checksum
     compare, the digest computed on this run from the file's bytes); the
     others are fetched and hashed before they take their name
-    (_fetch_verified); metadata (mode, mtime, owner, xattrs) is
-    re-applied from the index either way — the setfacl --restore
-    analogue. Extraneous local paths are deleted.
+    (_fetch_verified). Metadata (mode, mtime, owner, xattrs) is brought
+    to the index's either way — the setfacl --restore analogue: applied
+    whole to what this run made (fetched files, symlinks) and to
+    directories, and for a file left in place by the calls that change
+    something (_settle_meta, from the scan's record of it). Extraneous
+    local paths are deleted.
     """
     root = Path(root)
     root.mkdir(parents=True, exist_ok=True)
@@ -588,7 +676,8 @@ def sync_down(store: ObjectStore, prefix: str, root: Path, *,
     local_files = [r for r, e in local.items() if e["type"] == "file"
                    and r in entries and entries[r]["type"] == "file"
                    and entries[r]["size"] == e["size"]]
-    local_digests = hash_files(root, local_files)
+    local_digests = hash_files(root, local_files,
+                               [local[r]["size"] for r in local_files])
 
     # delete extraneous paths first (files, then emptied dirs bottom-up)
     deleted = 0
@@ -611,24 +700,23 @@ def sync_down(store: ObjectStore, prefix: str, root: Path, *,
               if local_digests.get(r) != e["digest"]}
     _fetch_verified(store, prefix, root, wanted, transfers)
 
+    base = os.path.join(os.fspath(root), "")
+    kept = 0
     with span("rclone.apply_meta"):
         for rel, entry in entries.items():
-            p = root / rel
             if entry["type"] == "symlink":
+                p = root / rel
                 _clear_path(p)
                 p.parent.mkdir(parents=True, exist_ok=True)
                 os.symlink(entry["target"], p)
                 _apply_xattrs(p, entry)
                 _apply_owner(p, entry)
             elif entry["type"] == "file":
-                # xattrs before chmod (read-only modes block setxattr),
-                # chown before chmod (chown clears suid) — the engine
-                # restore's ordering; the index carries the facl-dump
-                # analogue (owner + ACL xattrs)
-                _apply_xattrs(p, entry)
-                _apply_owner(p, entry)
-                os.chmod(p, entry["mode"])
-                os.utime(p, ns=(entry["mtime_ns"], entry["mtime_ns"]))
+                # a file not fetched is the inode the scan saw: its
+                # record says which of the calls would change anything
+                kept += _settle_meta(
+                    base + rel, entry,
+                    None if rel in wanted else local[rel])
     # dir metadata last (child writes bump parent mtimes), deepest first
     with span("rclone.apply_meta"):
         for rel in sorted((r for r, e in entries.items()
@@ -639,6 +727,9 @@ def sync_down(store: ObjectStore, prefix: str, root: Path, *,
             os.chmod(root / rel, entry["mode"])
             os.utime(root / rel, ns=(entry["mtime_ns"], entry["mtime_ns"]))
     nbytes = sum(e["size"] for e in files.values())
+    count("rclone.meta_files", len(files))
+    if kept:
+        count("rclone.meta_kept", kept)
     count("rclone.files_fetched", len(wanted))
     count("rclone.bytes_fetched", sum(e["size"] for e in wanted.values()))
     count("rclone.files_skipped", len(files) - len(wanted))
