@@ -332,9 +332,13 @@ _COMPONENT_STAGES = ("svc.admit", "svc.ingest", "svc.queue_wait",
 
 def _report_load_phase(tenants: list[dict], tallies: dict, wall: float,
                        dispatch_log: list) -> dict:
-    from volsync_tpu.obs import stage_seconds_by_tenant
+    from volsync_tpu.metrics import GLOBAL as metrics
 
-    tenant_stages = stage_seconds_by_tenant()
+    # volsync_svc_stage_seconds{tenant,stage}, as an operator scrapes it
+    tenant_stages = {
+        (s.labels["tenant"], s.labels["stage"]): s.value
+        for family in metrics.svc_stage_seconds.collect()
+        for s in family.samples if s.name.endswith("_total")}
     per_tenant: dict = {}
     admitted = sheds = 0
     aborts: list[str] = []

@@ -23,7 +23,7 @@ from volsync_tpu.engine.chunker import (
     params_from_config,
     stream_chunk_batches,
 )
-from volsync_tpu.obs import span
+from volsync_tpu.obs import off_ring, span
 from volsync_tpu.repo import blobid
 from volsync_tpu.repo.repository import (
     BLOB_DATA,
@@ -376,9 +376,14 @@ class TreeBackup:
         Per-blob stats are updated by the repository under its lock;
         everything else was counted in the walk.
 
-        One root span a file, ``backup.file``: its self time is the
-        per-file host path that no inner span names (read, ``blob_id``,
-        the repository's bookkeeping, slicing)."""
+        One root span a file, ``backup.file``. The read and the hash
+        of a host-path file (``backup.read``, ``backup.blob_id``), the
+        opening of a device-path file's reader (``backup.open``), the
+        repository (``repo.add`` and the waits inside it) and the wait
+        for the read-ahead (``engine.read_wait``) are spans inside it;
+        its self time is what is left: the slicing of a segment's
+        chunks, the tail carry, the closing ``lstat`` and the
+        generator's own loop."""
         on_host = (st.st_size <= self.params.min_size
                    or self._wants_full(st.st_size))
         with span("backup.file", path="host" if on_host else "device"):
@@ -387,8 +392,11 @@ class TreeBackup:
     def _hash_file_body(self, path: Path, rel: str, st, stats: BackupStats,
                         on_host: bool) -> tuple[str, tuple]:
         if on_host:
-            data = path.read_bytes()
-            digest = blobid.blob_id(data)
+            quiet = off_ring()  # two spans a file: totals, not events
+            with span("backup.read", ctx=quiet):
+                data = path.read_bytes()
+            with span("backup.blob_id", ctx=quiet):
+                digest = blobid.blob_id(data)
             self.repo.add_blob(BLOB_DATA, digest, data, stats)
             content = [digest]
             hashed = len(data)
@@ -398,7 +406,8 @@ class TreeBackup:
             # overlaps the device hashing of segment N (open() fallback).
             content = []
             hashed = 0
-            reader_cm = self._open_stream(path)
+            with span("backup.open"):
+                reader_cm = self._open_stream(path)
             with reader_cm as reader:
                 for batch in stream_chunk_batches(reader.read, self.params,
                                                   hasher=self.hasher):

@@ -776,7 +776,9 @@ class _SegmentReadahead:
                     continue
 
     def next_segment(self) -> tuple[bytearray, int, bool]:
-        item = self._q.get()
+        # the consumer's side of engine.read (the producer thread's)
+        with span("engine.read_wait"):
+            item = self._q.get()
         self._gauge.set(self._q.qsize())
         if isinstance(item, Exception):
             raise item

@@ -20,7 +20,9 @@ Three layers:
   (``VOLSYNC_TRACE_SAMPLE``), finished spans land in a bounded
   in-process ring buffer exported as Chrome-trace-event JSON
   (Perfetto-loadable) via :func:`dump_trace`, ``volsync trace dump``,
-  and the ``/debug/trace`` endpoint. :func:`record_trigger` marks
+  and the ``/debug/trace`` endpoint. A span that runs once a file or
+  a blob stays out of it (``ctx=off_ring()``: totals only) so that the
+  ring holds what names a gap. :func:`record_trigger` marks
   shed / breaker-open / injected-fault / deadline events in the ring
   and auto-dumps an annotated trace file when ``VOLSYNC_TRACE_DUMP``
   is set (throttled per reason).
@@ -65,7 +67,6 @@ _lock = lockcheck.make_lock("obs.spans")
 _totals: dict[str, list] = defaultdict(lambda: [0, 0.0, 0.0])
 # (name, outcome) -> [n, secs]; outcome is "ok" or "error"
 _outcomes: dict[tuple, list] = defaultdict(lambda: [0, 0.0])
-_tenant_stage: dict[tuple, float] = defaultdict(float)  # (tenant, stage)->s
 _counters: dict[str, int] = defaultdict(int)
 _histogram: Optional[Histogram] = None
 
@@ -304,8 +305,6 @@ class _SpanHandle:
             oacc = _outcomes[(self.name, outcome)]
             oacc[0] += 1
             oacc[1] += dt
-            if ctx is not None and ctx.tenant:
-                _tenant_stage[(ctx.tenant, self.name)] += dt
             if event is not None:
                 _ring_append(event)
         _hist_child(self.name, outcome).observe(dt)
@@ -336,14 +335,28 @@ def begin_span(name: str, ctx=_CURRENT, **attrs) -> _SpanHandle:
     return _SpanHandle(name, ctx, attrs or None)
 
 
+def off_ring() -> Optional[TraceContext]:
+    """The active context with its sampling bit off, as the ``ctx`` of
+    a span that runs once a file or a blob and lasts well under a
+    millisecond: it keeps its totals, its self time and its tenant and
+    leaves no event in the flight recorder, where thousands an
+    operation would push out what names an idle gap. Spans opened
+    inside such a span nest under its parent, on the ring as before."""
+    ctx = _CTX.get()
+    if ctx is None or not ctx.sampled:
+        return ctx
+    return TraceContext(ctx.trace_id, ctx.span_id, ctx.tenant,
+                        ctx.stream_id, False)
+
+
 @contextlib.contextmanager
-def span(name: str, **attrs):
+def span(name: str, ctx=_CURRENT, **attrs):
     """Time a named stage; feeds the span registry + the histogram,
     and — when a sampled TraceContext is active — the flight recorder,
     with spans opened inside nesting under this one. Its self time is
     its duration less the span()s that closed inside it on this
-    thread."""
-    h = begin_span(name, **attrs)
+    thread. ``ctx`` as for :func:`begin_span`."""
+    h = begin_span(name, ctx, **attrs)
     token = None
     if h.ctx is not None and h.ctx.sampled:
         token = _CTX.set(h.ctx.child(h.span_id))
@@ -398,14 +411,6 @@ def counter_totals() -> dict:
         return dict(_counters)
 
 
-def stage_seconds_by_tenant() -> dict:
-    """``{(tenant, stage): seconds}`` for spans finished under a
-    tenant-tagged context — the in-process mirror of
-    ``volsync_svc_stage_seconds`` that benches read without scraping."""
-    with _lock:
-        return dict(_tenant_stage)
-
-
 def reset_spans():
     """Zero the span registry, the counters AND the Prometheus children
     the spans populated (volsync_stage_duration_seconds /
@@ -414,7 +419,6 @@ def reset_spans():
     with _lock:
         _totals.clear()
         _outcomes.clear()
-        _tenant_stage.clear()
         _counters.clear()
         _hist_children.clear()
         _tenant_children.clear()

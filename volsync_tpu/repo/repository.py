@@ -54,7 +54,13 @@ from volsync_tpu import envflags
 from volsync_tpu.analysis import lockcheck
 from volsync_tpu.metrics import GLOBAL as GLOBAL_METRICS
 from volsync_tpu.objstore.store import NoSuchKey, ObjectStore
-from volsync_tpu.obs import carry_context, record_copy, record_trigger, span
+from volsync_tpu.obs import (
+    carry_context,
+    off_ring,
+    record_copy,
+    record_trigger,
+    span,
+)
 from volsync_tpu.repo import blobid, crypto
 from volsync_tpu.repo.shardedindex import ShardedBlobIndex
 from volsync_tpu.repo.compress import Compressor, Decompressor
@@ -981,8 +987,14 @@ class Repository:
         zstd+AES sealing to a worker pool and returns once the blob is
         queued; pack close and upload happen as sealed segments drain.
         A prior upload failure surfaces here (before flush) as
-        UploadError."""
-        with self._lock:  # lint: ignore[VL101] — reviewed: the drain/
+        UploadError.
+
+        One ``repo.add`` span a call, from before the lock: the dedup
+        query and the two waits (``repo.seal_wait``,
+        ``repo.upload_slot_wait``) close inside it, so its self time
+        is the bookkeeping. One a blob is kept off the flight
+        recorder's ring (``add_blobs``', one a segment, is on it)."""
+        with span("repo.add", ctx=off_ring()), self._lock:  # lint: ignore[VL101] — reviewed: the drain/
             # reap/flush paths under repo.state DO put to the store;
             # that is the serial fallback and the bounded-backpressure
             # design (docs/performance.md). Pool workers never take
@@ -1011,7 +1023,7 @@ class Repository:
         if not blobs:
             return 0
         new = 0
-        with self._lock:  # lint: ignore[VL101] — reviewed: same serial-
+        with span("repo.add"), self._lock:  # lint: ignore[VL101] — reviewed: same serial-
             # fallback/backpressure store puts as add_blob (above);
             # pool workers never take repo.state.
             with span("repo.dedup_query"):
@@ -1057,11 +1069,15 @@ class Repository:
                 stats.blobs_new += 1
                 stats.bytes_new += len(data)
             self._pl_drain(block=False)
-            while len(self._pl_open) >= self._pl_seal_limit:
+            if len(self._pl_open) >= self._pl_seal_limit:
                 # backpressure: bound raw+sealed bytes held by the
                 # seal queue by blocking on the head future (workers
-                # never need self._lock, so this cannot deadlock)
-                self._pl_drain_one()
+                # never need self._lock, so this cannot deadlock).
+                # What is left after the drain above has a head that
+                # is not done: the span is the caller held by sealing
+                with span("repo.seal_wait"):
+                    while len(self._pl_open) >= self._pl_seal_limit:
+                        self._pl_drain_one()
             self._pl_reap(block=False)
             return
         seg = self._encode_blob(data)
@@ -1134,7 +1150,9 @@ class Repository:
         segments = self._cur_segments
         entries = self._cur_entries
         self._cur_segments, self._cur_entries, self._cur_size = [], [], 0
-        self._pl_upload_slots.acquire()
+        if not self._pl_upload_slots.acquire(blocking=False):
+            with span("repo.upload_slot_wait"):  # the window is full
+                self._pl_upload_slots.acquire()  # lint: ignore[VL103] the try below releases
         try:
             fut = _get_upload_pool().submit(
                 carry_context(self._upload_pack), segments, entries)

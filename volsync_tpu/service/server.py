@@ -86,6 +86,10 @@ TRACE_METADATA_KEY = "x-volsync-trace"
 #: (scheduler.parse_deadline_classes); unknown/absent = no deadline
 DEADLINE_CLASS_METADATA_KEY = "x-volsync-deadline-class"
 
+#: the methods whose wait for a pool thread is recorded as
+#: svc.accept_wait (Info is a probe: its waits would thin the mean)
+_ACCEPT_WAITED = ("ChunkHash", "HashSpans")
+
 #: Stream segmentation mirrors engine/chunker.stream_chunks: a segment is
 #: processed once at least this much beyond max_size is buffered.
 DEFAULT_SEGMENT_SIZE = 32 * 1024 * 1024
@@ -142,12 +146,49 @@ class _TokenInterceptor(grpc.ServerInterceptor):
         scoped = self._registry.token_for(tenant)
         expected = scoped.encode() if scoped is not None else self._token
         supplied = str(meta.get(TOKEN_METADATA_KEY, "")).encode()
+        method = (handler_call_details.method or "").rsplit("/", 1)[-1]
         if not hmac.compare_digest(supplied, expected):
-            method = handler_call_details.method or ""
-            if method.rsplit("/", 1)[-1] == "ChunkHash":
+            if method == "ChunkHash":
                 return self._deny_stream
             return self._deny_unary
-        return continuation(handler_call_details)
+        handler = continuation(handler_call_details)
+        if handler is None or method not in _ACCEPT_WAITED:
+            return handler
+        # grpc runs the interceptors on its serving thread as the call
+        # arrives and only then queues the handler on the pool: from
+        # here to the handler's first line a stream waits for one of
+        # max_workers threads. Under the client's trace, as svc.stream.
+        return _finish_on_entry(handler, begin_span(
+            "svc.accept_wait", ctx=_client_trace(meta, tenant)))
+
+
+def _client_trace(meta: dict, tenant):
+    """The call's TraceContext: the client's ``x-volsync-trace``
+    header, or a fresh root when it is absent or malformed. The tenant
+    is the one resolved server-side (token-scoped); never trust one
+    riding the trace header."""
+    tctx = parse_trace_header(meta.get(TRACE_METADATA_KEY))
+    if tctx is None:
+        return new_trace(tenant=tenant)
+    return tctx.evolve(tenant=tenant)
+
+
+def _finish_on_entry(handler: grpc.RpcMethodHandler, wait):
+    """``handler`` (ChunkHash's or HashSpans') with ``wait``, a span
+    handle, finished as the pool thread enters its behavior."""
+    if handler.response_streaming:
+        behavior = handler.stream_stream
+        make = grpc.stream_stream_rpc_method_handler
+    else:
+        behavior = handler.unary_unary
+        make = grpc.unary_unary_rpc_method_handler
+
+    def enter(request, context):
+        wait.finish("ok")
+        return behavior(request, context)
+
+    return make(enter, handler.request_deserializer,
+                handler.response_serializer)
 
 
 class MoverJaxServer:
@@ -328,13 +369,7 @@ class MoverJaxServer:
         consumes it."""
         meta = dict(context.invocation_metadata())
         tenant = self._admission.tenant_from(meta)
-        tctx = parse_trace_header(meta.get(TRACE_METADATA_KEY))
-        if tctx is not None:
-            # the tenant claim is resolved server-side (token-scoped);
-            # never trust one riding the trace header
-            tctx = tctx.evolve(tenant=tenant)
-        else:
-            tctx = new_trace(tenant=tenant)
+        tctx = _client_trace(meta, tenant)
         handle = begin_span("svc.stream", ctx=tctx)
         stream_ctx = tctx.child(handle.span_id)
         try:
