@@ -213,9 +213,9 @@ def run_closed_loop(*, tenants: list[dict], requests_per_client: int = 3,
     srv = MoverJaxServer(
         params=params, segment_size=segment_kib * 1024,
         batch_window_ms=window_ms,
-        # enough executor workers that concurrency is bounded by
+        # enough handler threads that concurrency is bounded by
         # ADMISSION, not by gRPC's thread pool queueing ahead of it
-        max_workers=total_clients + 4,
+        handlers=total_clients + 4,
         tenants=registry, breaker=breaker,
         max_streams=max_streams or None,
         tenant_streams=tenant_streams or None,

@@ -6,6 +6,7 @@ processes via a real network address, and a gRPC client getting
 (boundaries, digests) for a streamed buffer, identical to local chunking.
 """
 
+import io
 import pathlib
 import subprocess
 import sys
@@ -38,6 +39,56 @@ def test_chunk_stream_matches_local(service, rng):
         np.frombuffer(data, np.uint8))
     assert remote == local
     assert b"".join(data[o: o + l] for o, l, _ in remote) == data
+
+
+@pytest.mark.parametrize("known,size,frames", [
+    (True, 0, [(0, True)]),
+    (True, 1, [(1, False), (0, True)]),
+    (True, 250_000, [(100_000, False), (100_000, False), (50_000, False),
+                     (0, True)]),
+    (True, 200_000, [(100_000, False), (100_000, False), (0, True)]),
+    (False, 100_000, [(100_000, False), (0, True)]),
+], ids=["empty", "one-byte", "three-frames", "exact-two", "a-reader"])
+def test_what_a_stream_is_on_the_wire(monkeypatch, known, size, frames):
+    """What ``chunk_stream`` puts on the wire, from ``chunk_bytes`` and
+    from a reader: the pieces in order, every byte once, in frames of
+    ``_SEND_CHUNK``, each sent as it is read; then an empty frame with
+    the end marker."""
+    from volsync_tpu.service import client as client_mod
+
+    monkeypatch.setattr(client_mod, "_SEND_CHUNK", 100_000)
+    sent = []
+
+    class Call:
+        def __init__(self, requests):
+            sent.extend((bytes(r.data), r.eof) for r in requests)
+
+        def __iter__(self):
+            return iter(())
+
+    c = MoverJaxClient("127.0.0.1", 1, "t")
+    monkeypatch.setattr(c, "_chunk_hash", lambda it, **kw: Call(it))
+    data = np.random.RandomState(size).bytes(size)
+    if known:
+        assert c.chunk_bytes(data) == []
+    else:
+        assert list(c.chunk_stream(io.BytesIO(data).read)) == []
+    c.close()
+    assert [(len(d), eof) for d, eof in sent] == frames
+    assert b"".join(d for d, _ in sent) == data
+
+
+def test_a_full_frame_passes_grpcs_receive_cap(service):
+    """``_SEND_CHUNK`` is as large as gRPC's default 4 MiB receive cap
+    allows: a stream of exactly one full frame, and one of a byte
+    more, are both served whole."""
+    from volsync_tpu.service.client import _SEND_CHUNK
+
+    assert 4 * 1024 * 1024 - 128 * 1024 < _SEND_CHUNK < 4 * 1024 * 1024
+    with MoverJaxClient("127.0.0.1", service.port, service.token) as client:
+        for n in (_SEND_CHUNK, _SEND_CHUNK + 1):
+            chunks = client.chunk_bytes(b"\x5a" * n)
+            assert sum(length for _, length, _ in chunks) == n
 
 
 @pytest.mark.slow
@@ -73,6 +124,16 @@ def test_hash_spans_and_info(service, rng):
     assert got == [blobid.blob_id(b) for b in blobs]
     assert info.avg_size == PARAMS.avg_size
     assert info.align == PARAMS.align
+
+
+def test_a_span_past_the_data_is_refused(service):
+    import grpc
+
+    with MoverJaxClient("127.0.0.1", service.port, service.token) as client:
+        assert len(client.hash_spans(b"abcd" * 1024, [(0, 4096)])) == 1
+        with pytest.raises(grpc.RpcError) as ei:
+            client.hash_spans(b"abcd" * 1024, [(0, 4096), (4000, 97)])
+    assert ei.value.code() == grpc.StatusCode.INVALID_ARGUMENT
 
 
 def test_bad_token_unauthenticated(service):

@@ -152,7 +152,7 @@ def test_overload_sheds_never_abort_admitted_work(rng):
     shed_lock = threading.Lock()
     with MoverJaxServer(params=P4K, segment_size=128 * 1024,
                         batch_window_ms=5.0, max_streams=2,
-                        max_workers=10) as srv:
+                        handlers=10) as srv:
         def run(i):
             data = payloads[i]
             while True:

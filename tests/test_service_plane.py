@@ -412,6 +412,186 @@ def test_client_surfaces_shed_as_typed_error():
             assert fut.result(timeout=10.0)  # the parked stream finishes
 
 
+# -- the handler pool: eight by default, apart from the batch limit ----------
+
+class _HeldStreams:
+    """N ChunkStreams parked in their handlers: each sends one frame
+    and then holds its request iterator open until ``release``."""
+
+    def __init__(self, srv, tenants: list[str]):
+        self.hold = threading.Event()
+        self._conns = [MoverJaxClient("127.0.0.1", srv.port, srv.token,
+                                      tenant=t) for t in tenants]
+        self._pool = ThreadPoolExecutor(len(tenants))
+        self._futs = [self._pool.submit(self._parked, c)
+                      for c in self._conns]
+
+    def _parked(self, conn):
+        sent = []
+
+        def reader(n):
+            if not sent:
+                sent.append(1)
+                return b"p" * 8192
+            self.hold.wait(60.0)
+            return b""
+
+        return list(conn.chunk_stream(reader))
+
+    def release(self) -> list:
+        self.hold.set()
+        try:
+            return [f.result(timeout=60.0) for f in self._futs]
+        finally:
+            self._pool.shutdown()
+            for c in self._conns:
+                c.close()
+
+
+def _wait_for(condition, what):
+    deadline = time.monotonic() + 60.0
+    while not condition():
+        assert time.monotonic() < deadline, what()
+        time.sleep(0.01)
+
+
+#: held streams (tenant of each), the tenant of one more stream, and
+#: the reason admission gives for refusing it (None: it is served)
+HANDLER_POOL_CASES = {
+    # more than the batch limit of eight, under both admission limits:
+    # every one is IN its handler, none waits for a thread
+    "twelve-held-all-in-their-handlers": (
+        ["a"] * 6 + ["b"] * 6, "c", None),
+    "over-tenant-streams": (["a"] * 16, "a", "tenant_streams"),
+    "over-max-streams": (
+        [t for t in "abcd" for _ in range(16)], "e", "global_streams"),
+}
+
+
+@pytest.mark.parametrize("held,extra,reason", HANDLER_POOL_CASES.values(),
+                         ids=list(HANDLER_POOL_CASES))
+def test_a_pool_as_wide_as_admission_has_a_handler_for_every_stream(
+        held, extra, reason):
+    """``handlers=72``: a thread for each of the 64 streams admission
+    admits (16 a tenant) and eight to spare, so N > 8 held streams are
+    all counted by admission at once and none waited for a thread; the
+    stream over a limit reaches ``admit_stream`` and is refused
+    RESOURCE_EXHAUSTED with its retry-after trailer (it does not queue
+    unseen behind the held ones), and ``Info`` still answers."""
+    from volsync_tpu import obs
+    from volsync_tpu.service.server import RETRY_AFTER_METADATA_KEY
+
+    obs.reset_spans()
+    with MoverJaxServer(params=P4K, segment_size=128 * 1024,
+                        handlers=72) as srv:
+        adm = srv.admission
+        assert (adm.max_streams, adm.tenant_streams) == (64, 16)
+        streams = _HeldStreams(srv, held)
+        try:
+            _wait_for(lambda: adm.active_streams() >= len(held),
+                      adm.active_streams)
+            assert adm.active_streams() == len(held)
+            count, total = obs.span_totals()["svc.accept_wait"]
+            assert count == len(held)
+            # a thread was free (or started) for each: with a pool of
+            # eight the ninth would still be waiting, and never counted
+            assert total < 0.25 * len(held)
+            with MoverJaxClient("127.0.0.1", srv.port, srv.token,
+                                tenant=extra) as c:
+                assert c.info().align == P4K.align
+                if reason is None:
+                    assert c.chunk_bytes(b"q" * 8192)
+                else:
+                    t0 = time.monotonic()
+                    with pytest.raises(ShedError) as ei:
+                        c.chunk_bytes(b"q" * 8192)
+                    assert time.monotonic() - t0 < 10.0
+                    assert reason in str(ei.value)
+                    rpc = ei.value.__cause__
+                    assert rpc.code() == grpc.StatusCode.RESOURCE_EXHAUSTED
+                    trailer = dict(rpc.trailing_metadata())
+                    assert int(trailer[RETRY_AFTER_METADATA_KEY]) >= 1
+                    assert ei.value.retry_after > 0
+                assert c.info().align == P4K.align
+            assert adm.active_streams() == len(held)
+        finally:
+            results = streams.release()
+        assert all(results) and len(results) == len(held)
+    assert adm.active_streams() == 0
+
+
+def test_a_default_server_serves_eight_streams_and_queues_the_ninth():
+    """The default pool is eight whatever the batch limit
+    (``max_workers``) is: of twelve held streams eight are in
+    their handlers, which is all admission ever counts, and four wait
+    in the executor's queue (``svc.accept_wait`` still open) until a
+    thread is free; every one is served in the end."""
+    from volsync_tpu import obs
+
+    obs.reset_spans()
+    with MoverJaxServer(params=P4K, segment_size=128 * 1024,
+                        max_workers=4) as srv:
+        assert srv.handlers == 8
+        streams = _HeldStreams(srv, ["a"] * 6 + ["b"] * 6)
+        try:
+            _wait_for(lambda: srv.admission.active_streams() >= 8,
+                      srv.admission.active_streams)
+            time.sleep(0.3)
+            assert srv.admission.active_streams() == 8
+            assert obs.span_totals()["svc.accept_wait"][0] == 8
+        finally:
+            results = streams.release()
+        assert all(results) and len(results) == 12
+        assert obs.span_totals()["svc.accept_wait"][0] == 12
+    assert srv.admission.active_streams() == 0
+
+
+@pytest.mark.parametrize("server_kwargs", [{}, {"handlers": 72}],
+                         ids=["default-built", "a-handler-a-stream"])
+def test_the_warm_plan_covers_what_the_server_dispatches(monkeypatch,
+                                                         server_kwargs):
+    """16 concurrent streams of two bucket sizes against a
+    default-built ``MoverJaxServer()``, and against one with twice the
+    batch limit in their handlers at once (``handlers=72``): no
+    dispatch coalesces more than eight lanes, and every program it
+    ran, (lanes padded to a power of two, bucket), is one that
+    ``benchmark/warm.py``'s ``stream_plan`` loads for those sizes
+    before the benchmark's window."""
+    from benchmark import warm
+    from volsync_tpu import obs
+    from volsync_tpu.ops.segment import BatchedSegmentHasher
+
+    sizes = [600_000, 1_500_000]  # buckets of 1 MiB and 2 MiB
+    ran = []
+    real = BatchedSegmentHasher._hash_bucket
+
+    def hash_bucket(self, P, items):
+        ran.append((warm._pow2ceil(len(items)), P))
+        return real(self, P, items)
+
+    monkeypatch.setattr(BatchedSegmentHasher, "_hash_bucket", hash_bucket)
+    rs = np.random.RandomState(41)
+    payloads = [rs.bytes(sizes[i % 2] + 4096 * i) for i in range(16)]
+    obs.reset_trace()
+    with MoverJaxServer(**server_kwargs) as srv:
+        def run(i):
+            with MoverJaxClient("127.0.0.1", srv.port, srv.token,
+                                tenant=f"t{i % 4}", timeout=600.0) as c:
+                return [c.chunk_bytes(payloads[i]) for _ in range(2)]
+
+        with ThreadPoolExecutor(16) as pool:
+            results = list(pool.map(run, range(16)))
+    for data, (first, second) in zip(payloads, results):
+        assert first == second
+        assert sum(length for _, length, _ in first) == len(data)
+    lanes = [e["args"]["lanes"] for e in obs.trace_events()
+             if e["ph"] == "X" and e["name"] == "ops.batch_dispatch"]
+    assert lanes and max(lanes) <= 8
+    plan = set(map(tuple, warm.stream_plan(
+        [len(p) for p in payloads], server_kwargs, 16)))
+    assert ran and set(ran) <= plan, sorted(set(ran) - plan)
+
+
 # -- byte identity through the scheduled path --------------------------------
 
 def test_scheduled_streams_chunk_bit_identically(rng):

@@ -88,7 +88,7 @@ def test_a_stream_waiting_for_a_handler_thread_is_timed(monkeypatch):
             out[tenant] = list(c.chunk_stream(reader))
 
     out = {}
-    with MoverJaxServer(params=P4K, segment_size=128 * 1024, max_workers=1,
+    with MoverJaxServer(params=P4K, segment_size=128 * 1024, handlers=1,
                         batch_window_ms=0) as srv:
         first = threading.Thread(
             target=stream, args=("first", held_reader(), out))

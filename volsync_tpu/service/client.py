@@ -37,8 +37,11 @@ from volsync_tpu.service.tenants import TENANT_METADATA_KEY
 
 #: Request frame payload. gRPC refuses a message over 4 MiB (its
 #: default receive cap) and the frame adds a few bytes of protobuf
-#: header, so a full 4 MiB payload can never be sent: stay well under.
-_SEND_CHUNK = 2 * 1024 * 1024
+#: header, so a full 4 MiB payload can never be sent: 64 KiB under.
+#: As large as that allows, because the server pays for a stream by
+#: the message: each is received, copied, parsed and handed over on
+#: gRPC's one serving thread (PERF.md section 6, PR 41).
+_SEND_CHUNK = 4 * 1024 * 1024 - 64 * 1024
 
 
 class ShedError(ThrottleError):

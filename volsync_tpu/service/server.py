@@ -157,7 +157,8 @@ class _TokenInterceptor(grpc.ServerInterceptor):
         # grpc runs the interceptors on its serving thread as the call
         # arrives and only then queues the handler on the pool: from
         # here to the handler's first line a stream waits for one of
-        # max_workers threads. Under the client's trace, as svc.stream.
+        # the pool's threads (MoverJaxServer.handlers). Under the
+        # client's trace, as svc.stream.
         return _finish_on_entry(handler, begin_span(
             "svc.accept_wait", ctx=_client_trace(meta, tenant)))
 
@@ -197,7 +198,19 @@ class MoverJaxServer:
 
     ``batch_window_ms > 0`` (default) coalesces concurrent streams'
     segments into single device dispatches via SegmentMicroBatcher;
-    0 keeps the per-request dispatch path.
+    0 keeps the per-request dispatch path. ``max_workers`` is the
+    batch limit: the most segments one dispatch coalesces (the
+    batcher's ``max_batch``, and what ``benchmark/warm.py`` loads
+    programs for). It is NOT the handler pool.
+
+    ``handlers`` is gRPC's thread pool: the calls that can be in their
+    handlers at once. A stream beyond it waits in the executor's FIFO
+    (``svc.accept_wait``), where admission does not count it and the
+    scheduler cannot weigh it. The default of 8 is measured, not
+    derived (PERF.md section 6, PR 41): the handlers share one
+    interpreter lock with gRPC's serving thread, so a pool of 16 to 72
+    moved no more bytes than one of 8 and served streams side by side
+    instead of in turn, which lengthened the tail by a quarter.
 
     ``tenants``/``max_streams``/``tenant_streams``/``max_queued``
     configure the admission controller (defaults from VOLSYNC_SVC_*).
@@ -217,6 +230,7 @@ class MoverJaxServer:
                  token: Optional[str] = None, params=None,
                  segment_size: int = DEFAULT_SEGMENT_SIZE,
                  max_workers: int = 8, batch_window_ms: float = 2.0,
+                 handlers: int = 8,
                  pipeline_depth: Optional[int] = None,
                  tenants: Optional[TenantRegistry] = None,
                  admission: Optional[AdmissionController] = None,
@@ -283,7 +297,7 @@ class MoverJaxServer:
         self.deadline_classes = deadline_classes
 
         serialize = lambda m: m.SerializeToString()  # noqa: E731
-        handlers = {
+        methods = {
             "ChunkHash": grpc.stream_stream_rpc_method_handler(
                 self._chunk_hash, pb.DataSegment.FromString, serialize),
             "HashSpans": grpc.unary_unary_rpc_method_handler(
@@ -291,12 +305,13 @@ class MoverJaxServer:
             "Info": grpc.unary_unary_rpc_method_handler(
                 self._info, pb.InfoRequest.FromString, serialize),
         }
+        self.handlers = max(1, handlers)
         self._server = grpc.server(
-            ThreadPoolExecutor(max_workers=max_workers),
+            ThreadPoolExecutor(max_workers=self.handlers),
             interceptors=[_TokenInterceptor(self.token, self.tenants)],
         )
         self._server.add_generic_rpc_handlers((
-            grpc.method_handlers_generic_handler(SERVICE_NAME, handlers),
+            grpc.method_handlers_generic_handler(SERVICE_NAME, methods),
         ))
         self.port = self._server.add_insecure_port(f"{host}:{port}")
         self.host = host
@@ -520,9 +535,10 @@ class MoverJaxServer:
             return (self._submit_segment(ticket, assemble(), eof), eof)
 
         for seg in request_iterator:
-            if seg.data:
-                pieces.append(seg.data)
-                plen += len(seg.data)
+            data = seg.data  # once: upb copies the field out a read
+            if data:
+                pieces.append(data)
+                plen += len(data)
             if inflight is not None and inflight[0].done():
                 yield collect(inflight)
                 inflight = None
@@ -553,13 +569,13 @@ class MoverJaxServer:
     def _hash_spans(self, request: pb.HashSpansRequest, context):
         from volsync_tpu.engine.chunker import hash_spans
 
+        data = request.data  # once: upb copies the field out a read
         spans = [(s.offset, s.length) for s in request.spans]
         for off, length in spans:
-            if off + length > len(request.data):
+            if off + length > len(data):
                 context.abort(grpc.StatusCode.INVALID_ARGUMENT,
                               "span out of range")
-        return pb.HashSpansResponse(
-            digests=hash_spans(request.data, spans))
+        return pb.HashSpansResponse(digests=hash_spans(data, spans))
 
     def _info(self, request: pb.InfoRequest, context):
         import jax
