@@ -1,10 +1,12 @@
-"""Batched delta scan: golden byte-identity vs the serial engine, device
-dispatch accounting, and the bidirectional rsync convergence scenario.
+"""Batched delta scan: golden byte-identity vs the unwindowed oracle,
+device dispatch accounting, and the bidirectional rsync convergence
+scenario.
 
-The oracle is ``compute_delta`` per file: ``delta_scan_batch`` must emit
-the exact same op streams (not merely equivalent ones), because both
-share the host-side greedy selection and the batch kernels are built to
-reproduce the serial per-file candidate sets.
+The oracle is ``compute_delta`` per file (one exact-shape scan of the
+whole file): ``delta_scan_batch`` must emit the exact same op streams
+(not merely equivalent ones) from its staged buffers, whatever the
+window, because both share the host-side greedy selection and the flat
+kernels reproduce the per-file verified sets.
 """
 
 import os
@@ -31,7 +33,17 @@ def _corpus(rng):
     edited[10_000:10_100] = rng.bytes(100)
     edited[150_000:150_001] = b""
     taily = rng.bytes(4096 * 3 + 789)  # partial tail block
+    several = bytearray(base)
+    for at, n in ((180_000, 5), (120_500, 1_300), (118_000, 1),
+                  (117_000, 640), (40_000, 4_097)):
+        several[at: at] = rng.bytes(n)
+    del several[90_000: 90_777]
+    zeros = base[:70_000] + bytes(60_000) + base[130_000:]
+    periodic = base[:37_000] + base[:512] * 80 + base[37_000:]
     return [
+        (base, bytes(several)),             # insertions inside one window
+        (zeros, zeros[:69_000] + b"\x01" + zeros[69_000:]),  # zeros moved
+        (periodic, periodic[:36_900] + rng.bytes(512) + periodic[36_900:]),
         (base, base),                       # identical -> zero DATA ops
         (base, shifted),                    # insertion, offsets slide
         (base, bytes(edited)),              # scattered edits
@@ -54,7 +66,12 @@ def _items(pairs):
     return out
 
 
-def test_batch_matches_serial_oracle(rng):
+@pytest.mark.parametrize("window", [64 << 20, 1 << 16, 1 << 14])
+def test_batch_matches_serial_oracle(rng, monkeypatch, window):
+    """At the mover's window, and at windows of sixteen and four blocks
+    (a file then spans several buffers, and the small files share
+    one)."""
+    monkeypatch.setattr(deltasync, "WINDOW", window)
     pairs = _corpus(rng)
     items = _items(pairs)
     batch = deltasync.delta_scan_batch(items)
@@ -93,11 +110,18 @@ def test_mixed_block_lengths_group_correctly(rng):
 
 
 def test_batch_uses_fewer_dispatches_than_files(rng, monkeypatch):
-    """The tentpole's whole point: N files, ONE match dispatch ladder +
-    ONE verify dispatch per block-length group, not one per file."""
-    calls = {"match": 0, "verify": 0}
-    real_match = deltasync.match_offsets_batch
-    real_verify = deltasync.verify_candidates_batch
+    """N files, ONE aligned probe, ONE search and ONE verify dispatch
+    a staged buffer, not one per file (each file here has an insertion:
+    the selection is followed through the searched rows of the one
+    buffer, none is staged again)."""
+    calls = {"probe": 0, "match": 0, "verify": 0}
+    real_probe = deltasync.delta_sig_flat
+    real_match = deltasync.delta_match_rows
+    real_verify = deltasync.delta_md5_flat
+
+    def spy_probe(*a, **kw):
+        calls["probe"] += 1
+        return real_probe(*a, **kw)
 
     def spy_match(*a, **kw):
         calls["match"] += 1
@@ -107,19 +131,20 @@ def test_batch_uses_fewer_dispatches_than_files(rng, monkeypatch):
         calls["verify"] += 1
         return real_verify(*a, **kw)
 
-    monkeypatch.setattr(deltasync, "match_offsets_batch", spy_match)
-    monkeypatch.setattr(deltasync, "verify_candidates_batch", spy_verify)
-
     base = rng.bytes(60_000)
     pairs = []
     for i in range(8):
         mutated = bytearray(base)
-        mutated[i * 1000:i * 1000 + 50] = rng.bytes(50)
+        mutated[i * 1000:i * 1000] = rng.bytes(50)
         pairs.append((base, bytes(mutated)))
     items = _items(pairs)
     assert len({sig.block_len for _, sig in items}) == 1
+    monkeypatch.setattr(deltasync, "delta_sig_flat", spy_probe)
+    monkeypatch.setattr(deltasync, "delta_match_rows", spy_match)
+    monkeypatch.setattr(deltasync, "delta_md5_flat", spy_verify)
     batch = deltasync.delta_scan_batch(items)
     assert calls["match"] >= 1 and calls["verify"] >= 1
+    assert calls["probe"] == 1
     assert calls["match"] < len(items)
     assert calls["verify"] < len(items)
     for (old, new), (src, sig), ops in zip(pairs, items, batch):
@@ -218,9 +243,10 @@ def test_bidirectional_sync_converges_with_delta(tmp_path, rng):
     assert s.delta_samples > 0
 
 
-def test_push_batch_respects_env_batch_size(tmp_path, rng, monkeypatch):
-    """VOLSYNC_DELTA_BATCH=1 pins the legacy serial per-file path (one
-    sig round trip per file); >1 coalesces into sigs batches."""
+def test_a_batch_shares_one_sigs_round_trip(tmp_path, rng):
+    """Five files under one window of bytes go through ONE ``sigs``
+    round trip (there is no per-file ``sig`` verb), and a resync after
+    appends converges."""
     from volsync_tpu.movers.rsync import entry
 
     src = tmp_path / "src"
@@ -230,26 +256,19 @@ def test_push_batch_respects_env_batch_size(tmp_path, rng, monkeypatch):
     for i in range(5):
         (src / f"f{i}.bin").write_bytes(rng.bytes(20_000))
 
-    seen = {"sig": 0, "sigs": 0}
+    seen = {"sigs": 0}
     verbs = entry._dest_verbs(dst)
-    real_sig, real_sigs = verbs["sig"], verbs["sigs"]
-    verbs["sig"] = lambda m: (seen.__setitem__("sig", seen["sig"] + 1),
-                              real_sig(m))[1]
+    assert "sig" not in verbs
+    real_sigs = verbs["sigs"]
     verbs["sigs"] = lambda m: (seen.__setitem__("sigs", seen["sigs"] + 1),
                                real_sigs(m))[1]
-
-    monkeypatch.setenv("VOLSYNC_DELTA_BATCH", "1")
     entry._push_tree(_Chan(verbs), src)
-    assert seen == {"sig": 5, "sigs": 0}
     assert _tree_bytes(dst) == _tree_bytes(src)
 
-    # mutate and resync batched: one sigs round trip for all five files
     for i in range(5):
         with open(src / f"f{i}.bin", "ab") as f:
             f.write(b"delta")
-    monkeypatch.setenv("VOLSYNC_DELTA_BATCH", "32")
-    seen.update(sig=0, sigs=0)
+    seen.update(sigs=0)
     entry._push_tree(_Chan(verbs), src)
-    assert seen["sig"] == 0
     assert seen["sigs"] == 1
     assert _tree_bytes(dst) == _tree_bytes(src)

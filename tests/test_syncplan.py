@@ -358,7 +358,5 @@ def test_env_knobs(monkeypatch):
     assert envflags.plan_ewma_alpha() == 1.0  # clamped
     monkeypatch.setenv("VOLSYNC_PLAN_EWMA", "junk")
     assert envflags.plan_ewma_alpha() == pytest.approx(0.3)
-    monkeypatch.setenv("VOLSYNC_DELTA_BATCH", "0")
-    assert envflags.delta_batch_files() == 1
     monkeypatch.setenv("VOLSYNC_PLAN_FULL_CAP", "1")
     assert envflags.plan_full_blob_cap() == 4096

@@ -634,6 +634,11 @@ def test_segment_program_names_its_stages_and_kernels(monkeypatch):
         jax.ShapeDtypeStruct((2,), jnp.bool_), **kw)
     single = segment.chunk_hash_segment.trace(
         jax.ShapeDtypeStruct((seg,), jnp.uint8), 1000, eof=True, **kw)
+    # the shared jit keeps this trace, kernels and all, for the next
+    # caller with these arguments on this worker: a CPU test that then
+    # runs the one-chip engine on a 1 MiB segment
+    # (tests/test_mesh_reference.py) would run the Pallas kernels
+    segment.chunk_hash_segment.clear_cache()
     for traced in (batched, single):
         text = traced.lower(lowering_platforms=("tpu",)).as_text(
             debug_info=True)

@@ -539,14 +539,6 @@ def plan_ewma_alpha() -> float:
     return min(max(v, 0.01), 1.0)
 
 
-def delta_batch_files() -> int:
-    """VOLSYNC_DELTA_BATCH: how many files the rsync source coalesces
-    into one batched signature round trip + one device delta-scan
-    dispatch ladder (engine/deltasync.delta_scan_batch); 1 = the serial
-    per-file path."""
-    return env_int("VOLSYNC_DELTA_BATCH", 32, minimum=1)
-
-
 def plan_full_blob_cap() -> int:
     """VOLSYNC_PLAN_FULL_CAP: largest file (bytes) the planner may store
     as a single whole-file blob on the CDC side's FULL_COPY path; larger

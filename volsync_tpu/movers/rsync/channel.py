@@ -26,7 +26,9 @@ from volsync_tpu.repo.crypto import IntegrityError, SecretBox
 _MAX_FRAME = 256 * 1024 * 1024
 
 #: Wire-format generation of the sealed framing. v2 added the
-#: raw/zstd flag byte inside the seal; the version is exchanged in a
+#: raw/zstd flag byte inside the seal; v3 sends a file's ``apply`` in
+#: parts (a v2 destination would take each part for a whole file) and
+#: drops the one-file ``sig`` verb; the version is exchanged in a
 #: fixed-format CLEARTEXT preamble (below) so a mixed-version
 #: source/destination pair (rolling operator upgrade) fails with an
 #: explicit version-mismatch error instead of an opaque
@@ -36,7 +38,7 @@ _MAX_FRAME = 256 * 1024 * 1024
 #: "pre-v2 peer"). Bump on any framing change. The preamble carries no
 #: secrets; tampering with it can only refuse a connection (DoS-
 #: equivalent to dropping packets), never weaken the sealed channel.
-CHANNEL_VERSION = 2
+CHANNEL_VERSION = 3
 _PREAMBLE_MAGIC = b"VSCH"
 _PREAMBLE_LEN = 8  # magic + >I version — FROZEN for all versions
 

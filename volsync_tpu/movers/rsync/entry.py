@@ -10,6 +10,12 @@ with that rc, exactly like the forced-command sshd wrapper
 Source: connect with bounded exponential-backoff retries
 (mover-rsync/source.sh:43-62), push a whole-tree delta (TPU delta scan,
 engine/deltasync.py), then send shutdown with the transfer rc.
+
+A file of any length moves in bounded memory: both sides read it a
+window at a time (``deltasync.WINDOW``), its op stream crosses the
+channel in parts of at most ``PART_BYTES`` of literals, and the
+destination builds the new file beside the old one and renames it over
+the name on the last part (rsync's temporary-then-rename).
 """
 
 from __future__ import annotations
@@ -20,14 +26,57 @@ import socket
 import stat as stat_mod
 import time
 from pathlib import Path
+from typing import Iterator, Optional
 
 from volsync_tpu.engine import deltasync
 from volsync_tpu.movers.rsync import channel
+from volsync_tpu.obs import count, off_ring, span
 from volsync_tpu.resilience import RetryPolicy
 
 log = logging.getLogger("volsync_tpu.mover.rsync")
 
 MAX_RETRIES = 5  # source.sh:43 (5 attempts, doubling backoff)
+
+#: Literal bytes in one part of a file's op stream. A part is one frame
+#: (channel.py refuses one over 256 MiB) and is packed, compressed and
+#: sealed whole on one side and opened whole on the other.
+PART_BYTES = 32 * 1024 * 1024
+#: Ops in one part: bounds a part of a delta with many short copies.
+PART_OPS = 65536
+#: A source batch: files that share one ``sigs`` round trip and the
+#: scan's staged buffers, up to one window of bytes or this many files.
+BATCH_FILES = 1024
+#: The destination reads a copied run of its basis this much at a time.
+_COPY_CHUNK = 8 * 1024 * 1024
+
+
+class _FileSource:
+    """A regular file read by offset, a piece at a time, as the delta
+    engine reads a source; the source side's reads are ``rsync.read``."""
+
+    def __init__(self, path, size: int, source_side: bool = False):
+        self.path, self.size, self._timed = os.fspath(path), size, source_side
+
+    def pread(self, offset: int, out) -> None:
+        if self._timed:
+            with span("rsync.read", ctx=off_ring()):
+                self._pread(offset, out)
+        else:
+            self._pread(offset, out)
+
+    def _pread(self, offset: int, out) -> None:
+        view = memoryview(out)
+        fd = os.open(self.path, os.O_RDONLY)
+        try:
+            got = 0
+            while got < len(view):
+                n = os.preadv(fd, [view[got:]], offset + got)
+                if n == 0:
+                    raise channel.ChannelError(
+                        f"{self.path} changed while it was read")
+                got += n
+        finally:
+            os.close(fd)
 
 
 # ---------------------------------------------------------------------------
@@ -50,41 +99,134 @@ def _apply_meta(path, msg: dict, *, utime: bool = True):
         os.utime(path, ns=(msg["mtime_ns"], msg["mtime_ns"]))
 
 
-def _dest_verbs(root: Path):
-    def sig(msg):
-        path = _safe_join(root, msg["path"])
-        if not path.is_file() or path.is_symlink():
-            return {"verb": "sig", "exists": False}
-        data = path.read_bytes()
-        s = deltasync.build_file_signature(
-            data, msg.get("block_len") or None)
-        return {"verb": "sig", "exists": True, **s.to_wire()}
+class _Incoming:
+    """A file on its way in: the temporary it is built in, and the old
+    file it copies blocks from."""
 
-    def apply(msg):
+    def __init__(self, path: Path):
+        self.path = path
+        self.basis = None
+        if path.is_file() and not path.is_symlink():
+            self.basis = os.open(path, os.O_RDONLY)
+        self.had_basis = self.basis is not None
+        path.parent.mkdir(parents=True, exist_ok=True)
+        # where the file is built until its last part has arrived
+        self.tmp = path.with_name(f".{path.name}.volsync-part")
+        self.f = open(self.tmp, "wb")
+        self.size = 0
+        self.parts = 0  # parts written: the index of the one expected
+
+    def write(self, ops, block_len: int) -> None:
         from volsync_tpu.engine.restore import _write_sparse
 
-        path = _safe_join(root, msg["path"])
-        old = b""
-        if path.is_file() and not path.is_symlink():
-            old = path.read_bytes()
-        ops = [tuple(op) if op[0] == "copy" else ("data", op[1])
-               for op in msg["ops"]]
-        new = deltasync.apply_delta(ops, old, msg["block_len"])
-        path.parent.mkdir(parents=True, exist_ok=True)
-        if path.is_dir() or path.is_symlink():
-            _rm(path)
-        elif path.exists() and (
-                not stat_mod.S_ISREG(path.lstat().st_mode)
-                or path.lstat().st_nlink > 1):
-            # a special (writing "into" a FIFO/device is a hang / data
-            # loss) or a hardlinked inode (in-place write would corrupt
-            # the other name) occupies the path — replace, don't reuse
-            path.unlink()
-        with open(path, "wb") as f:
-            _write_sparse(f, new)  # rsync -S semantics
-            f.truncate(len(new))
-        _apply_meta(path, msg)
-        return {"verb": "ok", "size": len(new)}
+        for op in ops:
+            if op[0] == "copy":
+                at, end = op[1] * block_len, (op[1] + op[2]) * block_len
+                if self.basis is None:
+                    raise channel.ChannelError(
+                        f"copy op for {self.path.name} with no basis")
+                while at < end:
+                    piece = os.pread(self.basis, min(_COPY_CHUNK, end - at),
+                                     at)
+                    if not piece:
+                        break  # the basis's short tail block
+                    _write_sparse(self.f, piece)  # rsync -S semantics
+                    self.size += len(piece)
+                    at += len(piece)
+            else:
+                _write_sparse(self.f, op[1])
+                self.size += len(op[1])
+
+    def close(self) -> None:
+        self.f.close()
+        if self.basis is not None:
+            os.close(self.basis)
+            self.basis = None
+
+    def abandon(self) -> None:
+        """The file will not be finished by the session that began it:
+        nothing of it stays, the old file keeps its name."""
+        self.close()
+        try:
+            os.unlink(self.tmp)
+        except FileNotFoundError:
+            pass
+
+    def commit(self, msg: dict) -> None:
+        """Metadata onto the temporary, then the rename over the name:
+        no half-written file is ever under it."""
+        self.f.truncate(self.size)
+        self.close()
+        _apply_meta(self.tmp, msg)
+        if self.path.is_dir() and not self.path.is_symlink():
+            _rm(self.path)
+        # over a regular file, a symlink, a special (writing "into" a
+        # FIFO would hang) or one name of a hardlinked inode (the other
+        # names keep the old bytes): the name alone is replaced
+        os.replace(self.tmp, self.path)
+
+
+def _dest_verbs(root: Path, incoming: Optional[dict] = None):
+    """The verb table of ONE session. ``incoming`` holds the files the
+    session has begun and not finished: the caller that serves the
+    session abandons them when it ends (``serve_destination``), so the
+    next session, the source's retry after a dropped link, never finds
+    a half-built file to append to."""
+    if incoming is None:
+        incoming = {}
+
+    def sigs(msg):
+        """The signatures of a batch of files in ONE round trip, built
+        on the device in padded dispatches a batch (deltasync
+        ``build_signatures``), a long file a window at a time."""
+        with span("rsync.sig"):
+            have = []
+            for item in msg["files"]:
+                path = _safe_join(root, item["path"])
+                if path.is_file() and not path.is_symlink():
+                    have.append((_FileSource(path, path.stat().st_size),
+                                 item.get("block_len") or None))
+                else:
+                    have.append(None)
+            built = iter(deltasync.build_signatures(
+                [h for h in have if h is not None]))
+            return {"verb": "sigs", "sigs": [
+                {"exists": False} if h is None
+                else {"exists": True, **next(built).to_wire()}
+                for h in have]}
+
+    def apply(msg):
+        """One part of a file's op stream; the file appears under its
+        name when the part marked ``last`` is in."""
+        with span("rsync.apply", ctx=off_ring()):
+            rel, part = msg["path"], msg.get("part", 0)
+            inc = incoming.pop(rel, None)
+            if part == 0:
+                if inc is not None:
+                    inc.abandon()  # the source began the file again
+                inc = _Incoming(_safe_join(root, rel))
+            elif inc is None or inc.parts != part:
+                if inc is not None:
+                    inc.abandon()
+                raise channel.ChannelError(
+                    f"part {part} of {rel!r} where part "
+                    f"{0 if inc is None else inc.parts} was expected")
+            incoming[rel] = inc
+            last = msg.get("last", True)
+            try:
+                inc.write(msg["ops"], msg["block_len"])
+                inc.parts += 1
+                if last:
+                    inc.commit(msg)
+            except BaseException:
+                del incoming[rel]
+                inc.abandon()
+                raise
+            if not last:
+                return {"verb": "ok"}
+            del incoming[rel]
+            return {"verb": "ok", "size": inc.size,
+                    "basis": inc.had_basis}
 
     def mkdir(msg):
         path = _safe_join(root, msg["path"])
@@ -114,13 +256,18 @@ def _dest_verbs(root: Path):
         already-transferred first-sighting path."""
         path = _safe_join(root, msg["path"])
         source = _safe_join(root, msg["to"])
+        # the first sighting is a regular file this sync put there: a
+        # symlink under that name is not followed out of the root
+        if not stat_mod.S_ISREG(source.lstat().st_mode):
+            raise channel.ChannelError(
+                f"link target is no regular file: {msg['to']!r}")
         if path.exists() and not path.is_symlink() \
                 and os.path.samestat(path.lstat(), source.lstat()):
             return {"verb": "ok"}
         if path.is_symlink() or path.exists():
             _rm(path)
         path.parent.mkdir(parents=True, exist_ok=True)
-        os.link(source, path)
+        os.link(source, path, follow_symlinks=False)
         return {"verb": "ok"}
 
     def special(msg):
@@ -148,33 +295,31 @@ def _dest_verbs(root: Path):
     def dirmeta(msg):
         """Directory metadata, bottom-up AFTER all children are written
         (a child write would bump the parent's restored mtime)."""
-        for d in msg["dirs"]:
-            path = _safe_join(root, d["path"]) if d["path"] else root
-            if path.is_dir():
-                _apply_meta(path, d)
+        with span("rsync.dirmeta"):
+            for d in msg["dirs"]:
+                path = _safe_join(root, d["path"]) if d["path"] else root
+                # chmod, chown and utime follow a symlink: only a
+                # directory that is itself under the name gets them
+                # (the root is the mount's own and may be a link)
+                if path.is_dir() and not (d["path"] and path.is_symlink()):
+                    _apply_meta(path, d)
         return {"verb": "ok"}
 
     def prune(msg):
         """--delete semantics: remove everything not in the keep set."""
         keep = set(msg["paths"])
         removed = 0
-        for dirpath, dirs, files in os.walk(root, topdown=False):
-            for name in files + dirs:
-                p = Path(dirpath, name)
-                rel = str(p.relative_to(root))
-                if rel not in keep:
-                    _rm(p)
-                    removed += 1
+        with span("rsync.prune"):
+            for dirpath, dirs, files in os.walk(root, topdown=False):
+                for name in files + dirs:
+                    p = Path(dirpath, name)
+                    rel = str(p.relative_to(root))
+                    if rel not in keep:
+                        _rm(p)
+                        removed += 1
         return {"verb": "ok", "removed": removed}
 
-    def sigs(msg):
-        """Batched ``sig``: one round trip for a whole file batch — the
-        round-trip half of the planner's DELTA wire cost (protoplan's
-        rt=2 is per BATCH now, which is what makes delta worth pricing
-        on high-latency links)."""
-        return {"verb": "sigs", "sigs": [sig(item) for item in msg["files"]]}
-
-    return {"sig": sig, "sigs": sigs, "apply": apply, "mkdir": mkdir,
+    return {"sigs": sigs, "apply": apply, "mkdir": mkdir,
             "symlink": symlink, "link": link, "special": special,
             "dirmeta": dirmeta, "prune": prune}
 
@@ -201,7 +346,6 @@ def serve_destination(root: Path, dst_private: bytes, source_id: str,
         on_port(port)
     log.info("rsync destination listening on %s:%d", bind, port)
     server.settimeout(0.5)
-    verbs = _dest_verbs(Path(root))
     try:
         while stop_event is None or not stop_event.is_set():
             try:
@@ -212,7 +356,17 @@ def serve_destination(root: Path, dst_private: bytes, source_id: str,
             if out is None:
                 continue  # unknown/failed device: refused at handshake
             ch, _peer = out
-            rc = channel.serve_channel(ch, verbs)
+            # a session's half-built files are the session's: a link
+            # that drops between a file's parts leaves none of them to
+            # the source's next attempt, which sends the file from its
+            # first part
+            incoming: dict = {}
+            try:
+                rc = channel.serve_channel(
+                    ch, _dest_verbs(Path(root), incoming))
+            finally:
+                for inc in incoming.values():
+                    inc.abandon()
             if rc is not None:  # source sent shutdown <rc>
                 return rc
         return 1  # stopped without a completed transfer
@@ -288,12 +442,14 @@ def rsync_source_entrypoint(ctx) -> int:
             raise _PushCancelled()
         # Mutual device auth: we pin the destination's ID, it pins
         # ours — neither side ever held the other's private key.
-        ch = dt.connect_device(address, port, src_private, dest_id)
+        with span("rsync.connect"):
+            ch = dt.connect_device(address, port, src_private, dest_id)
         try:
             t0 = time.perf_counter()
             stats = _push_tree(ch, root)
-            ch.send({"verb": "shutdown", "rc": 0})
-            ch.recv()
+            with span("rsync.finish"):
+                ch.send({"verb": "shutdown", "rc": 0})
+                ch.recv()
             log.info("rsync push complete: %s", stats)
             ctx.report_transfer(stats.get("bytes", 0),
                                 time.perf_counter() - t0)
@@ -325,28 +481,31 @@ def _meta_of(st, p=None) -> dict:
     return out
 
 
-def _push_tree(ch, root: Path) -> dict:
-    from volsync_tpu import envflags
+def _call(ch, msg: dict) -> dict:
+    """One verb and its ack: the frame packed, compressed, sealed and
+    sent, then the wait for the destination's reply."""
+    count("rsync.frames")
+    ch.send(msg)
+    return ch.recv()
 
-    stats = {"files": 0, "literal_bytes": 0, "copied_bytes": 0, "bytes": 0}
-    keep: list[str] = []
-    dirmeta: list[dict] = []
-    inode_first: dict = {}  # (dev, ino) -> rel (rsync -H)
-    # Regular files accumulate into planner-driven batches (one sig
-    # round trip + one device dispatch ladder per batch); VOLSYNC_DELTA_BATCH=1
-    # keeps the legacy serial per-file path.
-    batch_n = envflags.delta_batch_files()
-    pending: list[tuple] = []
 
-    def flush():
-        if pending:
-            _push_files_batch(ch, pending, stats)
-            pending.clear()
+def _apply(ch, msg: dict) -> dict:
+    """``_call`` of a verb that puts one entry, or one part of a file,
+    at the destination (once an entry: off the ring)."""
+    with span("rsync.apply_wait", ctx=off_ring()):
+        return _call(ch, msg)
+
+
+def _walk(root: Path) -> list[tuple]:
+    """The tree in push order as (kind, relative path, path, lstat,
+    wire metadata): a directory, then its files and symlinks by name,
+    then its subdirectories."""
     # rsync -x: one file system. stat(), not lstat(): a SYMLINKED
     # replication root (mount indirection) must anchor the device id at
     # the walk's actual filesystem, or every entry looks foreign and
     # prune would wipe the destination.
     root_dev = root.stat().st_dev
+    out = []
     for dirpath, dirs, files in os.walk(root):
         dirs.sort()
         for name in sorted(files) + dirs:
@@ -360,103 +519,122 @@ def _push_tree(ch, root: Path) -> dict:
                     dirs.remove(name)  # don't descend
                 else:
                     continue  # foreign non-dir: skip entirely
-            keep.append(rel)
-            if stat_mod.S_ISLNK(st.st_mode):
-                ch.send({"verb": "symlink", "path": rel,
-                         "target": os.readlink(p), **_meta_of(st, p)})
-                ch.recv()
-            elif stat_mod.S_ISDIR(st.st_mode):
-                ch.send({"verb": "mkdir", "path": rel,
-                         "mode": st.st_mode & 0o7777})
-                ch.recv()
-                dirmeta.append({"path": rel, **_meta_of(st, p)})
-            elif stat_mod.S_ISREG(st.st_mode):
-                if st.st_nlink > 1:
-                    ino = (st.st_dev, st.st_ino)
-                    first = inode_first.get(ino)
-                    if first is not None:
-                        # the link target must already exist at the
-                        # destination — drain any batch holding it
-                        flush()
-                        ch.send({"verb": "link", "path": rel,
-                                 "to": first})
-                        ch.recv()
-                        stats["files"] += 1
-                        continue
-                    inode_first[ino] = rel
-                if batch_n <= 1:
-                    _push_file(ch, p, rel, st, stats)
-                else:
-                    pending.append((p, rel, st))
-                    if len(pending) >= batch_n:
-                        flush()
-            elif stat_mod.S_ISFIFO(st.st_mode) or stat_mod.S_ISSOCK(
-                    st.st_mode) or stat_mod.S_ISBLK(st.st_mode) \
-                    or stat_mod.S_ISCHR(st.st_mode):
-                msg = {"verb": "special", "path": rel,
-                       "fmt": stat_mod.S_IFMT(st.st_mode),
-                       **_meta_of(st, p)}
-                if stat_mod.S_ISBLK(st.st_mode) or stat_mod.S_ISCHR(
-                        st.st_mode):
-                    msg["rdev"] = st.st_rdev
-                ch.send(msg)
-                ch.recv()
+            fmt = stat_mod.S_IFMT(st.st_mode)
+            kind = {stat_mod.S_IFLNK: "symlink", stat_mod.S_IFDIR: "dir",
+                    stat_mod.S_IFREG: "file"}.get(fmt, "special")
+            out.append((kind, rel, p, st, _meta_of(st, p)))
+    return out
+
+
+def _push_tree(ch, root: Path) -> dict:
+    stats = {"files": 0, "literal_bytes": 0, "copied_bytes": 0, "bytes": 0}
+    dirmeta: list[dict] = []
+    inode_first: dict = {}  # (dev, ino) -> rel (rsync -H)
+    # Regular files accumulate into batches: one sigs round trip and the
+    # scan's staged buffers a batch (deltasync.scan_ranges).
+    pending: list[tuple] = []
+    pending_bytes = 0
+
+    def flush():
+        nonlocal pending_bytes
+        if pending:
+            _push_files_batch(ch, pending, stats)
+            pending.clear()
+            pending_bytes = 0
+
+    with span("rsync.walk"):
+        entries = _walk(root)
+    for kind, rel, p, st, meta in entries:
+        if kind == "symlink":
+            _apply(ch, {"verb": "symlink", "path": rel,
+                        "target": os.readlink(p), **meta})
+        elif kind == "dir":
+            _apply(ch, {"verb": "mkdir", "path": rel,
+                        "mode": st.st_mode & 0o7777})
+            dirmeta.append({"path": rel, **meta})
+        elif kind == "file":
+            if st.st_nlink > 1:
+                ino = (st.st_dev, st.st_ino)
+                first = inode_first.get(ino)
+                if first is not None:
+                    # the link target must already exist at the
+                    # destination — drain any batch holding it
+                    flush()
+                    _apply(ch, {"verb": "link", "path": rel, "to": first})
+                    stats["files"] += 1
+                    count("rsync.files")
+                    continue
+                inode_first[ino] = rel
+            pending.append((p, rel, st, meta))
+            pending_bytes += st.st_size
+            if pending_bytes >= deltasync.WINDOW \
+                    or len(pending) >= BATCH_FILES:
+                flush()
+        else:
+            msg = {"verb": "special", "path": rel,
+                   "fmt": stat_mod.S_IFMT(st.st_mode), **meta}
+            if stat_mod.S_ISBLK(st.st_mode) or stat_mod.S_ISCHR(st.st_mode):
+                msg["rdev"] = st.st_rdev
+            _apply(ch, msg)
     flush()
-    ch.send({"verb": "prune", "paths": keep})
-    ch.recv()
-    # Directory metadata last, children-first (deepest paths first),
-    # with the replication ROOT itself last of all (path "" — rsync -a
-    # with a trailing slash replicates the root dir's meta too):
-    # every write above would have bumped the parent's mtime.
-    dirmeta.sort(key=lambda d: d["path"].count(os.sep), reverse=True)
-    dirmeta.append({"path": "", **_meta_of(root.lstat(), root)})
-    ch.send({"verb": "dirmeta", "dirs": dirmeta})
-    ch.recv()
+    with span("rsync.finish"):
+        out = _call(ch, {"verb": "prune",
+                         "paths": [e[1] for e in entries]})
+        count("rsync.pruned", int(out.get("removed", 0)))
+        # Directory metadata last, children-first (deepest paths first),
+        # with the replication ROOT itself last of all (path "" — rsync
+        # -a with a trailing slash replicates the root dir's meta too):
+        # every write above would have bumped the parent's mtime.
+        dirmeta.sort(key=lambda d: d["path"].count(os.sep), reverse=True)
+        dirmeta.append({"path": "", **_meta_of(root.lstat(), root)})
+        _call(ch, {"verb": "dirmeta", "dirs": dirmeta})
     return stats
 
 
-def _push_file(ch, path: Path, rel: str, st, stats: dict):
-    data = path.read_bytes()
-    block_len = deltasync.pick_block_len(max(len(data), st.st_size))
-    ch.send({"verb": "sig", "path": rel, "block_len": block_len})
-    reply = ch.recv()
-    if reply.get("exists"):
-        sig = deltasync.FileSignature.from_wire(reply)
-        ops = deltasync.compute_delta(data, sig)
-        block_len = sig.block_len
-    else:
-        ops = [("data", data)] if data else []
-    wire_ops = [list(op) for op in ops]
-    ch.send({"verb": "apply", "path": rel, "ops": wire_ops,
-             "block_len": block_len, **_meta_of(st, path)})
-    out = ch.recv()
-    if out.get("verb") != "ok":
-        raise channel.ChannelError(f"apply failed for {rel}: {out}")
-    d = deltasync.delta_stats(ops, block_len)
-    stats["files"] += 1
-    stats["bytes"] += len(data)
-    stats["literal_bytes"] += d["literal_bytes"]
-    stats["copied_bytes"] += d["copied_bytes"]
+def _parts(ops: list, source) -> Iterator[list]:
+    """A file's op stream cut into parts of at most ``PART_BYTES`` of
+    literals and ``PART_OPS`` ops, the literals read as their part is
+    made: at least one part, possibly empty (an empty file)."""
+    part, held = [], 0
+    for op in ops:
+        if op[0] == "copy":
+            part.append(list(op))
+        else:
+            at, end = op[1], op[2]
+            while at < end:
+                n = min(end - at, PART_BYTES - held)
+                part.append(["data", deltasync.read_range(source, at, n)])
+                at += n
+                held += n
+                if held >= PART_BYTES:
+                    yield part
+                    part, held = [], 0
+        if len(part) >= PART_OPS:
+            yield part
+            part, held = [], 0
+    yield part
 
 
 def _push_files_batch(ch, jobs: list, stats: dict):
     """Planner-driven batch push: price FULL vs DELTA per file
     (movers.common.plan_protocol -> engine/protoplan), fetch signatures
     for all delta-planned files in ONE ``sigs`` round trip, run the
-    delta scan for the whole batch through ONE device dispatch ladder
-    (deltasync.delta_scan_batch), then apply per file. Every completed
-    delta and timed round trip feeds the rsync ``SyncStatsBook``, so the
-    planner's next batch prices against what this one actually cost."""
+    delta scan for the whole batch through the staged buffers of
+    ``deltasync.scan_ranges``, then apply per file, each in parts.
+    Every completed delta and timed round trip feeds the rsync
+    ``SyncStatsBook``, so the planner's next batch prices against what
+    this one actually cost."""
     from volsync_tpu.engine.syncstats import book_for
     from volsync_tpu.movers import common
 
     book = book_for("rsync")
-    datas = [p.read_bytes() for p, _rel, _st in jobs]
+    sources = [_FileSource(p, st.st_size, source_side=True)
+               for p, _rel, st, _meta in jobs]
     plans = []
-    for (p, rel, st), data in zip(jobs, datas):
-        block_len = deltasync.pick_block_len(max(len(data), st.st_size))
+    for src in sources:
+        block_len = deltasync.pick_block_len(src.size)
         decision = common.plan_protocol(
-            "rsync", len(data), candidates=("full", "delta"),
+            "rsync", src.size, candidates=("full", "delta"),
             block_len=block_len)
         plans.append((decision.protocol, block_len))
     want = [i for i, (proto, _bl) in enumerate(plans) if proto == "delta"]
@@ -466,54 +644,82 @@ def _push_files_batch(ch, jobs: list, stats: dict):
         # destination's signature computation (and, first time, its jit
         # compile), which would poison the rtt EWMA by orders of
         # magnitude. Small apply acks below are the latency proxy.
-        ch.send({"verb": "sigs", "files": [
-            {"path": jobs[i][1], "block_len": plans[i][1]} for i in want]})
-        reply = ch.recv()
+        with span("rsync.sig_wait"):
+            reply = _call(ch, {"verb": "sigs", "files": [
+                {"path": jobs[i][1], "block_len": plans[i][1]}
+                for i in want]})
         for i, r in zip(want, reply["sigs"]):
             if r.get("exists"):
                 sig_by_idx[i] = deltasync.FileSignature.from_wire(r)
-    scanned = [i for i in want if i in sig_by_idx]
-    batch_ops = deltasync.delta_scan_batch(
-        [(datas[i], sig_by_idx[i]) for i in scanned]) if scanned else []
-    ops_by_idx = dict(zip(scanned, batch_ops))
-    for idx, ((p, rel, st), data) in enumerate(zip(jobs, datas)):
+    scanned = sorted(sig_by_idx)
+    ops_by_idx = dict(zip(scanned, deltasync.scan_ranges(
+        [(sources[i], sig_by_idx[i]) for i in scanned]))) if scanned else {}
+    for idx, ((_p, rel, _st, meta), src) in enumerate(zip(jobs, sources)):
         _proto, block_len = plans[idx]
         if idx in ops_by_idx:
             ops = ops_by_idx[idx]
             block_len = sig_by_idx[idx].block_len
         else:
             # planner said FULL, or the destination has no basis: the
-            # whole file ships as one literal op (still delta framing)
-            ops = [("data", data)] if data else []
-        wire_ops = [list(op) for op in ops]
+            # whole file ships as literals (still delta framing)
+            ops = [("lit", 0, src.size)] if src.size else []
+        literal = deltasync.literal_bytes(ops)
         t0 = time.perf_counter()
-        ch.send({"verb": "apply", "path": rel, "ops": wire_ops,
-                 "block_len": block_len, **_meta_of(st, p)})
-        out = ch.recv()
+        parts = _parts(ops, src)
+        part, index = next(parts), 0
+        while part is not None:
+            ahead = next(parts, None)
+            msg = {"verb": "apply", "path": rel, "ops": part,
+                   "block_len": block_len, "part": index,
+                   "last": ahead is None}
+            if ahead is None:
+                msg.update(meta)
+            out = _apply(ch, msg)
+            if out.get("verb") != "ok":
+                raise channel.ChannelError(f"apply failed for {rel}: {out}")
+            part, index = ahead, index + 1
         elapsed = time.perf_counter() - t0
-        if out.get("verb") != "ok":
-            raise channel.ChannelError(f"apply failed for {rel}: {out}")
-        d = deltasync.delta_stats(ops, block_len)
         if idx in ops_by_idx:
-            book.observe_delta(d["literal_bytes"], len(data))
+            book.observe_delta(literal, src.size)
+            count("rsync.files_delta")
+            count("rsync.copied_bytes", src.size - literal)
+            stats["copied_bytes"] += src.size - literal
+        elif out.get("basis"):
+            count("rsync.files_full")
+        else:
+            count("rsync.files_new")
         # same small/large split as resilience.link_totals(): bulk
         # applies sample bandwidth, near-empty ones sample latency
-        if d["literal_bytes"] >= 16 * 1024:
-            book.observe_link(d["literal_bytes"], elapsed)
+        if literal >= 16 * 1024:
+            book.observe_link(literal, elapsed)
         else:
             book.observe_rtt(elapsed)
+        count("rsync.files")
+        count("rsync.bytes_synced", src.size)
+        count("rsync.literal_bytes", literal)
         stats["files"] += 1
-        stats["bytes"] += len(data)
-        stats["literal_bytes"] += d["literal_bytes"]
-        stats["copied_bytes"] += d["copied_bytes"]
+        stats["bytes"] += src.size
+        stats["literal_bytes"] += literal
 
 
 # ---------------------------------------------------------------------------
 
 
 def _safe_join(root: Path, rel: str) -> Path:
-    p = (root / rel).resolve()
-    if not str(p).startswith(str(root.resolve()) + os.sep) and p != root.resolve():
+    """``root / rel`` with the directories above the last name
+    resolved and held inside ``root``. The last name is not followed: a
+    verb replaces the entry itself (``apply``, ``mkdir``, ``symlink``,
+    ``special``, ``link``'s new name, prune) or reads it only if it is
+    a regular file (``sigs``), and following a symlink that a first
+    sync put there would aim the second sync's ``symlink`` verb at its
+    target. The verbs that act ON an entry, ``dirmeta`` and ``link``'s
+    ``to``, check by ``lstat`` that it is no symlink: a link under a
+    peer-sent name is never followed out of the root."""
+    top = root.resolve()
+    p = top / rel
+    p = p.parent.resolve() / p.name
+    if p.name in ("", ".", "..") or (
+            not str(p).startswith(str(top) + os.sep) and p != top):
         raise channel.ChannelError(f"path escapes root: {rel!r}")
     return p
 

@@ -51,6 +51,10 @@ SANCTIONED_SITES = frozenset({
     "mesh.pad",            # unpadded segment copied out to the mesh's bucket
     "mesh.stage",          # segment laid out over the seq mesh's chips
     "verify.stage",        # restore verify staging onto the device
+    "delta.stage",         # rsync source: a window staged for the match scan
+    "sig.stage",           # rsync destination: a window staged for signing
+    "delta.search",        # a staged window read again by an every-offset search
+    "delta.verify",        # candidate windows the strong check gathers
     "objstore.assemble",   # iovec joined for a contiguous-transport backend
     "repo.buffered_read",  # blob read back while still in the write pipeline
     "svc.frame",           # gRPC frame materialization (protobuf wants bytes)

@@ -10,7 +10,13 @@ TPU mapping: the full rolling-weak scan is one parallel pass
 (volsync_tpu.ops.rolling); membership against the destination's weak set is
 a vectorized binary search (jnp.searchsorted) over the sorted signature;
 candidate offsets are compacted on device; strong verification batches MD5
-over the candidate windows (volsync_tpu.ops.md5.md5_fixed_blocks_device).
+over the candidate windows (volsync_tpu.ops.md5.md5_windows_device).
+The mover's path runs ``delta_sig_flat`` / ``delta_match_rows`` /
+``delta_md5_flat`` on one staged buffer of a fixed size (a batch of
+small files packed into it, or one window of a long file), so a tree of
+any sizes meets a small fixed set of programs; ``match_offsets`` and
+``verify_candidates`` stay as the exact-shape oracle the tests hold
+that path to (engine/deltasync.compute_delta).
 The final greedy left-to-right op selection (sequential, but only over the
 sparse verified matches) runs on host in the engine layer
 (volsync_tpu.engine.deltasync).
@@ -27,6 +33,7 @@ import numpy as np
 from volsync_tpu.ops.md5 import (
     md5_contiguous_blocks_device,
     md5_fixed_blocks_device,
+    md5_windows_device,
 )
 from volsync_tpu.ops.rolling import block_weak_checksums, rolling_weak_checksums
 
@@ -88,70 +95,115 @@ def verify_candidates(data: jax.Array, cand: np.ndarray, *,
 
 
 _M16 = np.uint32(0xFFFF)
+#: row length of the 2-D view the flat programs work in: every block
+#: length the engine picks is a multiple of it, so a shift by one block
+#: is a shift by whole rows (1-D strides and odd shifts lower badly on
+#: the TPU, docs/performance.md op classes)
+_COLS = 1024
+
+
+@functools.partial(jax.jit, static_argnames=("block_len",))
+def delta_sig_flat(data: jax.Array, *, block_len: int):
+    """Destination side, one staged buffer: the weak and the strong
+    checksum of every ``block_len`` block of ``data`` ([N] uint8,
+    N % block_len == 0, block_len % 1024 == 0) -> ([N / block_len]
+    uint32, [N / block_len, 4] uint32). Files are laid into the buffer
+    at block-aligned offsets by the engine, which keeps the blocks that
+    are whole blocks of a file and checksums short tails on the host."""
+    nb = data.shape[0] // block_len
+    x = data.reshape(nb, block_len).astype(jnp.uint32)
+    # b = sum (block_len - i) * x_i: uint32 wraparound keeps the
+    # mod-2^16 residue exact, as in ops/rolling.py
+    w = (np.uint32(block_len)
+         - jnp.arange(block_len, dtype=jnp.uint32))[None, :]
+    a = jnp.sum(x, axis=1, dtype=jnp.uint32) & _M16
+    b = jnp.sum(x * w, axis=1, dtype=jnp.uint32) & _M16
+    weak = a | (b << np.uint32(16))
+    strong = md5_contiguous_blocks_device(data, block_len=block_len)
+    return weak, strong
+
+
+def _flat_prefix(v: jax.Array):
+    """Exclusive prefix sums of ``v`` ([R, _COLS] uint32, row-major
+    flat order) and the total, by rows: a short scan along each row
+    plus a scan of the row totals."""
+    inc = jnp.cumsum(v, axis=1, dtype=jnp.uint32)
+    rows = inc[:, -1]
+    base = jnp.cumsum(rows, dtype=jnp.uint32) - rows
+    return inc - v + base[:, None], base[-1] + rows[-1]
 
 
 @functools.partial(jax.jit, static_argnames=("window", "max_candidates"))
-def match_offsets_batch(data: jax.Array, sorted_weak: jax.Array,
-                        nb: jax.Array, nscan: jax.Array, *,
-                        window: int, max_candidates: int):
-    """Multi-file ``match_offsets``: one rolling scan + membership pass
-    over a whole padded file batch (engine/deltasync.delta_scan_batch).
+def delta_match_rows(data: jax.Array, sorted_weak: jax.Array,
+                     n_sig: jax.Array, rows: jax.Array,
+                     row_until: jax.Array, lo: jax.Array, *,
+                     window: int, max_candidates: int):
+    """Source side, one staged buffer: among the offsets of the listed
+    rows of 1024, those whose rolling weak checksum over ``window``
+    bytes is in the signatures' weak set. The engine lists the rows
+    that the block-aligned probe (``delta_sig_flat`` on the same
+    buffer) left open: a search at every offset costs a table lookup an
+    offset, which is what the chip is slow at (a gather an element),
+    while the prefix sums over the whole buffer are cheap.
 
-    data:        [n, L] uint8, one zero-padded file per row.
-    sorted_weak: [n, nb_cap] uint32 per-row sorted signature weak sets,
-                 0xFFFFFFFF-padded past each row's true count.
-    nb:          [n] int32 true signature lengths (masks the padding —
-                 a real weak equal to the sentinel still matches inside
-                 its row's first ``nb`` entries, exactly like the serial
-                 clip-then-compare).
-    nscan:       [n] int32 valid scan offsets per row (len - window + 1);
-                 offsets whose window would read padding are masked out,
-                 which is what makes the batch candidate set per row
-                 identical to the serial per-file scan.
+    data:        [N] uint8: files laid at slot starts (or one window of
+                 a long file), zeros between them.
+    sorted_weak: [nb_cap] uint32: the weak checksums of the full blocks
+                 of every signature of the buffer, merged and sorted,
+                 0xFFFFFFFF past the first ``n_sig``. A hit on another
+                 file's block is a false candidate like any other: the
+                 engine keeps a candidate only if its own file's
+                 signature holds (weak, strong).
+    rows:        [G] int32 ascending: the rows to search (row r holds
+                 the offsets r * 1024 ...); unused places repeat a row
+                 with ``row_until`` 0.
+    row_until:   [G] int32: for each listed row, the flat offset one
+                 past the last window start that lies wholly inside the
+                 file that owns the row (0: nothing). Masks padding and
+                 windows that would run past a file's end.
+    lo:          int32 scalar: candidates below it are left out (the
+                 engine's next round after an overflow).
 
-    Returns (cand [max_candidates] int32 ascending row-major flattened
-    indices into [n, L-window+1] with n*(L-window+1) as fill,
-    true_count) — the host re-runs with a doubled bound on truncation,
-    same ladder as the serial path.
+    Returns (cand [max_candidates] int32 flat offsets ascending, N as
+    fill; their weak checksums [max_candidates] uint32; the true count
+    from ``lo`` on).
     """
-    n, L = data.shape
-    width = L - window + 1
-    # Rolling weak checksum of every row at every offset, batched: the
-    # same prefix-sum identity as ops/rolling.py with cumsums along the
-    # row axis (uint32 wraparound keeps the mod-2^16 residues exact).
-    x = data.astype(jnp.uint32)
-    j = jnp.arange(L, dtype=jnp.uint32)[None, :]
-    S = jnp.pad(jnp.cumsum(x, axis=1, dtype=jnp.uint32), ((0, 0), (1, 0)))
-    T = jnp.pad(jnp.cumsum(j * x, axis=1, dtype=jnp.uint32), ((0, 0), (1, 0)))
-    k = jnp.arange(width, dtype=jnp.uint32)[None, :]
-    dS = S[:, window:] - S[:, :width]
-    dT = T[:, window:] - T[:, :width]
+    N = data.shape[0]
+    R = N // _COLS
+    x = data.reshape(R, _COLS).astype(jnp.uint32)
+    k = (jnp.arange(R, dtype=jnp.uint32)[:, None] * np.uint32(_COLS)
+         + jnp.arange(_COLS, dtype=jnp.uint32)[None, :])
+    S, s_all = _flat_prefix(x)
+    T, t_all = _flat_prefix(k * x)
+    shift = window // _COLS  # E[k + window]: whole rows up
+
+    def ahead(E, total):
+        fill = jnp.broadcast_to(total, (shift, _COLS))
+        return jnp.concatenate([E[shift:], fill], axis=0)
+
+    dS = ahead(S, s_all) - S
+    dT = ahead(T, t_all) - T
     a = dS & _M16
     b = ((k + np.uint32(window)) * dS - dT) & _M16
-    weak = a | (b << np.uint32(16))                      # [n, width]
-    # Per-row membership against that row's sorted signature.
-    pos = jax.vmap(jnp.searchsorted)(sorted_weak, weak)  # [n, width]
-    clipped = jnp.minimum(pos, sorted_weak.shape[1] - 1)
-    found = jnp.take_along_axis(sorted_weak, clipped, axis=1)
-    hit = (found == weak) & (pos < nb[:, None])
-    hit = hit & (jnp.arange(width, dtype=jnp.int32)[None, :]
-                 < nscan[:, None])
-    flat = hit.reshape(-1)
-    cand = jnp.nonzero(flat, size=max_candidates, fill_value=n * width)[0]
-    return cand.astype(jnp.int32), jnp.sum(hit)
+    weak = (a | (b << np.uint32(16)))[rows]              # [G, _COLS]
+    at = (rows[:, None] * _COLS
+          + jnp.arange(_COLS, dtype=jnp.int32)[None, :])
+    pos = jnp.searchsorted(sorted_weak, weak.reshape(-1),
+                           method="sort").reshape(weak.shape)
+    found = sorted_weak[jnp.minimum(pos, sorted_weak.shape[0] - 1)]
+    hit = ((found == weak) & (pos < n_sig) & (at < row_until[:, None])
+           & (at >= lo)).reshape(-1)
+    G = rows.shape[0]
+    idx = jnp.nonzero(hit, size=max_candidates, fill_value=G * _COLS)[0]
+    safe = jnp.minimum(idx, G * _COLS - 1)
+    cand = jnp.where(idx < G * _COLS, at.reshape(-1)[safe], N)
+    return cand.astype(jnp.int32), weak.reshape(-1)[safe], jnp.sum(hit)
 
 
-def verify_candidates_batch(data: jax.Array, rows: np.ndarray,
-                            offs: np.ndarray, *,
-                            block_len: int) -> np.ndarray:
-    """Batch MD5 over candidate windows across a padded [n, L] file
-    batch -> [k, 4] uint32 states. One dispatch for the whole batch:
-    rows flatten to offsets into the [n*L] buffer, and a candidate
-    window never crosses a row boundary (offs <= row_len - block_len)."""
-    if len(rows) == 0:
-        return np.zeros((0, 4), dtype=np.uint32)
-    L = data.shape[1]
-    starts = (np.asarray(rows, dtype=np.int64) * L
-              + np.asarray(offs, dtype=np.int64)).astype(np.int32)
-    return np.asarray(md5_fixed_blocks_device(  # lint: ignore[VL501] host-result contract: one batched strong-check fetch
-        data.reshape(-1), jnp.asarray(starts), block_len=block_len))
+@functools.partial(jax.jit, static_argnames=("block_len",))
+def delta_md5_flat(data: jax.Array, starts: jax.Array, *,
+                   block_len: int) -> jax.Array:
+    """The strong check of one staged buffer's candidates: MD5 of the
+    ``block_len`` bytes at each of ``starts`` ([K] int32, padded with 0
+    by the engine to its fixed capacity) -> [K, 4] uint32 states."""
+    return md5_windows_device(data, starts, block_len=block_len)

@@ -219,15 +219,19 @@ def md5_contiguous_blocks_device(data: jax.Array, *,
     sizes.
     """
     assert block_len % 1024 == 0, "fast path needs 256-word columns"
-    from volsync_tpu.ops.sha256 import pack_words_rows
-
     L = data.shape[0]
-    B = L // block_len
-    r = data.reshape(B, block_len)
+    return _md5_rows(data.reshape(L // block_len, block_len))
+
+
+def _md5_rows(r: jax.Array) -> jax.Array:
+    """MD5 of every row of ``r`` ([B, block_len] uint8, block_len a
+    multiple of 1024) -> [B, 4] uint32 states: the body of
+    ``md5_contiguous_blocks_device``, shared with the windowed strong
+    check (``md5_windows_device``), traced inside the caller's jit."""
+    from volsync_tpu.ops.sha256 import pack_words_rows, use_pallas_leaves
+
+    B, block_len = r.shape
     w = pack_words_rows(r, little_endian=True)  # [B, W] LE words
-
-    from volsync_tpu.ops.sha256 import use_pallas_leaves
-
     if not use_pallas_leaves():
         # Shares sha256's predicate: off the TPU the XLA transpose
         # stands in for the Mosaic kernel.
@@ -260,3 +264,19 @@ def md5_contiguous_blocks_device(data: jax.Array, *,
     pad[15] = (bitlen >> 32) & 0xFFFFFFFF
     pad_block = jnp.broadcast_to(jnp.asarray(pad), (Bp, 16))
     return _compress(state, pad_block)[:B]
+
+
+@functools.partial(jax.jit, static_argnames=("block_len",))
+def md5_windows_device(data: jax.Array, starts: jax.Array, *,
+                       block_len: int) -> jax.Array:
+    """MD5 of the ``block_len`` bytes at each of ``starts`` in ``data``
+    ([L] uint8; starts [K] int32, each at most L - block_len) ->
+    [K, 4] uint32 states: the delta scan's strong check at a fixed
+    candidate capacity. Each window is one contiguous slice (a gather
+    of K rows of block_len bytes, not of K * block_len single bytes)
+    hashed by the signature's transposed-lane body. block_len must be a
+    multiple of 1024."""
+    assert block_len % 1024 == 0, "fast path needs 256-word columns"
+    rows = jax.vmap(
+        lambda s: jax.lax.dynamic_slice(data, (s,), (block_len,)))(starts)
+    return _md5_rows(rows)
