@@ -230,9 +230,14 @@ def test_bidirectional_sync_converges_with_delta(tmp_path, rng):
     assert stats["copied_bytes"] > 0, "delta never engaged A->B"
     assert stats["literal_bytes"] < len(payload) // 4
 
-    # round 3: B grows its own change; push B->A must delta the other way
+    # round 3: B grows its own changes; push B->A must delta the other
+    # way (a file B left as round 2 put it has A's size and mtime, and
+    # rsync's quick check skips it: data.bin changes too)
     with open(b / "logs" / "app.log", "ab") as f:
         f.write(rng.bytes(8_000))
+    with open(b / "data.bin", "r+b") as f:
+        f.seek(2_000_000)
+        f.write(rng.bytes(50))
     stats = entry._push_tree(_Chan(entry._dest_verbs(a)), b)
     assert _tree_bytes(a) == _tree_bytes(b)
     assert stats["copied_bytes"] > 0, "delta never engaged B->A"

@@ -98,9 +98,11 @@ def _write_states(work: Path):
         old, new = _bytes(kind, rng)
         (a / f"{kind}.bin").write_bytes(old)
         (b / f"{kind}.bin").write_bytes(new)
-        if old == new:  # a copy keeps its mtime, as the cell's does
-            st = os.stat(a / f"{kind}.bin")
-            os.utime(b / f"{kind}.bin", ns=(st.st_atime_ns, st.st_mtime_ns))
+        # a copy keeps its mtime, as the cell's does; a file written
+        # again has another, whatever the file system's clock resolves
+        st = os.stat(a / f"{kind}.bin")
+        mtime = st.st_mtime_ns + (0 if old == new else 10**9)
+        os.utime(b / f"{kind}.bin", ns=(st.st_atime_ns, mtime))
     (a / "sub" / "only_a").write_bytes(rng.bytes(9_000))
     (b / "sub" / "only_b").write_bytes(rng.bytes(12_000))
     for root in (a, b):
@@ -111,6 +113,20 @@ def _write_states(work: Path):
 def _state(work: Path):
     return rsync_push.relationship(rsync_push.State(), work / "d",
                                    {"VOLSYNC_SYNC_PROTO": "delta"})
+
+
+def _quick_same(source: Path, dest: Path) -> dict:
+    """{relative path: bytes} of the regular files of ``source`` that
+    rsync's quick check leaves alone on ``dest``: a regular file there
+    (by ``lstat``) of the same size and mtime."""
+    import stat
+
+    want, have = treecmp.entries(source), treecmp.entries(dest)
+    return {rel: st.st_size for rel, st in want.items()
+            if stat.S_ISREG(st.st_mode) and rel in have
+            and stat.S_ISREG(have[rel].st_mode)
+            and (st.st_size, st.st_mtime_ns)
+            == (have[rel].st_size, have[rel].st_mtime_ns)}
 
 
 @pytest.fixture(scope="module")
@@ -134,9 +150,10 @@ def pushed(tmp_path_factory):
         held.mkdir()
         for stage, root in (("first", a), ("churned", b), ("back", a)):
             want = rsyncdelta.tree_delta(root, held)
+            same = _quick_same(root, held)
             reset_spans()
             got = rsync_push.push(st, {"root": root})
-            out[stage] = {"got": got, "want": want,
+            out[stage] = {"got": got, "want": want, "same": same,
                           "tree": treecmp.compare(root, st.dest),
                           "counters": counter_totals(),
                           "spans": span_totals()}
@@ -200,11 +217,21 @@ def test_a_push_moves_the_references_literal_bytes(pushed, stage):
     assert c["rsync.pruned"] == want["pruned"]
     assert c["rsync.files_delta"] + c["rsync.files_skipped"] \
         == want["files_basis"]
+    assert c["rsync.files_delta"] + c["rsync.files_skipped"] \
+        + c["rsync.files_new"] == c["rsync.files"] == want["files"]
     assert c["rsync.files_full"] == 0
     assert pushed[stage]["got"]["staged"] >= want["staged_floor"]
+    # the quick check took the files of the same size and mtime, which
+    # had no literal bytes to move, and no other
+    same, counted = pushed[stage]["same"], pushed[stage]["counters"]
+    assert c["rsync.files_skipped"] == len(same)
+    assert counted.get("rsync.bytes_skipped", 0) == sum(same.values())
+    assert counted["rsync.bytes_synced"] == want["bytes"]
+    assert all(want["by_file"][rel] == 0 for rel in same)
     if stage != "first":
         assert 0 < want["literal_bytes"] < want["bytes"] // 2
         assert want["files_new"] == 1 and want["pruned"] == 1
+        assert sorted(same) == ["empty.bin", "tiny.bin", "unchanged.bin"]
 
 
 def test_a_file_over_one_frame_arrives_in_parts(pushed):
@@ -436,15 +463,401 @@ def test_a_symlink_out_of_the_root_is_not_followed(tmp_path, verb):
                         "block_len": 4096, "last": True, "mode": 0o600})
         assert not (dst / "file").is_symlink()  # the name was replaced
         assert (dst / "file").read_bytes() == b"mine"
-    else:
-        out = verbs["sigs"]({"files": [{"path": "file", "block_len": 4096}]})
+    else:  # what a stat() through the link would find is the request's
+        was = os.stat(outside / "secret")
+        out = verbs["sigs"]({"files": [
+            {"path": "file", "block_len": 4096, "size": was.st_size,
+             "mtime_ns": was.st_mtime_ns, "mode": 0o600}]})
         assert out["sigs"] == [{"exists": False}]
+        assert os.stat(outside / "secret").st_mode == was.st_mode
     after = os.stat(outside)
     assert (after.st_mode, after.st_mtime_ns) \
         == (before.st_mode, before.st_mtime_ns)
     assert os.stat(outside / "secret").st_nlink == 1
     assert (outside / "secret").read_bytes() == b"s" * 5000
     assert sorted(os.listdir(outside)) == ["secret"]
+
+
+# -- rsync's quick check (guarantee (b): no -c and no -I) ------------------
+
+#: every call of ``os`` that asks the kernel about a file or changes it
+ASKING = ("stat", "lstat", "open", "listxattr", "getxattr")
+CHANGING = ("chown", "chmod", "utime", "setxattr", "removexattr")
+
+
+def _record_calls(monkeypatch, names, calls):
+    """Let ``os.<name>`` append (name, path) to ``calls`` before it
+    does its work (``Path.lstat`` is ``os.stat``: both read ``stat``)."""
+    for name in names:
+        def recorded(path, *args, _name=name, _fn=getattr(os, name), **kw):
+            if isinstance(path, (str, os.PathLike)):
+                calls.append(("stat" if _name == "lstat" else _name,
+                              os.fspath(path)))
+            return _fn(path, *args, **kw)
+        monkeypatch.setattr(os, name, recorded)
+
+
+def _tree(root: Path, files: int = 6) -> dict[str, int]:
+    """A small volume: ``files`` regular files over three directories
+    (one of them empty, one under a block), a symlink."""
+    rng = np.random.default_rng(SEED)
+    sizes = {}
+    for i in range(files):
+        rel = ("", "sub/", "sub/deeper/")[i % 3] + f"f{i:02d}"
+        (root / rel).parent.mkdir(parents=True, exist_ok=True)
+        sizes[rel] = (0, 300, 9_000, 20_000, 40_000)[i % 5]
+        (root / rel).write_bytes(rng.bytes(sizes[rel]))
+    os.symlink("f00", root / "link")
+    return sizes
+
+
+def _push(src: Path, dst: Path, verbs=None) -> dict:
+    """One push over the loopback channel; what it counted."""
+    reset_spans()
+    entry._push_tree(_Chan(verbs or entry._dest_verbs(dst)), src)
+    return counter_totals()
+
+
+def _clean(tree: dict) -> bool:
+    return not any(tree[k] for k in ("missing", "extra", "size", "content",
+                                     "meta"))
+
+
+@pytest.fixture
+def synced(tmp_path, small_window):
+    """(source, destination, {relative path: bytes}) after a first
+    sync."""
+    src, dst = tmp_path / "src", tmp_path / "dst"
+    src.mkdir()
+    dst.mkdir()
+    sizes = _tree(src)
+    c = _push(src, dst)
+    assert c["rsync.files_new"] == len(sizes)
+    assert "rsync.files_skipped" not in c
+    return src, dst, sizes
+
+
+@pytest.mark.parametrize("files", [5, 40])
+def test_a_second_push_of_an_unchanged_tree_opens_no_file(
+        tmp_path, monkeypatch, files):
+    """Every regular file is skipped: nothing is staged on either side,
+    no file of either tree is opened, the frames are the directories',
+    the symlink's and the batch's one ``sigs`` (with prune and
+    directory metadata) however many files there are, and every
+    destination file is the inode it was."""
+    from volsync_tpu.obs import copies_by_site
+
+    monkeypatch.setenv("VOLSYNC_SYNC_PROTO", "delta")
+    monkeypatch.setattr(deltasync, "WINDOW", 64 << 20)  # the mover's: a batch
+    src, dst = tmp_path / "src", tmp_path / "dst"
+    src.mkdir()
+    dst.mkdir()
+    sizes = _tree(src, files)
+    _push(src, dst)
+    inodes = {rel: os.lstat(dst / rel).st_ino for rel in sizes}
+    staged, calls = copies_by_site(), []
+    _record_calls(monkeypatch, ("open",), calls)
+    c = _push(src, dst)
+    monkeypatch.undo()
+    assert calls == []
+    assert c["rsync.files_skipped"] == c["rsync.files"] == len(sizes)
+    assert c["rsync.bytes_skipped"] == c["rsync.bytes_synced"] \
+        == sum(sizes.values())
+    for name in ("delta.batches", "delta.files", "rsync.files_delta",
+                 "rsync.files_new", "rsync.files_full",
+                 "rsync.copied_bytes"):
+        assert name not in c, name
+    assert c.get("rsync.literal_bytes", 0) == 0
+    now = copies_by_site()
+    for site in ("sig.stage", "delta.stage"):
+        assert now.get(site, 0) == staged.get(site, 0), site
+    # two mkdirs, one symlink, one sigs, prune, dirmeta
+    assert c["rsync.frames"] == 6
+    assert {rel: os.lstat(dst / rel).st_ino for rel in sizes} == inodes
+    assert _clean(treecmp.compare(src, dst))
+
+
+@pytest.mark.parametrize("other", ["mtime", "size"])
+def test_another_size_or_mtime_moves_by_delta(synced, other):
+    """The check is both: a file of the destination's size with another
+    mtime, and one of its mtime with another size, move by delta with
+    the reference's literal bytes; the rest is skipped."""
+    src, dst, sizes = synced
+    f = src / "sub" / "f04"
+    was, body = os.stat(f), bytearray(f.read_bytes())
+    if other == "mtime":
+        body[10_000: 10_100] = bytes(100)
+        f.write_bytes(bytes(body))
+        os.utime(f, ns=(was.st_atime_ns, was.st_mtime_ns + 1))
+    else:
+        f.write_bytes(bytes(body) + b"more")
+        os.utime(f, ns=(was.st_atime_ns, was.st_mtime_ns))
+    want = rsyncdelta.tree_delta(src, dst)
+    assert 0 < want["literal_bytes"] < 3 * 4096
+    c = _push(src, dst)
+    assert c["rsync.files_delta"] == 1
+    assert c["rsync.files_skipped"] == len(sizes) - 1
+    assert c["rsync.literal_bytes"] == want["literal_bytes"]
+    assert _clean(treecmp.compare(src, dst))
+
+
+def test_the_quick_check_comes_before_the_planners_choice(
+        tmp_path, monkeypatch):
+    """Where the planner sends files whole (``rsync -W``; here pinned)
+    an unchanged file is skipped all the same, and a changed one is
+    looked at by the destination (its one ``lstat``) but not signed:
+    the request says ``sign`` false for it."""
+    from volsync_tpu.obs import copies_by_site
+
+    monkeypatch.setenv("VOLSYNC_SYNC_PROTO", "full")
+    src, dst = tmp_path / "src", tmp_path / "dst"
+    src.mkdir()
+    dst.mkdir()
+    sizes = _tree(src)
+    _push(src, dst)
+    (src / "sub" / "f04").write_bytes(b"another body")
+    verbs, asked = entry._dest_verbs(dst), []
+    real = verbs["sigs"]
+    verbs["sigs"] = lambda msg: (asked.extend(msg["files"]), real(msg))[1]
+    staged = copies_by_site().get("sig.stage", 0)
+    c = _push(src, dst, verbs)
+    assert [item["sign"] for item in asked] == [False] * len(sizes)
+    assert c["rsync.files_skipped"] == len(sizes) - 1
+    assert c["rsync.files_full"] == 1 and "rsync.files_delta" not in c
+    assert c["rsync.literal_bytes"] == len(b"another body")
+    assert copies_by_site().get("sig.stage", 0) == staged
+    assert _clean(treecmp.compare(src, dst))
+
+
+def _to_another_owner(f: Path) -> None:
+    st = os.stat(f)
+    if os.geteuid() == 0:
+        os.chown(f, st.st_uid + 1234, st.st_gid + 4321)
+        return
+    groups = [g for g in os.getgroups() if g != st.st_gid]
+    if not groups:
+        pytest.skip("this user can give a file to no other group")
+    os.chown(f, -1, groups[0])
+
+
+def _xattr(f: Path, value: bytes = b"v") -> None:
+    try:
+        os.setxattr(f, "user.keep", value)
+    except OSError:
+        pytest.skip("the file system takes no user.* xattr")
+
+
+#: what differs -> (before the first sync, at the source after it, the
+#: changing calls the second sync makes on the destination's file, the
+#: questions it asks about it)
+SKIPPED_META = {
+    "nothing": (None, None, [], ["stat"]),
+    "mode": (None, lambda f: os.chmod(f, 0o600), ["chmod"], ["stat"]),
+    # chown clears suid, so a chown brings its chmod
+    "owner": (None, _to_another_owner, ["chown", "chmod"], ["stat"]),
+    "xattr": (None, _xattr, ["setxattr"],
+              ["stat", "listxattr", "listxattr"]),
+    "xattr_value": (_xattr, lambda f: _xattr(f, b"other"), ["setxattr"],
+                    ["stat", "listxattr", "getxattr", "listxattr"]),
+    "xattr_held": (_xattr, None, [],
+                   ["stat", "listxattr", "getxattr"]),
+}
+
+
+@pytest.mark.parametrize("differs", sorted(SKIPPED_META))
+def test_a_skipped_files_metadata_converges(tmp_path, small_window,
+                                            monkeypatch, differs):
+    """``rsync -a`` sets permissions, owner and xattrs on a file its
+    quick check skips: the file keeps its inode and its bytes are not
+    read, its metadata ends as the source's, and the destination makes
+    a changing call only where something differs. A file without
+    xattrs at the source costs the destination ONE ``lstat``, and the
+    source nothing after the walk's ``lstat`` and ``listxattr``."""
+    before, drift, changing, asked = SKIPPED_META[differs]
+    src, dst = tmp_path / "src", tmp_path / "dst"
+    src.mkdir()
+    dst.mkdir()
+    sizes = _tree(src)
+    f, g = src / "sub" / "f04", dst / "sub" / "f04"
+    if before:
+        before(f)
+    _push(src, dst)
+    was = os.stat(f)
+    if drift:
+        drift(f)
+    assert os.stat(f).st_mtime_ns == was.st_mtime_ns  # ctime alone moved
+    inode, calls = os.lstat(g).st_ino, []
+    _record_calls(monkeypatch, ASKING + CHANGING, calls)
+    c = _push(src, dst)
+    monkeypatch.undo()
+    assert c["rsync.files_skipped"] == len(sizes)
+    there = [name for name, path in calls if path == str(g)]
+    assert [n for n in there if n in CHANGING] == changing
+    assert [n for n in there if n in ASKING] == asked
+    assert [name for name, path in calls if path == str(f)] \
+        == ["stat", "listxattr"] + ["getxattr"] * differs.startswith("xattr")
+    # no other regular file got a changing call either
+    assert {path for name, path in calls if name in CHANGING
+            and os.path.isfile(path) and not os.path.islink(path)} \
+        == ({str(g)} if changing else set())
+    st, want = os.lstat(g), os.lstat(f)
+    assert st.st_ino == inode
+    assert (st.st_mode, st.st_uid, st.st_gid, st.st_mtime_ns) \
+        == (want.st_mode, want.st_uid, want.st_gid, want.st_mtime_ns)
+    assert {n: os.getxattr(g, n) for n in os.listxattr(g)} \
+        == {n: os.getxattr(f, n) for n in os.listxattr(f)}
+    assert _clean(treecmp.compare(src, dst))
+
+
+@pytest.mark.parametrize("there", ["symlink", "directory", "nothing"])
+def test_what_is_no_regular_file_there_is_not_skipped(synced, there):
+    """The check is an ``lstat``: a symlink under the name whose target
+    has the source's size and mtime, a directory or nothing is no
+    basis, and the push puts the file there."""
+    src, dst, sizes = synced
+    g = dst / "sub" / "f04"
+    os.unlink(g)
+    if there == "symlink":
+        held = dst / "sub" / "held"
+        held.write_bytes((src / "sub" / "f04").read_bytes())
+        was = os.stat(src / "sub" / "f04")
+        os.utime(held, ns=(was.st_atime_ns, was.st_mtime_ns))
+        os.symlink("held", g)
+        assert os.stat(g).st_mtime_ns == was.st_mtime_ns
+    elif there == "directory":
+        (g / "inside").mkdir(parents=True)
+    c = _push(src, dst)
+    assert c["rsync.files_skipped"] == len(sizes) - 1
+    assert c["rsync.files_new"] == 1
+    assert g.is_file() and not g.is_symlink()
+    assert _clean(treecmp.compare(src, dst))
+
+
+def test_a_change_behind_an_equal_size_and_mtime_is_left(synced):
+    """rsync's stated semantics, the configuration's guarantee (b), and
+    no accident: a bit flipped at the destination with the size and
+    mtime put back is NOT found by the next push (there is no ``-c``),
+    and is found by the first push that sees another mtime."""
+    src, dst, sizes = synced
+    f, g = src / "sub" / "f04", dst / "sub" / "f04"
+    was, body = os.stat(g), bytearray(g.read_bytes())
+    body[len(body) // 2] ^= 0x10
+    g.write_bytes(bytes(body))
+    os.utime(g, ns=(was.st_atime_ns, was.st_mtime_ns))
+    c = _push(src, dst)
+    assert c["rsync.files_skipped"] == len(sizes)
+    assert g.read_bytes() == bytes(body) != f.read_bytes()
+    os.utime(g, ns=(was.st_atime_ns, was.st_mtime_ns - 1))
+    c = _push(src, dst)
+    assert (c["rsync.files_skipped"], c["rsync.files_delta"]) \
+        == (len(sizes) - 1, 1)
+    assert c["rsync.literal_bytes"] == 4096  # the block that holds the bit
+    assert g.read_bytes() == f.read_bytes()
+    assert _clean(treecmp.compare(src, dst))
+
+
+@pytest.mark.parametrize("older", ["source", "destination"])
+def test_the_quick_check_is_additive_on_the_wire(synced, older):
+    """A mixed pair of movers scans: a ``sigs`` request without ``size``
+    (an older source's) is never answered ``same`` but with the file's
+    signature, and a reply without ``same`` (an older destination's: it
+    reads ``path`` and ``block_len`` alone) is scanned as before."""
+    src, dst, sizes = synced
+    verbs = entry._dest_verbs(dst)
+    real = verbs["sigs"]
+    if older == "source":
+        out = real({"files": [{"path": rel, "block_len": 4096}
+                              for rel in sizes]})
+        assert not any("same" in r for r in out["sigs"])
+        assert [r["size"] for r in out["sigs"]] == list(sizes.values())
+        assert all(len(r["strong"]) == 16 * -(-r["size"] // 4096)
+                   for r in out["sigs"])
+        return
+    verbs["sigs"] = lambda msg: real({**msg, "files": [
+        {"path": item["path"], "block_len": item["block_len"]}
+        for item in msg["files"]]})
+    c = _push(src, dst, verbs)
+    assert c["rsync.files_delta"] == c["rsync.files"] == len(sizes)
+    assert "rsync.files_skipped" not in c and c["rsync.literal_bytes"] == 0
+    assert _clean(treecmp.compare(src, dst))
+
+
+def test_a_skipped_first_name_still_gets_its_later_names_linked(synced):
+    """rsync -H through the quick check: the first name of a hardlinked
+    inode is skipped, and a later name the destination lost is linked
+    to it again."""
+    src, dst, sizes = synced
+    os.link(src / "f03", src / "sub" / "f03.again")
+    os.link(src / "f03", src / "z.third")  # the walk meets f03 first
+    _push(src, dst)
+    os.unlink(dst / "sub" / "f03.again")
+    (dst / "z.third").unlink()
+    (dst / "z.third").write_bytes(b"a file of its own")
+    inode = os.lstat(dst / "f03").st_ino
+    c = _push(src, dst)
+    assert c["rsync.files_skipped"] == len(sizes)
+    assert c["rsync.files"] == len(sizes) + 2
+    for name in ("f03", "sub/f03.again", "z.third"):
+        assert os.lstat(dst / name).st_ino == inode, name
+    assert _clean(treecmp.compare(src, dst))
+
+
+@pytest.mark.parametrize("place", ["first", "later"])
+def test_sigs_holds_every_name_of_a_directory_inside_the_root(tmp_path,
+                                                              place):
+    """The once-a-directory resolve holds what ``_safe_join`` held: a
+    destination directory that is a symlink out of the root is refused
+    for the first name under it in a batch and for a later one (the
+    directory already resolved), and nothing outside is asked about or
+    changed."""
+    outside, dst = tmp_path / "outside", tmp_path / "dst"
+    outside.mkdir()
+    (dst / "in").mkdir(parents=True)
+    (dst / "in" / "ok").write_bytes(b"k" * 100)
+    for name in ("x", "y"):
+        (outside / name).write_bytes(b"s" * 5000)
+    os.symlink(outside, dst / "dir")
+    was = os.stat(outside / "y")
+
+    def item(rel):
+        return {"path": rel, "block_len": 4096, "size": was.st_size,
+                "mtime_ns": was.st_mtime_ns, "mode": 0o600}
+
+    names = {"first": ["dir/y", "in/ok"],
+             "later": ["in/ok", "dir/x", "dir/y"]}[place]
+    calls = []
+    with pytest.MonkeyPatch.context() as mp:
+        _record_calls(mp, ASKING + CHANGING, calls)
+        with pytest.raises(entry.channel.ChannelError):
+            entry._dest_verbs(dst)["sigs"](
+                {"files": [item(rel) for rel in names]})
+        # a request that begins with a good name of the directory
+        # cannot carry a bad one in behind it
+        with pytest.raises(entry.channel.ChannelError):
+            entry._dest_verbs(dst)["sigs"](
+                {"files": [item("in/ok"), item("in/../dir/y")]})
+    assert not [c for c in calls if c[1].startswith(str(outside) + os.sep)]
+    assert os.stat(outside / "y").st_mode == was.st_mode
+
+
+def test_sigs_resolves_a_directory_once_a_call(synced, monkeypatch):
+    """Six names in three directories: the root and each directory are
+    resolved once in the call, not twice a file."""
+    src, dst, sizes = synced
+    resolved = []
+    real = Path.resolve
+    monkeypatch.setattr(
+        Path, "resolve",
+        lambda self, *a, **kw: (resolved.append(str(self)),
+                                real(self, *a, **kw))[1])
+    out = entry._dest_verbs(dst)["sigs"]({"files": [
+        {"path": rel, "block_len": 4096, "size": n,
+         "mtime_ns": os.lstat(src / rel).st_mtime_ns}
+        for rel, n in sizes.items()]})
+    monkeypatch.undo()
+    assert out["sigs"] == [{"exists": True, "same": True}] * len(sizes)
+    assert sorted(resolved) == sorted(
+        str(dst / d) for d in ("", "", "sub", "sub/deeper"))
 
 
 def _compiles():
@@ -500,6 +913,7 @@ DEST_SPANS = ("rsync.sig", "sig.stage", "sig.launch", "sig.fetch",
 OFF_RING = ("rsync.read", "rsync.apply_wait", "rsync.apply")
 COUNTERS = ("rsync.files", "rsync.bytes_synced", "rsync.literal_bytes",
             "rsync.copied_bytes", "rsync.files_delta", "rsync.files_new",
+            "rsync.files_skipped", "rsync.bytes_skipped",
             "rsync.pruned", "rsync.frames", "delta.batches", "delta.files",
             "delta.bytes_valid", "delta.bytes_padded", "delta.candidates",
             "delta.verified")
@@ -542,7 +956,6 @@ def test_spans_and_counters_are_recorded_on_their_threads(
     for name in COUNTERS:
         assert counts.get(name, 0) > 0, name
     assert "rsync.files_full" not in counts
-    assert "rsync.files_skipped" not in counts
     # the strong check's three are inside it
     assert totals["delta.verify"][1] >= sum(
         totals[f"delta.verify_{k}"][1] for k in ("stage", "launch", "fetch"))

@@ -16,6 +16,10 @@ window at a time (``deltasync.WINDOW``), its op stream crosses the
 channel in parts of at most ``PART_BYTES`` of literals, and the
 destination builds the new file beside the old one and renames it over
 the name on the last part (rsync's temporary-then-rename).
+
+A file whose size and mtime at the destination are the source's is
+left as it is (rsync's quick check, decided by the destination in the
+batch's ``sigs`` round trip): neither side opens it.
 """
 
 from __future__ import annotations
@@ -176,24 +180,56 @@ def _dest_verbs(root: Path, incoming: Optional[dict] = None):
         incoming = {}
 
     def sigs(msg):
-        """The signatures of a batch of files in ONE round trip, built
-        on the device in padded dispatches a batch (deltasync
-        ``build_signatures``), a long file a window at a time."""
+        """A batch of files in ONE round trip: rsync's quick check,
+        then the signatures of the files it did not settle.
+
+        A name costs one ``lstat``. A regular file whose size and mtime
+        are the request's is answered ``same`` and is not opened: its
+        permissions, owner and xattrs are brought to the request's by
+        the calls that change something (``rsync -a`` without ``-c`` or
+        ``-I``; a request without ``size``, an older source's, is never
+        ``same``). The other regular files are signed on the device in
+        padded dispatches a batch (deltasync ``build_signatures``), a
+        long file a window at a time; one the source will send whole
+        (``sign`` false) is only looked at. The directories above a
+        name are resolved once a directory of the call, not once a
+        file: nothing else runs at the destination meanwhile."""
+        from volsync_tpu.movers.rclone.sync import _settle_meta
+
         with span("rsync.sig"):
-            have = []
+            top = root.resolve()
+            parents: dict = {}  # a name's directory -> resolved
+            replies, have = [], []
             for item in msg["files"]:
-                path = _safe_join(root, item["path"])
-                if path.is_file() and not path.is_symlink():
-                    have.append((_FileSource(path, path.stat().st_size),
-                                 item.get("block_len") or None))
+                p = top / item["path"]
+                above = p.parent
+                parent = parents.get(above)
+                if parent is None:
+                    parent = parents[above] = above.resolve()
+                path = os.fspath(_held(top, parent, p.name, item["path"]))
+                try:
+                    st = os.lstat(path)
+                except OSError:
+                    st = None
+                if st is None or not stat_mod.S_ISREG(st.st_mode):
+                    replies.append({"exists": False})
+                elif (item.get("size") == st.st_size
+                        and item.get("mtime_ns") == st.st_mtime_ns):
+                    at = {"mode": st.st_mode & 0o7777, "uid": st.st_uid,
+                          "gid": st.st_gid, "mtime_ns": st.st_mtime_ns}
+                    _settle_meta(path, {**at, **item}, at)
+                    replies.append({"exists": True, "same": True})
                 else:
-                    have.append(None)
-            built = iter(deltasync.build_signatures(
-                [h for h in have if h is not None]))
-            return {"verb": "sigs", "sigs": [
-                {"exists": False} if h is None
-                else {"exists": True, **next(built).to_wire()}
-                for h in have]}
+                    reply = {"exists": True}
+                    replies.append(reply)
+                    if item.get("sign", True):
+                        have.append((reply, _FileSource(path, st.st_size),
+                                     item.get("block_len") or None))
+            built = deltasync.build_signatures(
+                [(src, block_len) for _reply, src, block_len in have])
+            for (reply, _src, _bl), sig in zip(have, built):
+                reply.update(sig.to_wire())
+            return {"verb": "sigs", "sigs": replies}
 
     def apply(msg):
         """One part of a file's op stream; the file appears under its
@@ -617,10 +653,16 @@ def _parts(ops: list, source) -> Iterator[list]:
 
 def _push_files_batch(ch, jobs: list, stats: dict):
     """Planner-driven batch push: price FULL vs DELTA per file
-    (movers.common.plan_protocol -> engine/protoplan), fetch signatures
-    for all delta-planned files in ONE ``sigs`` round trip, run the
-    delta scan for the whole batch through the staged buffers of
+    (movers.common.plan_protocol -> engine/protoplan), put the batch's
+    files to the destination in ONE ``sigs`` round trip, run the delta
+    scan for the files it signed through the staged buffers of
     ``deltasync.scan_ranges``, then apply per file, each in parts.
+
+    The request carries each file's size and wire metadata: a file the
+    destination answers ``same`` (rsync's quick check: its size and
+    mtime there are the source's) is done: not read, scanned or framed.
+    A reply without ``same``, an older destination's, is scanned.
+
     Every completed delta and timed round trip feeds the rsync
     ``SyncStatsBook``, so the planner's next batch prices against what
     this one actually cost."""
@@ -637,24 +679,37 @@ def _push_files_batch(ch, jobs: list, stats: dict):
             "rsync", src.size, candidates=("full", "delta"),
             block_len=block_len)
         plans.append((decision.protocol, block_len))
-    want = [i for i, (proto, _bl) in enumerate(plans) if proto == "delta"]
-    sig_by_idx: dict = {}
-    if want:
-        # NOT timed as a latency sample: the reply embeds the
-        # destination's signature computation (and, first time, its jit
-        # compile), which would poison the rtt EWMA by orders of
-        # magnitude. Small apply acks below are the latency proxy.
-        with span("rsync.sig_wait"):
-            reply = _call(ch, {"verb": "sigs", "files": [
-                {"path": jobs[i][1], "block_len": plans[i][1]}
-                for i in want]})
-        for i, r in zip(want, reply["sigs"]):
-            if r.get("exists"):
-                sig_by_idx[i] = deltasync.FileSignature.from_wire(r)
+    # NOT timed as a latency sample: the reply embeds the destination's
+    # signature computation (and, first time, its jit compile), which
+    # would poison the rtt EWMA by orders of magnitude. Small apply
+    # acks below are the latency proxy.
+    with span("rsync.sig_wait"):
+        reply = _call(ch, {"verb": "sigs", "files": [
+            {"path": rel, "block_len": block_len, "size": src.size,
+             "sign": proto == "delta", **meta}
+            for (_p, rel, _st, meta), src, (proto, block_len)
+            in zip(jobs, sources, plans)]})
+    same = {i for i, r in enumerate(reply["sigs"]) if r.get("same")}
+    sig_by_idx = {i: deltasync.FileSignature.from_wire(r)
+                  for i, r in enumerate(reply["sigs"])
+                  if r.get("exists") and i not in same
+                  and plans[i][0] == "delta"}
     scanned = sorted(sig_by_idx)
     ops_by_idx = dict(zip(scanned, deltasync.scan_ranges(
         [(sources[i], sig_by_idx[i]) for i in scanned]))) if scanned else {}
+
+    def synced(size: int) -> None:
+        count("rsync.files")
+        count("rsync.bytes_synced", size)
+        stats["files"] += 1
+        stats["bytes"] += size
+
     for idx, ((_p, rel, _st, meta), src) in enumerate(zip(jobs, sources)):
+        if idx in same:
+            count("rsync.files_skipped")
+            count("rsync.bytes_skipped", src.size)
+            synced(src.size)
+            continue
         _proto, block_len = plans[idx]
         if idx in ops_by_idx:
             ops = ops_by_idx[idx]
@@ -694,12 +749,9 @@ def _push_files_batch(ch, jobs: list, stats: dict):
             book.observe_link(literal, elapsed)
         else:
             book.observe_rtt(elapsed)
-        count("rsync.files")
-        count("rsync.bytes_synced", src.size)
         count("rsync.literal_bytes", literal)
-        stats["files"] += 1
-        stats["bytes"] += src.size
         stats["literal_bytes"] += literal
+        synced(src.size)
 
 
 # ---------------------------------------------------------------------------
@@ -717,8 +769,16 @@ def _safe_join(root: Path, rel: str) -> Path:
     peer-sent name is never followed out of the root."""
     top = root.resolve()
     p = top / rel
-    p = p.parent.resolve() / p.name
-    if p.name in ("", ".", "..") or (
+    return _held(top, p.parent.resolve(), p.name, rel)
+
+
+def _held(top: Path, parent: Path, name: str, rel: str) -> Path:
+    """``parent / name`` for a ``parent`` already resolved, or the
+    refusal of a name that is none or lies outside ``top``
+    (``_safe_join``'s check; ``sigs`` resolves a directory once for the
+    names it holds)."""
+    p = parent / name
+    if name in ("", ".", "..") or (
             not str(p).startswith(str(top) + os.sep) and p != top):
         raise channel.ChannelError(f"path escapes root: {rel!r}")
     return p
