@@ -23,7 +23,7 @@ from volsync_tpu.engine.chunker import (
     params_from_config,
     stream_chunk_batches,
 )
-from volsync_tpu.obs import off_ring, span
+from volsync_tpu.obs import count, off_ring, span, use_context
 from volsync_tpu.repo import blobid
 from volsync_tpu.repo.repository import (
     BLOB_DATA,
@@ -140,7 +140,8 @@ class TreeBackup:
 
         The coordinating thread's spans come one after another and add
         up to the operation: ``backup.prepare`` (lock, index, snapshots,
-        parent files), ``backup.walk``, ``backup.hash`` (the wall of the
+        parent files; ``repo.load_index`` and ``backup.parent`` close
+        inside it), ``backup.walk``, ``backup.hash`` (the wall of the
         per-file phase), ``backup.tree``, then the repository's
         ``repo.flush`` and ``repo.save_snapshot``.
         """
@@ -159,16 +160,18 @@ class TreeBackup:
 
     def _parent_files(self, parent: Optional[str]) -> tuple:
         """(parent snapshot id, its files by path): the newest snapshot
-        where the caller names none."""
-        snaps = self.repo.list_snapshots()
-        if parent is None and snaps:
-            parent = snaps[-1][0]
-        parent_files = {}
-        if parent:
-            parent_manifest = dict(snaps).get(parent)
-            if parent_manifest:
-                parent_files = _load_parent_files(
-                    self.repo, parent_manifest["tree"])
+        where the caller names none. ``backup.parent`` is the listing
+        and the parent's tree blobs, one read a directory."""
+        with span("backup.parent"):
+            snaps = self.repo.list_snapshots()
+            if parent is None and snaps:
+                parent = snaps[-1][0]
+            parent_files = {}
+            if parent:
+                parent_manifest = dict(snaps).get(parent)
+                if parent_manifest:
+                    parent_files = _load_parent_files(
+                        self.repo, parent_manifest["tree"])
         return parent, parent_files
 
     def _run_locked(self, root: Path, hostname, tags, parent, parent_files):
@@ -179,14 +182,31 @@ class TreeBackup:
         # hashing in walk order, deterministic tree assembly.
         jobs: list[tuple[Path, str, object]] = []
         inode_first: dict = {}  # (st_dev, st_ino) -> rel of first sight
-        with span("backup.walk"):
+        # the walk asks the index once a file that its parent entry
+        # matches (``repo.dedup_query``): totals, no ring event a file
+        with span("backup.walk"), use_context(off_ring()):
             skeleton = self._walk_dir(root, "", parent_files, stats, jobs,
                                       inode_first)
+        # so far only the walk has counted dedup: the parent's content
+        walk_blobs, walk_bytes = stats.blobs_dedup, stats.bytes_dedup
         contents: dict = {}
         with span("backup.hash", files=len(jobs)):
             for job in jobs:
                 rel, resolved = self._hash_file(*job, stats)
                 contents[rel] = resolved
+        # once an operation, and before the tree's blobs join the
+        # repository's tallies: what is new here is data
+        for name, n in (
+                ("backup.files", stats.files),
+                ("backup.files_unchanged", stats.files_unchanged),
+                ("backup.files_changed", len(jobs)),
+                ("backup.bytes_unchanged", walk_bytes),
+                ("backup.bytes_changed",
+                 sum(hashed for _, hashed, _ in contents.values())),
+                ("repo.blobs_new", stats.blobs_new),
+                ("repo.bytes_new", stats.bytes_new),
+                ("repo.blobs_dedup", stats.blobs_dedup - walk_blobs)):
+            count(name, n)
         with span("backup.tree"):
             tree_id = self._assemble_tree(skeleton, contents, stats)
         manifest = {
@@ -312,6 +332,7 @@ class TreeBackup:
                 and prev["mtime_ns"] == st.st_mtime_ns
                 and (not prev["content"]
                      or bool(self.repo.has_blobs(prev["content"]).all()))):
+            stats.files_unchanged += 1
             stats.blobs_dedup += len(prev["content"])
             stats.bytes_dedup += st.st_size
             content = list(prev["content"])
@@ -410,7 +431,8 @@ class TreeBackup:
                 reader_cm = self._open_stream(path)
             with reader_cm as reader:
                 for batch in stream_chunk_batches(reader.read, self.params,
-                                                  hasher=self.hasher):
+                                                  hasher=self.hasher,
+                                                  size_hint=st.st_size):
                     # one batched dedup query + one lock acquisition
                     # per device segment, not per chunk
                     self.repo.add_blobs(

@@ -661,10 +661,18 @@ class _SegmentFill:
     accumulated. ``readinto()`` sources fill the buffer in place; plain
     ``read()`` sources pay one sanctioned ``chunker.ingest`` copy. The
     extra page-bucket slack past the fill window lets the consumer hand
-    the device a pre-padded view with no np.pad copy."""
+    the device a pre-padded view with no np.pad copy.
+
+    ``size_hint`` is what the source said it holds (a file's ``lstat``).
+    A stream that holds exactly that and ends on a full fill window is
+    closed WITH that fill: one read past it finds the end, so its tail
+    is not a segment of its own (a device round trip, and a program of
+    whatever small shape the tail has). A source that holds more than it
+    said goes on as without the hint."""
 
     def __init__(self, reader: Callable[[int], bytes], piece_size: int,
-                 max_size: int, bucket=_buffer_bucket):
+                 max_size: int, bucket=_buffer_bucket,
+                 size_hint: Optional[int] = None):
         self._read, self._readinto = _resolve_reader(reader)
         self._piece = piece_size
         self.head = max_size
@@ -675,6 +683,19 @@ class _SegmentFill:
         self.capacity = max_size + bucket(self.target + max_size)
         self._eof = False
         self._carry: Optional[memoryview] = None  # over-returned piece
+        self._left = size_hint  # of what the source said it holds
+
+    def _at_hinted_end(self) -> bool:
+        """One read past the hinted size: nothing there is the end; a
+        byte there is carried into the next fill."""
+        if self._readinto is not None:
+            one = bytearray(1)
+            ahead = memoryview(one)[:self._readinto(memoryview(one)) or 0]
+        else:
+            ahead = memoryview(self._read(1))
+        if len(ahead):
+            self._carry = ahead
+        return not len(ahead)
 
     def next_segment(self) -> tuple[bytearray, int, bool]:
         """-> (pooled buffer, fill end, eof). Data lives in
@@ -713,6 +734,11 @@ class _SegmentFill:
                             if take < len(p):  # reader over-returned
                                 self._carry = p[take:]
                             fill += take
+            if self._left is not None:
+                self._left -= fill - self.head
+                if (self._left == 0 and fill == limit and not self._eof
+                        and self._carry is None):
+                    self._eof = self._at_hinted_end()
         except BaseException:
             # ownership only transfers to the caller on success — give
             # the slot back to the pool before propagating
@@ -798,7 +824,7 @@ class _SegmentReadahead:
 
 
 def _segment_source(reader, params: GearParams, segment_size: int,
-                    hasher) -> _SegmentFill:
+                    hasher, size_hint: Optional[int] = None) -> _SegmentFill:
     """The fill of a stream over ``hasher``. A hasher that shards a
     segment over several chips says how large a segment it wants
     (``stream_segment_size``: every chip gets what one chip is
@@ -809,7 +835,8 @@ def _segment_source(reader, params: GearParams, segment_size: int,
     if scale is not None:
         segment_size = scale(segment_size)
     return _SegmentFill(reader, segment_size, params.max_size,
-                        getattr(hasher, "buffer_bucket", _buffer_bucket))
+                        getattr(hasher, "buffer_bucket", _buffer_bucket),
+                        size_hint)
 
 
 def stream_chunk_batches(reader: Callable[[int], bytes],
@@ -817,6 +844,7 @@ def stream_chunk_batches(reader: Callable[[int], bytes],
                          segment_size: int = 32 * 1024 * 1024,
                          hasher: Optional[DeviceChunkHasher] = None,
                          readahead: Optional[int] = None,
+                         size_hint: Optional[int] = None,
                          ) -> Iterator[list[tuple[memoryview, str]]]:
     """Chunk an arbitrary-length stream -> per-segment batches of
     (chunk payload, sha256 hex).
@@ -857,11 +885,15 @@ def stream_chunk_batches(reader: Callable[[int], bytes],
     ahead on a producer thread so host reads overlap device work — the
     read-ahead stage of the backup pipeline. Chunk boundaries and
     digests are identical either way.
+
+    ``size_hint`` (a file's size as the walk saw it) lets a stream that
+    ends exactly on a segment's fill end with that segment
+    (``_SegmentFill``); the chunks are the same with or without it.
     """
     hasher = hasher or DeviceChunkHasher(params)
     if readahead is None:
         readahead = envflags.readahead_segments()
-    src = _segment_source(reader, params, segment_size, hasher)
+    src = _segment_source(reader, params, segment_size, hasher, size_hint)
     bucket = src.bucket
     ra: Optional[_SegmentReadahead] = None
     if readahead > 0:

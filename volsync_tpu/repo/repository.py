@@ -56,6 +56,7 @@ from volsync_tpu.metrics import GLOBAL as GLOBAL_METRICS
 from volsync_tpu.objstore.store import NoSuchKey, ObjectStore
 from volsync_tpu.obs import (
     carry_context,
+    count,
     off_ring,
     record_copy,
     record_trigger,
@@ -254,6 +255,7 @@ class IndexEntry:
 @dataclass
 class BackupStats:
     files: int = 0
+    files_unchanged: int = 0  # content taken from the parent, unread
     bytes_scanned: int = 0
     blobs_new: int = 0
     bytes_new: int = 0       # plaintext bytes newly stored
@@ -779,7 +781,7 @@ class Repository:
         refreshes the pending-delete pack set (the dedup exclusion) and
         the fencing generation.
         """
-        with self._lock:  # lint: ignore[VL101] — reviewed: holding
+        with span("repo.load_index"), self._lock:  # lint: ignore[VL101] — reviewed: holding
             # repo.state across the index GETs is what makes the
             # swap + in-flight re-insert atomic w.r.t. a concurrent
             # local writer; pool workers never take this lock.
@@ -790,8 +792,12 @@ class Repository:
                 # (retryable= is checked first) — store weather is the
                 # ResilientStore wrap's budget, not ours (VL602).
                 classify_fn=lambda exc: False)
-            fresh, pending = reload_policy.call(self._read_index_snapshot)
+            fresh, pending, objects = reload_policy.call(
+                self._read_index_snapshot)
             self._index = fresh
+            count("repo.index_loads")
+            count("repo.index_objects", objects)
+            count("repo.index_entries", len(fresh))
             self._pending_packs = pending
             GLOBAL_METRICS.repo_pending_delete_packs.set(len(pending))
             self.generation = max(self.generation,
@@ -822,10 +828,11 @@ class Repository:
     def _decode_index_delta(self, raw: bytes) -> dict:
         return json.loads(self._zd.decompress(self.box.open(raw)))
 
-    def _read_index_snapshot(self) -> tuple[ShardedBlobIndex, set]:
+    def _read_index_snapshot(self) -> tuple[ShardedBlobIndex, set, int]:
         """One full pass over ``index/`` + ``pending-delete/`` into a
-        fresh index (load_index holds repo.state and swaps it in).
-        Raises _IndexReloadRace when the pass must restart."""
+        fresh index (load_index holds repo.state and swaps it in), with
+        the pending-delete packs and the index objects it read. Raises
+        _IndexReloadRace when the pass must restart."""
         from volsync_tpu.repo.compress import CompressError
 
         fresh = ShardedBlobIndex()
@@ -838,7 +845,8 @@ class Repository:
             pending.update(man.get("packs", ()))
         # Streaming: one index delta decoded at a time; entries land
         # in the flat compact index, never in per-entry objects.
-        for key in list(self.store.list("index/")):
+        keys = list(self.store.list("index/"))
+        for key in keys:
             try:
                 raw = self.store.get(key)
             except NoSuchKey:
@@ -867,7 +875,7 @@ class Repository:
                     fresh.insert(e["id"], pack_id, e["type"],
                                  e["offset"], e["length"],
                                  e["raw_length"], replace=replace)
-        return fresh, pending
+        return fresh, pending, len(keys)
 
     def _load_pending_manifests(self) -> list[tuple[str, dict]]:
         """``[(key, manifest)]`` under ``pending-delete/``, skipping
@@ -1533,10 +1541,12 @@ class Repository:
 
     def list_snapshots(self) -> list[tuple[str, dict]]:
         out = []
-        for key in self.store.list("snapshots/"):
-            snap_id = key.split("/", 1)[1]
-            manifest = json.loads(self.box.open(self.store.get(key)))
-            out.append((snap_id, manifest))
+        with span("repo.list_snapshots"):
+            for key in self.store.list("snapshots/"):
+                snap_id = key.split("/", 1)[1]
+                manifest = json.loads(self.box.open(self.store.get(key)))
+                out.append((snap_id, manifest))
+        count("repo.snapshots_listed", len(out))
         # Chronological, not lexicographic: manifests may carry non-UTC
         # offsets, where the ISO strings don't sort by instant.
         out.sort(key=lambda kv: _parse_time(kv[1]["time"]))
@@ -1575,10 +1585,12 @@ class Repository:
         """Apply a restic-style retain policy; returns deleted snapshot ids
         (restic ``forget`` — the FORGET_OPTIONS the reference builds in
         controllers/mover/restic/mover.go:440-471)."""
-        with self.lock(exclusive=True):
-            return self._forget_locked(
+        with span("repo.forget"), self.lock(exclusive=True):
+            doomed = self._forget_locked(
                 last=last, hourly=hourly, daily=daily, weekly=weekly,
                 monthly=monthly, yearly=yearly, within=within)
+        count("repo.forget_removed", len(doomed))
+        return doomed
 
     def _forget_locked(self, *, last=None, hourly=None, daily=None,
                        weekly=None, monthly=None, yearly=None,
@@ -1810,7 +1822,7 @@ class Repository:
         # manifest + grace + live-lock sweep gate protects concurrent
         # backups (grace 0 falls back to a genuinely exclusive lock).
         # lint: ignore[VL101]
-        with self.lock(mode=mode), self._lock:
+        with span("repo.prune"), self.lock(mode=mode), self._lock:
             return self._prune_locked(grace)
 
     def _prune_locked(self, grace: float) -> dict:
