@@ -183,6 +183,43 @@ def test_rs_encode_4_plus_2(tpu_arms, one_chip):
     assert c.memory_analysis().output_size_in_bytes == 2 * 1024 * 4096
 
 
+def test_delta_match_rows_moves_no_element_an_offset(no_compile_cache,
+                                                     one_chip):
+    """The rsync source's every-offset search at the mover's 64 MiB
+    window and 8 KiB blocks (``rsync-1g.push``): it compiles, loops on
+    the device over groups of rows by two sorts, and no scatter or
+    gather in it moves an element an offset (only whole rows of bytes,
+    the rows' bases and the hits come by a gather): what made PR 44's
+    program cost 75 ms whatever it was given."""
+    import re
+
+    import jax.numpy as jnp
+
+    from volsync_tpu.engine.deltasync import _Geometry
+    from volsync_tpu.ops.delta import delta_match_rows
+
+    geo = _Geometry.of(8192)
+    i32 = _sds((), jnp.int32, one_chip)
+    text = delta_match_rows.lower(
+        _sds((geo.window,), jnp.uint8, one_chip),
+        _sds((geo.sig_cap(1),), jnp.uint32, one_chip), i32,
+        _sds((geo.rows,), jnp.int32, one_chip),
+        _sds((geo.rows,), jnp.int32, one_chip), i32, i32, i32,
+        window=8192, group_rows=geo.group_rows,
+        max_candidates=geo.cand_cap, capacity=geo.search_cap,
+    ).compile().as_text()
+    assert " while(" in text and len(re.findall(r" sort\(", text)) == 2
+    assert " scatter(" not in text
+    gathered = [(dtype, [int(d) for d in dims.split(",") if d])
+                for dtype, dims in re.findall(
+                    r"= (\w+)\[([\d,]*)\]\S* gather\(", text)]
+    assert gathered
+    for dtype, dims in gathered:
+        elements = int(np.prod(dims))
+        assert (dtype == "u8" and dims == [geo.group_rows, 1024]) \
+            or elements <= max(geo.group_rows, geo.cand_cap), (dtype, dims)
+
+
 def test_mesh_fused_fn_four_devices(tpu_arms, topo):
     """parallel/sharded_chunker's fused program on a 4-device ``seq``
     mesh of the described chips, 4 x 8 MiB: the per-shard kernels plus

@@ -12,10 +12,13 @@ kernels reproduce the per-file verified sets.
 import os
 import pathlib
 
+import numpy as np
 import pytest
 
 from volsync_tpu.engine import deltasync
 from volsync_tpu.engine.syncstats import reset_books
+from volsync_tpu.obs import counter_totals
+from volsync_tpu.ops import delta
 
 
 @pytest.fixture(autouse=True)
@@ -109,11 +112,15 @@ def test_mixed_block_lengths_group_correctly(rng):
         assert deltasync.apply_delta(ops, old, sig.block_len) == new
 
 
-def test_batch_uses_fewer_dispatches_than_files(rng, monkeypatch):
+@pytest.mark.parametrize("window", [64 << 20, 1 << 20])
+def test_batch_uses_fewer_dispatches_than_files(rng, monkeypatch, window):
     """N files, ONE aligned probe, ONE search and ONE verify dispatch
     a staged buffer, not one per file (each file here has an insertion:
     the selection is followed through the searched rows of the one
-    buffer, none is staged again)."""
+    buffer, none is staged again). At the small window the open rows
+    pass one group of the search's loop (256 rows there too): a buffer
+    is still searched in one dispatch."""
+    monkeypatch.setattr(deltasync, "WINDOW", window)
     calls = {"probe": 0, "match": 0, "verify": 0}
     real_probe = deltasync.delta_sig_flat
     real_match = deltasync.delta_match_rows
@@ -139,16 +146,163 @@ def test_batch_uses_fewer_dispatches_than_files(rng, monkeypatch):
         pairs.append((base, bytes(mutated)))
     items = _items(pairs)
     assert len({sig.block_len for _, sig in items}) == 1
+    geo = deltasync._Geometry.of(items[0][1].block_len)
     monkeypatch.setattr(deltasync, "delta_sig_flat", spy_probe)
     monkeypatch.setattr(deltasync, "delta_match_rows", spy_match)
     monkeypatch.setattr(deltasync, "delta_md5_flat", spy_verify)
+    before = counter_totals()
     batch = deltasync.delta_scan_batch(items)
-    assert calls["match"] >= 1 and calls["verify"] >= 1
+    now = counter_totals()
+    listed = now["delta.search_rows"] - before.get("delta.search_rows", 0)
+    assert listed > geo.group_rows, "the case no longer passes one group"
     assert calls["probe"] == 1
-    assert calls["match"] < len(items)
-    assert calls["verify"] < len(items)
+    assert calls["match"] == 1
+    assert 1 <= calls["verify"] < len(items)
     for (old, new), (src, sig), ops in zip(pairs, items, batch):
         assert ops == deltasync.compute_delta(src, sig)
+
+
+# -- the search program against the every-offset oracle ----------------------
+
+_BLOCK, _GROUP, _CAP = 2048, 8, 64
+
+
+def _search_case(rng, zeros: bool = False):
+    """One 64 KiB buffer (64 rows) and the sorted weak table of a
+    destination whose blocks lie in it off the alignment (from byte 37,
+    so nearly every row holds a match), and ``match_offsets``' answer.
+    ``zeros``: a zero-filled half against a table that holds the zero
+    block, so every offset of it is a hit."""
+    data = bytearray(rng.bytes(1 << 16))
+    if zeros:
+        data[1 << 15:] = bytes(1 << 15)
+    data = np.frombuffer(bytes(data), np.uint8)
+    blocks = [data[at: at + _BLOCK]
+              for at in range(37, len(data) - _BLOCK, 1500)]
+    table = np.sort(np.array(
+        [deltasync.weak_checksum_host(b) for b in blocks]
+        + ([deltasync.weak_checksum_host(bytes(_BLOCK))] if zeros else []),
+        np.uint32))
+    cand, n = delta.match_offsets(data, table, window=_BLOCK,
+                                  max_candidates=1 << 16)
+    oracle = np.asarray(cand)[: int(n)]
+    assert len(oracle) >= len(blocks)
+    return data, table, oracle
+
+
+def _search(data, table, rows, until):
+    """The engine's loop over ``delta_match_rows`` at a group of
+    ``_GROUP`` rows: (offsets, their weak checksums, groups run, calls)."""
+    R = len(data) // 1024
+    sw = np.full(64, 0xFFFFFFFF, np.uint32)
+    sw[: len(table)] = table
+    take, take_until = np.zeros(R, np.int32), np.zeros(R, np.int32)
+    take[: len(rows)] = rows
+    take_until[: len(rows)] = until
+    groups = -(-len(rows) // _GROUP)
+    group = lo = ran_all = calls = 0
+    offs, weaks = [], []
+    while group < groups:
+        cand, weak, state = delta.delta_match_rows(
+            data, sw, np.int32(len(table)), take, take_until,
+            np.int32(groups), np.int32(group), np.int32(lo),
+            window=_BLOCK, group_rows=_GROUP, max_candidates=_CAP,
+            capacity=2 * _CAP)
+        n, nxt, ran = np.asarray(state).tolist()
+        assert nxt > group or (nxt == group and n == _CAP)
+        cand = np.asarray(cand)
+        assert (cand[n:] == len(data)).all()
+        assert (np.diff(cand[:n]) > 0).all()
+        offs += cand[:n].tolist()
+        weaks += np.asarray(weak)[:n].tolist()
+        group, ran_all, calls = nxt, ran_all + ran, calls + 1
+        if group < groups:
+            lo = offs[-1] + 1
+    return offs, weaks, ran_all, calls
+
+
+@pytest.mark.parametrize("listed", [0, 1, _GROUP - 1, _GROUP, _GROUP + 1,
+                                    3 * _GROUP + 2, 64])
+def test_search_finds_what_every_offset_scan_finds(rng, listed):
+    """Rows listed: none, one, a group less one, a group, a group and
+    one, several groups, every row of the buffer. The candidates are the
+    oracle's offsets inside the listed rows, ascending, with their
+    checksums, and the loop runs the groups that hold a row."""
+    data, table, oracle = _search_case(rng)
+    rows = np.sort(rng.choice(64, listed, replace=False)).astype(np.int32)
+    last = len(data) - _BLOCK
+    offs, weaks, ran, calls = _search(data, table, rows,
+                                      [last + 1] * listed)
+    want = oracle[np.isin(oracle // 1024, rows)]
+    assert offs == want.tolist()
+    assert listed == 0 or len(want) > 0
+    assert weaks == deltasync._weak_at_offsets(
+        data, np.asarray(offs, np.int64), _BLOCK).tolist()
+    assert ran == -(-listed // _GROUP)
+    assert calls == (1 if listed else 0)
+
+
+@pytest.mark.parametrize("until", ["file_end", "padding", "mid_row"])
+def test_search_masks_by_row_until(rng, until):
+    """A window that would run past its file's end, and the padding
+    between slots, are not searched: ``row_until`` cuts each listed row
+    at its own file's last window start."""
+    data, table, oracle = _search_case(rng)
+    rows = np.arange(64, dtype=np.int32)
+    if until == "file_end":  # two files of 32 rows: each ends its rows
+        ends = np.where(rows < 32, 32 * 1024, 64 * 1024) - _BLOCK + 1
+    elif until == "padding":  # every other eight rows lie between slots
+        ends = np.where((rows // 8) % 2 == 0, 64 * 1024 - _BLOCK + 1, 0)
+    else:  # the file ends inside row 40
+        ends = np.full(64, 40 * 1024 + 300, np.int64)
+    offs, _weaks, _ran, _calls = _search(data, table, rows, ends)
+    want = oracle[oracle < ends[oracle // 1024]]
+    assert 0 < len(want) < len(oracle)
+    assert offs == want.tolist()
+
+
+@pytest.mark.parametrize("listed", [64, 20])
+def test_search_overflow_goes_on_from_lo(rng, listed):
+    """A run of one repeated block (a zero-filled half whose block the
+    table holds): every offset of it is a candidate, more than a call
+    holds. Each call takes the next ones from ``lo`` on, from the group
+    it stopped at, and together they are the oracle's."""
+    data, table, oracle = _search_case(rng, zeros=True)
+    rows = np.arange(64 - listed, 64, dtype=np.int32)
+    last = len(data) - _BLOCK
+    offs, _weaks, ran, calls = _search(data, table, rows,
+                                       [last + 1] * listed)
+    want = oracle[np.isin(oracle // 1024, rows)]
+    assert len(want) > 16 * 1024
+    assert offs == want.tolist()
+    assert calls > len(want) // (2 * _CAP)
+    assert ran >= calls
+
+
+@pytest.mark.parametrize("open_blocks", [0, 1, 3, 16])
+def test_search_counters_follow_the_open_rows(rng, monkeypatch, open_blocks):
+    """A buffer with k open blocks counts the rows the engine listed
+    (a block's rows each, here 4), and the rows the device looked up
+    are whole groups: never under the rows listed."""
+    monkeypatch.setattr(deltasync, "WINDOW", 1 << 16)
+    old = rng.bytes(1 << 16)
+    new = bytearray(old)
+    for t in range(open_blocks):  # not the last block: its run ends early
+        new[(3 * t) % 15 * 4096 + 5: (3 * t) % 15 * 4096 + 9] = b"\0\1\2\3"
+    opened = len({(3 * t) % 15 for t in range(open_blocks)})
+    sig = deltasync.build_file_signature(old, 4096)
+    before = counter_totals()
+    ops = deltasync.delta_scan_batch([(bytes(new), sig)])[0]
+    now = counter_totals()
+    assert ops == deltasync.compute_delta(bytes(new), sig)
+    listed = now.get("delta.search_rows", 0) \
+        - before.get("delta.search_rows", 0)
+    ran = now.get("delta.search_rows_run", 0) \
+        - before.get("delta.search_rows_run", 0)
+    geo = deltasync._Geometry.of(4096)
+    assert listed == 4 * opened
+    assert ran >= listed
+    assert ran == (geo.group_rows if opened else 0)
 
 
 def test_serial_kernels_not_called_by_batch(rng, monkeypatch):
@@ -191,12 +345,16 @@ def _tree_bytes(root: pathlib.Path) -> dict:
     return out
 
 
-def test_bidirectional_sync_converges_with_delta(tmp_path, rng):
+def test_bidirectional_sync_converges_with_delta(tmp_path, rng, monkeypatch):
     """Two trees, pushed A->B then (after divergent edits) B->A: both
-    directions run the planner-batched DELTA path and the trees end
-    byte-identical."""
+    directions run the DELTA path and the trees end byte-identical.
+    The protocol is pinned as the rsync cell's ``mover_env`` pins it:
+    under several test workers the planner prices FULL from the timings
+    it takes itself, and this test is about the delta path."""
     from volsync_tpu.engine.syncstats import book_for
     from volsync_tpu.movers.rsync import entry
+
+    monkeypatch.setenv("VOLSYNC_SYNC_PROTO", "delta")
 
     a = tmp_path / "a"
     b = tmp_path / "b"

@@ -17,8 +17,8 @@ Block size follows rsync's heuristic (~sqrt(file size), bounded).
 Every device program runs on ONE staged buffer of ``WINDOW`` bytes: a
 batch of files laid into it at slot-aligned offsets, or one window of a
 file longer than that (``_pack``). Signature capacity, candidate
-capacity and the rows of a search are functions of the block length
-alone, so a tree of any sizes meets three programs a block length
+capacities and the rows of a search's group are functions of the block
+length alone, so a tree of any sizes meets three programs a block length
 (``delta_sig_flat``, ``delta_match_rows``, ``delta_md5_flat``), and a
 file of any length is signed and scanned a window at a time against its
 whole signature.
@@ -29,8 +29,9 @@ signs its own buffer at the blocks' own alignment (``delta_sig_flat``,
 the destination's program) and looks each block up in the file's
 signature on the host; only where an aligned block fails is every
 offset searched (``delta_match_rows`` over the rows between it and the
-next aligned block that holds). The selection's way through the piece
-is then followed on the host from what the buffer knows
+next aligned block that holds: all of a buffer's open rows in one
+dispatch, whose cost follows their number). The selection's way through
+the piece is then followed on the host from what the buffer knows
 (``_walk_piece``): over the aligned blocks that held, and from match to
 match inside the searched runs, at whatever alignment. So data that an
 insertion moved off the blocks' alignment costs the searches of its
@@ -187,16 +188,27 @@ class _Geometry:
 
     @property
     def cand_cap(self) -> int:
-        # candidates of one search: matches at other alignments than
-        # the blocks' own, and the weak checksum's false hits
+        # candidates of one strong check, and of one group of a search:
+        # matches at other alignments than the blocks' own, and the
+        # weak checksum's false hits
         return max(64, self.blocks // 8)
 
     @property
-    def search_rows(self) -> int:
-        # rows of 1024 offsets one search takes: a thirty-second of the
-        # buffer, all of a small one
-        rows = self.window // 1024
-        return max(rows // 32, min(rows, 64))
+    def rows(self) -> int:
+        return self.window // 1024
+
+    @property
+    def group_rows(self) -> int:
+        # rows of 1024 offsets the search's loop takes at a time: what
+        # a listed row more can cost (a sort of this many rows' offsets
+        # with the table), all of a small buffer
+        return min(self.rows, 256)
+
+    @property
+    def search_cap(self) -> int:
+        # candidates of one search: a match at every block of the
+        # buffer (data an insertion moved), and a group's beside them
+        return max(self.blocks, self.cand_cap) + self.cand_cap
 
     def sig_cap(self, n: int) -> int:
         cap = self.blocks
@@ -578,7 +590,8 @@ def _walk_piece(off: int, n: int, B: int, runs: list, hits: list,
 def _search_rows(buffer, dev, items, geo: _Geometry, keys: dict,
                  rows: list, until: list) -> list[list]:
     """Every offset of the listed rows of the staged buffer against the
-    buffer's signatures, ``search_rows`` a dispatch: a piece's verified
+    buffer's signatures, all of them in one dispatch (another only
+    where the candidates pass what one holds): a piece's verified
     (source offset, destination block) pairs, ascending."""
     import jax
 
@@ -586,6 +599,7 @@ def _search_rows(buffer, dev, items, geo: _Geometry, keys: dict,
     found: list[list] = [[] for _ in buffer]
     if not rows:
         return found
+    count("delta.search_rows", len(rows))
     with span("delta.stage"):
         owners = sorted({p[0] for p in buffer})
         table = np.sort(np.concatenate(
@@ -596,49 +610,50 @@ def _search_rows(buffer, dev, items, geo: _Geometry, keys: dict,
         owner = np.zeros(geo.window // geo.slot, np.int32)
         for at, (_item, _off, n, base) in enumerate(buffer):
             owner[base // geo.slot: -(-(base + n) // geo.slot)] = at
-    G = geo.search_rows
-    for first in range(0, len(rows), G):
-        with span("delta.stage"):
-            take = np.full(G, rows[first], np.int32)
-            take_until = np.zeros(G, np.int32)
-            part = rows[first: first + G]
-            take[: len(part)] = part
-            take_until[: len(part)] = until[first: first + G]
-            take_dev = jax.device_put(take)
-            until_dev = jax.device_put(take_until)
+        take = np.zeros(geo.rows, np.int32)
+        take_until = np.zeros(geo.rows, np.int32)
+        take[: len(rows)] = rows
+        take_until[: len(rows)] = until
+        take_dev = jax.device_put(take)
+        until_dev = jax.device_put(take_until)
+    groups = -(-len(rows) // geo.group_rows)
+    group = lo = 0
+    while group < groups:
+        with span("delta.launch"):
+            # every run of the program reads the staged window
             record_copy("delta.search", geo.window)
-        lo = 0
-        while True:
-            with span("delta.launch"):
-                out = delta_match_rows(  # lint: ignore[VL502] one dispatch a round of rows
-                    dev, sw_dev, np.int32(len(table)), take_dev, until_dev,
-                    np.int32(lo), window=B, max_candidates=geo.cand_cap)
-            with span("delta.fetch"):
-                total = int(out[2])
-                n = min(total, geo.cand_cap)
-                cand = np.asarray(out[0])[:n]
-                weak_at = np.asarray(out[1])[:n]
-            count("delta.candidates", n)
-            if n:
-                with span("delta.verify"):
-                    strongs = _verify(dev, cand, geo)
-                with span("delta.select"):
-                    held = 0
-                    at = owner[cand // geo.slot]
-                    for c, w, strong, a in zip(cand.tolist(),
-                                               weak_at.tolist(), strongs,
-                                               at.tolist()):
-                        item, off, _n, base = buffer[a]
-                        block = keys[item].get((w, strong))
-                        if block is not None:
-                            found[a].append((c - base + off, block))
-                            held += 1
-                    count("delta.verified", held)
-            if total <= geo.cand_cap:
-                break
-            # more candidates than one strong check holds (a run of one
-            # repeated block inside an open run): again, from past the
-            # last one taken
+            out = delta_match_rows(  # lint: ignore[VL502] one dispatch a buffer, more where the candidates overflow
+                dev, sw_dev, np.int32(len(table)), take_dev, until_dev,
+                np.int32(groups), np.int32(group), np.int32(lo), window=B,
+                group_rows=geo.group_rows, max_candidates=geo.cand_cap,
+                capacity=geo.search_cap)
+        with span("delta.fetch"):
+            n, group, ran = np.asarray(out[2]).tolist()  # lint: ignore[VL501] host-result contract: a search's counts
+            cand = np.asarray(out[0])[:n]  # lint: ignore[VL501] host-result contract: a search's candidates
+            weak_at = np.asarray(out[1])[:n]  # lint: ignore[VL501] host-result contract: a search's candidates
+        count("delta.search_rows_run", ran * geo.group_rows)
+        count("delta.candidates", n)
+        for first in range(0, n, geo.cand_cap):
+            part = cand[first: first + geo.cand_cap]
+            with span("delta.verify"):
+                strongs = _verify(dev, part, geo)
+            with span("delta.select"):
+                held = 0
+                at = owner[part // geo.slot]
+                for c, w, strong, a in zip(
+                        part.tolist(),
+                        weak_at[first: first + geo.cand_cap].tolist(),
+                        strongs, at.tolist()):
+                    item, off, _n, base = buffer[a]
+                    block = keys[item].get((w, strong))
+                    if block is not None:
+                        found[a].append((c - base + off, block))
+                        held += 1
+                count("delta.verified", held)
+        if group < groups:
+            # more candidates than one search holds (a run of one
+            # repeated block inside an open run): again, from the group
+            # it stopped at and past the last one taken
             count("delta.overflow_retries")
             lo = int(cand[-1]) + 1
     return found
