@@ -2,10 +2,11 @@
 the open + seek + write it guards.
 
 ``restore.write`` (engine/restorepipe.py ``flush_batch``) is, a blob:
-find the blob's holes, then at each placement open the target, seek,
-and write. This script times those pieces apart on the host it runs
-on, behind the real open-seek of ``_write_at``, 256 MiB of blobs a
-size, seconds a GiB:
+find the blob's holes, then write at each placement; up to PR 45 a
+placement opened the target and sought (since PR 46 one ``pwrite`` on
+a held descriptor: ``--files`` below). This script times those pieces
+apart on the host it runs on, behind that open + seek, 256 MiB of
+blobs a size, seconds a GiB:
 
 - ``historical``: the writer up to PR 32, kept here (and as the oracle
   of tests/test_zerocopy.py): ``np.flatnonzero`` over the blob's bytes,
@@ -22,8 +23,23 @@ Blobs are random (no hole, as the benchmark's volumes) at 512 KiB, 1,
 2 and 8 MiB, and one 2 MiB shape with holes (every fourth 64 KiB zero).
 Runs on the host alone, no JAX; not part of the test suite.
 
+``--files N`` times something else and nothing of the above: what the
+pipeline's system calls cost a restored file. N one-blob files
+(log-uniform 1 KiB-1 MiB, 50 a directory, as ``restic-dest-10g``'s
+small files) are put down into fresh directories twice over: by the
+call sequence of engine/restorepipe.py up to PR 45, kept here
+(``files_before``: four ``stat``s that find nothing, a truncating open
+to claim, a second open + seek + write, a third open + truncate,
+``chown`` / ``chmod`` / ``utime`` by path; ~25 calls), and by the
+sequence since PR 46 (``files_after``: an exclusive create, ``pwrite``,
+``fchown`` / ``fchmod`` / ``futimens``, close; 6 calls), the same bytes
+both ways; and one ``lstat`` a file beside them, as a host's price of
+one call. Milliseconds a file.
+
 Usage: python scripts/profile_restore_write.py [--mib 256] [--reps 3]
            [--dir DIR] [--out chiprun_out/profile_restore_write.json]
+       python scripts/profile_restore_write.py --files 1000 [--reps 5]
+           [--dir DIR] [--out ...]
 """
 from __future__ import annotations
 
@@ -34,6 +50,7 @@ import statistics
 import sys
 import tempfile
 import time
+from pathlib import Path
 
 import numpy as np
 
@@ -123,7 +140,8 @@ def make_blobs(shape: str, size: int, total: int, seed: int) -> list:
 
 def time_writer(write, blobs: list, target: str) -> float:
     """One file claimed, then every blob put down at its offset behind
-    an open + seek of its own, as ``_write_at`` does; seconds."""
+    an open + seek of its own, as the pipeline did up to PR 45;
+    seconds."""
     with open(target, "wb"):
         pass
     offset = 0
@@ -145,8 +163,111 @@ def time_scan(scan, blobs: list) -> float:
     return time.perf_counter() - t0
 
 
+def file_sizes(n: int, seed: int = 2) -> list:
+    rng = np.random.RandomState(seed)
+    return np.exp(rng.uniform(np.log(1 << 10), np.log(1 << 20),
+                              n)).astype(np.int64).tolist()
+
+
+def file_paths(root: str, n: int) -> list:
+    """N targets, 50 a fresh directory under ``root``."""
+    paths = []
+    for i in range(n):
+        if i % 50 == 0:
+            os.mkdir(os.path.join(root, f"d{i // 50:03d}"))
+        paths.append(Path(root, f"d{i // 50:03d}", f"f{i:05d}.bin"))
+    return paths
+
+
+def files_before(paths: list, sizes: list, buf, mtime_ns: int) -> None:
+    """A one-blob file into an empty directory as the pipeline put it
+    down up to PR 45: ``_plan`` (``_skip_unchanged``, ``_clear_target``,
+    the claim), ``_write_at``, ``_finish_file`` + ``_finalize_file``."""
+    for target, size in zip(paths, sizes):
+        if target.is_file():  # _skip_unchanged: ENOENT
+            raise RuntimeError("the directory was to be empty")
+        if target.is_symlink() or target.is_dir():  # _clear_target
+            raise RuntimeError("the directory was to be empty")
+        elif target.exists():
+            raise RuntimeError("the directory was to be empty")
+        with open(target, "wb"):  # the claim
+            pass
+        with open(target, "r+b") as f:  # _write_at
+            f.seek(0)
+            f.write(buf[:size])
+        with open(target, "r+b") as f:  # _finish_file
+            f.truncate(size)
+        os.chown(target, 0, 0, follow_symlinks=False)  # _finalize_file
+        os.chmod(target, 0o644)
+        os.utime(target, ns=(mtime_ns, mtime_ns))
+
+
+def files_after(paths: list, sizes: list, buf, mtime_ns: int) -> None:
+    """The same file since PR 46: created at its first write, written
+    and stamped through that descriptor."""
+    flags = os.O_WRONLY | os.O_CREAT | os.O_EXCL | os.O_CLOEXEC
+    for target, size in zip(paths, sizes):
+        fd = os.open(target, flags, 0o600)
+        try:
+            data, at = buf[:size], 0
+            while len(data):
+                n = os.pwrite(fd, data, at)
+                data, at = data[n:], at + n
+            os.chown(fd, 0, 0)
+            os.chmod(fd, 0o644)
+            os.utime(fd, ns=(mtime_ns, mtime_ns))
+        finally:
+            os.close(fd)
+
+
+def profile_files(args) -> list:
+    """Two lines: the cell's sizes, then one byte a file (the calls
+    with next to nothing between them)."""
+    buf = memoryview(np.random.RandomState(1).bytes(1 << 20))
+    lines = []
+    for payload, sizes in (("1KiB-1MiB", file_sizes(args.files)),
+                           ("1B", [1] * args.files)):
+        samples = {"before": [], "after": [], "lstat": []}
+        for rep in range(args.reps):  # interleaved, as the blob timings
+            for name, put in (("before", files_before),
+                              ("after", files_after)):
+                with tempfile.TemporaryDirectory(dir=args.dir) as work:
+                    paths = file_paths(work, args.files)
+                    t0 = time.perf_counter()
+                    put(paths, sizes, buf, 1_600_000_000 * 10**9 + rep)
+                    samples[name].append(time.perf_counter() - t0)
+                    if name == "after":
+                        t0 = time.perf_counter()
+                        for target in paths:
+                            os.lstat(target)
+                        samples["lstat"].append(time.perf_counter() - t0)
+        line = {"files": args.files, "payload": payload,
+                "bytes": sum(sizes), "reps": args.reps, "unit": "ms/file",
+                "calls_before": 25, "calls_after": 6}
+        for name, vals in samples.items():
+            line[name] = round(1e3 * statistics.median(vals) / args.files, 5)
+            line[name + "_min"] = round(1e3 * min(vals) / args.files, 5)
+        # what each of the 19 calls that went cost, beside a path's lstat
+        line["saved_us_per_call"] = round(
+            1e3 * (line["before"] - line["after"]) / 19, 2)
+        print(json.dumps(line), flush=True)
+        lines.append(line)
+    return lines
+
+
+def write_out(path, lines: list) -> None:
+    if path:
+        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+        with open(path, "w") as fh:
+            for line in lines:
+                fh.write(json.dumps(line) + "\n")
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--files", type=int, default=0,
+                    help="time the calls of N small restored files, "
+                         "before PR 46 against since, and nothing else")
     ap.add_argument("--mib", type=int, default=256,
                     help="MiB of blobs a size")
     ap.add_argument("--reps", type=int, default=3)
@@ -155,6 +276,9 @@ def main() -> int:
                          "directory under the temporary directory)")
     ap.add_argument("--out", default=None, help="also write the lines here")
     args = ap.parse_args()
+    if args.files:
+        write_out(args.out, profile_files(args))
+        return 0
     total = args.mib << 20
     lines = []
     with tempfile.TemporaryDirectory(dir=args.dir) as work:
@@ -179,11 +303,7 @@ def main() -> int:
                 line[name + "_min"] = round(min(vals), 4)
             print(json.dumps(line), flush=True)
             lines.append(line)
-    if args.out:
-        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
-        with open(args.out, "w") as fh:
-            for line in lines:
-                fh.write(json.dumps(line) + "\n")
+    write_out(args.out, lines)
     return 0
 
 

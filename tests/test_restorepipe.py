@@ -217,6 +217,253 @@ def test_skip_unchanged_and_delete_extra(tmp_path):
     _assert_trees_identical(src, dst)
 
 
+# -- one descriptor a file (PR 46) --------------------------------------------
+
+def _flat_corpus(root: Path) -> Path:
+    """A small tree for the destination-state cases: plain files, a
+    file ending in zero pages, an empty one, a holed one, a
+    duplicate, a subdirectory."""
+    rng = np.random.RandomState(23)
+    src = root / "src"
+    src.mkdir()
+    (src / "a.bin").write_bytes(rng.bytes(150_000))
+    (src / "dup.bin").write_bytes((src / "a.bin").read_bytes())
+    (src / "empty").write_bytes(b"")
+    (src / "zero_tail.bin").write_bytes(rng.bytes(20_000) + bytes(90_000))
+    holed = bytearray(200_000)
+    holed[100_000:100_300] = rng.bytes(300)
+    (src / "holed.bin").write_bytes(bytes(holed))
+    (src / "sub").mkdir()
+    (src / "sub" / "leaf.bin").write_bytes(rng.bytes(40_000))
+    os.chmod(src / "sub" / "leaf.bin", 0o440)
+    return src
+
+
+@pytest.fixture(scope="module")
+def flat(tmp_path_factory):
+    src = _flat_corpus(tmp_path_factory.mktemp("flat"))
+    store = MemObjectStore()
+    _backup(store, src)
+    return src, store
+
+
+def _restore_stats(store, dest, *, pipeline, delete_extra=True) -> dict:
+    repo = Repository.open(store)
+    with repo.lock(exclusive=False):
+        repo.load_index()
+        snap_id, manifest = repo.select_snapshot()
+        return TreeRestore(repo, pipeline=pipeline)._run_locked(
+            snap_id, manifest, dest, delete_extra=delete_extra)
+
+
+def _there_identical(store, dest, outside):
+    _restore_stats(store, dest, pipeline=False)
+
+
+def _there_differing(store, dest, outside):
+    dest.mkdir()
+    (dest / "a.bin").write_bytes(b"stale" * 50_000)  # longer than a.bin
+    (dest / "empty").write_bytes(b"no longer")
+    os.utime(dest / "a.bin", ns=(1, 1))
+
+
+def _there_hardlinked(store, dest, outside):
+    dest.mkdir()
+    outside.write_bytes(b"precious")
+    os.link(outside, dest / "a.bin")
+
+
+def _there_symlink(store, dest, outside):
+    dest.mkdir()
+    outside.write_bytes(b"precious")
+    os.symlink(outside, dest / "a.bin")
+
+
+def _there_directory(store, dest, outside):
+    (dest / "a.bin" / "inner").mkdir(parents=True)
+    (dest / "a.bin" / "inner" / "x").write_bytes(b"x")
+
+
+def _there_fifo(store, dest, outside):
+    dest.mkdir()
+    os.mkfifo(dest / "a.bin")
+
+
+def _there_populated(store, dest, outside):
+    _restore_stats(store, dest, pipeline=False)
+    for extra, body in (("kept.bin", b"k" * 5_000), ("sub/kept2", b"k")):
+        (dest / extra).write_bytes(body)
+        os.utime(dest / extra, ns=(7, 7))  # the same on both sides
+    (dest / "zero_tail.bin").write_bytes(b"other")
+    os.unlink(dest / "holed.bin")
+
+
+@pytest.mark.parametrize("there, delete_extra", [
+    (None, True),
+    (_there_identical, True),
+    (_there_differing, True),
+    (_there_hardlinked, True),
+    (_there_symlink, True),
+    (_there_directory, True),
+    (_there_fifo, True),
+    (_there_populated, False),
+    (_there_populated, True),
+], ids=["fresh", "identical", "differing", "hardlinked", "symlink",
+        "directory", "fifo", "populated_keep_extra",
+        "populated_delete_extra"])
+def test_pipeline_matches_serial_over(flat, tmp_path, there, delete_extra):
+    """Whatever stands at the destination, the pipeline leaves the tree
+    the serial oracle leaves (content, modes, mtimes, allocation) and
+    the same stats; a file outside the destination that a target was
+    linked to is not written through."""
+    src, store = flat
+    got = {}
+    for name, pipeline in (("serial", False), ("pipe", True)):
+        dest = tmp_path / name / "dst"
+        dest.parent.mkdir()
+        outside = tmp_path / name / "outside.bin"
+        if there is not None:
+            there(store, dest, outside)
+        got[name] = _restore_stats(store, dest, pipeline=pipeline,
+                                   delete_extra=delete_extra)
+        if outside.exists():
+            assert outside.read_bytes() == b"precious"
+    assert got["serial"] == got["pipe"]
+    d_serial, d_pipe = tmp_path / "serial" / "dst", tmp_path / "pipe" / "dst"
+    _assert_trees_identical(d_serial, d_pipe, blocks=True)
+    if there is _there_identical:
+        assert got["pipe"]["files"] == 0 and got["pipe"]["skipped"] == 6
+    if delete_extra:
+        _assert_trees_identical(src, d_pipe)
+    else:
+        assert (d_pipe / "kept.bin").read_bytes() == b"k" * 5_000
+        assert (d_pipe / "sub" / "kept2").read_bytes() == b"k"
+    # a trailing hole is a hole and an empty file is empty, either way
+    tail = (d_pipe / "zero_tail.bin").stat()
+    assert tail.st_size == 110_000
+    assert tail.st_blocks * 512 <= 20_000 + 2 * 4096
+    assert (d_pipe / "empty").stat().st_size == 0
+
+
+class _FdWatch:
+    """``os.open`` / ``os.close`` / builtin ``open`` wrapped: every open
+    of a path under ``root`` is recorded, and how many descriptors from
+    ``os.open`` were held on such paths at once."""
+
+    def __init__(self, monkeypatch, root: Path):
+        import builtins
+
+        self.root = str(root)
+        self.opened: list[str] = []
+        self.held: dict[int, str] = {}
+        self.most_held = 0
+        real_open, real_close, real_builtin = os.open, os.close, open
+
+        def os_open(path, *args, **kwargs):
+            fd = real_open(path, *args, **kwargs)
+            if os.fspath(path).startswith(self.root):
+                self.opened.append(os.fspath(path))
+                self.held[fd] = os.fspath(path)
+                self.most_held = max(self.most_held, len(self.held))
+            return fd
+
+        def os_close(fd):
+            self.held.pop(fd, None)
+            return real_close(fd)
+
+        def builtin_open(file, *args, **kwargs):
+            if (isinstance(file, (str, os.PathLike))
+                    and os.fspath(file).startswith(self.root)):
+                self.opened.append(os.fspath(file))
+            return real_builtin(file, *args, **kwargs)
+
+        monkeypatch.setattr(os, "open", os_open)
+        monkeypatch.setattr(os, "close", os_close)
+        monkeypatch.setattr(builtins, "open", builtin_open)
+
+
+def test_fresh_destination_opens_each_target_once(flat, tmp_path,
+                                                  monkeypatch):
+    from volsync_tpu.obs import counter_totals, reset_spans
+
+    src, store = flat
+    dest = tmp_path / "dst"
+    reset_spans()
+    with monkeypatch.context() as patched:
+        watch = _FdWatch(patched, dest)
+        stats = _restore_stats(store, dest, pipeline=True)
+    files = sorted(str(p) for p in dest.rglob("*") if p.is_file())
+    assert sorted(watch.opened) == files and len(files) == stats["files"]
+    assert watch.held == {}
+    counts = counter_totals()
+    assert counts["restore.opens"] == counts["restore.files_finished"] \
+        == stats["files"]
+    _assert_trees_identical(src, dest)
+
+
+def test_open_descriptors_are_bounded(tmp_path, monkeypatch):
+    """Files that share their tails stay unfinished side by side (the
+    shared blobs land in all of them at once, each one's own head
+    arrives later): with the bound at 2 no more than 2 targets are
+    ever open, the evicted ones reopen, and the tree is the source's."""
+    from volsync_tpu.engine import restorepipe
+    from volsync_tpu.obs import counter_totals, reset_spans
+
+    rng = np.random.RandomState(29)
+    src = tmp_path / "src"
+    src.mkdir()
+    shared = rng.bytes(400_000)
+    for i in range(8):
+        # a head of whole pages: the chunker cuts on the page grid
+        (src / f"f{i}.bin").write_bytes(rng.bytes(17 * 4096) + shared)
+    store = MemObjectStore()
+    _backup(store, src)
+    dest = tmp_path / "dst"
+    monkeypatch.setattr(restorepipe, "_MAX_OPEN", 2)
+    reset_spans()
+    with monkeypatch.context() as patched:
+        watch = _FdWatch(patched, dest)
+        stats = _restore_stats(store, dest, pipeline=True)
+    assert stats["files"] == 8
+    assert watch.most_held == 2 and watch.held == {}
+    counts = counter_totals()
+    assert counts["restore.files_finished"] == 8
+    assert counts["restore.opens"] == len(watch.opened) > 8  # reopened
+    d_serial = tmp_path / "serial"
+    _restore_stats(store, d_serial, pipeline=False)
+    _assert_trees_identical(d_serial, dest, blocks=True)
+    _assert_trees_identical(src, dest)
+
+
+def test_a_path_that_appears_after_the_walk_is_claimed(flat, tmp_path,
+                                                       monkeypatch):
+    """A name the walk found absent exists by the time its first blob
+    lands, hardlinked to a file outside the destination: the exclusive
+    create refuses it, it is cleared like any present name, restored
+    with its final content, and the outside file is not written
+    through."""
+    from volsync_tpu.engine import restorepipe
+
+    src, store = flat
+    dest = tmp_path / "dst"
+    outside = tmp_path / "outside.bin"
+    outside.write_bytes(b"precious")
+    planned = restorepipe._plan
+
+    def plan_then_intrude(*args):
+        out = planned(*args)
+        os.link(outside, dest / "a.bin")
+        os.link(outside, dest / "empty")
+        return out
+
+    monkeypatch.setattr(restorepipe, "_plan", plan_then_intrude)
+    stats = _restore_stats(store, dest, pipeline=True)
+    assert stats["files"] == 6 and stats["skipped"] == 0
+    assert outside.read_bytes() == b"precious"
+    assert outside.stat().st_nlink == 1
+    _assert_trees_identical(src, dest)
+
+
 def test_pipeline_env_flag(monkeypatch):
     repo = Repository.init(MemObjectStore())
     monkeypatch.setenv("VOLSYNC_RESTORE_PIPELINE", "0")
@@ -228,6 +475,20 @@ def test_pipeline_env_flag(monkeypatch):
 
 
 # -- integrity ---------------------------------------------------------------
+
+def _fds_into(root: Path) -> list[str]:
+    """What ``/proc/self/fd`` holds under ``root`` (a deleted file's
+    link still starts with its path)."""
+    held = []
+    for fd in os.listdir("/proc/self/fd"):
+        try:
+            where = os.readlink(f"/proc/self/fd/{fd}")
+        except OSError:
+            continue  # the listing's own descriptor, closed by now
+        if where.startswith(str(root)):
+            held.append(where)
+    return held
+
 
 def test_corrupt_pack_rejected_before_any_write(tmp_path):
     """Seeded corrupt pack: device-side verify rejects the batch and
@@ -288,6 +549,7 @@ def test_failed_restore_keeps_complete_files_only(tmp_path):
         if p.is_file():
             assert p.read_bytes() == (src / p.name).read_bytes(), \
                 f"partial file survived a failed restore: {p.name}"
+    assert _fds_into(dst) == [], "failed restore left descriptors open"
 
 
 # -- shared cache / single-flight --------------------------------------------
@@ -436,6 +698,7 @@ def test_read_repair_both_copies_corrupt_raises_no_partial(tmp_path,
         restore_snapshot(Repository.open(mem), dst)
     assert [p for p in dst.rglob("*") if p.is_file()] == [], \
         "failed restore left partial files behind"
+    assert _fds_into(dst) == [], "failed restore left descriptors open"
 
 
 def test_read_repair_disabled_by_flag(tmp_path, monkeypatch):

@@ -79,7 +79,9 @@ class TreeRestore:
         dest = Path(dest)
         dest.mkdir(parents=True, exist_ok=True)
         stats = {"files": 0, "bytes": 0, "skipped": 0, "deleted": 0}
-        jobs: list[tuple[dict, Path]] = []
+        # (entry, target, absent): ``absent`` is the walk's word that no
+        # name stood at ``target`` when its directory was listed
+        jobs: list[tuple[dict, Path, bool]] = []
         dirs: list[tuple[Path, dict]] = []
         links: list[tuple[dict, Path]] = []
         with span("restore.tree"):
@@ -121,27 +123,38 @@ class TreeRestore:
         — ``dirs`` holds a parent BEFORE every descendant, so the
         caller's reversed() metadata pass runs children-first — holds
         because a directory is appended when first visited and its
-        subtree is pushed afterwards."""
-        stack = [(tree_id, dirpath)]
+        subtree is pushed afterwards.
+
+        Each directory that was there before the walk is listed ONCE
+        (one the walk made is empty): extras go, and every file job
+        carries whether its name was in the listing, which is what
+        lets the pipeline create an absent target at its first write
+        with no call before it (engine/restorepipe.py)."""
+        stack = [(tree_id, dirpath, False)]  # ..., made by this walk
         while stack:
-            cur_id, cur_dir = stack.pop()
+            cur_id, cur_dir, made = stack.pop()
             tree = json.loads(self.repo.read_blob(cur_id))
             wanted = {e["name"] for e in tree["entries"]}
-            if delete_extra:
-                for child in cur_dir.iterdir():
-                    if child.name not in wanted:
-                        _rmtree(child)
+            # the names that survive the listing are all there are: an
+            # entry whose name is not among them needs no call to tell
+            present = set()
+            if not made:
+                with os.scandir(cur_dir) as listing:
+                    names = [child.name for child in listing]
+                for name in names:
+                    if delete_extra and name not in wanted:
+                        _rmtree(cur_dir / name)
                         stats["deleted"] += 1
+                    else:
+                        present.add(name)
             subdirs = []
             for entry in tree["entries"]:
                 target = cur_dir / entry["name"]
+                absent = entry["name"] not in present
                 if entry["type"] == "dir":
-                    if target.is_symlink() or (target.exists()
-                                               and not target.is_dir()):
-                        target.unlink()
-                    target.mkdir(exist_ok=True)
                     dirs.append((target, entry))
-                    subdirs.append((entry["subtree"], target))
+                    subdirs.append((entry["subtree"], target,
+                                    _make_dir(target, absent)))
                 elif entry["type"] == "symlink":
                     if target.is_symlink() or target.exists():
                         _rmtree(target)
@@ -157,7 +170,7 @@ class TreeRestore:
                     if entry.get("hardlink_to"):
                         links.append((entry, target))
                     else:
-                        jobs.append((entry, target))
+                        jobs.append((entry, target, absent))
             # reversed: the LIFO pop then visits subtrees in entry
             # order, matching the recursive walk
             stack.extend(reversed(subdirs))
@@ -201,7 +214,7 @@ class TreeRestore:
         stats["files"] += 1
 
     def _restore_files(self, jobs: list, stats: dict) -> None:
-        """Restore every (entry, target) file job. Pipelined mode
+        """Restore every (entry, target, absent) file job. Pipelined mode
         (VOLSYNC_RESTORE_PIPELINE, default on) plans pack-granular
         fetches and device-verifies in batches
         (engine/restorepipe.py); the serial fallback reads blob by
@@ -218,9 +231,9 @@ class TreeRestore:
 
             with ThreadPoolExecutor(self.workers) as pool:
                 results = list(pool.map(
-                    lambda j: self._restore_file(*j), jobs))
+                    lambda j: self._restore_file(j[0], j[1]), jobs))
         else:
-            results = [self._restore_file(*j) for j in jobs]
+            results = [self._restore_file(j[0], j[1]) for j in jobs]
         for key, nbytes in results:
             stats[key] += 1
             stats["bytes"] += nbytes
@@ -261,10 +274,13 @@ class TreeRestore:
                 # against its own restore job under the worker pool).
                 target.unlink()
 
-    def _finalize_file(self, entry: dict, target: Path) -> None:
+    def _finalize_file(self, entry: dict, target) -> None:
         """Post-content metadata stamp, shared by both restore paths:
         xattrs before chmod (read-only modes), chown before chmod
-        (chown clears suid), mtime last."""
+        (chown clears suid), mtime last. ``target`` is the file's path
+        (the serial writer) or a descriptor open on it (the pipeline,
+        which stamps before it closes: ``fchown``, ``fchmod``,
+        ``futimens``)."""
         _apply_xattrs(target, entry)
         _apply_owner(target, entry)
         os.chmod(target, entry["mode"])
@@ -320,6 +336,13 @@ class TreeRestore:
         flush()
 
 
+def _nofollow(where) -> dict:
+    """The keyword that keeps a metadata call off a symlink's target:
+    for a path ``follow_symlinks=False``; a descriptor already names
+    its file, and ``os`` refuses the keyword beside one."""
+    return {} if isinstance(where, int) else {"follow_symlinks": False}
+
+
 def _apply_owner(path, entry: dict) -> None:
     """uid/gid (rsync -o -g analogue). Backup records them on EVERY
     entry (root:root drift must converge too); an ABSENT key means a
@@ -329,14 +352,15 @@ def _apply_owner(path, entry: dict) -> None:
     if "uid" not in entry:
         return
     try:
-        os.chown(path, entry["uid"], entry["gid"], follow_symlinks=False)
+        os.chown(path, entry["uid"], entry["gid"], **_nofollow(path))
     except OSError:
         pass
 
 
 def _apply_xattrs(path, entry: dict) -> None:
-    """Restore recorded extended attributes (rsync -A analogue);
-    follow_symlinks=False throughout. Namespaces the filesystem rejects
+    """Restore recorded extended attributes (rsync -A analogue) on a
+    path, never followed through a symlink, or on an open descriptor
+    (``_nofollow``). Namespaces the filesystem rejects
     (e.g. user.* on symlinks) are skipped — fidelity degrades to what
     the destination supports, as the reference movers' setfacl
     --restore does.
@@ -351,20 +375,20 @@ def _apply_xattrs(path, entry: dict) -> None:
     if "xattrs" not in entry:
         return
     want = entry["xattrs"]
+    nofollow = _nofollow(path)
     try:
-        have = os.listxattr(path, follow_symlinks=False)
+        have = os.listxattr(path, **nofollow)
     except OSError:
         return
     for n in have:
         if n not in want:
             try:
-                os.removexattr(path, n, follow_symlinks=False)
+                os.removexattr(path, n, **nofollow)
             except OSError:
                 pass
     for n, v in want.items():
         try:
-            os.setxattr(path, n, base64.b64decode(v),
-                        follow_symlinks=False)
+            os.setxattr(path, n, base64.b64decode(v), **nofollow)
         except OSError:
             pass
 
@@ -429,6 +453,23 @@ def _write_sparse(f, data) -> None:
     the caller's."""
     view = memoryview(data).cast("B")
     _write_runs(f, view, _sparse_runs(view))
+
+
+def _make_dir(target: Path, absent: bool) -> bool:
+    """Make ``target`` a directory; True where this call created it,
+    so that it is empty. ``absent`` is the listing's word that no name
+    stood there: then one ``mkdir``, and a name that has appeared
+    since is cleared like any other."""
+    if absent:
+        try:
+            os.mkdir(target)
+            return True
+        except FileExistsError:
+            pass
+    if target.is_symlink() or (target.exists() and not target.is_dir()):
+        target.unlink()
+    target.mkdir(exist_ok=True)
+    return False
 
 
 def _rmtree(path: Path):

@@ -11,7 +11,11 @@ module mirrors that work for reads, in four stages:
    (``raw_length`` is the plaintext length, known before any fetch),
    group needed blobs by the pack that holds them, and order pack
    fetches by first need — each pack is downloaded ONCE and all ranges
-   within it coalesce into that one GET.
+   within it coalesce into that one GET. A target whose name the walk
+   found ABSENT from its directory's listing costs this stage no
+   system call: nothing to compare, nothing to clear, nothing to
+   claim. A name that was present is compared (skip-unchanged),
+   cleared and claimed by a truncating open, as the serial path does.
 2. **Fetch** (``restore.fetch``): a bounded async pool
    (``VOLSYNC_RESTORE_FETCHERS`` threads, ``VOLSYNC_RESTORE_FETCH_WINDOW``
    packs submitted ahead) pulls whole packs through the shared
@@ -38,7 +42,16 @@ module mirrors that work for reads, in four stages:
 4. **Write** (``restore.write``): verified blobs are written at their
    planned offsets with the serial path's sparse semantics (aligned
    all-zero pages become holes; chunk boundaries are page-aligned, so
-   the hole grid matches the serial writer's byte for byte).
+   the hole grid matches the serial writer's byte for byte). A target
+   is opened ONCE, when its first verified blob lands (an absent one
+   is created there, ``O_EXCL``), and that descriptor takes every
+   ``pwrite`` and, as the last blob lands, the metadata stamp
+   (``restore.finalize``: ``ftruncate`` for a trailing hole, xattrs,
+   ``fchown``, ``fchmod``, ``futimens``), then closes. At most
+   ``_MAX_OPEN`` targets are held open; past that the least recently
+   written is closed and reopens at its next blob. A failed restore
+   closes them all and unlinks every target it had created and not
+   finished.
 
 The pipeline runs under the caller's shared-mode repository lock for
 its WHOLE fetch window, so a concurrent two-phase pruner can mark packs
@@ -66,7 +79,7 @@ from pathlib import Path
 from typing import Optional
 
 from volsync_tpu import envflags
-from volsync_tpu.engine.restore import _sparse_runs, _write_runs
+from volsync_tpu.engine.restore import _sparse_runs
 from volsync_tpu.metrics import GLOBAL as GLOBAL_METRICS
 from volsync_tpu.objstore.store import NoSuchKey
 from volsync_tpu.obs import (
@@ -95,69 +108,178 @@ _M_HEALED = GLOBAL_METRICS.scrub_packs.labels(outcome="healed")
 _BUFFERED = ""
 
 
+#: target files held open at once. Blobs arrive in pack order, which
+#: is mostly file order, but dedup can leave a file unfinished for
+#: long: past the bound the least recently written target is closed
+#: and reopens at its next blob
+_MAX_OPEN = 64
+
+_O_WRITE = os.O_WRONLY | os.O_CLOEXEC
+
+
 class _FilePlan:
     """One file's restore state: where it goes, how many blob writes
-    remain, and the final length to truncate to (trailing holes)."""
+    remain, the final length, and the descriptor it is written through
+    while one is open on it."""
 
-    __slots__ = ("entry", "target", "total", "remaining", "claimed")
+    __slots__ = ("entry", "target", "total", "remaining", "claimed",
+                 "existed", "fd", "written", "finished")
 
     def __init__(self, entry: dict, target: Path):
         self.entry = entry
         self.target = target
         self.total = 0
         self.remaining = 0
+        # the restore made (or emptied) the file: a failure unlinks it
         self.claimed = False
+        # a name stood at the target before this restore: cleared and
+        # claimed by a truncating open, its length set at the finish
+        self.existed = False
+        self.fd = -1
+        self.written = 0  # one past the highest byte written
+        self.finished = False  # stamped and closed: it stays
+
+
+class _OpenTargets:
+    """The descriptors a restore holds on its targets: one a file from
+    its first write to its stamp, at most ``_MAX_OPEN`` at once (least
+    recently written out first). Owned by one ``restore_files_pipelined``
+    call and used from its thread alone."""
+
+    def __init__(self, tr):
+        self._tr = tr
+        self._open: "OrderedDict[_FilePlan, None]" = OrderedDict()
+
+    def claim(self, plan: _FilePlan) -> None:
+        """The present-name path: whatever stands at the target is
+        cleared and the file created or emptied NOW, so a failure
+        anywhere later knows to remove it."""
+        self._tr._clear_target(plan.target)
+        with open(plan.target, "wb"):
+            pass
+        count("restore.opens")
+        plan.claimed = plan.existed = True
+
+    def fd(self, plan: _FilePlan) -> int:
+        """The descriptor ``plan`` is written through, opened here if
+        it has none: an absent target is created (exclusively: a name
+        that has appeared since the walk's listing is claimed like any
+        present one), a claimed one reopened."""
+        if plan.fd >= 0:
+            self._open.move_to_end(plan)
+            return plan.fd
+        if len(self._open) >= _MAX_OPEN:
+            self._close(self._open.popitem(last=False)[0])
+        if not plan.claimed:
+            try:
+                plan.fd = os.open(
+                    plan.target, _O_WRITE | os.O_CREAT | os.O_EXCL, 0o600)
+                plan.claimed = True
+            except FileExistsError:
+                self.claim(plan)
+        if plan.fd < 0:
+            plan.fd = os.open(plan.target, _O_WRITE)
+        count("restore.opens")
+        self._open[plan] = None
+        return plan.fd
+
+    def write(self, plan: _FilePlan, offset: int, view, runs) -> None:
+        """One blob placement: ``runs`` are the serial path's sparse
+        semantics (``_sparse_runs``), or one dense run with sparse
+        writes off. A data run is one ``pwrite`` on the held
+        descriptor, a hole nothing. No open, seek or close a
+        placement: a restore is priced in system calls (on the chip's
+        host 0.10 ms one on a path and a fifth of it one on a
+        descriptor, beside a write of 0.25 s/GiB), and an open a
+        placement made seven of one
+        (scripts/profile_restore_write.py --files)."""
+        fd = self.fd(plan)
+        for start, stop, hole in runs:
+            if hole:
+                continue
+            data, at = view[start:stop], offset + start
+            while len(data):
+                n = os.pwrite(fd, data, at)
+                data, at = data[n:], at + n
+            plan.written = max(plan.written, offset + stop)
+
+    def finish(self, plan: _FilePlan, stats: dict) -> None:
+        """All content written: materialize a trailing hole and stamp
+        metadata exactly as the serial writer does, through the
+        descriptor, then close it."""
+        with span("restore.finalize"):
+            fd = self.fd(plan)  # an empty file is created here
+            if plan.existed or plan.written < plan.total:
+                os.ftruncate(fd, plan.total)
+            self._tr._finalize_file(plan.entry, fd)
+            del self._open[plan]
+            self._close(plan)
+        plan.finished = True
+        count("restore.files_finished")
+        stats["files"] += 1
+        stats["bytes"] += plan.entry["size"]
+
+    def _close(self, plan: _FilePlan) -> None:
+        fd, plan.fd = plan.fd, -1
+        os.close(fd)
+
+    def close_all(self) -> None:
+        while self._open:
+            self._close(self._open.popitem()[0])
 
 
 def restore_files_pipelined(tr, jobs: list, stats: dict) -> None:
-    """Restore every (entry, target) file job through the four-stage
-    pipeline. ``tr`` is the owning TreeRestore (skip/clear/finalize
-    semantics and the sparse toggle are ITS methods, so the two paths
-    cannot drift); must run under the repo's shared store lock."""
+    """Restore every (entry, target, absent) file job through the
+    four-stage pipeline. ``tr`` is the owning TreeRestore (skip/clear/
+    finalize semantics and the sparse toggle are ITS methods, so the
+    two paths cannot drift); must run under the repo's shared store
+    lock."""
     repo = tr.repo
     cache = tr.pack_cache
     if cache is None:
         cache = PackCache(repo.store, rescue=repo.ec_reconstruct)
-    with span("restore.plan"):
-        plans, placements, groups = _plan(tr, jobs, stats)
-    for plan in plans:
-        if plan.remaining == 0:  # an empty file: nothing to fetch
-            _finish_file(tr, plan, stats)
-    if not plans:
-        return
+    targets = _OpenTargets(tr)
+    plans: list[_FilePlan] = []
     try:
-        _execute(tr, repo, cache, plans, placements, groups, stats)
-    except BaseException:
-        # zero partial files on a failed restore: complete files stay,
-        # every claimed-but-incomplete target is removed
+        with span("restore.plan"):
+            placements, groups = _plan(tr, jobs, stats, targets, plans)
         for plan in plans:
-            if plan.claimed and plan.remaining > 0:
+            if plan.remaining == 0:  # an empty file: nothing to fetch
+                targets.finish(plan, stats)
+        if plans:
+            _execute(tr, repo, cache, placements, groups, stats, targets)
+    except BaseException:
+        # zero partial files and no descriptor left on a failed
+        # restore: finished files stay, every target created or
+        # emptied and not finished is removed
+        targets.close_all()
+        for plan in plans:
+            if plan.claimed and not plan.finished:
                 plan.target.unlink(missing_ok=True)
         raise
 
 
-def _plan(tr, jobs: list, stats: dict):
-    """Stage 1: skip-unchanged filtering, target claiming, offset
-    derivation, and pack grouping (module docstring)."""
+def _plan(tr, jobs: list, stats: dict, targets: _OpenTargets,
+          plans: list):
+    """Stage 1: skip-unchanged filtering and claiming of the targets
+    whose names were present, offset derivation, and pack grouping
+    (module docstring). Appends to ``plans`` as it goes, so the caller
+    can clean up after a failure part-way."""
     repo = tr.repo
-    plans: list[_FilePlan] = []
     # blob_id -> [(plan, offset_in_file)] across ALL files (dedup means
     # one fetched blob may land in many places)
     placements: dict[str, list] = {}
     # pack id (or _BUFFERED) -> [(blob_id, offset_in_pack, length)],
     # ordered by first need so early files' packs fetch first
     groups: "OrderedDict[str, list]" = OrderedDict()
-    for entry, target in jobs:
-        if tr._skip_unchanged(entry, target):
-            stats["skipped"] += 1
-            continue
-        tr._clear_target(target)
+    for entry, target, absent in jobs:
         plan = _FilePlan(entry, target)
-        # claim: create/truncate now, so a failure ANYWHERE later knows
-        # exactly which targets to clean up
-        with open(target, "wb"):
-            pass
-        plan.claimed = True
+        if not absent:
+            if tr._skip_unchanged(entry, target):
+                stats["skipped"] += 1
+                continue
+            targets.claim(plan)
+        plans.append(plan)
         offset = 0
         for blob_id in entry["content"]:
             ie = repo._entry(blob_id)
@@ -175,8 +297,7 @@ def _plan(tr, jobs: list, stats: dict):
             offset += ie.raw_length
             plan.remaining += 1
         plan.total = offset
-        plans.append(plan)
-    return plans, placements, groups
+    return placements, groups
 
 
 def _mirror_heal(repo, cache: PackCache, pack_id: str) -> Optional[bytes]:
@@ -211,8 +332,9 @@ def _mirror_heal(repo, cache: PackCache, pack_id: str) -> Optional[bytes]:
     return body
 
 
-def _execute(tr, repo, cache: PackCache, plans, placements,
-             groups: "OrderedDict[str, list]", stats: dict) -> None:
+def _execute(tr, repo, cache: PackCache, placements,
+             groups: "OrderedDict[str, list]", stats: dict,
+             targets: _OpenTargets) -> None:
     """Stages 2-4: bounded async pack fetch -> decode -> device-batched
     verify -> positional writes, consuming packs in plan order."""
     ctx = current_context()
@@ -307,16 +429,16 @@ def _execute(tr, repo, cache: PackCache, plans, placements,
                 runs = (_sparse_runs(view) if tr.sparse
                         else [(0, len(view), False)])
                 for plan, offset in placements[blob_id]:
-                    _write_at(plan, offset, view, runs)
+                    targets.write(plan, offset, view, runs)
                     plan.remaining -= 1
                     if plan.remaining == 0:
                         done.append(plan)
             for plan in done:
-                _finish_file(tr, plan, stats)
+                targets.finish(plan, stats)
             places = len(placements[blob_id])
             count("restore.writes", places)
             if len(runs) == 1 and not runs[0][2]:
-                # went down as one write and no seek at every place
+                # went down as one write at every place
                 count("restore.writes_dense", places)
             _M_RESTORE_BYTES.inc(len(view) * places)
             count("restore.bytes_restored", len(view) * places)
@@ -383,29 +505,6 @@ def _execute(tr, repo, cache: PackCache, plans, placements,
                     # carries the failure
                     pass
             raise
-
-
-def _write_at(plan: _FilePlan, offset: int, view, runs) -> None:
-    """One positional blob write: ``runs`` are the serial path's
-    sparse semantics (``_sparse_runs``), or one dense run with sparse
-    writes off. Opens per write: restores span more files than fd
-    limits, and an open + seek + close costs ~0.23 ms beside a write
-    of 0.25 s/GiB (scripts/profile_restore_write.py on the chip's
-    host, PR 33: 0.70 s/GiB at 512 KiB blobs against 0.26 at 8 MiB)."""
-    with open(plan.target, "r+b") as f:
-        f.seek(offset)
-        _write_runs(f, view, runs)
-
-
-def _finish_file(tr, plan: _FilePlan, stats: dict) -> None:
-    """All content written: materialize trailing holes and stamp
-    metadata exactly as the serial writer does."""
-    with span("restore.finalize"):
-        with open(plan.target, "r+b") as f:
-            f.truncate(plan.total)
-        tr._finalize_file(plan.entry, plan.target)
-    stats["files"] += 1
-    stats["bytes"] += plan.entry["size"]
 
 
 class RestoreGroup:
