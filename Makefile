@@ -4,7 +4,7 @@
 
 .PHONY: lint lint-locks lint-buf lint-fx test chaos chaos-concurrent chaos-fleet \
 	chaos-restore chaos-scrub chaos-ec scrub-smoke static-check \
-	trace-smoke session-smoke clean-lint
+	clean-lint
 
 # Cached SARIF lint over the whole tree (package + scripts/):
 # all rule families, VL001-VL005 + VL105/VL106 + VL301 per-file + VL101-VL104
@@ -116,21 +116,6 @@ chaos-ec:
 
 static-check:
 	scripts/static_check.sh
-
-# Flight-recorder gate (docs/observability.md): a tiny pipelined backup
-# under a tenant-tagged trace must export a Perfetto-loadable
-# Chrome-trace-event dump (span shape, trace/tenant tags, parent/child
-# edges, thread names, trigger annotation).
-trace-smoke:
-	JAX_PLATFORMS=cpu python scripts/trace_smoke.py
-
-# Supervised-session soak (docs/sessions.md): seeded FakeSessionBackend
-# chaos — probe hang, keepalive drop, zombie-holds-device — must recycle
-# within the hard TTL, complete a job on the fresh session, fence the
-# zombie's stale write, and reproduce the identical transition trace on
-# a second run of the same seed. No chip required.
-session-smoke:
-	JAX_PLATFORMS=cpu python scripts/session_smoke.py
 
 clean-lint:
 	rm -f lint.sarif .lint-cache lock-graph.json provenance.json \

@@ -19,12 +19,12 @@ up, literal bytes.
 
     python scripts/profile_delta.py --insertions
 
-What a search costs, and where (``--split``): the search program as it
-stood before PR 45 (kept below as ``delta_match_rows_pr44``), whole and
-its stages apart, at the mover's window for blocks of 4, 8 and 16 KiB
-with 64, 700 and 2,048 rows listed; its operations' device times under
-the compiler's names from a trace, with the compiled text written to
-``chiprun_out/profile_delta/`` to name them by.
+What a search costs by the rows it is given (``--split``):
+``delta_match_rows`` at the mover's window for blocks of 4, 8 and 16 KiB
+with 64, 700, 2,048 and a quarter of the window's rows listed; at 8 KiB
+its operations' device times under the compiler's names from a trace,
+with the compiled text written to ``chiprun_out/profile_delta/`` to
+name them by.
 
     python scripts/profile_delta.py --split
 """
@@ -186,66 +186,6 @@ def insertions() -> int:
     return 0
 
 
-def _pr44_program():
-    """The every-offset search as PR 42 wrote it and PR 44 left it: the
-    prefix sums of all 64 Mi offsets, then a fixed 2,048 rows looked up
-    by ``searchsorted(method="sort")`` and compacted by ``nonzero``."""
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-
-    from volsync_tpu.ops.delta import _COLS, _M16 as M16, \
-        _flat_prefix as flat_prefix
-
-    def offsets(R):
-        return (jnp.arange(R, dtype=jnp.uint32)[:, None] * np.uint32(_COLS)
-                + jnp.arange(_COLS, dtype=jnp.uint32)[None, :])
-
-    def prefixes(data):
-        R = data.shape[0] // _COLS
-        x = data.reshape(R, _COLS).astype(jnp.uint32)
-        return flat_prefix(x), flat_prefix(offsets(R) * x)
-
-    def weak_all(S, s_all, T, t_all, window):
-        shift = window // _COLS
-
-        def ahead(E, total):
-            fill = jnp.broadcast_to(total, (shift, _COLS))
-            return jnp.concatenate([E[shift:], fill], axis=0)
-
-        dS = ahead(S, s_all) - S
-        dT = ahead(T, t_all) - T
-        a = dS & M16
-        b = ((offsets(S.shape[0]) + np.uint32(window)) * dS - dT) & M16
-        return a | (b << np.uint32(16))
-
-    def compact(hit, at, weak, N, max_candidates):
-        n = hit.shape[0]
-        idx = jnp.nonzero(hit, size=max_candidates, fill_value=n)[0]
-        safe = jnp.minimum(idx, n - 1)
-        cand = jnp.where(idx < n, at[safe], N)
-        return cand.astype(jnp.int32), weak[safe], jnp.sum(hit)
-
-    @functools.partial(jax.jit,
-                       static_argnames=("window", "max_candidates"))
-    def delta_match_rows_pr44(data, sorted_weak, n_sig, rows, row_until,
-                              lo, *, window, max_candidates):
-        (S, s_all), (T, t_all) = prefixes(data)
-        weak = weak_all(S, s_all, T, t_all, window)[rows]
-        at = (rows[:, None] * _COLS
-              + jnp.arange(_COLS, dtype=jnp.int32)[None, :])
-        pos = jnp.searchsorted(sorted_weak, weak.reshape(-1),
-                               method="sort").reshape(weak.shape)
-        found = sorted_weak[jnp.minimum(pos, sorted_weak.shape[0] - 1)]
-        hit = ((found == weak) & (pos < n_sig) & (at < row_until[:, None])
-               & (at >= lo)).reshape(-1)
-        return compact(hit, at.reshape(-1), weak.reshape(-1),
-                       data.shape[0], max_candidates)
-
-    return delta_match_rows_pr44, prefixes, weak_all, compact
-
-
 def _listed(geo, n_rows: int, cap: int):
     """``n_rows`` rows spread over the window in runs of a block's rows
     (what the engine lists: open blocks), at a capacity of ``cap``."""
@@ -288,11 +228,9 @@ def _device_ops(fn, runs: int = 3, top: int = 14) -> dict:
 
 def split() -> int:
     import jax
-    import jax.numpy as jnp
 
     from volsync_tpu.engine import deltasync
     from volsync_tpu.ops import delta
-    from volsync_tpu.ops.delta import _COLS
 
     # the rows of a group of the search's loop: the engine's own, or
     # ``--groups 128,512`` to time others
@@ -306,7 +244,6 @@ def split() -> int:
     rng = np.random.default_rng(7)
     W = deltasync.WINDOW
     dev = jax.device_put(np.frombuffer(rng.bytes(W), np.uint8))
-    old, prefixes, weak_all, compact = _pr44_program()
     for bl in (4096, 8192, 16384):
         geo = deltasync._Geometry.of(bl)
         weak, _strong = delta.delta_sig_flat(dev, block_len=bl)
@@ -315,92 +252,31 @@ def split() -> int:
                      np.uint32)
         sw[: len(table)] = table
         sw_dev, n_sig = jax.device_put(sw), np.int32(len(table))
-        G = max(W // _COLS // 32, 64)  # PR 44's fixed rows a search
-        kw = dict(window=bl, max_candidates=geo.cand_cap)
         line = {"block_len": bl, "sig_cap": len(sw),
                 "cand_cap": geo.cand_cap}
-        for n_rows in (64, 700, 2048):
-            take, until = _listed(geo, n_rows, G)
-            args = (dev, sw_dev, n_sig, jax.device_put(take),
-                    jax.device_put(until), np.int32(0))
-            line[f"pr44_rows_{n_rows}_s"] = timed(old, *args, **kw)
-            line[f"pr44_rows_{n_rows}_candidates"] = int(
-                old(*args, **kw)[2])
         for group_rows in group_sizes or [geo.group_rows]:
             for n_rows in (64, 700, 2048, geo.rows // 4):
                 take, until = _listed(geo, n_rows, geo.rows)
                 groups = -(-n_rows // group_rows)
-                new_args = (dev, sw_dev, n_sig, jax.device_put(take),
-                            jax.device_put(until), np.int32(groups),
-                            np.int32(0), np.int32(0))
-                new_kw = dict(window=bl, group_rows=group_rows,
-                              max_candidates=geo.cand_cap,
-                              capacity=geo.search_cap)
+                args = (dev, sw_dev, n_sig, jax.device_put(take),
+                        jax.device_put(until), np.int32(groups),
+                        np.int32(0), np.int32(0))
+                kw = dict(window=bl, group_rows=group_rows,
+                          max_candidates=geo.cand_cap,
+                          capacity=geo.search_cap)
                 key = f"group_{group_rows}_rows_{n_rows}"
                 line[key + "_s"] = timed(delta.delta_match_rows,
-                                         *new_args, **new_kw)
-                state = delta.delta_match_rows(*new_args, **new_kw)[2]  # lint: ignore[VL502] one timed case a turn
+                                         *args, **kw)
+                state = delta.delta_match_rows(*args, **kw)[2]  # lint: ignore[VL502] one timed case a turn
                 line[key + "_taken_next_ran"] = np.asarray(state).tolist()
         print(json.dumps(line), flush=True)
         if bl != 8192:
             continue
-        # the stages of PR 44's program apart, each a program of its own
-        # (what a fusion across them saves is not in these)
-        (S, s_all), (T, t_all) = jax.jit(prefixes)(dev)
-        weak_full = jax.jit(weak_all, static_argnums=4)(
-            S, s_all, T, t_all, bl)
-        rows_dev = args[3]  # PR 44's fixed rows, all listed
-        q = weak_full[rows_dev].reshape(-1)
-        M = q.shape[0]
-        at = (rows_dev[:, None] * _COLS
-              + jnp.arange(_COLS, dtype=jnp.int32)[None, :]).reshape(-1)
-        merged = jnp.concatenate([q, sw_dev])
-        order = jnp.argsort(merged)
-        order_q = jnp.argsort(q)
-        pos = jnp.searchsorted(sw_dev, q, method="sort")
-        hit = sw_dev[jnp.minimum(pos, len(sw) - 1)] == q
-        ranks = jax.jit(lambda o: jnp.zeros_like(o).at[o].set(
-            jnp.arange(o.shape[0], dtype=o.dtype)))
-        cum = jnp.cumsum(hit)
-        stages = {
-            "prefix_sums_both_s": timed(jax.jit(prefixes), dev),
-            "weak_elementwise_s": timed(
-                jax.jit(weak_all, static_argnums=4), S, s_all, T, t_all, bl),
-            "row_gather_s": timed(jax.jit(lambda w, r: w[r]), weak_full,
-                                  rows_dev),
-            "lookup_whole_s": timed(jax.jit(
-                lambda t, v: jnp.searchsorted(t, v, method="sort")),
-                sw_dev, q),
-            "lookup_sort_merged_s": timed(jax.jit(
-                lambda v, t: jnp.argsort(jnp.concatenate([v, t]))),
-                q, sw_dev),
-            "lookup_scatter_ranks_merged_s": timed(ranks, order),
-            "lookup_sort_queries_s": timed(jax.jit(jnp.argsort), q),
-            "lookup_scatter_ranks_queries_s": timed(ranks, order_q),
-            "found_gather_s": timed(jax.jit(
-                lambda t, p: t[jnp.minimum(p, t.shape[0] - 1)]),
-                sw_dev, pos),
-            "nonzero_whole_s": timed(jax.jit(lambda h: jnp.nonzero(
-                h, size=geo.cand_cap, fill_value=M)[0]), hit),
-            "nonzero_cumsum_s": timed(jax.jit(jnp.cumsum), hit),
-            "nonzero_bincount_s": timed(jax.jit(lambda c: jnp.bincount(
-                c, length=geo.cand_cap)), cum),
-            "compact_whole_s": timed(jax.jit(
-                lambda h, a, w: compact(h, a, w, W, geo.cand_cap)),
-                hit, at, q),
-        }
-        print(json.dumps({"block_len": bl, "rows": G, "offsets": M,
-                          "pr44_stages": stages}), flush=True)
-        del S, T, weak_full
-        (out_dir / "pr44_program.txt").write_text(
-            old.lower(*args, **kw).compile().as_text())
-        print(json.dumps({"pr44_device_ops_s": _device_ops(
-            lambda: jax.block_until_ready(old(*args, **kw)))}), flush=True)
         (out_dir / "program.txt").write_text(delta.delta_match_rows.lower(
-            *new_args, **new_kw).compile().as_text())
+            *args, **kw).compile().as_text())
         print(json.dumps({"rows": geo.rows // 4, "device_ops_s": _device_ops(
             lambda: jax.block_until_ready(delta.delta_match_rows(
-                *new_args, **new_kw)))}), flush=True)
+                *args, **kw)))}), flush=True)
     print(json.dumps({"peak_bytes": (dev0.memory_stats() or {}).get(
         "peak_bytes_in_use")}))
     return 0

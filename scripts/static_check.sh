@@ -31,40 +31,33 @@
 #   2. The pipeline + crash-recovery suites with the lock-order/race
 #      detector armed at process start (VOLSYNC_TPU_LOCKCHECK=1), so
 #      module-level locks are instrumented too.
-#   3. The flight-recorder smoke (`make trace-smoke`): a tiny pipeline
-#      run must export a Perfetto-loadable Chrome-trace-event dump
-#      (docs/observability.md).
-#   4. The supervised-session smoke (`make session-smoke`): seeded
-#      FakeSessionBackend chaos — wedge -> recycle -> job completes,
-#      zombie write fenced, deterministic transition trace
-#      (docs/sessions.md).
-#   5. The multi-writer chaos acceptance (`make chaos-concurrent`):
+#   3. The multi-writer chaos acceptance (`make chaos-concurrent`):
 #      4 fenced concurrent writers + a two-phase pruner under the
 #      seeded MW_SCHEDULES fault/crash matrix — crash at every prune
 #      step boundary, forced double-takeover — always ending in a
 #      clean check(read_data=True) with byte-identical restores
 #      (docs/robustness.md, "Multi-writer protocol").
-#   6. The fleet replica drill (`make chaos-fleet`): 3 fenced mover
+#   4. The fleet replica drill (`make chaos-fleet`): 3 fenced mover
 #      replicas + a continuous GC service under the FLEET_SCHEDULES
 #      seeded matrix — kill-a-replica-mid-stream, store partition,
 #      GC-writer crash — failover completes every admitted job, the
 #      dead writer's late publish is fenced, no live pack is swept
 #      (docs/service.md, "Fleet operations").
-#   7. The restore-storm chaos drill (`make chaos-restore`): the golden
+#   5. The restore-storm chaos drill (`make chaos-restore`): the golden
 #      serial≡pipelined byte-identity suite plus N concurrent restores
 #      sharing one PackCache under seeded read-path faults — identical
 #      trees, single-flight pack fetches, no partial file on a crashed
 #      restore (docs/robustness.md, "Restore storms").
-#   8. The scrub smoke (`make scrub-smoke`): ScrubService
+#   6. The scrub smoke (`make scrub-smoke`): ScrubService
 #      heal/quarantine/backfill units, the serial≡device
 #      check(read_data=True) golden, and the `volsync scrub` exit-code
 #      contract (docs/robustness.md, "Silent corruption & scrub").
-#   9. The bit-rot chaos drill (`make chaos-scrub`): seeded bitflip
+#   7. The bit-rot chaos drill (`make chaos-scrub`): seeded bitflip
 #      schedules under a live restore storm + scrub + ContinuousGC +
 #      concurrent backup — quarantine-empty, check-clean,
 #      byte-identical restores, plus the read-repair suite
 #      (docs/robustness.md, "Silent corruption & scrub").
-#  10. The erasure-coding drill (`make chaos-ec`): RS kernel goldens,
+#   8. The erasure-coding drill (`make chaos-ec`): RS kernel goldens,
 #      EC-armed seal layout + any-k restores, heal-arm priority
 #      (mirror-first, then stripe reconstruction, then quarantine),
 #      RepackService crash-at-every-boundary safety, seeded
@@ -118,7 +111,7 @@ stats = json.loads(sys.argv[1])
 # The committed suppression budget: every `# lint: ignore` pragma in
 # the tree is a reviewed one-off. New suppressions need review — bump
 # this number in the same change that adds the pragma.
-BUDGET = 70
+BUDGET = 75
 total = stats["total_suppressions"]
 if total > BUDGET:
     sys.exit(f"suppression budget exceeded: {total} `# lint: ignore` "
@@ -133,12 +126,6 @@ echo "== lockcheck-armed pipeline suites =="
 JAX_PLATFORMS=cpu VOLSYNC_TPU_LOCKCHECK=1 \
     python -m pytest tests/test_lockcheck.py tests/test_pipeline.py \
         tests/test_crash_recovery.py -q -p no:cacheprovider
-
-echo "== trace-smoke =="
-make --no-print-directory trace-smoke
-
-echo "== session-smoke =="
-make --no-print-directory session-smoke
 
 echo "== chaos-concurrent =="
 make --no-print-directory chaos-concurrent

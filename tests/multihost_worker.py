@@ -1,12 +1,11 @@
 """Worker process for the 2-process multi-host execution test.
 
 Launched by tests/test_multihost_exec.py with the standard env triplet
-(JAX_COORDINATOR_ADDRESS / JAX_NUM_PROCESSES / JAX_PROCESS_ID). Joins
-the coordinator through the framework's own wiring
-(parallel/multihost.init_distributed), builds the (wave, seq) mesh over
-the GLOBAL device set — collectives here cross the process boundary,
-the DCN-analogue path — runs the sharded chunk+hash step, and verifies
-its addressable digest shards against a pure-host hashlib reference.
+(JAX_COORDINATOR_ADDRESS / JAX_NUM_PROCESSES / JAX_PROCESS_ID) as
+``multihost_worker.py treebackup <volume>``. Joins the coordinator
+through the framework's own wiring (parallel/multihost.init_distributed)
+and builds the ``seq`` mesh over the GLOBAL device set — collectives
+here cross the process boundary, the DCN-analogue path.
 """
 
 import sys
@@ -17,55 +16,7 @@ jax.config.update("jax_platforms", "cpu")
 # Cross-process CPU collectives (the ICI/DCN stand-in for tests).
 jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
-import hashlib  # noqa: E402
-
-import numpy as np  # noqa: E402
-
 from volsync_tpu.parallel.multihost import init_distributed  # noqa: E402
-
-
-def main() -> int:
-    info = init_distributed()  # env triplet -> explicit, fail-hard path
-    assert info["process_count"] == 2, info
-    assert info["global_devices"] > info["local_devices"], info
-
-    from volsync_tpu.parallel.engine import make_chunk_hash_step
-    from volsync_tpu.parallel.mesh import make_mesh, stream_sharding
-
-    mesh = make_mesh(jax.devices())  # GLOBAL mesh: spans both processes
-    wave, seq = mesh.devices.shape
-    block = 256
-    W, L = 2 * wave, seq * 4 * block
-    host = np.random.RandomState(5).randint(0, 256, size=(W, L),
-                                            dtype=np.uint8)
-    sharding = stream_sharding(mesh)
-    data = jax.make_array_from_callback(host.shape, sharding,
-                                        lambda idx: host[idx])
-    out = make_chunk_hash_step(mesh, block_len=block, bloom_log2=12)(data)
-    jax.block_until_ready(out)
-
-    # Stats are psum'd over the whole mesh — every process must see the
-    # GLOBAL totals (proves the cross-process collectives ran).
-    stats = {k: int(v) for k, v in out["stats"].items()}
-    assert stats["total_bytes"] == W * L, stats
-
-    # Verify THIS process's addressable digest shards against hashlib.
-    checked = 0
-    for shard in out["digests"].addressable_shards:
-        vals = np.asarray(shard.data)
-        w_slice, b_slice, _ = shard.index
-        for wi, w in enumerate(range(*w_slice.indices(W))):
-            for bi, b in enumerate(range(*b_slice.indices(L // block))):
-                want = hashlib.sha256(
-                    host[w, b * block:(b + 1) * block].tobytes()).digest()
-                got = vals[wi, bi].astype(">u4").tobytes()
-                assert got == want, f"digest mismatch at ({w},{b})"
-                checked += 1
-    assert checked > 0
-    print(f"MULTIHOST-OK p{info['process_index']}: mesh="
-          f"{dict(zip(mesh.axis_names, mesh.devices.shape))} "
-          f"verified={checked} stats={stats}", flush=True)
-    return 0
 
 
 def product_main(volume: str) -> int:
@@ -108,6 +59,6 @@ def product_main(volume: str) -> int:
 
 
 if __name__ == "__main__":
-    if len(sys.argv) > 1 and sys.argv[1] == "treebackup":
-        sys.exit(product_main(sys.argv[2]))
-    sys.exit(main())
+    if len(sys.argv) != 3 or sys.argv[1] != "treebackup":
+        sys.exit("usage: multihost_worker.py treebackup <volume>")
+    sys.exit(product_main(sys.argv[2]))

@@ -140,6 +140,32 @@ def test_parse_tree(tmp_path):
                       "--copy-method", "Bogus", "--dest-name", "d"])
 
 
+@pytest.mark.parametrize("verb", ["lint", "trace", "repair", "scrub",
+                                  "repack"])
+def test_a_delegated_verb_resolves(verb, monkeypatch, capsys):
+    """``run`` imports a delegated verb's module only when the verb is
+    called, so a module that has gone breaks nothing until then: call
+    each, by its cheapest no-op, before the operator's boot."""
+    import volsync_tpu.operator as operator_module
+    from volsync_tpu.cli.main import main
+
+    def boot(*_args, **_kw):
+        raise AssertionError("the verb booted the operator runtime")
+
+    monkeypatch.setattr(operator_module, "OperatorRuntime", boot)
+    with pytest.raises(SystemExit) as done:
+        main([verb, "--help"])
+    assert done.value.code == 0
+    assert f"usage: volsync {verb}" in capsys.readouterr().out
+
+
+def test_session_is_no_verb(capsys):
+    with pytest.raises(SystemExit) as refused:
+        run(["session", "status"], {})
+    assert refused.value.code == 2
+    assert "invalid choice: 'session'" in capsys.readouterr().err
+
+
 def test_relationship_files(tmp_path):
     rel = Relationship.create(tmp_path, "r1", TYPE_REPLICATION)
     rel.data["x"] = 1

@@ -1,10 +1,10 @@
 """Two-PROCESS execution of the sharded engine (the DCN-analogue path).
 
-The unit tier (tests/test_parallel.py) runs the mesh engine on one
-process's 8 virtual devices; this tier actually crosses a process
+The unit tier (tests/test_sharded_chunker.py) runs the mesh engine on
+one process's 8 virtual devices; this tier actually crosses a process
 boundary: two interpreters join a local coordinator through
-parallel/multihost.init_distributed, build one global (wave, seq) mesh,
-and the step's psum/ppermute collectives run over gloo between them —
+parallel/multihost.init_distributed, build one global ``seq`` mesh,
+and the program's psum/ppermute collectives run over gloo between them —
 the closest this container gets to the reference's multi-node NCCL/MPI
 backend (SURVEY §2.3) without real multi-chip hardware.
 """
@@ -64,16 +64,6 @@ def _run_pair(argv_tail, extra_env=None, timeout=300):
             raise AssertionError(f"multihost worker hung:\n{err[-800:]}")
         results.append((p.returncode, out, err))
     return results
-
-
-@pytest.mark.slow
-def test_two_process_sharded_step():
-    results = _run_pair([])
-    for i, (rc, out, err) in enumerate(results):
-        assert rc == 0, f"worker {i} rc={rc}\n{err[-1200:]}"
-        assert f"MULTIHOST-OK p{i}" in out, out
-    # both processes saw the same global mesh and verified digests
-    assert "verified=" in results[0][1] and "verified=" in results[1][1]
 
 
 @pytest.mark.slow

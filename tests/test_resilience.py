@@ -14,6 +14,7 @@ from volsync_tpu.objstore.faultstore import (
     FaultSpec,
     FaultStore,
     InjectedCrash,
+    InjectedHang,
     InjectedPartition,
     InjectedThrottle,
     default_specs,
@@ -726,6 +727,37 @@ def test_fault_latency_sleeps(monkeypatch):
     fs.put("k", b"v")
     assert slept == [0.005]
     assert fs.get("k") == b"v"
+
+
+def test_faultstore_hang_blocks_then_raises_retryable():
+    """The ``hang`` kind consumes the caller's patience on the injected
+    sleep before surfacing as a retryable drop."""
+    slept = []
+    fs = FaultStore(
+        MemObjectStore(),
+        FaultSchedule(seed=3, specs=[
+            FaultSpec(kind="hang", at=1, op="get", key_prefix="data/",
+                      latency=120.0)]),
+        sleep_fn=slept.append)
+    fs.put("data/a", b"payload")
+    with pytest.raises(InjectedHang):
+        fs.get("data/a")
+    assert slept == [120.0]
+    assert classify(InjectedHang("x")) is True   # retryable
+    assert fs.get("data/a") == b"payload"        # once only (at=1)
+
+
+def test_faultstore_hang_default_duration():
+    slept = []
+    fs = FaultStore(
+        MemObjectStore(),
+        FaultSchedule(seed=3, specs=[
+            FaultSpec(kind="hang", at=1, op="put")]),
+        sleep_fn=slept.append)
+    with pytest.raises(InjectedHang):
+        fs.put("k", b"v")
+    assert slept == [60.0]          # _HANG_DEFAULT_S
+    assert fs.exists("k") is False  # the op never landed
 
 
 def test_resilient_over_faultstore_masks_transients():

@@ -258,3 +258,14 @@ def test_fused_mesh_capacity_retry(rng):
     finally:
         seg.segment_caps = real_caps
     assert out_tiny == out_normal
+
+
+@pytest.mark.slow
+def test_graft_entry_dryrun():
+    import jax
+
+    import __graft_entry__ as ge
+
+    fn, args = ge.entry()
+    jax.jit(fn).lower(*args)  # compiles
+    ge.dryrun_multichip(8)

@@ -8,8 +8,9 @@ tenant + stream id tags and the stage breakdown covering the measured
 p50), the `volsync trace` CLI, and the tracing-disabled overhead gate; self
 time and counters, the dispatch thread's spans in the ring under the
 submitter's trace, the ring's compact events and its eviction count,
-the names the segment program carries onto the device, and the
-program-load totals.
+the names the segment program carries onto the device, the
+program-load totals, and one trace across the seal and upload threads
+of a pipelined backup.
 """
 
 import glob
@@ -397,6 +398,68 @@ def test_chrome_trace_shape_and_dump_trace(tmp_path):
     assert json.loads(Path(path).read_text())["traceEvents"]
     # no path + no dump dir -> None, no file side effects
     assert dump_trace() is None
+
+
+def test_one_trace_spans_the_seal_and_upload_threads(tmp_path):
+    """A pipelined backup under one tenant-tagged context: the dump is
+    what Perfetto loads, and the ``repo.*`` spans the seal and upload
+    threads record carry the caller's trace id and tenant, a parent
+    edge leading from each to the caller's outer span."""
+    import numpy as np
+
+    from volsync_tpu.engine.chunker import (
+        DeviceChunkHasher, stream_chunk_batches)
+    from volsync_tpu.objstore.store import MemObjectStore
+    from volsync_tpu.ops.gearcdc import GearParams
+    from volsync_tpu.repo.repository import Repository
+
+    data = np.random.RandomState(3).randint(
+        0, 256, size=(2 << 20,), dtype=np.uint8).tobytes()
+    params = GearParams(min_size=64 * 1024, avg_size=128 * 1024,
+                        max_size=256 * 1024, seed=7, align=4096)
+    pos = [0]
+
+    def reader(nbytes: int) -> bytes:
+        piece = data[pos[0]: pos[0] + nbytes]
+        pos[0] += len(piece)
+        return piece
+
+    repo = Repository.init(MemObjectStore())
+    repo.pipelined = True
+    with trace_context(tenant="smoke", stream_id="pipeline") as root:
+        with span("smoke.pipeline"):
+            for chunks in stream_chunk_batches(
+                    reader, params, segment_size=512 * 1024,
+                    hasher=DeviceChunkHasher(params), readahead=2):
+                repo.add_blobs(
+                    "data", [(digest, chunk) for chunk, digest in chunks])
+            repo.flush()
+
+    path = dump_trace(path=str(tmp_path / "trace.json"), trigger="smoke")
+    doc = json.loads(Path(path).read_text())
+    assert doc["trigger"]["reason"] == "smoke"
+    events = doc["traceEvents"]
+    assert isinstance(events, list)
+    assert any(e["ph"] == "M" and e["name"] == "thread_name"
+               for e in events)
+    spans = [e for e in events if e["ph"] == "X"]
+    for e in spans:
+        assert {"name", "ts", "dur", "pid", "tid", "args"} <= set(e), e
+    by_id = {e["args"]["span_id"]: e for e in spans}
+    (outer,) = [e for e in spans if e["name"] == "smoke.pipeline"]
+    assert {"engine.read", "engine.device", "repo.seal",
+            "repo.pack_upload"} <= {e["name"] for e in spans}
+    elsewhere = [e for e in spans if e["name"].startswith("repo.")
+                 and e["tid"] != outer["tid"]]
+    assert {"repo.seal", "repo.pack_upload"} <= {
+        e["name"] for e in elsewhere}
+    for e in elsewhere:
+        assert e["args"]["trace_id"] == root.trace_id, e
+        assert e["args"]["tenant"] == "smoke", e
+        up = e
+        while up is not outer:
+            up = by_id.get(up["args"].get("parent_span_id"))
+            assert up is not None, f"no way up from {e['name']}"
 
 
 # -- self time and counters -----------------------------------------------

@@ -335,8 +335,9 @@ def test_backup_file_is_its_children_and_its_self_time(tree, monkeypatch):
                 and e["args"]["parent_span_id"] == f["args"]["span_id"]}
         if f["args"]["path"] == "host":
             # once a file and well under a millisecond: totals, and no
-            # events to push the rest out of the ring
-            assert not kids
+            # events to push the rest out of the ring; a full seal
+            # queue under load is a wait, which is an event
+            assert kids <= {"repo.seal_wait"}
         else:
             assert {"backup.open", "engine.read_wait", "engine.device",
                     "repo.add"} <= kids

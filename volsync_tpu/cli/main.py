@@ -102,10 +102,6 @@ def build_parser() -> argparse.ArgumentParser:
         help="span flight recorder: dump Chrome-trace JSON / summary "
              "(volsync_tpu.obs)")
     sub.add_parser(
-        "session", add_help=False,
-        help="supervised accelerator sessions: serialized bench jobs, "
-             "status, forced recycle (volsync_tpu.cluster.sessioncli)")
-    sub.add_parser(
         "repair", add_help=False,
         help="repository recovery: orphaned packs, expired "
              "pending-deletes, dangling index entries "
@@ -134,10 +130,6 @@ def run(argv, contexts: dict, out=print) -> int:
         from volsync_tpu.obs.cli import main as trace_main
 
         return trace_main(list(argv[1:]), out=out)
-    if argv and argv[0] == "session":
-        from volsync_tpu.cluster.sessioncli import main as session_main
-
-        return session_main(list(argv[1:]), out=out)
     if argv and argv[0] == "repair":
         from volsync_tpu.cli.repair import main as repair_main
 
@@ -195,17 +187,14 @@ def run(argv, contexts: dict, out=print) -> int:
 def main(argv=None) -> int:
     """Demo-mode entry: boot a full in-process stack as the 'default'
     context (the operator's packaged entry point wires real state).
-    ``volsync lint`` / ``volsync trace`` / ``volsync session`` /
-    ``volsync repair`` / ``volsync scrub`` / ``volsync repack`` never
-    need the runtime —
+    ``volsync lint`` / ``volsync trace`` / ``volsync repair`` /
+    ``volsync scrub`` / ``volsync repack`` never need the runtime —
     dispatch them before the boot so the linter runs in CI containers
     with no cluster state, the flight recorder is readable from a
-    half-broken process, ``session status`` works on a host whose
-    accelerator is held by a stuck process, and repair/scrub can run against a
+    half-broken process, and repair/scrub can run against a
     store whose operator stack is exactly what crashed."""
     argv = argv if argv is not None else sys.argv[1:]
-    if argv and argv[0] in ("lint", "trace", "session", "repair",
-                            "scrub", "repack"):
+    if argv and argv[0] in ("lint", "trace", "repair", "scrub", "repack"):
         return run(argv, {})
     from volsync_tpu.operator import OperatorRuntime
 

@@ -18,10 +18,6 @@ package provides the equivalent substrate natively:
 - ``runner``    — the kubelet analogue: executes Job/Deployment payloads
                   from a registered entrypoint catalog in worker threads.
                   Optional — envtest-style tests flip Job status manually.
-- ``sessions``  — supervised accelerator sessions: TTL leases with
-                  keepalive, the ACQUIRING/HEALTHY/DEGRADED/RECYCLING
-                  supervisor with fencing epochs, and the serialized
-                  verify-then-measure bench queue (docs/sessions.md).
 """
 
 from volsync_tpu.cluster.objects import (
@@ -48,16 +44,6 @@ from volsync_tpu.cluster.objects import (
 from volsync_tpu.cluster.cluster import Cluster, NotFound, Conflict
 from volsync_tpu.cluster.storage import StorageProvider
 from volsync_tpu.cluster.runner import JobRunner, EntrypointCatalog
-from volsync_tpu.cluster.sessions import (
-    BenchQueue,
-    FakeSessionBackend,
-    FencedError,
-    JaxSessionBackend,
-    Lease,
-    SessionBusy,
-    SessionError,
-    SessionSupervisor,
-)
 
 __all__ = [
     "Volume",
@@ -85,12 +71,4 @@ __all__ = [
     "StorageProvider",
     "JobRunner",
     "EntrypointCatalog",
-    "BenchQueue",
-    "FakeSessionBackend",
-    "FencedError",
-    "JaxSessionBackend",
-    "Lease",
-    "SessionBusy",
-    "SessionError",
-    "SessionSupervisor",
 ]

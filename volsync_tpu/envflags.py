@@ -480,45 +480,6 @@ def prune_grace_seconds() -> Optional[float]:
         return None
 
 
-# -- supervised accelerator sessions (cluster/sessions.py) ----------------
-
-def session_ttl_seconds() -> float:
-    """VOLSYNC_SESSION_TTL_S: hard lease TTL — a session whose keepalive
-    has not succeeded for this long is recycled no matter what (the
-    8-hour wedge of rounds 4/5 becomes a bounded outage)."""
-    return env_float("VOLSYNC_SESSION_TTL_S", 900.0, minimum=1.0)
-
-
-def session_keepalive_seconds() -> float:
-    """VOLSYNC_SESSION_KEEPALIVE_S: interval between keepalive beats."""
-    return env_float("VOLSYNC_SESSION_KEEPALIVE_S", 30.0, minimum=0.1)
-
-
-def session_keepalive_failures() -> int:
-    """VOLSYNC_SESSION_KEEPALIVE_FAILS: consecutive keepalive failures
-    before the supervisor force-recycles the session."""
-    return env_int("VOLSYNC_SESSION_KEEPALIVE_FAILS", 3, minimum=1)
-
-
-def session_probe_timeout() -> float:
-    """VOLSYNC_SESSION_PROBE_TIMEOUT_S: verify-probe budget; a probe
-    that exceeds it counts as a wedged backend and triggers a recycle."""
-    return env_float("VOLSYNC_SESSION_PROBE_TIMEOUT_S", 300.0, minimum=1.0)
-
-
-def session_job_deadline() -> float:
-    """VOLSYNC_SESSION_JOB_DEADLINE_S: per-job hard deadline in the
-    serialized bench queue — a job is killed at this wall-clock bound,
-    never allowed to hold the single-tenant device open-endedly."""
-    return env_float("VOLSYNC_SESSION_JOB_DEADLINE_S", 1800.0, minimum=1.0)
-
-
-def session_status_path() -> Optional[str]:
-    """VOLSYNC_SESSION_STATUS: file where the supervisor mirrors its
-    state for observers (``volsync session status``); None = no mirror."""
-    return env_str("VOLSYNC_SESSION_STATUS")
-
-
 # -- sync-protocol planner knobs (engine/protoplan.py, syncstats.py) ------
 
 def sync_protocol() -> str:

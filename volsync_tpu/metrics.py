@@ -215,37 +215,6 @@ class Metrics:
             "fenced by a stale-lock takeover",
             registry=self.registry,
         )
-        # Supervised accelerator sessions (cluster/sessions.py):
-        # state machine position per backend (0=acquiring, 1=healthy,
-        # 2=degraded, 3=recycling), transition/recycle counts by cause,
-        # keepalive outcomes, and writes refused by fencing.
-        self.session_state = Gauge(
-            "volsync_session_state",
-            "Supervised session state per backend "
-            "(0=acquiring, 1=healthy, 2=degraded, 3=recycling)",
-            ["backend"], registry=self.registry,
-        )
-        self.session_transitions = Counter(
-            "volsync_session_transitions_total",
-            "Supervised session state transitions per backend",
-            ["backend", "to"], registry=self.registry,
-        )
-        self.session_recycles = Counter(
-            "volsync_session_recycles_total",
-            "Forced session recycles per backend, by cause",
-            ["backend", "cause"], registry=self.registry,
-        )
-        self.session_keepalives = Counter(
-            "volsync_session_keepalive_total",
-            "Session keepalive beats per backend, by outcome",
-            ["backend", "outcome"], registry=self.registry,
-        )
-        self.session_fenced_writes = Counter(
-            "volsync_session_fenced_writes_total",
-            "Results refused because the producing session's fencing "
-            "epoch was stale",
-            ["backend"], registry=self.registry,
-        )
         # Fleet replica plane (service/fleet.py): per-replica advertised
         # headroom from the last heartbeat stamp the router read, where
         # the router sent each admitted stream, and how many streams

@@ -137,17 +137,6 @@ class GearParams:
     def mask_l(self) -> int:
         return _top_mask(self.eff_bits - self.norm_level)
 
-    @property
-    def dense_mask_s(self) -> int:
-        """Strict mask for PER-POSITION evaluation (no align discount) —
-        what consumers applying the mask at every byte must use, e.g. the
-        (wave, seq) batch step in parallel/engine.py."""
-        return _top_mask(self.bits + self.norm_level)
-
-    @property
-    def dense_mask_l(self) -> int:
-        return _top_mask(self.bits - self.norm_level)
-
     @functools.cached_property
     def table(self) -> np.ndarray:
         return _make_gear_table(self.seed)

@@ -1,31 +1,9 @@
-"""Mesh-parallel data plane: (wave, seq) sharding of the chunk+hash engine.
-
-The scaling story of the framework (SURVEY.md §2.3): concurrent
-relationships batch over the ``wave`` mesh axis, a single volume's bytes
-shard over the ``seq`` axis with ppermute halo exchange, and dedup state is
-unioned with psum collectives — ICI-resident, no host round-trips.
+"""Mesh-parallel data plane: a single volume's bytes shard over the
+``seq`` ring of devices with ppermute halo exchange at the seams
+(parallel/sharded_chunker.py), within one host or, after
+``multihost.init_distributed``, across hosts.
 """
 
-from volsync_tpu.parallel.mesh import (
-    SEQ_AXIS,
-    WAVE_AXIS,
-    make_mesh,
-    replicated,
-    stream_sharding,
-)
-from volsync_tpu.parallel.engine import (
-    chunk_hash_block,
-    make_chunk_hash_step,
-    sha256_fixed_blocks,
-)
+from volsync_tpu.parallel.mesh import SEQ, make_stream_mesh
 
-__all__ = [
-    "SEQ_AXIS",
-    "WAVE_AXIS",
-    "make_mesh",
-    "replicated",
-    "stream_sharding",
-    "chunk_hash_block",
-    "make_chunk_hash_step",
-    "sha256_fixed_blocks",
-]
+__all__ = ["SEQ", "make_stream_mesh"]

@@ -1,65 +1,28 @@
-"""Device-mesh construction for the data plane.
+"""The device mesh of the data plane: one ``seq`` ring.
 
-The reference scales by running up to 100 concurrent mover pods
-(controllers/replicationsource_controller.go:145 MaxConcurrentReconciles)
-and has *no* intra-volume parallel scan (SURVEY.md §5 long-context note).
-The TPU design replaces both with a 2-D mesh:
+The reference has *no* intra-volume parallel scan (SURVEY.md §5
+long-context note); here a single volume's byte stream shards across
+every chip of the ring (parallel/sharded_chunker.py). Chunk-boundary
+continuity across a seam is a ``ppermute`` halo along ``seq``; the
+candidate tables and digests are gathered with ``all_gather`` / ``psum``
+over it.
 
-- ``wave`` axis — batches independent replication relationships (the
-  data-parallel analogue of concurrent mover pods).
-- ``seq`` axis — shards a single volume's byte stream across chips (the
-  sequence/context-parallel analogue; the reference simply has nothing
-  here, which is where the performance win comes from).
-
-Collectives ride this mesh: halo exchange for chunk-boundary continuity is
-a ``ppermute`` along ``seq``; dedup statistics are ``psum`` over both axes.
+The analyzer's VL205 checks every ``PartitionSpec`` entry and collective
+axis name in the package against the axes this module declares.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Sequence
-
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+import numpy as np
+from jax.sharding import Mesh
 
-WAVE_AXIS = "wave"
-SEQ_AXIS = "seq"
-
-
-def _factor_2d(n: int) -> tuple[int, int]:
-    """Split n devices into (wave, seq) as square as possible, seq-major
-    (a longer seq axis gives more intra-volume sharding, which is the
-    scarce resource; wave concurrency can also come from host batching)."""
-    best = (1, n)
-    f = 1
-    while f * f <= n:
-        if n % f == 0:
-            best = (f, n // f)
-        f += 1
-    return best
+SEQ = "seq"
 
 
-def make_mesh(devices: Optional[Sequence] = None,
-              shape: Optional[tuple[int, int]] = None) -> Mesh:
-    """Build the (wave, seq) mesh over ``devices`` (default: all)."""
+def make_stream_mesh(devices=None) -> Mesh:
+    """All devices as one ``seq`` ring: one big backup wants the whole
+    machine."""
     if devices is None:
         devices = jax.devices()
-    n = len(devices)
-    if shape is None:
-        shape = _factor_2d(n)
-    wave, seq = shape
-    if wave * seq != n:
-        raise ValueError(f"mesh shape {shape} != {n} devices")
-    import numpy as np
-
-    dev_array = np.asarray(devices).reshape(wave, seq)
-    return Mesh(dev_array, (WAVE_AXIS, SEQ_AXIS))
-
-
-def stream_sharding(mesh: Mesh) -> NamedSharding:
-    """Sharding for a [W, L] batch of byte streams: W over wave, L over seq."""
-    return NamedSharding(mesh, P(WAVE_AXIS, SEQ_AXIS))
-
-
-def replicated(mesh: Mesh) -> NamedSharding:
-    return NamedSharding(mesh, P())
+    return Mesh(np.asarray(devices), (SEQ,))

@@ -10,10 +10,10 @@ hosts, so ONE volume's scan can shard over an entire pod slice.
 
 ``init_distributed()`` wires ``jax.distributed`` from the standard TPU
 pod environment (or explicit arguments), after which ``jax.devices()``
-returns every chip in the slice and the existing mesh builders
-(parallel/mesh.make_mesh, sharded_chunker.make_stream_mesh) span hosts
-transparently. The fused sharded engine's only collectives are an
-all-gather of the 32B-per-4KiB digest stream and the candidate tables
+returns every chip in the slice and the mesh builder
+(parallel/mesh.make_stream_mesh) spans hosts transparently. The fused
+sharded engine's only collectives are an all-gather of the
+32B-per-4KiB digest stream and the candidate tables
 (sharded_chunker._build_fused_fn) — XLA routes them over ICI within a
 host and DCN between hosts; no framework code changes.
 

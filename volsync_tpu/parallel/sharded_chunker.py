@@ -37,23 +37,11 @@ import numpy as np
 from volsync_tpu.engine.chunker import PendingSegment, _pow2ceil
 from volsync_tpu.obs import count, record_copy, span
 from volsync_tpu.ops.gearcdc import GearParams, _mix_u32, select_boundaries
+from volsync_tpu.parallel.mesh import SEQ, make_stream_mesh
 from volsync_tpu.repo import blobid
 
-_HALO = 31              # gear window context (see parallel/engine.py)
+_HALO = 31              # gear window is 32 bytes -> 31 bytes of left context
 _LEAF = blobid.LEAF_SIZE
-SEQ = "seq"
-
-
-def make_stream_mesh(devices=None):
-    """All devices as one ``seq`` ring — a single volume's byte stream
-    shards across every chip (the wave axis of parallel/mesh.py batches
-    *independent* streams; one big backup wants the whole machine)."""
-    import jax
-    from jax.sharding import Mesh
-
-    if devices is None:
-        devices = jax.devices()
-    return Mesh(np.asarray(devices), (SEQ,))
 
 
 class MeshChunkHasher:
@@ -505,13 +493,25 @@ def _build_fused_fn(mesh, params: GearParams, shard_len: int,
     return jax.jit(mesh_fused_segment)
 
 
+def _gear_doubling(g):
+    """The 5 shift-scale-add passes turning per-byte table values ([L]
+    uint32) into the 32-byte-window gear hash (see ops/gearcdc.py
+    ``gear_hash_positions``, which starts from the bytes and so cannot
+    take a halo whose table values were zeroed)."""
+    import jax.numpy as jnp
+
+    h = g
+    for m in (1, 2, 4, 8, 16):
+        shifted = jnp.pad(h[:-m], (m, 0))
+        h = h + (shifted << np.uint32(m))
+    return h
+
+
 def _build_cand_fn(mesh, params: GearParams, shard_len: int, cap: int):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from jax import shard_map
-
-    from volsync_tpu.parallel.engine import _gear_doubling
 
     seed = np.uint32(params.seed & 0xFFFFFFFF)
     mask_s = np.uint32(params.mask_s)
@@ -523,8 +523,9 @@ def _build_cand_fn(mesh, params: GearParams, shard_len: int, cap: int):
         row = data[0]
         # Left halo: previous shard's 31-byte tail, shifted right around
         # the ring; shard 0 (true stream start) contributes zero table
-        # values for its halo positions, reproducing the unsharded
-        # recurrence's h=0 start (see parallel/engine.py local_step).
+        # values for its halo positions, because the unsharded
+        # recurrence starts from h=0 (zeroing the halo BYTES would
+        # still contribute _mix_u32(seed) a position).
         halo = jax.lax.ppermute(
             row[-_HALO:], SEQ, [(j, (j + 1) % n) for j in range(n)])
         ext = jnp.concatenate([halo, row])

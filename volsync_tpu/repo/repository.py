@@ -156,8 +156,8 @@ class UploadError(RepoError):
 
 class StaleWriterError(RepoError):
     """This writer was fenced by a peer's stale-lock takeover; its index
-    and snapshot publishes are refused (the fence-first recycle order
-    from cluster/sessions.py applied to repository writers)."""
+    and snapshot publishes are refused (fence first: the takeover
+    marks the victim fenced before it deletes the victim's lock)."""
 
 
 class _IndexReloadRace(RuntimeError):
@@ -546,9 +546,9 @@ class Repository:
         if not self.store.exists(key):
             self.store.delete(marker_key)
             return False
-        # Fence FIRST (cluster/sessions.py recycle order): by the time
-        # the victim could observe its lock missing, its publishes are
-        # already refused. Reclaiming one's OWN stale lock (a stalled
+        # Fence FIRST, release second: by the time the victim could
+        # observe its lock missing, its publishes are already
+        # refused. Reclaiming one's OWN stale lock (a stalled
         # but living writer) must not self-fence — same process, no
         # split brain to guard against.
         victim = info.get("writer", "")

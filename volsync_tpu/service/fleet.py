@@ -8,8 +8,9 @@ module is that composition:
 
 - :class:`ReplicaStamp` / :class:`ReplicaHeartbeat` — each replica
   publishes a small heartbeat record at ``fleet/<replica-id>`` in the
-  shared object store (the lease/TTL idiom of cluster/sessions.py,
-  with the store as the bulletin board): address, admission headroom,
+  shared object store (a lease that silence expires: whoever stops
+  beating is presumed dead after a TTL, with the store as the
+  bulletin board): address, admission headroom,
   scheduler backlog, writer id + generation, beat seq, wall-clock
   stamp. A stamp older than VOLSYNC_FLEET_TTL_S is a presumed-dead
   replica; ``volsync repair`` clears stamps past the lock-stale
