@@ -101,6 +101,21 @@ def test_every_listed_exception_still_exists():
     assert not gone, f"repaired and still listed in EXCEPTIONS: {gone}"
 
 
+def test_the_service_client_imports_no_server_and_no_engine():
+    """A mover on a CPU node links against ``service/client.py``: the
+    wire's names come from the leaf ``service/wire.py``, not from the
+    server, whose import loads the batcher, the scheduler and the
+    device programs."""
+    for name in ("client.py", "wire.py"):
+        wrong = [f"{name}:{line} imports {module}"
+                 for module, _name, line in _package_imports(
+                     PKG / "service" / name)
+                 if module.split(".")[1:2] in (["ops"], ["engine"])
+                 or module == "volsync_tpu.service.server"]
+        assert not wrong, wrong
+    assert not list(_package_imports(PKG / "service" / "wire.py"))
+
+
 def test_the_cluster_package_loads_no_fault_injector():
     """Every mover entry and every cell imports
     ``volsync_tpu.cluster.runner``; the store's fault injector is for

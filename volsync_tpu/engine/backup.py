@@ -427,21 +427,35 @@ class TreeBackup:
             # overlaps the device hashing of segment N (open() fallback).
             content = []
             hashed = 0
-            with span("backup.open"):
-                reader_cm = self._open_stream(path)
-            with reader_cm as reader:
-                for batch in stream_chunk_batches(reader.read, self.params,
-                                                  hasher=self.hasher,
-                                                  size_hint=st.st_size):
-                    # one batched dedup query + one lock acquisition
-                    # per device segment, not per chunk
-                    self.repo.add_blobs(
-                        BLOB_DATA,
-                        [(digest, chunk) for chunk, digest in batch],
-                        stats)
-                    for chunk, digest in batch:
-                        content.append(digest)
-                        hashed += len(chunk)
+
+            def add(batch):
+                # one batched dedup query + one lock acquisition
+                # per device segment, not per chunk
+                nonlocal hashed
+                self.repo.add_blobs(
+                    BLOB_DATA,
+                    [(digest, chunk) for chunk, digest in batch],
+                    stats)
+                for chunk, digest in batch:
+                    content.append(digest)
+                    hashed += len(chunk)
+
+            def open_reader():
+                with span("backup.open"):
+                    return self._open_stream(path)
+
+            # a hasher that is a client of the mover-jax service
+            # (service/hasher.py) takes the file whole, one stream of
+            # it, and answers in the same batches
+            hash_file = getattr(self.hasher, "hash_file", None)
+            if hash_file is not None:
+                hash_file(open_reader, add)
+            else:
+                with open_reader() as reader:
+                    for batch in stream_chunk_batches(
+                            reader.read, self.params, hasher=self.hasher,
+                            size_hint=st.st_size):
+                        add(batch)
         try:
             mtime_ns = path.lstat().st_mtime_ns
         except OSError:  # deleted mid-backup: keep the walk-time stamp

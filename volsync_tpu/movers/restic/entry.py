@@ -123,16 +123,36 @@ def mesh_hasher(params):
     return hasher
 
 
-def _select_hasher(env: dict, repo: Repository):
+#: Mover exit code for "the mover-jax service did not hash the volume"
+#: (unreachable, wrong token, other chunker parameters, an answer that
+#: does not cover a file, the retry policy exhausted): nonzero, no
+#: snapshot saved, the Job's backoff retries the sync.
+RC_SERVICE = 5
+
+#: what VOLSYNC_ENGINE=service needs beside it, and cannot guess
+_SERVICE_ENV = ("MOVER_JAX_ADDRESS", "MOVER_JAX_TOKEN")
+
+
+def _select_hasher(env: dict, repo: Repository, namespace: str):
     """VOLSYNC_ENGINE=mesh shards the scan over the device mesh
-    (parallel/sharded_chunker.py); default is the single-chip engine.
-    Both produce bit-identical snapshots, so the switch is purely a
-    throughput/topology choice."""
-    if env.get("VOLSYNC_ENGINE", "").lower() != "mesh":
+    (parallel/sharded_chunker.py); VOLSYNC_ENGINE=service hashes
+    through the mover-jax service at MOVER_JAX_ADDRESS, as tenant
+    MOVER_JAX_TENANT (default: the mover's namespace), in a mover that
+    holds no accelerator (service/hasher.py); default is the
+    single-chip engine. All produce bit-identical snapshots, so the
+    switch is purely a throughput/topology choice."""
+    engine = env.get("VOLSYNC_ENGINE", "").lower()
+    if engine not in ("mesh", "service"):
         return None
     from volsync_tpu.engine.chunker import params_from_config
 
-    return mesh_hasher(params_from_config(repo.chunker_params))
+    params = params_from_config(repo.chunker_params)
+    if engine == "mesh":
+        return mesh_hasher(params)
+    from volsync_tpu.service.hasher import open_hasher
+
+    return open_hasher(env["MOVER_JAX_ADDRESS"], env["MOVER_JAX_TOKEN"],
+                       env.get("MOVER_JAX_TENANT") or namespace, params)
 
 
 def restic_entrypoint(ctx) -> int:
@@ -142,8 +162,27 @@ def restic_entrypoint(ctx) -> int:
         if required not in env:
             log.error("missing env %s (entry.sh:232-240)", required)
             return 2
+    service_errors: tuple = ()
+    if (direction == "backup"
+            and env.get("VOLSYNC_ENGINE", "").lower() == "service"):
+        # never a silent fall back to a local device
+        for required in _SERVICE_ENV:
+            if not env.get(required):
+                log.error("VOLSYNC_ENGINE=service without %s", required)
+                return 2
+        host, _, port = env["MOVER_JAX_ADDRESS"].rpartition(":")
+        if not host or not port.isdigit():
+            log.error("MOVER_JAX_ADDRESS %r is not host:port",
+                      env["MOVER_JAX_ADDRESS"])
+            return 2
+        from volsync_tpu.service.hasher import ServiceHashError
+
+        service_errors = (ServiceHashError,)
     try:
         return _dispatch(ctx, env, direction)
+    except service_errors as ex:
+        log.error("backup failed, no snapshot saved: %s", ex)
+        return RC_SERVICE
     except RepoLockedError as ex:
         # Two CRs sharing one repository collide (shared backup vs
         # exclusive forget/prune): fail this attempt cleanly and let the
@@ -171,11 +210,16 @@ def _dispatch(ctx, env: dict, direction: str) -> int:
         proto = normalize_protocol(env.get("SYNC_PROTOCOL"), default="cdc")
         if proto == "delta":
             proto = "cdc"
-        with span("mover.restic.backup"):
-            snap_id, stats = TreeBackup(
-                repo, hasher=_select_hasher(env, repo),
-                protocol=proto).run(
-                data, hostname=env.get("HOSTNAME", "volsync"))
+        hasher = _select_hasher(env, repo, ctx.namespace)
+        try:
+            with span("mover.restic.backup"):
+                snap_id, stats = TreeBackup(
+                    repo, hasher=hasher, protocol=proto).run(
+                    data, hostname=env.get("HOSTNAME", "volsync"))
+        finally:
+            close = getattr(hasher, "close", None)
+            if close is not None:
+                close()
         log.info("backup snapshot=%s stats=%s", snap_id, stats.as_dict())
         ctx.report_transfer(stats.bytes_scanned, time.perf_counter() - t0)
         # Maintenance after a durable snapshot must not fail the sync: a

@@ -387,6 +387,15 @@ def count(name: str, n: int = 1) -> None:
         _counters[name] += n
 
 
+def count_max(name: str, n: int) -> None:
+    """Keep the largest ``n`` seen under a counter's name: a gauge's
+    high-water (bytes held, a queue's depth) beside the counters, read
+    and zeroed with them."""
+    with _lock:
+        if n > _counters[name]:
+            _counters[name] = n
+
+
 def span_totals(by_outcome: bool = False) -> dict:
     """``{stage: (count, total seconds)}`` — inspection/tests/CLI.
     With ``by_outcome=True``: ``{(stage, outcome): (count, seconds)}``

@@ -15,6 +15,7 @@ import queue
 import threading
 from concurrent.futures import Future
 from concurrent.futures import wait as futures_wait
+from typing import Optional
 
 from volsync_tpu import envflags
 from volsync_tpu.analysis import lockcheck
@@ -78,10 +79,14 @@ class SegmentMicroBatcher:
     is always resolved, never stranded."""
 
     def __init__(self, params: GearParams, *, max_batch: int = 16,
-                 window_ms: float = 2.0, pipeline_depth: int = 2):
+                 window_ms: float = 2.0, pipeline_depth: int = 2,
+                 stage_limit: Optional[int] = None):
         from volsync_tpu.ops.segment import BatchedSegmentHasher
 
-        self._hasher = BatchedSegmentHasher(params)
+        # ``stage_limit``: the most bytes one coalesced dispatch stages
+        # (BatchedSegmentHasher); None, as the engine's shared batcher
+        # has it, coalesces whatever the window collected
+        self._hasher = BatchedSegmentHasher(params, stage_limit)
         self._q: queue.Queue = queue.Queue()
         self._max_batch = max_batch
         self._window = window_ms / 1000.0

@@ -46,6 +46,7 @@ inputs only). eof is a static arg (two compiled variants per shape).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -849,6 +850,18 @@ class FusedSegmentHasher:
                 chunk_cap=chunk_cap)
 
 
+def coalesced_lanes(bucket: int, stage_limit: int) -> int:
+    """The most same-bucket segments one dispatch takes under
+    ``stage_limit``: the largest power of two of them whose staged rows
+    (lanes x bucket) stay within the limit, and one where even two do
+    not. A plan of the (lanes, bucket) programs a producer can meet
+    asks this (``benchmark/warm_fleet.py``)."""
+    lanes = 1
+    while lanes * 2 * bucket <= stage_limit:
+        lanes *= 2
+    return lanes
+
+
 class BatchedSegmentHasher:
     """Host driver for ``chunk_hash_segments``: many independent
     streams' segments in one dispatch + one fetch (the cross-PVC batch
@@ -863,12 +876,19 @@ class BatchedSegmentHasher:
     device as it is, uncopied. Lanes whose true counts overflow
     the compiled capacities retry INDIVIDUALLY through the
     single-segment path (adversarial data only — the batch result for
-    the other lanes is already in hand)."""
+    the other lanes is already in hand).
 
-    def __init__(self, params: GearParams):
+    ``stage_limit`` bounds what one dispatch stages: same-bucket lanes
+    go to the device :func:`coalesced_lanes` at a time, so a large
+    bucket meets the program at few lane counts (one, from half the
+    limit up) and a small one at all of them. None: no bound."""
+
+    def __init__(self, params: GearParams,
+                 stage_limit: Optional[int] = None):
         assert params.align == LEAF_SIZE, \
             "batched path requires the page-aligned cut format"
         self.params = params
+        self.stage_limit = stage_limit
         self._single = FusedSegmentHasher(params)
 
     def hash_segments(self, items) -> list:
@@ -884,10 +904,13 @@ class BatchedSegmentHasher:
                               []).append(i)
         out: list = [None] * len(items)
         for P, idxs in groups.items():
-            for i, res in zip(idxs,
-                              self._hash_bucket(P,
-                                                [items[i] for i in idxs])):
-                out[i] = res
+            per = (len(idxs) if self.stage_limit is None
+                   else coalesced_lanes(P, self.stage_limit))
+            for k in range(0, len(idxs), per):
+                part = idxs[k: k + per]
+                for i, res in zip(part, self._hash_bucket(
+                        P, [items[i] for i in part])):
+                    out[i] = res
         return out
 
     def _hash_bucket(self, P: int, items) -> list:

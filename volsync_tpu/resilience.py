@@ -243,7 +243,9 @@ class RetryPolicy:
                 if not retryable or exhausted:
                     raise
                 last = exc
-                delay = next(delays)
+                # a throttle that says how long to stay away is heard
+                delay = max(next(delays),
+                            getattr(exc, "retry_after", None) or 0.0)
                 elapsed = time.monotonic() - t0
                 if (self.deadline is not None
                         and elapsed + delay > self.deadline):

@@ -89,6 +89,10 @@ class StreamTicket:
     #: relative queue-wait deadline (seconds) from the stream's
     #: deadline class; None = no deadline (pure WDRR)
     deadline: Optional[float] = None
+    #: the stream's bytes and the segments they were cut into, for the
+    #: once-a-stream counters (svc.stream_bytes, svc.segments)
+    stream_bytes: int = 0
+    segments: int = 0
     _released: bool = field(default=False, repr=False)
 
 

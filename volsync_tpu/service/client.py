@@ -17,7 +17,7 @@ opaque RPC failure.
 
 from __future__ import annotations
 
-from typing import Callable, Iterator, Optional
+from typing import Callable, Iterable, Iterator, Optional
 
 import grpc
 
@@ -25,7 +25,7 @@ from volsync_tpu.obs import (begin_span, format_trace_header, new_id,
                              new_trace, record_copy)
 from volsync_tpu.resilience import RetryPolicy, ThrottleError
 from volsync_tpu.service import moverjax_pb2 as pb
-from volsync_tpu.service.server import (
+from volsync_tpu.service.wire import (
     DEADLINE_CLASS_METADATA_KEY,
     RETRY_AFTER_METADATA_KEY,
     SERVICE_NAME,
@@ -105,9 +105,9 @@ class MoverJaxClient:
         self._timeout = timeout
         # Unary calls retry under the shared policy (grpc.RpcError's
         # .code() is classified: UNAVAILABLE-family retries,
-        # UNAUTHENTICATED/INVALID_ARGUMENT... is fatal). chunk_stream
-        # does NOT retry — a partially consumed reader() stream cannot
-        # be replayed; its caller owns re-driving the whole transfer.
+        # UNAUTHENTICATED/INVALID_ARGUMENT... is fatal). A stream does
+        # NOT retry here — a partially consumed one cannot be replayed;
+        # its caller owns re-driving the whole transfer (chunk_batches).
         self._policy = RetryPolicy.from_env("service.client",
                                             call_timeout=timeout)
         ser = lambda m: m.SerializeToString()  # noqa: E731
@@ -135,10 +135,24 @@ class MoverJaxClient:
 
     # -- calls ---------------------------------------------------------------
 
-    def chunk_stream(self, reader: Callable[[int], bytes],
-                     ) -> Iterator[tuple[int, int, str]]:
-        """Stream ``reader`` to the service -> (offset, length, digest)
-        per finalized chunk, in order, covering the whole stream.
+    def chunk_batches(self, payloads: Iterable[bytes],
+                      timeout: Optional[float] = None,
+                      ) -> Iterator[tuple[list[tuple[int, int, str]], bool]]:
+        """One ChunkHash stream of the frames ``payloads`` yields (each
+        ``bytes`` of at most :data:`_SEND_CHUNK`; the eof marker is
+        appended here) -> ([(offset, length, digest)], final) per
+        answered ``ChunkBatch``, in order. gRPC pulls ``payloads`` on a
+        thread of its own, as fast as the server's credit pause and the
+        channel's window let it.
+
+        The replay contract: this does NOT retry. A frame that was
+        pulled is gone, so a shed (:class:`ShedError`, with its
+        ``retry_after``), an ``UNAVAILABLE`` or a stream that ends
+        before its ``final`` batch leaves the CALLER to send the whole
+        stream again from its first byte, or to fail; chunks answered
+        before the break are the same chunks in the replay (the cuts
+        depend on the bytes alone). ``service/hasher.py`` is such a
+        caller. Closing the iterator early cancels the call.
 
         Each call is the root of a fresh trace (tenant + generated
         stream id) whose context rides ``x-volsync-trace`` metadata, so
@@ -152,26 +166,18 @@ class MoverJaxClient:
                               format_trace_header(tctx.child(handle.span_id))),)
 
         def segments():
-            while True:
-                piece = reader(_SEND_CHUNK)
-                if not piece:
-                    yield pb.DataSegment(data=b"", eof=True)
-                    return
-                if not isinstance(piece, bytes):
-                    # protobuf bytes fields reject memoryview — the
-                    # wire frame is the one sanctioned materialization
-                    # on this path
-                    piece = bytes(piece)
-                    record_copy("svc.frame", len(piece))
+            for piece in payloads:
                 yield pb.DataSegment(data=piece)
+            yield pb.DataSegment(data=b"", eof=True)
 
-        call = self._chunk_hash(segments(), metadata=meta,
-                                timeout=self._timeout)
+        call = self._chunk_hash(
+            segments(), metadata=meta,
+            timeout=self._timeout if timeout is None else timeout)
         ok = False
         try:
             for batch in call:
-                for c in batch.chunks:
-                    yield int(c.offset), int(c.length), c.digest
+                yield ([(int(c.offset), int(c.length), c.digest)
+                        for c in batch.chunks], bool(batch.final))
             ok = True
         except grpc.RpcError as err:
             shed = shed_from_rpc(err)
@@ -179,7 +185,32 @@ class MoverJaxClient:
                 raise shed from err
             raise
         finally:
+            if not ok:
+                call.cancel()
             handle.finish("ok" if ok else "error")
+
+    def chunk_stream(self, reader: Callable[[int], bytes],
+                     ) -> Iterator[tuple[int, int, str]]:
+        """Stream ``reader`` to the service -> (offset, length, digest)
+        per finalized chunk, in order, covering the whole stream
+        (:meth:`chunk_batches`, flattened; its replay contract holds:
+        a partially consumed ``reader`` cannot be replayed here)."""
+
+        def payloads():
+            while True:
+                piece = reader(_SEND_CHUNK)
+                if not piece:
+                    return
+                if not isinstance(piece, bytes):
+                    # protobuf bytes fields reject memoryview — the
+                    # wire frame is the one sanctioned materialization
+                    # on this path
+                    piece = bytes(piece)
+                    record_copy("svc.frame", len(piece))
+                yield piece
+
+        for chunks, _final in self.chunk_batches(payloads()):
+            yield from chunks
 
     def chunk_bytes(self, data) -> list[tuple[int, int, str]]:
         """Chunk one in-memory buffer (bytes/bytearray/memoryview).

@@ -47,14 +47,15 @@ import logging
 import os
 from collections import deque
 from concurrent.futures import Future, ThreadPoolExecutor
+from itertools import islice
 from typing import Optional
 
 import grpc
 import numpy as np
 
 from volsync_tpu import envflags
-from volsync_tpu.obs import begin_span, new_trace, parse_trace_header, \
-    record_copy, use_context
+from volsync_tpu.obs import begin_span, count, new_trace, \
+    parse_trace_header, record_copy, use_context
 from volsync_tpu.ops.batcher import BatcherStopped, SegmentMicroBatcher
 from volsync_tpu.service import moverjax_pb2 as pb
 from volsync_tpu.service.admission import (
@@ -68,23 +69,16 @@ from volsync_tpu.service.scheduler import (
     parse_deadline_classes,
 )
 from volsync_tpu.service.tenants import TenantRegistry
+from volsync_tpu.service.wire import (  # noqa: F401 — re-exported names
+    DEADLINE_CLASS_METADATA_KEY,
+    RETRY_AFTER_METADATA_KEY,
+    SERVICE_NAME,
+    SIBLING_METADATA_KEY,
+    TOKEN_METADATA_KEY,
+    TRACE_METADATA_KEY,
+)
 
 log = logging.getLogger("volsync_tpu.moverjax")
-
-SERVICE_NAME = "moverjax.MoverJax"
-TOKEN_METADATA_KEY = "x-volsync-token"
-#: trailing-metadata key carrying the shed retry-after hint (ms)
-RETRY_AFTER_METADATA_KEY = "x-volsync-retry-after-ms"
-#: trailing-metadata key carrying a sibling replica's host:port on a
-#: shed, when a fleet router is wired (cross-replica admission: retry
-#: THERE, not here)
-SIBLING_METADATA_KEY = "x-volsync-sibling"
-#: request-metadata key carrying the client's trace context
-#: (obs.format_trace_header) so client + server spans join one trace
-TRACE_METADATA_KEY = "x-volsync-trace"
-#: request-metadata key naming the stream's deadline class
-#: (scheduler.parse_deadline_classes); unknown/absent = no deadline
-DEADLINE_CLASS_METADATA_KEY = "x-volsync-deadline-class"
 
 #: the methods whose wait for a pool thread is recorded as
 #: svc.accept_wait (Info is a probe: its waits would thin the mean)
@@ -93,6 +87,28 @@ _ACCEPT_WAITED = ("ChunkHash", "HashSpans")
 #: Stream segmentation mirrors engine/chunker.stream_chunks: a segment is
 #: processed once at least this much beyond max_size is buffered.
 DEFAULT_SEGMENT_SIZE = 32 * 1024 * 1024
+
+
+def stream_segment_spans(nbytes: int, cut: int, max_size: int,
+                         ) -> tuple[int, list[tuple[int, int]]]:
+    """(most full segments, [(lo, hi)] lengths the last segment can
+    have) for a ChunkHash stream of ``nbytes``: what ``_serve_stream``
+    hands the device for it, as far as the size alone says. A full
+    segment is ``cut`` bytes and leaves less than ``max_size`` of them
+    uncut, so each moves the stream's end between ``cut`` -
+    ``max_size`` + 1 and ``cut`` bytes nearer; the last is what is
+    left, 1 to ``cut`` bytes (0 for an empty stream)."""
+    lo = hi = nbytes
+    full = 0
+    last = []
+    while hi > cut:
+        if lo <= cut:
+            last.append((lo, cut))
+            lo = cut + 1
+        full += 1
+        lo, hi = lo - cut, hi - (cut - max_size + 1)
+    last.append((max(lo, 0), hi))
+    return full, last
 
 
 def _timed_ingest(request_iterator, ctx):
@@ -203,6 +219,16 @@ class MoverJaxServer:
     batcher's ``max_batch``, and what ``benchmark/warm.py`` loads
     programs for). It is NOT the handler pool.
 
+    The (lanes, bucket) programs concurrent streams can make the device
+    meet are bounded by two rules a plan can enumerate
+    (:func:`stream_segment_spans`, ``ops/segment.coalesced_lanes``): a
+    stream's segments follow its bytes (every full one is
+    ``segment_size`` + ``max_size`` long; ``_serve_stream``), and one
+    dispatch stages no more than one full segment does
+    (``stage_limit``), so segments of a small bucket coalesce up to
+    the batch limit, those of a bucket over half a full segment go
+    alone, and no large bucket is met at every lane count.
+
     ``handlers`` is gRPC's thread pool: the calls that can be in their
     handlers at once. A stream beyond it waits in the executor's FIFO
     (``svc.accept_wait``), where admission does not count it and the
@@ -244,9 +270,15 @@ class MoverJaxServer:
                  deadline_classes: Optional[dict] = None):
         from volsync_tpu.engine.chunker import DeviceChunkHasher
         from volsync_tpu.ops.gearcdc import DEFAULT_PARAMS
+        from volsync_tpu.ops.segment import _buffer_bucket
 
         self.params = params or DEFAULT_PARAMS
         self.segment_size = segment_size
+        self.max_batch = max_workers
+        #: what one coalesced dispatch may stage: as much as one full
+        #: segment of one stream does (its staging bucket)
+        self.stage_limit = _buffer_bucket(segment_size
+                                          + self.params.max_size)
         self.token = token or os.urandom(32).hex()
         self._hasher = DeviceChunkHasher(self.params)
         # The server manages its own batching: the process-wide
@@ -259,7 +291,8 @@ class MoverJaxServer:
                 pipeline_depth = envflags.batch_pipeline_depth()
             self._batcher = SegmentMicroBatcher(
                 self.params, window_ms=batch_window_ms,
-                max_batch=max_workers, pipeline_depth=pipeline_depth)
+                max_batch=max_workers, pipeline_depth=pipeline_depth,
+                stage_limit=self.stage_limit)
 
         self.tenants = tenants if tenants is not None \
             else TenantRegistry.from_env()
@@ -436,6 +469,11 @@ class MoverJaxServer:
             raise
         else:
             handle.finish("ok")
+            # once a stream, at its end: what the service did for whom
+            count("svc.streams")
+            count("svc.stream_bytes", ticket.stream_bytes)
+            count("svc.segments", ticket.segments)
+            count("svc.tenant_bytes." + ticket.tenant, ticket.stream_bytes)
         finally:
             self._admission.release(ticket)
 
@@ -465,13 +503,26 @@ class MoverJaxServer:
         return f
 
     def _serve_stream(self, request_iterator, ticket):
-        """The streaming loop, with a credit-based pause: while one
-        segment is in flight on the device, the handler keeps reading
-        request bytes only up to ``stream_credits`` further segments'
-        worth — past that it blocks on the in-flight result, gRPC flow
-        control pauses the sender, and server-side buffering stays
-        bounded no matter how slow the device or how greedy the
-        client."""
+        """The streaming loop.
+
+        Segments follow the stream's BYTES, not the timing of its
+        frames: while more than ``cut`` (``segment_size`` + ``max_size``)
+        bytes of the stream lie beyond the last chunk cut, the next
+        segment is exactly the first ``cut`` of them, and what is left
+        at the stream's end (at most ``cut``) is its last segment. The
+        chunker's cuts depend on the bytes alone, so the same stream is
+        the same segments whatever the frames, the device's pace or the
+        other streams do: every full segment is ``cut`` long (one
+        staging bucket), and :func:`stream_segment_spans` bounds the last.
+        A stream of at most ``cut`` bytes is one segment, flushed at
+        its eof.
+
+        Credit-based pause: while one segment is in flight on the
+        device, the handler keeps reading request bytes only up to
+        ``stream_credits`` segments' worth in all — past that it blocks
+        on the in-flight result, gRPC flow control pauses the sender,
+        and server-side buffering stays bounded no matter how slow the
+        device or how greedy the client."""
         # gRPC frames buffered UNJOINED: each pb frame is immutable
         # bytes, so the rolling buffer is a deque of them plus a
         # consumed-prefix offset into the head frame. The old bytearray
@@ -487,20 +538,27 @@ class MoverJaxServer:
         credit_bytes = self._stream_credits * cut
         inflight: Optional[tuple[Future, bool]] = None
 
-        def assemble():
-            # one snapshot of the WHOLE buffer: frames are immutable,
-            # so views/joins over them are stable while the device
-            # works and later appends don't disturb the consumed prefix
-            if not pieces:
+        def assemble(n: int):
+            # one snapshot of the buffer's first n bytes: frames are
+            # immutable, so views/joins over them are stable while the
+            # device works and later appends don't disturb the consumed
+            # prefix
+            if not n:
                 return b""
-            if len(pieces) == 1:
-                if head == 0:
+            first = memoryview(pieces[0])[head:]
+            if len(first) >= n:
+                if head == 0 and len(first) == n:
                     return pieces[0]  # zero-copy pass-through
-                return memoryview(pieces[0])[head:]
-            out = b"".join([memoryview(pieces[0])[head:],
-                            *list(pieces)[1:]])
-            record_copy("svc.frame", len(out))
-            return out
+                return first[:n]
+            parts, need = [first], n - len(first)
+            for piece in islice(pieces, 1, None):
+                if len(piece) >= need:
+                    parts.append(memoryview(piece)[:need])
+                    break
+                parts.append(piece)
+                need -= len(piece)
+            record_copy("svc.frame", n)
+            return b"".join(parts)
 
         def collect(handle) -> pb.ChunkBatch:
             nonlocal base, head, plen
@@ -532,7 +590,21 @@ class MoverJaxServer:
             return batch
 
         def flush(eof: bool) -> tuple[Future, bool]:
-            return (self._submit_segment(ticket, assemble(), eof), eof)
+            ticket.segments += 1
+            return (self._submit_segment(
+                ticket, assemble(plen if eof else cut), eof), eof)
+
+        def finish():
+            # what the stream's end still holds: full segments while
+            # more than a cut is left, then the last
+            nonlocal inflight
+            if inflight is not None:
+                yield collect(inflight)
+                inflight = None
+            while plen > cut:
+                yield collect(flush(False))
+            yield collect(flush(True))
+            ticket.stream_bytes = base
 
         for seg in request_iterator:
             data = seg.data  # once: upb copies the field out a read
@@ -542,29 +614,23 @@ class MoverJaxServer:
             if inflight is not None and inflight[0].done():
                 yield collect(inflight)
                 inflight = None
-            if inflight is None and plen >= cut:
+            if inflight is None and plen > cut:
                 inflight = flush(False)
             while inflight is not None and plen >= credit_bytes:
                 # credits exhausted: stop reading, wait out the device
                 yield collect(inflight)
                 inflight = None
-                if plen >= cut:
+                if plen > cut:
                     inflight = flush(False)
             if inflight is not None:
                 ticket.buffered_high_water = max(
                     ticket.buffered_high_water, plen)
             if seg.eof:
-                if inflight is not None:
-                    yield collect(inflight)
-                    inflight = None
-                yield collect(flush(True))
+                yield from finish()
                 return
         # Stream ended without an eof marker: finalize what we have
         # (client disconnect mid-stream just drops the call).
-        if inflight is not None:
-            yield collect(inflight)
-            inflight = None
-        yield collect(flush(True))
+        yield from finish()
 
     def _hash_spans(self, request: pb.HashSpansRequest, context):
         from volsync_tpu.engine.chunker import hash_spans
