@@ -6,16 +6,25 @@ one back (PERF.md section 6, PR 27). What a backup produces is held
 against ids computed here, from the files' bytes.
 """
 
+import builtins
+import collections
+import errno
+import io
 import json
 import os
 import threading
 from concurrent.futures import ThreadPoolExecutor
 
+import numpy as np
 import pytest
 
+from benchmark.reference import blobid as ref_blobid
+from benchmark.reference import gearcdc as ref_gearcdc
 from volsync_tpu import envflags, obs
-from volsync_tpu.engine import TreeBackup
+from volsync_tpu import io as volsync_io
+from volsync_tpu.engine import DeviceChunkHasher, TreeBackup, params_from_config
 from volsync_tpu.engine import backup as backup_mod
+from volsync_tpu.engine import chunker as chunker_mod
 from volsync_tpu.objstore import MemObjectStore
 from volsync_tpu.repo import blobid
 from volsync_tpu.repo.repository import BLOB_DATA, Repository
@@ -282,3 +291,377 @@ def test_two_backups_at_once_into_one_repository(tree):
     # every blob, data or tree, was new to exactly one of the two
     assert sum(s.blobs_new for _, _, s in got) == len(repo.blob_ids())
     assert repo.check() == []
+
+
+# -- a file that fits one segment costs one descriptor (PR 49) ---------------
+#
+# What a file costs on the chip's host is its system calls (PERF.md
+# section 6, PR 38), and a timing taken here says nothing about them:
+# these count the calls.
+
+FILL_SEGMENT = 65536
+#: one fill of a stream over ``SmallFill`` under CHUNKER_4K: 128 KiB
+FILL = FILL_SEGMENT + CHUNKER_4K["max_size"]
+HOST = {"h/tiny": 300, "h/at_min": CHUNKER_4K["min_size"]}
+SHORT = {"s/short": 100_000, "s/at_fill": FILL}
+LONG = {"l/over": FILL + 1, "l/long": 300_000}
+SIZES = {**HOST, **SHORT, **LONG}
+PARAMS_4K = params_from_config(CHUNKER_4K)
+REF_CHUNKER = {**CHUNKER_4K, "norm_level": PARAMS_4K.norm_level}
+
+
+class SmallFill(DeviceChunkHasher):
+    """The one-chip engine saying how large a segment it fills, as the
+    mesh hasher does: one fill is 128 KiB, so the tier-1 tree holds
+    files on both sides of it."""
+
+    def stream_segment_size(self, segment_size):
+        return FILL_SEGMENT
+
+
+@pytest.fixture
+def sized(tmp_path):
+    root = tmp_path / "src"
+    files = {rel: np.random.default_rng([11, n]).bytes(n)
+             for rel, n in SIZES.items()}
+    for rel, data in files.items():
+        (root / rel).parent.mkdir(parents=True, exist_ok=True)
+        (root / rel).write_bytes(data)
+    return root, files
+
+
+def reference_content(data):
+    if len(data) <= CHUNKER_4K["min_size"]:
+        return [ref_blobid.blob_id(data)]
+    return [ref_blobid.blob_id(data[off: off + n])
+            for off, n in ref_gearcdc.cuts(data, REF_CHUNKER)]
+
+
+def backup_4k(root, hasher=None, repo=None):
+    repo = repo or Repository.init(MemObjectStore(), chunker=CHUNKER_4K)
+    hasher = hasher or SmallFill(PARAMS_4K)
+    snap_id, _ = TreeBackup(repo, hasher=hasher).run(root)
+    manifest = dict(repo.list_snapshots())[snap_id]
+    return repo, manifest, file_entries(repo, manifest["tree"])
+
+
+class Calls:
+    """What the hash phase asks the kernel and the runtime about each
+    file under ``root``, by relative path: ``open`` / ``fstat`` /
+    ``close`` of a descriptor ``os.open`` gave, ``path_stat`` (any stat
+    by name, ``Path.lstat`` included), ``io_open`` (a buffered or
+    ``pathlib`` open), ``readahead`` (the native reader built), and
+    ``thread`` (a ``vtpk-readahead`` thread started while the file was
+    hashed)."""
+
+    def __init__(self, monkeypatch, root):
+        self.by = collections.defaultdict(collections.Counter)
+        self.fds = {}
+        self.current = None
+        prefix = str(root) + "/"
+
+        def rel(path):
+            try:
+                path = os.fsdecode(os.fspath(path))
+            except TypeError:  # a descriptor
+                return None
+            return path[len(prefix):] if path.startswith(prefix) else None
+
+        def on_path(module, name, tally):
+            real = getattr(module, name)
+
+            def wrapped(path, *args, **kwargs):
+                if rel(path) is not None:
+                    self.by[rel(path)][tally] += 1
+                return real(path, *args, **kwargs)
+
+            monkeypatch.setattr(module, name, wrapped)
+
+        real_open, real_fstat, real_close = os.open, os.fstat, os.close
+
+        def os_open(path, *args, **kwargs):
+            fd = real_open(path, *args, **kwargs)
+            if rel(path) is not None:
+                self.fds[fd] = rel(path)
+                self.by[rel(path)]["open"] += 1
+            return fd
+
+        def os_fstat(fd):
+            if fd in self.fds:
+                self.by[self.fds[fd]]["fstat"] += 1
+            return real_fstat(fd)
+
+        def os_close(fd):
+            if fd in self.fds:
+                self.by[self.fds.pop(fd)]["close"] += 1
+            return real_close(fd)
+
+        monkeypatch.setattr(os, "open", os_open)
+        monkeypatch.setattr(os, "fstat", os_fstat)
+        monkeypatch.setattr(os, "close", os_close)
+        on_path(os, "stat", "path_stat")
+        on_path(os, "lstat", "path_stat")
+        on_path(io, "open", "io_open")
+        on_path(builtins, "open", "io_open")
+        on_path(volsync_io, "ReadaheadReader", "readahead")
+
+        real_hash = TreeBackup._hash_file
+
+        def hash_file(backup, path, rel_, st, stats):
+            self.current = rel_
+            return real_hash(backup, path, rel_, st, stats)
+
+        monkeypatch.setattr(TreeBackup, "_hash_file", hash_file)
+        real_start = threading.Thread.start
+
+        def start(thread):
+            if thread.name == "vtpk-readahead":
+                self.by[self.current]["thread"] += 1
+            return real_start(thread)
+
+        monkeypatch.setattr(threading.Thread, "start", start)
+
+
+def watch_hash_phase(monkeypatch, root, then=None) -> dict:
+    """-> a box that holds ``calls`` once the next backup's walk is
+    over: the walk's own calls are not counted. ``then(calls)`` runs
+    after the walk too."""
+    box = {}
+
+    def start():
+        box["calls"] = Calls(monkeypatch, root)
+        if then is not None:
+            then(box["calls"])
+
+    _after_the_walk(monkeypatch, start)
+    return box
+
+
+def watched_backup(monkeypatch, root, then=None, hasher=None):
+    box = watch_hash_phase(monkeypatch, root, then)
+    out = backup_4k(root, hasher)
+    monkeypatch.undo()  # what the test itself asks is not counted
+    return box["calls"], out
+
+
+@pytest.mark.parametrize("rel", sorted({**HOST, **SHORT}))
+def test_a_file_that_fits_one_segment_costs_one_descriptor(
+        sized, rel, monkeypatch):
+    """One open, one fstat, one close; nothing asked by name, no
+    buffered file object, no read-ahead reader and no thread."""
+    root, files = sized
+    calls, (_, _, entries) = watched_backup(monkeypatch, root)
+    assert dict(+calls.by[rel]) == {"open": 1, "fstat": 1, "close": 1}
+    assert calls.fds == {}
+    assert entries[rel]["content"] == reference_content(files[rel])
+    assert entries[rel]["size"] == len(files[rel])
+    assert entries[rel]["mtime_ns"] == (root / rel).lstat().st_mtime_ns
+
+
+@pytest.mark.parametrize("rel", sorted(LONG))
+def test_a_file_over_one_fill_keeps_the_readahead(sized, rel, monkeypatch):
+    """From one byte over the fill: the native reader, the read-ahead
+    thread behind its segments, and the closing stamp by name."""
+    root, files = sized
+    calls, (_, _, entries) = watched_backup(monkeypatch, root)
+    seen = calls.by[rel]
+    assert seen["readahead"] == 1 and seen["path_stat"] == 1
+    assert seen["thread"] == 1
+    assert not seen["open"] and not seen["fstat"] and not seen["io_open"]
+    assert entries[rel]["content"] == reference_content(files[rel])
+    assert len(entries[rel]["content"]) > 1
+    assert entries[rel]["mtime_ns"] == (root / rel).lstat().st_mtime_ns
+
+
+def test_the_fill_is_the_hashers(sized):
+    """The rule reads the fill of the hasher in use: the one-chip
+    engine's 32 MiB segment + max_size, what a hasher says through
+    ``stream_segment_size`` (the mesh's is the larger)."""
+    import jax
+
+    from volsync_tpu.parallel.sharded_chunker import (MeshChunkHasher,
+                                                      make_stream_mesh)
+
+    def one_fill(hasher=None):
+        repo = Repository.init(MemObjectStore(), chunker=CHUNKER_4K)
+        return TreeBackup(repo, hasher=hasher)._one_fill
+
+    one_chip = 32 * 1024 * 1024 + CHUNKER_4K["max_size"]
+    assert one_fill() == one_chip
+    assert one_fill(SmallFill(PARAMS_4K)) == FILL
+    mesh = MeshChunkHasher(PARAMS_4K, make_stream_mesh(jax.devices()[:4]))
+    assert one_fill(mesh) == chunker_mod._segment_source(
+        None, PARAMS_4K, 32 * 1024 * 1024, mesh).target > one_chip
+
+
+@pytest.fixture(scope="module")
+def service():
+    from volsync_tpu.service.server import MoverJaxServer
+
+    with MoverJaxServer(params=PARAMS_4K, segment_size=FILL_SEGMENT) as srv:
+        yield srv
+
+
+def _engine(name, request):
+    """-> (hasher, the files that take the read-ahead reader under it)."""
+    if name == "one-chip":
+        return DeviceChunkHasher(PARAMS_4K), {}
+    if name == "small-fill":
+        return SmallFill(PARAMS_4K), LONG
+    if name == "mesh":
+        import jax
+
+        from volsync_tpu.parallel.sharded_chunker import (
+            MeshChunkHasher, make_stream_mesh)
+        return MeshChunkHasher(
+            PARAMS_4K, make_stream_mesh(jax.devices()[:4])), {}
+    from volsync_tpu.service.hasher import open_hasher
+
+    srv = request.getfixturevalue("service")
+    remote = open_hasher(f"127.0.0.1:{srv.port}", srv.token, "t", PARAMS_4K)
+    request.addfinalizer(remote.close)
+    if name == "service-small-fill":
+        remote.stream_segment_size = lambda segment_size: FILL_SEGMENT
+        return remote, LONG
+    return remote, {}
+
+
+@pytest.mark.parametrize("engine", ["one-chip", "small-fill", "mesh",
+                                    "service", "service-small-fill"])
+def test_the_snapshot_is_the_references_whichever_reader(
+        sized, engine, request, monkeypatch):
+    """Tree id, every blob id and the packs are one snapshot: the
+    reference's cuts and hashlib's ids, whether a file was read through
+    one descriptor or the read-ahead reader, in process, over the mesh
+    hasher's fill or through ``service/hasher.py`` ``hash_file``."""
+    root, files = sized
+    hasher, long_files = _engine(engine, request)
+    calls, (repo, manifest, entries) = watched_backup(
+        monkeypatch, root, hasher=hasher)
+    for rel, data in files.items():
+        assert entries[rel]["content"] == reference_content(data), rel
+        assert entries[rel]["size"] == len(data)
+        assert b"".join(repo.read_blob(b)
+                        for b in entries[rel]["content"]) == data
+        assert bool(calls.by[rel]["readahead"]) == (rel in long_files), rel
+        assert calls.by[rel]["open"] == (rel not in long_files), rel
+    plain, expected, _ = backup_4k(root, DeviceChunkHasher(PARAMS_4K))
+    assert manifest["tree"] == expected["tree"]
+    assert repo.blob_ids() == plain.blob_ids()
+    assert sorted(repo.store.list("data/")) \
+        == sorted(plain.store.list("data/"))
+    assert repo.check() == []
+
+
+GROWN = {"h/tiny": 9_000, "s/short": 3 * FILL + 777}
+
+
+@pytest.mark.parametrize("rel", sorted(GROWN))
+def test_a_file_that_grows_after_the_walk_is_read_to_its_end(
+        sized, rel, monkeypatch):
+    """A host-path file past min_size is stored whole, as it was read;
+    a short device-path file past one fill is read on serially and cut
+    where the reference cuts it. Neither gets a reader or a thread it
+    did not have, and both are described by the bytes read."""
+    root, _ = sized
+    new = np.random.default_rng([12, GROWN[rel]]).bytes(GROWN[rel])
+    calls, (repo, _, entries) = watched_backup(
+        monkeypatch, root,
+        then=lambda calls: (root / rel).write_bytes(new))
+    e = entries[rel]
+    assert e["size"] == len(new)
+    assert e["mtime_ns"] == (root / rel).lstat().st_mtime_ns
+    if rel in HOST:
+        assert e["content"] == [ref_blobid.blob_id(new)]
+    else:
+        assert e["content"] == reference_content(new)
+        assert len(e["content"]) > 3
+    assert b"".join(repo.read_blob(b) for b in e["content"]) == new
+    seen = calls.by[rel]
+    assert (seen["open"], seen["close"]) == (1, 1)
+    assert not seen["readahead"] and not seen["thread"]
+    assert not seen["path_stat"]
+
+
+@pytest.mark.parametrize("rel", ["h/tiny", "s/short"])
+def test_a_file_renamed_over_after_the_open_is_stamped_as_read(
+        sized, rel, monkeypatch):
+    """The entry's time is the inode's whose bytes were read, not that
+    of whatever holds its name once the read is over."""
+    root, files = sized
+    victim, other = root / rel, root.parent / "other"
+    os.utime(victim, ns=(1_600_000_000_000_000_000,) * 2)
+    other.write_bytes(b"another file" * 1000)
+    os.utime(other, ns=(1_700_000_000_000_000_000,) * 2)
+
+    def rename_once_open(calls):
+        opened = os.open  # Calls' wrapper
+
+        def os_open(path, *args, **kwargs):
+            fd = opened(path, *args, **kwargs)
+            if os.fspath(path) == str(victim) and other.exists():
+                os.replace(other, victim)
+            return fd
+
+        monkeypatch.setattr(os, "open", os_open)
+
+    _, (repo, _, entries) = watched_backup(monkeypatch, root,
+                                           then=rename_once_open)
+    assert not other.exists()
+    assert victim.lstat().st_mtime_ns == 1_700_000_000_000_000_000
+    e = entries[rel]
+    assert e["mtime_ns"] == 1_600_000_000_000_000_000
+    assert e["size"] == len(files[rel])
+    assert b"".join(repo.read_blob(b) for b in e["content"]) == files[rel]
+
+
+def _open_descriptors():
+    return len(os.listdir("/proc/self/fd"))
+
+
+@pytest.mark.parametrize("victim, call", [(None, None),
+                                          ("h/at_min", "read"),
+                                          ("s/short", "readv")])
+def test_no_descriptor_is_left_open(sized, victim, call, monkeypatch):
+    """After a backup of the tree, and after a read that raises."""
+    root, _ = sized
+    backup_4k(root)  # the pools and the programs are up
+    before = _open_descriptors()
+    if victim is None:
+        backup_4k(root)
+        assert _open_descriptors() == before
+        return
+
+    def fail_the_read(calls):
+        real = getattr(os, call)
+
+        def failing(fd, *args):
+            if calls.fds.get(fd) == victim:
+                raise OSError(errno.EIO, "the disk gave up")
+            return real(fd, *args)
+
+        monkeypatch.setattr(os, call, failing)
+
+    repo = Repository.init(MemObjectStore(), chunker=CHUNKER_4K)
+    box = watch_hash_phase(monkeypatch, root, fail_the_read)
+    with pytest.raises(OSError, match="the disk gave up"):
+        TreeBackup(repo, hasher=SmallFill(PARAMS_4K)).run(root)
+    assert repo.list_snapshots() == []
+    seen = box["calls"].by[victim]
+    assert (seen["open"], seen["close"]) == (1, 1)
+    assert box["calls"].fds == {}
+    monkeypatch.undo()
+    assert _open_descriptors() == before
+
+
+@pytest.mark.parametrize("hasher, direct", [
+    (None, len(SIZES)), ("small-fill", len(HOST) + len(SHORT))])
+def test_reads_direct_counts_the_files_that_took_one_descriptor(
+        sized, hasher, direct):
+    root, _ = sized
+    obs.reset_spans()
+    backup_4k(root, DeviceChunkHasher(PARAMS_4K) if hasher is None
+              else SmallFill(PARAMS_4K))
+    counted = obs.counter_totals()
+    assert counted["backup.files_changed"] == len(SIZES)
+    assert counted["backup.reads_direct"] == direct

@@ -16,7 +16,8 @@ import numpy as np
 import pytest
 
 from volsync_tpu import obs
-from volsync_tpu.engine import TreeBackup
+from volsync_tpu.engine import (DeviceChunkHasher, TreeBackup,
+                                params_from_config)
 from volsync_tpu.engine.chunker import stream_chunk_batches
 from volsync_tpu.objstore.store import LatencyStore, MemObjectStore
 from volsync_tpu.ops.gearcdc import GearParams
@@ -372,3 +373,50 @@ def test_a_sampled_context_changes_nothing_a_backup_stores(tree):
     assert keys == sorted(traced.store.list("data/")) and keys
     for key in keys:
         assert plain.store.get(key) == traced.store.get(key)
+
+
+# -- (f) a file that fits one fill: the wait is the read (PR 49) ---------------
+
+class _SmallFill(DeviceChunkHasher):
+    """One fill is 64 KiB + max_size = 128 KiB, so a tier-1 file can be
+    longer than one."""
+
+    def stream_segment_size(self, segment_size):
+        return 65536
+
+
+@pytest.mark.parametrize("size, fits", [(100_000, True), (300_000, False)],
+                         ids=["one-fill", "longer"])
+def test_the_wait_for_a_segment_is_the_read_where_nothing_reads_ahead(
+        tmp_path, rng, size, fits):
+    """``engine.read_wait`` is the hash thread's wait for a segment's
+    bytes on both paths. A file that fits one fill is read by that
+    thread: one wait a file with ``engine.read`` closing inside it, off
+    the ring. A longer file keeps the read-ahead thread, whose
+    ``engine.read`` is beside the wait and on the ring."""
+    root = tmp_path / "src"
+    root.mkdir()
+    (root / "f").write_bytes(rng.bytes(size))
+    repo = Repository.init(MemObjectStore(), chunker=CHUNKER_4K)
+    with obs.trace_context(sampled=True):
+        TreeBackup(repo, hasher=_SmallFill(params_from_config(CHUNKER_4K))).run(root)
+    totals, own = obs.span_totals(), obs.span_self_totals()
+    (file_event,) = events("backup.file")
+    waits, reads = events("engine.read_wait"), events("engine.read")
+    assert totals["backup.open"][0] == len(events("backup.open")) == 1
+    assert {w["tid"] for w in waits} == {file_event["tid"]}
+    assert len(waits) == totals["engine.read_wait"][0]
+    if fits:
+        assert len(waits) == 1
+        assert reads == [] and totals["engine.read"][0] >= 2
+        assert totals["engine.read"][1] <= totals["engine.read_wait"][1]
+        assert own["engine.read_wait"][1] == pytest.approx(
+            totals["engine.read_wait"][1] - totals["engine.read"][1])
+        assert obs.counter_totals()["backup.reads_direct"] == 1
+    else:
+        assert len(waits) >= 3  # one a segment
+        assert len(reads) == totals["engine.read"][0] >= 3
+        assert file_event["tid"] not in {r["tid"] for r in reads}
+        assert own["engine.read_wait"][1] == pytest.approx(
+            totals["engine.read_wait"][1])
+        assert not obs.counter_totals().get("backup.reads_direct")

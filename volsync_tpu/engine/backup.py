@@ -22,7 +22,9 @@ from volsync_tpu.engine.chunker import (
     DeviceChunkHasher,
     params_from_config,
     stream_chunk_batches,
+    stream_fill_bytes,
 )
+from volsync_tpu.engine.directread import DirectReader, read_small
 from volsync_tpu.obs import count, off_ring, span, use_context
 from volsync_tpu.repo import blobid
 from volsync_tpu.repo.repository import (
@@ -123,6 +125,9 @@ class TreeBackup:
             raise ValueError(
                 f"hasher params {self.params} != repository chunker "
                 f"params {want}")
+        # what one segment of a stream over this hasher takes in: a
+        # file of up to that many bytes has nothing to read ahead of
+        self._one_fill = stream_fill_bytes(self.params, self.hasher)
         self.skip_if_empty = skip_if_empty
         if protocol not in ("cdc", "full", "auto"):
             raise ValueError(f"unknown backup protocol {protocol!r}")
@@ -392,19 +397,30 @@ class TreeBackup:
                    stats: BackupStats) -> tuple[str, tuple]:
         """Chunk+hash one file, store its blobs. Returns
         (rel, (content, size, mtime_ns)) where size is the byte count
-        actually hashed and mtime_ns a post-read lstat — the entry must
-        describe the content that was stored, not the walk-time stat.
-        Per-blob stats are updated by the repository under its lock;
-        everything else was counted in the walk.
+        actually hashed and mtime_ns a stat taken after the last read
+        — the entry must describe the content that was stored, not the
+        walk-time stat. Per-blob stats are updated by the repository
+        under its lock; everything else was counted in the walk.
+
+        A file that fits one segment (every host-path file, and a
+        device-path file of at most the stream's one fill) is read
+        through one descriptor where it is hashed
+        (``engine/directread.py``: no read-ahead reader, no thread) and
+        stamped by that descriptor's ``fstat``; ``backup.reads_direct``
+        counts them. A longer file streams through the read-ahead
+        reader and thread and is stamped by an ``lstat`` of its path.
 
         One root span a file, ``backup.file``. The read and the hash
-        of a host-path file (``backup.read``, ``backup.blob_id``), the
-        opening of a device-path file's reader (``backup.open``), the
-        repository (``repo.add`` and the waits inside it) and the wait
-        for the read-ahead (``engine.read_wait``) are spans inside it;
-        its self time is what is left: the slicing of a segment's
-        chunks, the tail carry, the closing ``lstat`` and the
-        generator's own loop."""
+        of a host-path file (``backup.read``: open, read, ``fstat`` and
+        close; ``backup.blob_id``), the opening of a device-path file's
+        reader (``backup.open``, whichever reader), the repository
+        (``repo.add`` and the waits inside it) and the wait for a
+        segment's bytes (``engine.read_wait``: the read-ahead queue, or
+        the read itself for a file that fits one fill) are spans inside
+        it; its self time is what is left: the slicing of a segment's
+        chunks, the tail carry, the reader's close, a long file's
+        closing ``lstat`` and its thread, and the generator's own
+        loop."""
         on_host = (st.st_size <= self.params.min_size
                    or self._wants_full(st.st_size))
         with span("backup.file", path="host" if on_host else "device"):
@@ -412,21 +428,25 @@ class TreeBackup:
 
     def _hash_file_body(self, path: Path, rel: str, st, stats: BackupStats,
                         on_host: bool) -> tuple[str, tuple]:
+        direct = on_host or st.st_size <= self._one_fill
         if on_host:
             quiet = off_ring()  # two spans a file: totals, not events
             with span("backup.read", ctx=quiet):
-                data = path.read_bytes()
+                data, after = read_small(path, st.st_size)
             with span("backup.blob_id", ctx=quiet):
                 digest = blobid.blob_id(data)
             self.repo.add_blob(BLOB_DATA, digest, data, stats)
             content = [digest]
             hashed = len(data)
         else:
-            # Large files stream through the native readahead reader
-            # when available (native/volio.cpp): disk IO for segment N+1
-            # overlaps the device hashing of segment N (open() fallback).
+            # A file longer than one fill streams through the native
+            # readahead reader when available (native/volio.cpp): disk
+            # IO for segment N+1 overlaps the device hashing of segment
+            # N (open() fallback). A file that fits one fill has no
+            # segment N+1: one plain descriptor, read where it is hashed.
             content = []
             hashed = 0
+            reader = None  # the last one opened: a replay opens another
 
             def add(batch):
                 # one batched dedup query + one lock acquisition
@@ -441,8 +461,11 @@ class TreeBackup:
                     hashed += len(chunk)
 
             def open_reader():
+                nonlocal reader
                 with span("backup.open"):
-                    return self._open_stream(path)
+                    reader = (DirectReader(path) if direct
+                              else self._open_stream(path))
+                return reader
 
             # a hasher that is a client of the mover-jax service
             # (service/hasher.py) takes the file whole, one stream of
@@ -454,12 +477,19 @@ class TreeBackup:
                 with open_reader() as reader:
                     for batch in stream_chunk_batches(
                             reader.read, self.params, hasher=self.hasher,
+                            readahead=0 if direct else None,
                             size_hint=st.st_size):
                         add(batch)
-        try:
-            mtime_ns = path.lstat().st_mtime_ns
-        except OSError:  # deleted mid-backup: keep the walk-time stamp
-            mtime_ns = st.st_mtime_ns
+            if direct:
+                after = reader.stat
+        if direct:
+            count("backup.reads_direct")
+            mtime_ns = after.st_mtime_ns
+        else:
+            try:
+                mtime_ns = path.lstat().st_mtime_ns
+            except OSError:  # deleted mid-backup: the walk-time stamp
+                mtime_ns = st.st_mtime_ns
         return rel, (content, hashed, mtime_ns)
 
     def _wants_full(self, size: int) -> bool:

@@ -25,7 +25,7 @@ import numpy as np
 
 from volsync_tpu import envflags
 from volsync_tpu.engine import bufpool
-from volsync_tpu.obs import count, record_copy, span
+from volsync_tpu.obs import count, off_ring, record_copy, span, use_context
 from volsync_tpu.repo import blobid
 
 from volsync_tpu.ops.gearcdc import (
@@ -774,7 +774,6 @@ class _SegmentReadahead:
         self._thread.start()
 
     def _produce(self):
-        from volsync_tpu.obs import use_context
         with use_context(self._trace_ctx):
             self._produce_loop()
 
@@ -823,6 +822,36 @@ class _SegmentReadahead:
                 bufpool.GLOBAL.release(item[0])
 
 
+class _SegmentInline:
+    """The fill of a stream that said it fits one segment, run where it
+    is consumed: there is no segment N+1 whose read a thread could
+    overlap with the device's work on segment N, so no thread and no
+    queue. ``engine.read_wait`` stays the consumer's wait for its
+    segment, which is now the read itself: ``engine.read`` inside it,
+    on the same thread, and off the ring, where the wait around it
+    already names the gap. A source that holds more than it said is
+    read on, serially."""
+
+    def __init__(self, fill: _SegmentFill):
+        self.head = fill.head
+        self._fill = fill
+
+    def next_segment(self) -> tuple[bytearray, int, bool]:
+        with span("engine.read_wait"), use_context(off_ring()):
+            return self._fill.next_segment()
+
+
+#: what a stream fills a segment at unless its caller or its hasher
+#: says otherwise (``_segment_source``)
+_STREAM_SEGMENT = 32 * 1024 * 1024
+
+
+def stream_fill_bytes(params: GearParams, hasher) -> int:
+    """The most bytes one segment of a default stream over ``hasher``
+    takes in: a source of up to that many is one fill, one dispatch."""
+    return _segment_source(None, params, _STREAM_SEGMENT, hasher).target
+
+
 def _segment_source(reader, params: GearParams, segment_size: int,
                     hasher, size_hint: Optional[int] = None) -> _SegmentFill:
     """The fill of a stream over ``hasher``. A hasher that shards a
@@ -841,7 +870,7 @@ def _segment_source(reader, params: GearParams, segment_size: int,
 
 def stream_chunk_batches(reader: Callable[[int], bytes],
                          params: GearParams,
-                         segment_size: int = 32 * 1024 * 1024,
+                         segment_size: int = _STREAM_SEGMENT,
                          hasher: Optional[DeviceChunkHasher] = None,
                          readahead: Optional[int] = None,
                          size_hint: Optional[int] = None,
@@ -884,7 +913,9 @@ def stream_chunk_batches(reader: Callable[[int], bytes],
     VOLSYNC_TPU_PIPELINE=0) runs the segment fill that many buffers
     ahead on a producer thread so host reads overlap device work — the
     read-ahead stage of the backup pipeline. Chunk boundaries and
-    digests are identical either way.
+    digests are identical either way. With 0 and a ``size_hint`` of at
+    most one fill (``TreeBackup``'s files that fit one segment) the one
+    read is the consumer's ``engine.read_wait`` (``_SegmentInline``).
 
     ``size_hint`` (a file's size as the walk saw it) lets a stream that
     ends exactly on a segment's fill end with that segment
@@ -898,6 +929,8 @@ def stream_chunk_batches(reader: Callable[[int], bytes],
     ra: Optional[_SegmentReadahead] = None
     if readahead > 0:
         ra = src = _SegmentReadahead(src, readahead)
+    elif size_hint is not None and size_hint <= src.target:
+        src = _SegmentInline(src)
     head = src.head
     begin = getattr(hasher, "begin", None)
 
@@ -973,7 +1006,7 @@ def stream_chunk_batches(reader: Callable[[int], bytes],
 
 
 def stream_chunks(reader: Callable[[int], bytes], params: GearParams,
-                  segment_size: int = 32 * 1024 * 1024,
+                  segment_size: int = _STREAM_SEGMENT,
                   hasher: Optional[DeviceChunkHasher] = None,
                   readahead: Optional[int] = None,
                   ) -> Iterator[tuple[bytes, str]]:
