@@ -587,3 +587,263 @@ def test_a_lone_stream_sends_every_segment_direct(counted, batch_segments,
     pool.release(pool.acquire(4096))  # an acquire re-probes what is parked
     assert pool._parked == []
     assert pool._free_bytes > 4096  # and the segments' buffers came back
+
+
+# -- a batch that cannot grow is not made to wait (PR 50) ------------------
+
+LONG_MS = 5000.0  # a window no test could sit out and still pass
+
+
+@pytest.fixture
+def fake_batcher(counted):
+    """-> make(**kw): a SegmentMicroBatcher whose device is a fake
+    (each lane answers with its own bytes and its batch's size), the
+    batches it saw in ``.seen``; stopped when the test ends."""
+    from volsync_tpu.ops.batcher import SegmentMicroBatcher
+
+    made = []
+
+    def make(**kw):
+        mb = SegmentMicroBatcher(P, **kw)
+        mb.seen = []
+
+        def hash_segments(items):
+            mb.seen.append(len(items))
+            return [((bytes(data), len(items)), length)
+                    for data, length, _eof in items]
+
+        mb._hasher.hash_segments = hash_segments
+        made.append(mb)
+        return mb
+
+    yield make
+    for mb in made:
+        mb.stop()
+
+
+def _timed(fn, *args):
+    import time
+
+    t0 = time.monotonic()
+    got = fn(*args)
+    return got, time.monotonic() - t0
+
+
+def test_a_lone_registered_producer_is_not_made_to_wait(fake_batcher):
+    """One registered producer: its batch is complete as it arrives, so
+    a window of five seconds costs it nothing; the batch is still one
+    ``ops.queue_wait`` and one ``ops.batch_dispatch``."""
+    mb = fake_batcher(window_ms=LONG_MS)
+    with mb.producer():
+        got, took = _timed(mb.submit, b"mine", 4, True)
+    assert got == ((b"mine", 1), 4)
+    assert took < 1.0
+    counts = counter_totals()
+    assert counts["ops.batches"] == counts["ops.batches_complete"] == 1
+    spans = span_totals()
+    assert spans["ops.queue_wait"][0] == 1
+    assert spans["ops.batch_dispatch"][0] == 1
+
+
+def test_two_registered_producers_go_as_the_second_arrives(fake_batcher):
+    """Two registered producers on two threads: the first waits for the
+    second, not for the window, and they share ONE batch of two lanes,
+    each with its own lane's result."""
+    import threading
+    import time
+
+    mb = fake_batcher(window_ms=LONG_MS)
+    both = threading.Barrier(2)
+    got = {}
+
+    def stream(name, delay):
+        with mb.producer():
+            both.wait()  # neither submits before both are registered
+            time.sleep(delay)
+            got[name] = mb.submit(name, len(name), True)
+
+    threads = [threading.Thread(target=stream, args=(n, d), name=f"s-{d}")
+               for n, d in ((b"first", 0.0), (b"second", 0.05))]
+    _, took = _timed(lambda: [t.start() for t in threads]
+                     and [t.join() for t in threads])
+    assert took < 2.0
+    assert mb.seen == [2]
+    assert got == {b"first": ((b"first", 2), 5),
+                   b"second": ((b"second", 2), 6)}
+    counts = counter_totals()
+    assert counts["ops.batches"] == counts["ops.batches_complete"] == 1
+
+
+def test_nobody_registered_waits_the_window_as_before(fake_batcher):
+    """The service's shape: nobody registered, two ``submit_async`` 50
+    ms apart inside a window of 500: one batch of two, after the window,
+    and no batch counted complete."""
+    import time
+
+    mb = fake_batcher(window_ms=500.0)
+    t0 = time.monotonic()
+    a = mb.submit_async(b"a", 1, True)
+    time.sleep(0.05)
+    b = mb.submit_async(b"b", 1, True)
+    assert mb.wait(a) == ((b"a", 2), 1) and mb.wait(b) == ((b"b", 2), 1)
+    assert time.monotonic() - t0 >= 0.45
+    assert mb.seen == [2]
+    counts = counter_totals()
+    assert counts["ops.batches"] == 1
+    assert counts.get("ops.batches_complete", 0) == 0
+
+
+def test_a_producer_that_leaves_lets_the_waiter_go(fake_batcher):
+    """Two registered, one submits: it waits for the other. When the
+    other leaves without a segment, the waiter goes at once and not at
+    the window's end."""
+    import threading
+
+    mb = fake_batcher(window_ms=LONG_MS)
+    other = mb.producer()
+    other.__enter__()
+    done = []
+    with mb.producer():
+        t = threading.Thread(
+            target=lambda: done.append(mb.submit(b"w", 1, True)),
+            name="waiter")
+        t.start()
+        t.join(0.2)
+        assert t.is_alive()  # held for the producer that is absent
+        other.__exit__(None, None, None)
+        t.join(2.0)
+    assert done == [((b"w", 1), 1)]
+    assert counter_totals()["ops.batches_complete"] == 1
+
+
+def test_a_leaked_registration_costs_the_window_never_a_hang(fake_batcher):
+    """A registration nobody took back (an abandoned generator): the
+    live producer pays the window, as before registrations existed, and
+    still gets its result."""
+    mb = fake_batcher(window_ms=100.0)
+    leaked = mb.producer()  # (held: the collector would end it)
+    leaked.__enter__()  # never left
+    with mb.producer():
+        got, took = _timed(mb.submit, b"live", 4, True)
+    assert got == ((b"live", 1), 4)
+    assert 0.08 <= took < 2.0
+    counts = counter_totals()
+    assert counts["ops.batches"] == 1
+    assert counts.get("ops.batches_complete", 0) == 0
+
+
+def test_stop_resolves_a_registered_producers_queued_item(fake_batcher):
+    """``stop()`` with a registered producer's item in hand (held for a
+    second producer that never comes): the future still resolves, at
+    the window's end."""
+    import threading
+
+    mb = fake_batcher(window_ms=300.0)
+    done = []
+    with mb.producer(), mb.producer():
+        t = threading.Thread(
+            target=lambda: done.append(mb.submit(b"q", 1, True)),
+            name="queued")
+        t.start()
+        t.join(0.1)
+        assert t.is_alive()
+        stopper = threading.Thread(target=mb.stop, name="stopper")
+        stopper.start()
+        t.join(7.0)
+    stopper.join(10.0)
+    assert done == [((b"q", 1), 1)]
+
+
+def test_producers_that_come_and_go_keep_their_own_lanes(fake_batcher):
+    """More producers than cores register, send and leave, over and
+    over, under a short switch interval: every caller gets its own
+    lane's result, every submitted lane was dispatched once, every
+    batch was counted, and nobody is left registered."""
+    import sys
+    import threading
+
+    mb = fake_batcher(window_ms=20.0, max_batch=4)
+    streams, files, segments = 24, 20, 2
+    wrong = []
+
+    def stream(i):
+        for f in range(files):
+            with mb.producer():
+                for k in range(segments):
+                    mine = b"%d/%d/%d" % (i, f, k)
+                    (data, lanes), length = mb.submit(mine, len(mine), True)
+                    if data != mine or length != len(mine) \
+                            or not 1 <= lanes <= 4:
+                        wrong.append((mine, data, lanes))
+
+    threads = [threading.Thread(target=stream, args=(i,), name=f"stream-{i}")
+               for i in range(streams)]
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60.0)
+    finally:
+        sys.setswitchinterval(was)
+    assert not any(t.is_alive() for t in threads)
+    assert wrong == []
+    assert mb._producers == 0
+    assert sum(mb.seen) == streams * files * segments
+    assert counter_totals()["ops.batches"] == len(mb.seen)
+
+
+@pytest.mark.parametrize("ending", ["exhausted", "closed-early", "raises"])
+def test_a_stream_is_a_producer_for_as_long_as_it_lives(
+        counted, batch_segments, monkeypatch, ending):
+    """``stream_chunk_batches`` over the shared batcher with a window
+    nobody could sit out: the stream registers itself, so every batch is
+    complete as it arrives and none waits; the chunks are the
+    reference's; and however the stream ends (exhausted, closed early,
+    its reader raising) the batcher counts no producer afterwards."""
+    import io
+    import time
+
+    from volsync_tpu.engine import bufpool
+    from volsync_tpu.engine.chunker import stream_chunk_batches
+    from volsync_tpu.ops.batcher import shared_batcher
+
+    batch_segments(True)
+    monkeypatch.setenv("VOLSYNC_BATCH_WINDOW_MS", str(LONG_MS))
+    monkeypatch.setattr(bufpool, "GLOBAL", bufpool.BufferPool())
+    data = np.random.default_rng(50).bytes(3 * BUCKET + 12_345)
+    source = io.BytesIO(data)
+
+    def read(n):
+        if ending == "raises" and source.tell() >= 3 * BUCKET:
+            raise OSError("the volume went away")
+        return source.read(n)
+
+    mb = shared_batcher(P)
+    assert mb._window == LONG_MS / 1000.0
+    stream = stream_chunk_batches(read, P, segment_size=BUCKET, readahead=0)
+    got, at = [], 0
+    t0 = time.monotonic()
+    try:
+        for batch in stream:
+            assert mb._producers == 1  # for as long as it lives
+            for view, digest in batch:
+                got.append((at, len(view), digest))
+                at += len(view)
+            if ending == "closed-early":
+                stream.close()
+                break
+    except OSError:
+        assert ending == "raises"
+    else:
+        assert ending != "raises"
+    assert time.monotonic() - t0 < LONG_MS / 1000.0  # no window sat out
+    assert mb._producers == 0
+    want = _reference(data)
+    assert got == (want if ending == "exhausted" else want[:len(got)])
+    assert got
+    counts = counter_totals()
+    assert counts["ops.batches"] == counts["ops.batches_complete"] >= 1
+    assert counts["ops.batches"] == span_totals()["ops.batch_dispatch"][0] \
+        == span_totals()["ops.queue_wait"][0]

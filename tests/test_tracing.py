@@ -547,14 +547,18 @@ _DISPATCH_SPANS = ("ops.queue_wait", "ops.batch_dispatch", "ops.stage",
                    "ops.launch", "ops.fetch", "ops.decode")
 
 
+@pytest.mark.parametrize("registered", [False, True],
+                         ids=["unregistered", "registered"])
 @pytest.mark.parametrize("sampled", [True, False])
 def test_batched_dispatch_enters_the_ring_under_the_submitters_trace(
-        sampled):
+        sampled, registered):
     """A sampled submit puts the wait for the batcher, the dispatch and
     its four stages in the ring with the submitter's trace id — on the
-    dispatch thread, which holds no context of its own; an unsampled
-    one puts nothing there, and the registry and the counters record
-    both."""
+    dispatch thread, which holds no context of its own, be the
+    submitter a registered producer (its batch does not wait) or not;
+    an unsampled one puts nothing there, and the registry and the
+    counters record both."""
+    import contextlib
     from volsync_tpu.ops.batcher import SegmentMicroBatcher
     from volsync_tpu.ops.gearcdc import GearParams
 
@@ -564,7 +568,8 @@ def test_batched_dispatch_enters_the_ring_under_the_submitters_trace(
     mb = SegmentMicroBatcher(params, max_batch=2, window_ms=1.0,
                              pipeline_depth=1)
     try:
-        with trace_context(sampled=sampled) as root:
+        with mb.producer() if registered else contextlib.nullcontext(), \
+                trace_context(sampled=sampled) as root:
             with span("engine.device"):
                 chunks, consumed = mb.submit(data, len(data), True)
     finally:
@@ -579,6 +584,8 @@ def test_batched_dispatch_enters_the_ring_under_the_submitters_trace(
     assert span_self_totals()["ops.batch_dispatch"][1] == pytest.approx(
         totals["ops.batch_dispatch"][1] - stages, abs=1e-9)
     assert counter_totals() == {
+        "ops.batches": 1, **({"ops.batches_complete": 1} if registered
+                             else {}),
         "ops.dispatches": 1, "ops.lanes": 1, "ops.lanes_padded": 1,
         "ops.bytes_valid": len(data), "ops.bytes_padded": 65536}
 

@@ -111,6 +111,25 @@ class DeviceChunkHasher:
         is withheld when not ``eof`` — the caller re-feeds it)."""
         return self.begin(buffer, eof=eof).finish()
 
+    def _batcher(self):
+        """The process-wide microbatcher ``begin`` routes to, or None."""
+        if self.use_shared_batcher and self.fused is not None:
+            from volsync_tpu.ops.batcher import shared_batcher
+
+            return shared_batcher(self.params)
+        return None
+
+    def producer(self):
+        """What a stream holds while it feeds this hasher (a context
+        manager): where ``begin`` routes to the shared batcher, the
+        stream is one of its blocking producers for that long
+        (ops/batcher.py ``producer``), so that a batch which holds a
+        segment of every stream is not made to wait for more."""
+        batcher = self._batcher()
+        if batcher is None:
+            return contextlib.nullcontext()
+        return batcher.producer()
+
     def begin(self, buffer, *, eof: bool = True,
               valid_len: Optional[int] = None) -> "PendingSegment":
         """Upload + dispatch the segment's device work, leaving it IN
@@ -153,20 +172,17 @@ class DeviceChunkHasher:
             return PendingSegment(
                 [(0, length, blobid.blob_id(buffer[:length]))], None, None)
 
-        if self.use_shared_batcher and self.fused is not None:
-            from volsync_tpu.ops.batcher import shared_batcher
-
-            batcher = shared_batcher(p)
-            if batcher is not None:
-                # consumed == the last chunk's end by the walk's
-                # semantics, which is exactly what PendingSegment.end
-                # derives from the chunk list. The ndarray is handed
-                # over as it is; whether it is copied is the batch's to
-                # say (_hash_bucket: not when it is bucket-shaped and
-                # alone). submit blocks until the result is fetched, so
-                # it stays alive and unchanged for as long as it is read.
-                chunks, _consumed = batcher.submit(buffer, length, eof)
-                return PendingSegment(chunks, None, None)
+        batcher = self._batcher()
+        if batcher is not None:
+            # consumed == the last chunk's end by the walk's
+            # semantics, which is exactly what PendingSegment.end
+            # derives from the chunk list. The ndarray is handed
+            # over as it is; whether it is copied is the batch's to
+            # say (_hash_bucket: not when it is bucket-shaped and
+            # alone). submit blocks until the result is fetched, so
+            # it stays alive and unchanged for as long as it is read.
+            chunks, _consumed = batcher.submit(buffer, length, eof)
+            return PendingSegment(chunks, None, None)
 
         padded = _buffer_bucket(length)
         # the single-lane way to the device, timed and counted as the
@@ -933,6 +949,7 @@ def stream_chunk_batches(reader: Callable[[int], bytes],
         src = _SegmentInline(src)
     head = src.head
     begin = getattr(hasher, "begin", None)
+    producer = getattr(hasher, "producer", contextlib.nullcontext)
 
     def _dispatch(buf, start, fill, eof):
         length = fill - start
@@ -962,7 +979,11 @@ def stream_chunk_batches(reader: Callable[[int], bytes],
                     for s, length, digest in cuts]
         return None
 
+    registered = contextlib.ExitStack()
     try:
+        # one of the batcher's producers for the life of the stream,
+        # whichever way it ends (exhausted, closed early, an exception)
+        registered.enter_context(producer())
         tail: Optional[memoryview] = None  # lives in prev's buffer
         prev = None  # (buf, start, token)
         while True:
@@ -1001,6 +1022,7 @@ def stream_chunk_batches(reader: Callable[[int], bytes],
             # guaranteed; assert to fail loudly rather than loop forever.
             assert consumed > 0, "chunker made no progress"
     finally:
+        registered.close()
         if ra is not None:
             ra.close()
 

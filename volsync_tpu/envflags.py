@@ -115,8 +115,11 @@ def batch_max() -> int:
 
 
 def batch_window_ms() -> float:
-    """How long (ms) the first segment of a batch waits for
-    companions."""
+    """The longest (ms) the first segment of a batch waits for
+    companions: for a producer that is registered and absent, or for
+    anybody on a batcher nobody registered with. A batch that holds a
+    segment of every registered producer does not wait
+    (ops/batcher.py)."""
     return env_float("VOLSYNC_BATCH_WINDOW_MS", 2.0, minimum=0.0)
 
 

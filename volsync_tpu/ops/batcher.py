@@ -4,13 +4,24 @@ Concurrent producers — gRPC ChunkHash handlers (service/server.py) or
 the backups of one process (engine/backup.py) — submit segments
 that coalesce into ONE batched device dispatch
 (ops/segment.chunk_hash_segments): the service/engine-side form of
-BASELINE configs[5]'s cross-PVC batching. A lone producer pays at most
-``window_ms``; a busy pipeline pays it never (the queue is already
-non-empty when the worker looks).
+BASELINE configs[5]'s cross-PVC batching.
+
+A batch that cannot grow is not made to wait. A *blocking* producer — a
+stream of ``engine/chunker.stream_chunk_batches``, which has one segment
+out and waits for it — says that it exists (``producer()``), and a
+batch goes the moment it holds a segment of every one of them: a lone
+stream's at once, two streams' as the second arrives. ``window_ms`` is
+the longest a segment waits for a producer that is registered and
+absent (between two files, or gone without saying so), or for anybody
+on a batcher nobody registered with (the service's, fed through
+``submit_async`` with many segments out at once, where a count of
+producers says nothing); a busy pipeline pays it never (the queue is
+already non-empty when the worker looks).
 """
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 from concurrent.futures import Future
@@ -19,7 +30,8 @@ from typing import Optional
 
 from volsync_tpu import envflags
 from volsync_tpu.analysis import lockcheck
-from volsync_tpu.obs import begin_span, current_context, span, use_context
+from volsync_tpu.obs import (begin_span, count, current_context, span,
+                             use_context)
 from volsync_tpu.ops.gearcdc import GearParams
 
 
@@ -53,14 +65,17 @@ def _dispatch_context(batch):
 
 class _Item:
     """One submitted segment: its lane, the caller's future, the
-    caller's trace context and the open ``ops.queue_wait`` span (submit
-    to the dispatch thread taking the batch: collector window, slot
-    wait, hand-over)."""
+    caller's trace context, the open ``ops.queue_wait`` span (submit
+    to the dispatch thread taking the batch: the collector's wait for
+    companions, slot wait, hand-over) and whether its caller waits for
+    it (``submit``: the way a registered producer comes)."""
 
-    __slots__ = ("data", "length", "eof", "future", "ctx", "qspan")
+    __slots__ = ("data", "length", "eof", "future", "ctx", "qspan",
+                 "blocking")
 
-    def __init__(self, data, length, eof):
+    def __init__(self, data, length, eof, blocking):
         self.data, self.length, self.eof = data, length, eof
+        self.blocking = blocking
         self.future: Future = Future()
         self.ctx = current_context()
         self.qspan = begin_span("ops.queue_wait", ctx=self.ctx)
@@ -72,8 +87,10 @@ class _Item:
 
 
 class SegmentMicroBatcher:
-    """Queue + worker thread: the first item waits up to ``window_ms``
-    for companions (bounded by ``max_batch``), the batch dispatches via
+    """Queue + worker thread: the first item waits for companions until
+    the batch is full (``max_batch``), holds a segment of every
+    registered producer (``producer()``: at once for a lone stream), or
+    ``window_ms`` has passed; the batch dispatches via
     BatchedSegmentHasher, and each caller's future resolves with its
     lane. ``stop()`` drains the queue — a future enqueued before stop
     is always resolved, never stranded."""
@@ -111,14 +128,47 @@ class SegmentMicroBatcher:
             for i in range(self._depth)]
         for t in self._dispatchers:
             t.start()
+        # blocking producers that said they exist (producer()); the
+        # collector's rule reads it, correctness never rests on it
+        self._producers = 0
+        self._producers_lock = lockcheck.make_lock("batcher.producers")
         self._stop = threading.Event()
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="segment-microbatcher")
         self._thread.start()
 
+    @contextlib.contextmanager
+    def producer(self):
+        """For as long as it is held, a blocking producer exists: one
+        that has at most one segment out (``submit``) and waits for it.
+        The collector stops waiting once it holds a segment of every
+        such producer. A registration that outlives its producer costs
+        the others the window, as before it existed; one that is
+        missing costs a companion; every caller gets its own lane's
+        result either way."""
+        with self._producers_lock:
+            self._producers += 1
+        try:
+            yield
+        finally:
+            with self._producers_lock:
+                self._producers -= 1
+                left = self._producers
+            if left:
+                # whoever waits for this producer need not any more
+                self._q.put(None)
+
+    def _complete(self, batch) -> bool:
+        """The batch holds a segment of every registered producer and
+        nothing else: nobody is left who could add to it."""
+        with self._producers_lock:
+            registered = self._producers
+        return (0 < registered <= len(batch)
+                and all(item.blocking for item in batch))
+
     def submit(self, data: bytes, length: int, eof: bool):
         """Blocking: returns (chunks, consumed) for this segment."""
-        return self.wait(self.submit_async(data, length, eof))
+        return self.wait(self._enqueue(data, length, eof, True))
 
     def wait(self, fut: Future):
         """Result of a future this batcher resolves. There is no
@@ -151,9 +201,12 @@ class SegmentMicroBatcher:
         transfer reads the caller's memory. The result is fetched
         before the future resolves, so the transfer has ended by
         then; the batcher lets go of ``data`` as it resolves."""
+        return self._enqueue(data, length, eof, False)
+
+    def _enqueue(self, data, length, eof, blocking: bool) -> Future:
         if self._stop.is_set():
             raise BatcherStopped("microbatcher stopped")
-        item = _Item(data, length, eof)
+        item = _Item(data, length, eof, blocking)
         self._q.put(item)
         return item.future
 
@@ -167,16 +220,26 @@ class SegmentMicroBatcher:
                 if self._stop.is_set():
                     return
                 continue
+            if first is None:  # a producer left, nobody was waiting
+                continue
             batch = [first]
             deadline = time_mod.monotonic() + self._window
-            while len(batch) < self._max_batch:
+            while True:
+                complete = self._complete(batch)
+                if complete or len(batch) >= self._max_batch:
+                    break
                 remaining = deadline - time_mod.monotonic()
                 if remaining <= 0:
                     break
                 try:
-                    batch.append(self._q.get(timeout=remaining))
+                    item = self._q.get(timeout=remaining)
                 except queue.Empty:
                     break
+                if item is not None:  # else a producer left: look again
+                    batch.append(item)
+            count("ops.batches")
+            if complete:
+                count("ops.batches_complete")
             # Interruptible slot wait: if every dispatch slot stays
             # occupied for 30 s AFTER stop() fires (the same bound
             # stop() grants in-flight dispatches — a healthy-but-slow
@@ -258,7 +321,8 @@ class SegmentMicroBatcher:
                 item = self._q.get_nowait()
             except queue.Empty:
                 break
-            item.fail(BatcherStopped("microbatcher stopped"))
+            if item is not None:
+                item.fail(BatcherStopped("microbatcher stopped"))
 
 
 _SHARED: dict = {}
