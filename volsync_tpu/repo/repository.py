@@ -64,7 +64,11 @@ from volsync_tpu.obs import (
 )
 from volsync_tpu.repo import blobid, crypto
 from volsync_tpu.repo.shardedindex import ShardedBlobIndex
-from volsync_tpu.repo.compress import Compressor, Decompressor
+from volsync_tpu.repo.compress import (
+    CompressError,
+    Compressor,
+    Decompressor,
+)
 from volsync_tpu.resilience import ResilientStore, RetryPolicy
 
 BLOB_DATA = "data"
@@ -832,50 +836,60 @@ class Repository:
         """One full pass over ``index/`` + ``pending-delete/`` into a
         fresh index (load_index holds repo.state and swaps it in), with
         the pending-delete packs and the index objects it read. Raises
-        _IndexReloadRace when the pass must restart."""
-        from volsync_tpu.repo.compress import CompressError
+        _IndexReloadRace when the pass must restart.
 
+        Three spans split the pass, one of each an index object and
+        all inside ``repo.load_index``: ``repo.index_fetch`` (the two
+        listings and the GETs), ``repo.index_decode`` (``box.open``,
+        zstd, ``json.loads``) and ``repo.index_insert`` (one
+        ``ShardedBlobIndex.insert`` an entry). They keep totals and
+        stay off the flight recorder's ring: a repository late in its
+        week holds a hundred small index objects, read twice a sync."""
         fresh = ShardedBlobIndex()
         # Pending set FIRST: a blob listed by several deltas (a crashed
         # pruner's old delta parks it in a marked pack, the consolidated
         # shard repoints it) must resolve to the non-pending home —
         # pending-pack entries never overwrite an existing entry below.
         pending: set[str] = set()
-        for _key, man in self._load_pending_manifests():
-            pending.update(man.get("packs", ()))
+        quiet = off_ring()
+        with span("repo.index_fetch", ctx=quiet):
+            for _key, man in self._load_pending_manifests():
+                pending.update(man.get("packs", ()))
+            keys = list(self.store.list("index/"))
         # Streaming: one index delta decoded at a time; entries land
         # in the flat compact index, never in per-entry objects.
-        keys = list(self.store.list("index/"))
         for key in keys:
+            payload = self._fetch_index_delta(key, quiet)
+            with span("repo.index_insert", ctx=quiet):
+                for pack_id, entries in payload["packs"].items():
+                    replace = pack_id not in pending
+                    for e in entries:
+                        fresh.insert(e["id"], pack_id, e["type"],
+                                     e["offset"], e["length"],
+                                     e["raw_length"], replace=replace)
+        return fresh, pending, len(keys)
+
+    def _fetch_index_delta(self, key: str, quiet) -> dict:
+        """One index object, fetched and decoded. A torn body (the
+        writer's PUT may still be retrying: a torn write leaves a
+        truncated object the retry overwrites) is fetched once more;
+        one that stays undecodable, or a key consolidated away
+        mid-scan, restarts the pass (_IndexReloadRace)."""
+        torn = None
+        for _attempt in range(2):
             try:
-                raw = self.store.get(key)
+                with span("repo.index_fetch", ctx=quiet):
+                    raw = self.store.get(key)
             except NoSuchKey:
                 raise _IndexReloadRace(
                     f"index delta {key} consolidated mid-scan") from None
             try:
-                payload = self._decode_index_delta(raw)
-            except (ValueError, CompressError):
-                # Torn body: the writer's PUT may still be retrying
-                # (a torn write leaves a truncated object the retry
-                # overwrites). One re-fetch, then restart the pass.
-                try:
-                    payload = self._decode_index_delta(
-                        self.store.get(key))
-                except NoSuchKey:
-                    raise _IndexReloadRace(
-                        f"index delta {key} consolidated mid-scan"
-                    ) from None
-                except (ValueError, CompressError) as ex:
-                    raise _IndexReloadRace(
-                        f"index delta {key} stayed undecodable: {ex}"
-                    ) from ex
-            for pack_id, entries in payload["packs"].items():
-                replace = pack_id not in pending
-                for e in entries:
-                    fresh.insert(e["id"], pack_id, e["type"],
-                                 e["offset"], e["length"],
-                                 e["raw_length"], replace=replace)
-        return fresh, pending, len(keys)
+                with span("repo.index_decode", ctx=quiet):
+                    return self._decode_index_delta(raw)
+            except (ValueError, CompressError) as ex:
+                torn = ex
+        raise _IndexReloadRace(
+            f"index delta {key} stayed undecodable: {torn}") from torn
 
     def _load_pending_manifests(self) -> list[tuple[str, dict]]:
         """``[(key, manifest)]`` under ``pending-delete/``, skipping

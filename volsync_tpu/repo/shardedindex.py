@@ -2,10 +2,13 @@
 prefilter in front of the flat CompactIndex.
 
 At million-to-billion-chunk scale the dedup *index* — not the hash —
-becomes the bottleneck (PAPERS.md, arxiv 2602.22237): PR 1's pipeline
-batches chunking and hashing on device, but every chunk's dedup
-decision still funneled through one repository-wide mutex into a
-per-key Python probe loop. This module removes both serializers:
+becomes the bottleneck (PAPERS.md, arxiv 2602.22237): the engine
+chunks and hashes a whole segment on the device, and what is left a
+chunk on the host is the question "does the repository hold this
+id?". The repository asks it a segment's chunks at a time
+(``Repository.add_blobs``/``has_blobs`` -> ``contains_many``) and
+without ``repo.state`` (``has_blobs``), so this module is what
+synchronizes the index:
 
 * **Sharding.** Blob ids are uniform SHA-256, so splitting on the top
   ``log2(S)`` key bits is free and perfectly balanced. Each shard is a
@@ -21,7 +24,11 @@ per-key Python probe loop. This module removes both serializers:
   batch (hex list or ``(N, 32)`` array — see
   ``compactindex.as_key_rows``), partition it by shard, and resolve
   each partition with CompactIndex's vectorized numpy probe — a
-  handful of gather/compare passes instead of N Python loops.
+  handful of gather/compare passes instead of N Python loops. A batch
+  of at most ``_SMALL_BATCH_PER_SHARD`` keys a shard (512 at the
+  default 16 shards: every batch a backup's segment makes) takes
+  scalar probes under one lock a touched shard instead, and does not
+  consult the prefilter.
 
 * **Prefilter.** A per-shard blocked-bloom filter answers "definitely
   absent" for the first-backup workload where nearly every query is a
@@ -30,6 +37,20 @@ per-key Python probe loop. This module removes both serializers:
   update there would be a *false negative*, which a bloom filter must
   never produce). Removes don't clear bits (stale "maybe" is just an
   extra probe); vacuum and auto-grow rebuild from live keys.
+
+* **Counters.** Membership questions are counted in the repository's
+  own tracing (``obs.count``; ``INDEX_COUNTERS``), once a call with
+  the batch's size: ``index.queries`` (keys asked, ``__contains__``
+  and the batched calls alike), ``index.hits`` (keys found),
+  ``index.prefilter_skips`` (keys the filter answered "absent", no
+  probe) and ``index.prefilter_false_positives`` (keys the filter let
+  through that the probe did not find). A key that takes the scalar
+  path is a query and, found, a hit, and neither of the other two: of
+  a vectorized batch, with the filter on, skips + hits + false
+  positives are its queries.
+  The Prometheus counters (``volsync_index_queries_total``,
+  ``volsync_index_prefilter_total``) move on the batched paths as
+  before.
 
 Lock order: ``repo.state`` -> ``repo.index.shard{i}``. The index never
 calls back into the repository or the object store, so no blocking
@@ -46,7 +67,13 @@ import numpy as np
 from volsync_tpu import envflags
 from volsync_tpu.analysis import lockcheck
 from volsync_tpu.metrics import GLOBAL as GLOBAL_METRICS
+from volsync_tpu.obs import count
 from volsync_tpu.repo.compactindex import CompactIndex, as_key_rows
+
+#: the membership counters this module keeps in ``obs.count`` (see the
+#: module's docstring); a reader of layer metrics asks for them by name
+INDEX_COUNTERS = ("index.queries", "index.hits", "index.prefilter_skips",
+                  "index.prefilter_false_positives")
 
 # Metric children resolved once: .labels() costs a dict lookup under a
 # lock per call — real money on the per-batch query path.
@@ -204,7 +231,11 @@ class ShardedBlobIndex:
         k4 = CompactIndex._key4(hex_id)
         s = self._shard_of(k4)
         with self._locks[s]:
-            return self._shards[s]._probe(k4)[1] >= 0
+            found = self._shards[s]._probe(k4)[1] >= 0
+        count("index.queries")
+        if found:
+            count("index.hits")
+        return found
 
     def lookup(self, hex_id: str):
         k4 = CompactIndex._key4(hex_id)
@@ -357,7 +388,9 @@ class ShardedBlobIndex:
                         if entries is not None:
                             entries[i] = sh._decode_row(j)
         nhit = int(mask.sum())
+        count("index.queries", int(mask.shape[0]))
         if nhit:
+            count("index.hits", nhit)
             _M_HIT.inc(nhit)
         if mask.shape[0] - nhit:
             _M_MISS.inc(mask.shape[0] - nhit)
@@ -408,15 +441,19 @@ class ShardedBlobIndex:
                 passes += int(hits.sum())
                 false_pos += sel.shape[0] - nskip - int(hits.sum())
         nhit = int(mask.sum())
+        count("index.queries", n)
         if nhit:
+            count("index.hits", nhit)
             _M_HIT.inc(nhit)
         if n - nhit:
             _M_MISS.inc(n - nhit)
         if skips:
+            count("index.prefilter_skips", skips)
             _M_SKIP.inc(skips)
         if passes:
             _M_PASS.inc(passes)
         if false_pos:
+            count("index.prefilter_false_positives", false_pos)
             _M_FP.inc(false_pos)
         return mask, entries
 
