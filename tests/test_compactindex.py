@@ -6,7 +6,11 @@ no-false-negative guarantee.
 The fuzz drives every mutating op (insert, replace, setdefault-insert,
 remove, vacuum, copy) from tiny capacities so table rebuilds and
 tombstone reuse happen constantly, then checks the index agrees with
-the dict byte for byte. Snapshot keys are compared as raw 32-byte
+the dict byte for byte. The bulk insert (``insert_many``, what
+``load_index`` places a whole load with) is held to ``insert`` an entry
+on the same steps, order of rows and of interned names included, and
+the slot table's numpy placement to the list loop it replaced. Snapshot
+keys are compared as raw 32-byte
 values (S32), never via ``.hex()`` of a ``tolist()`` round-trip —
 numpy strips trailing NULs from S32 scalars.
 """
@@ -17,7 +21,13 @@ import zlib
 import numpy as np
 import pytest
 
-from volsync_tpu.repo.compactindex import CompactIndex, as_key_rows
+from volsync_tpu.repo.compactindex import (
+    _EMPTY,
+    CompactIndex,
+    as_key_rows,
+    id_bytes,
+    place_slots,
+)
 from volsync_tpu.repo.shardedindex import (
     BloomPrefilter,
     ShardedBlobIndex,
@@ -317,3 +327,314 @@ def test_snapshot_arrays_remap_under_concurrent_inserts():
     assert got == expect_raw
     assert set(names) == set(packs)
     assert idx.live_packs() == set(packs)
+
+
+# -- the bulk insert against insert an entry ----------------------------------
+
+BULK_KINDS = {
+    "compact": lambda: CompactIndex(capacity=16),
+    **{f"sharded{n}-{'filter' if on else 'nofilter'}":
+       (lambda n=n, on=on: ShardedBlobIndex(shards=n, capacity=16,
+                                            prefilter=on))
+       for n in (1, 4, 16) for on in (True, False)},
+}
+
+
+def _entries(ids, rng, replace, packs=7):
+    """(id, pack, type, offset, length, raw_length, replace) an id."""
+    n = len(ids)
+    rep = (rng.random_sample(n) < 0.5 if replace == "mixed"
+           else np.full(n, replace))
+    return [(h, f"pack{rng.randint(packs)}", ("data", "tree")[i % 5 == 0],
+             int(rng.randint(2**40)), int(rng.randint(2**32)),
+             int(rng.randint(2**32)), bool(r))
+            for i, (h, r) in enumerate(zip(ids, rep))]
+
+
+def _bulk(idx, entries, keys=None):
+    """``entries`` through ``insert_many``: names in their order of
+    appearance, a code an entry."""
+    packs, kinds = {}, {}
+    codes = [packs.setdefault(e[1], len(packs)) for e in entries]
+    tcodes = [kinds.setdefault(e[2], len(kinds)) for e in entries]
+    if keys is None:
+        keys = [e[0] for e in entries]
+    if isinstance(idx, CompactIndex):
+        keys = as_key_rows(keys)
+    return idx.insert_many(
+        keys, list(packs), codes, list(kinds), tcodes,
+        [e[3] for e in entries], [e[4] for e in entries],
+        [e[5] for e in entries], [e[6] for e in entries])
+
+
+def _one_by_one(idx, entries):
+    before = len(idx)
+    for h, pack, kind, off, length, raw, rep in entries:
+        idx.insert(h, pack, kind, off, length, raw, replace=rep)
+    return len(idx) - before
+
+
+def _run(idx, steps, bulk):
+    added = []
+    for step, arg in steps:
+        if step == "batch":
+            added.append((_bulk if bulk else _one_by_one)(idx, arg))
+        elif step == "remove":
+            assert all([idx.remove(h) for h in arg])
+        else:
+            idx.vacuum()
+    return added
+
+
+def _state(idx):
+    keys, codes, names = idx.snapshot_arrays()
+    return (list(idx.items()), len(idx), idx.live_packs(),
+            keys.tobytes(), codes.tolist(), names)
+
+
+def _assert_same(one, many, asked):
+    assert _state(many) == _state(one)
+    assert list(many) == list(one) and _state(many.copy()) == _state(one)
+    if isinstance(one, CompactIndex):
+        batches = [asked]
+    else:  # the scalar path of a batched question, and the vectorized
+        batches = [asked[:_SMALL_BATCH_PER_SHARD // 2], asked]
+    for batch in batches:
+        assert (many.contains_many(batch).tolist()
+                == one.contains_many(batch).tolist()
+                == [h in one for h in batch])
+        assert (many.lookup_many(batch) == one.lookup_many(batch)
+                == [many.lookup(h) for h in batch])
+
+
+def _steps(case, rng):
+    ids, absent = hex_ids(rng, 1400), hex_ids(rng, 700)
+    if case == "duplicates-in-a-batch-replace":
+        # an id three times in one batch: the last occurrence wins
+        listed = ids[:900] + ids[100:500] + ids[300:400]
+        steps = [("batch", _entries(listed, rng, True))]
+    elif case == "duplicates-in-a-batch-keep":
+        listed = ids[:900] + ids[100:500] + ids[300:400]
+        steps = [("batch", _entries(listed, rng, False))]  # the first
+    elif case == "duplicates-in-a-batch-mixed":
+        # the last that replaces if any does, else the first
+        listed = ids[:900] + ids[:900] + ids[200:700] + ids[850:1000]
+        steps = [("batch", _entries(listed, rng, "mixed"))]
+    elif case == "duplicates-across-two-batches":
+        steps = [("batch", _entries(ids[:800], rng, "mixed")),
+                 ("batch", _entries(ids[400:1200] + ids[300:500], rng,
+                                    "mixed"))]
+    elif case == "remove-then-bulk":
+        # tombstones in the table and dead rows under the batch; a
+        # removed id comes back as a new row
+        steps = [("batch", _entries(ids[:600], rng, True)),
+                 ("remove", ids[100:400]),
+                 ("batch", _entries(ids[300:1000], rng, "mixed"))]
+    elif case == "bulk-then-remove-then-vacuum":
+        steps = [("batch", _entries(ids[:1000] + ids[:50], rng, "mixed")),
+                 ("remove", ids[200:900:2]),
+                 ("vacuum", None),
+                 ("batch", _entries(ids[800:1400], rng, "mixed", packs=11)),
+                 ("remove", ids[1300:1400]),
+                 ("vacuum", None)]
+    elif case == "an-empty-batch":
+        steps = [("batch", []), ("batch", _entries(ids[:40], rng, True)),
+                 ("batch", [])]
+    else:
+        raise AssertionError(case)
+    return steps, ids + absent
+
+
+@pytest.mark.parametrize("case", [
+    "duplicates-in-a-batch-replace", "duplicates-in-a-batch-keep",
+    "duplicates-in-a-batch-mixed", "duplicates-across-two-batches",
+    "remove-then-bulk", "bulk-then-remove-then-vacuum", "an-empty-batch"])
+@pytest.mark.parametrize("kind", list(BULK_KINDS))
+def test_bulk_insert_leaves_what_insert_an_entry_leaves(kind, case):
+    """The same steps with every batch through ``insert`` an entry and
+    through ``insert_many``: the same items in the same order, the same
+    snapshot arrays (the interned names' order too), the same answers
+    to present and absent ids, the same count of ids added a batch."""
+    steps, asked = _steps(case, np.random.RandomState(
+        zlib.crc32(case.encode()) % 2**31))
+    one, many = BULK_KINDS[kind](), BULK_KINDS[kind]()
+    assert _run(many, steps, bulk=True) == _run(one, steps, bulk=False)
+    _assert_same(one, many, asked)
+    if case == "an-empty-batch":
+        assert len(many) == 40
+
+
+@pytest.mark.parametrize("bad", ["short-id", "not-hex", "length",
+                                 "raw-length", "negative-length",
+                                 "ragged-columns"])
+@pytest.mark.parametrize("kind", list(BULK_KINDS))
+def test_bulk_insert_refuses_a_bad_batch_whole(kind, bad):
+    """A batch with one entry ``insert`` would refuse raises
+    ``ValueError`` and leaves the index as it was: no shard has taken
+    its part."""
+    rng = np.random.RandomState(31)
+    ids = hex_ids(rng, 300)
+    one, many = BULK_KINDS[kind](), BULK_KINDS[kind]()
+    held = [("batch", _entries(ids[:100], rng, True))]
+    _run(one, held, bulk=False)
+    _run(many, held, bulk=True)
+    batch = _entries(ids[50:300], rng, True)
+    keys = [e[0] for e in batch]
+    at = 249  # the last entry: every shard's part comes before it
+    if bad == "short-id":
+        keys[at] = keys[at][:62]
+    elif bad == "not-hex":
+        keys[at] = "zz" + keys[at][2:]
+    elif bad == "length":
+        batch[at] = batch[at][:4] + (2**32,) + batch[at][5:]
+    elif bad == "raw-length":
+        batch[at] = batch[at][:5] + (2**32, True)
+    elif bad == "negative-length":
+        batch[at] = batch[at][:4] + (-1,) + batch[at][5:]
+    with pytest.raises(ValueError):
+        if bad == "ragged-columns":
+            many.insert_many(as_key_rows(keys), ["p"], [0] * 249, ["data"],
+                             [0] * 250, [0] * 250, [1] * 250, [1] * 250)
+        else:
+            _bulk(many, batch, keys=keys)
+    _assert_same(one, many, ids)
+    if bad in ("length", "raw-length"):  # what insert an entry says
+        with pytest.raises(ValueError):
+            _one_by_one(one, batch[at:])
+    if bad in ("short-id", "not-hex"):
+        with pytest.raises(ValueError):
+            id_bytes(keys)
+    # two ids whose lengths add up are not two ids
+    with pytest.raises(ValueError):
+        id_bytes([ids[0][:62], ids[1] + "00"])
+    assert id_bytes(ids[:2]) == bytes.fromhex(ids[0] + ids[1])
+
+
+@pytest.mark.parametrize("shards", [1, 4, 16])
+def test_bulk_insert_never_makes_the_prefilter_say_absent(shards):
+    """After a first batch that outgrows every shard's filter (built
+    once, at the shard's final size) and a second that fits (added to
+    it), the filter says "maybe" of every id either put in, and the
+    vectorized question, which asks it first, finds them all."""
+    idx = ShardedBlobIndex(shards=shards, capacity=16, prefilter=True)
+    rng = np.random.RandomState(37)
+    ids, absent = hex_ids(rng, 5000 * shards + 600), hex_ids(rng, 2000)
+    first, second = ids[:5000 * shards], ids[5000 * shards - 50:]
+    small = {f.capacity for f in idx._filters}
+    assert _bulk(idx, _entries(first, rng, True)) == len(first)
+    grown = [f.capacity for f in idx._filters]
+    assert all(cap > max(small) for cap in grown)
+    assert _bulk(idx, _entries(second, rng, "mixed")) == 600
+    assert [f.capacity for f in idx._filters] == grown  # added, not rebuilt
+    rows = as_key_rows(ids)
+    sid = idx._shard_ids(rows)
+    for s, f in enumerate(idx._filters):
+        assert f.maybe_contains_rows(rows[sid == s]).all()
+    assert idx.contains_many(ids).all()
+    assert not idx.contains_many(absent).any()
+    assert 0.0 < idx.prefilter_saturation() < 0.5
+    for h in ids[::7]:  # a removed id stays "maybe"; vacuum rebuilds
+        idx.remove(h)
+    idx.vacuum()
+    assert idx.contains_many(ids).tolist() == [i % 7 != 0
+                                               for i in range(len(ids))]
+
+
+def test_bulk_insert_takes_every_key_form_and_one_replace_for_all():
+    rng = np.random.RandomState(41)
+    ids = hex_ids(rng, 64)
+    raw = id_bytes(ids)
+    forms = [ids, np.frombuffer(raw, dtype=np.uint8).reshape(-1, 32),
+             np.frombuffer(raw, dtype="S32"), as_key_rows(ids)]
+    want = None
+    for form in forms:
+        idx = ShardedBlobIndex(shards=4, capacity=16)
+        codes = np.arange(64) % 3
+        assert idx.insert_many(form, ["a", "b", "c"], codes, ["data"],
+                               np.zeros(64, dtype=np.uint8),
+                               np.arange(64), np.full(64, 5),
+                               np.full(64, 4, dtype=np.uint32)) == 64
+        # replace=False for all: nothing moves
+        assert idx.insert_many(form, ["z"], np.zeros(64, dtype=int),
+                               ["tree"], np.zeros(64, dtype=int),
+                               np.zeros(64), np.ones(64), np.ones(64),
+                               replace=False) == 0
+        assert idx.live_packs() == {"a", "b", "c"}
+        got = list(idx.items())
+        assert want is None or got == want
+        want = got
+    assert dict(want)[ids[4]] == ("b", "data", 4, 5, 4)
+
+
+# -- the slot table's placement ------------------------------------------------
+
+
+def _list_loop(rows, homes, size):
+    """The loop ``_rebuild_table`` ran up to PR 51."""
+    table, mask = [_EMPTY] * size, size - 1
+    for j, i in zip(rows.tolist(), homes.tolist()):
+        while table[i] != _EMPTY:
+            i = (i + 1) & mask
+        table[i] = j
+    return np.asarray(table, dtype=np.int64)
+
+
+def _homes(pattern, n, size, rng):
+    if pattern == "random":
+        return rng.randint(0, size, n)
+    if pattern == "one-home":
+        return np.full(n, size // 3)
+    if pattern == "one-home-at-the-end":  # all but one position wrap
+        return np.full(n, size - 1)
+    if pattern == "the-last-slots":  # runs that pass the end and wrap
+        return rng.randint(size - 8, size, n)
+    if pattern == "both-ends":  # the wrapped meet keys whose home is 0..
+        return np.concatenate([rng.randint(size - 4, size, n // 2),
+                               rng.randint(0, 6, n - n // 2)])
+    raise AssertionError(pattern)
+
+
+@pytest.mark.parametrize("n,size", [(1, 32), (10, 32), (300, 1024),
+                                    (341, 1024)])
+@pytest.mark.parametrize("pattern", ["random", "one-home",
+                                     "one-home-at-the-end",
+                                     "the-last-slots", "both-ends"])
+def test_place_slots_against_the_list_loop(pattern, n, size):
+    """The numpy placement fills the slots the list loop fills (linear
+    probing's occupied set does not depend on the order of insertion),
+    every key is found from its home by ``_probe`` and by
+    ``probe_rows``, and a key that is not there ends at an ``_EMPTY``
+    slot: on random homes and on adversarial ones."""
+    rng = np.random.RandomState(n * 7 + size)
+    homes = _homes(pattern, n, size, rng).astype(np.int64)
+    # rows out of order and not dense, as live rows among dead ones are
+    rows = np.sort(rng.permutation(3 * n)[:n]).astype(np.int64)
+    table = place_slots(rows, homes, size)
+    loop = _list_loop(rows, homes, size)
+    assert table.shape == loop.shape == (size,)
+    assert ((table == _EMPTY) == (loop == _EMPTY)).all()
+    assert sorted(table[table >= 0].tolist()) == rows.tolist()
+    # an index over that table: word 0's low bits are the home
+    idx = CompactIndex(capacity=3 * n)
+    words = rng.randint(0, 2**62, (3 * n, 4)).astype(np.uint64)
+    words[rows, 0] = ((words[rows, 0] & ~np.uint64(size - 1))
+                      | homes.astype(np.uint64))
+    idx._keys[: 3 * n] = words
+    idx._n, idx._live = 3 * n, n
+    idx._table, idx._mask = table, size - 1
+    assert idx.probe_rows(words[rows]).tolist() == rows.tolist()
+    for row in rows.tolist():
+        assert idx._probe(words[row].tolist())[1] == row
+    # the same homes, other ids: each walk ends at an _EMPTY, not found
+    absent = words[rows].copy()
+    absent[:, 3] ^= np.uint64(1)
+    assert (idx.probe_rows(absent) == -1).all()
+    for k4 in absent.tolist():
+        slot, row = idx._probe(k4)
+        assert row == -1 and table[slot] == _EMPTY
+
+
+def test_place_slots_refuses_a_table_it_would_fill():
+    with pytest.raises(ValueError):
+        place_slots(np.arange(32), np.zeros(32, dtype=np.int64), 32)
+    assert (place_slots(np.arange(0), np.arange(0), 32) == _EMPTY).all()

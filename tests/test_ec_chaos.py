@@ -26,6 +26,7 @@ import hashlib
 import json
 import threading
 import time
+from datetime import datetime, timezone
 from collections import Counter
 
 import numpy as np
@@ -397,6 +398,12 @@ def test_chaos_ec_storm(tmp_path, monkeypatch, name, seed, make_specs):
     root = tmp_path / "store"
     fs = FsObjectStore(str(root))
     _backup(fs, src)
+    # the restores ask for the volume as of NOW (RESTORE_AS_OF): the
+    # snapshot the live writer saves mid-storm is newer, and whether it
+    # lands before a restore lists the snapshots is the scheduler's to
+    # say (a restore that picked it up after loading its index found
+    # its tree in no index it held, and it has 3 files, not 6)
+    as_of = datetime.now(timezone.utc)
     # durable loss up front: m shards of one stripe are just gone
     shards = _shards_of(fs)
     assert len(shards) >= 2  # need a second stripe to carry the weather
@@ -424,7 +431,7 @@ def test_chaos_ec_storm(tmp_path, monkeypatch, name, seed, make_specs):
         group = RestoreGroup()
         dests = [tmp_path / f"dst{i}" for i in range(2)]
         for d in dests:
-            group.add(Repository.open(top), d)
+            group.add(Repository.open(top), d, restore_as_of=as_of)
         results = group.run()
         writer.join()
     assert all(r is not None and r["files"] == 6 for r in results)

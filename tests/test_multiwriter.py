@@ -299,6 +299,65 @@ def test_backup_started_mid_prune_completes(tmp_path):
     assert Repository.open(fs).check(read_data=True) == []
 
 
+class _PublishAfterListing:
+    """A store that, once armed, runs ``publish`` right after the next
+    listing of ``index/`` made through it: the listing a pruner's
+    ``load_index`` reads from."""
+
+    def __init__(self, inner):
+        self._inner, self._publish = inner, None
+
+    def arm(self, publish):
+        self._publish = publish
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    def list(self, prefix=""):
+        keys = list(self._inner.list(prefix))
+        if prefix == "index/" and self._publish is not None:
+            publish, self._publish = self._publish, None
+            publish()
+        return iter(keys)
+
+
+def test_a_delta_published_after_the_load_survives_the_consolidation(
+        tmp_path):
+    """A writer publishes a pack and its index delta between the
+    listing a prune's ``load_index`` reads and the consolidation (its
+    snapshot comes later: a backup still under way). The prune never
+    read that delta, so it does not supersede it: a second listing at
+    that point named it, it was deleted unread, and the snapshot saved
+    next pointed at blobs no index held."""
+    fs = MemObjectStore()
+    Repository.init(fs, chunker=CHUNKER)
+    TreeBackup(Repository.open(fs)).run(_write_tree(tmp_path, "a", seed=1))
+    writer = Repository.open(fs)
+    data = np.random.RandomState(5).bytes(50_000)
+    published = []
+
+    def publish():
+        before = set(fs.list("index/"))
+        bid = blobid.blob_id(data)
+        assert writer.add_blob("data", bid, data)
+        writer.flush()
+        published.append((bid, set(fs.list("index/")) - before))
+
+    store = _PublishAfterListing(fs)
+    pruner = Repository.open(store)
+    store.arm(publish)
+    pruner.prune(grace_seconds=0.2)
+    (bid, delta), = published
+    assert len(delta) == 1 and not delta & pruner._loaded_deltas
+    assert delta <= set(fs.list("index/"))
+    # the index still holds the entry and the blob reads back (its pack,
+    # which no index object the prune read names, is parked as an
+    # orphan: the sweep's gate, a live writer's lock, is another test's)
+    fresh = Repository.open(fs)
+    assert bid in fresh.blob_ids() and fresh.read_blob(bid) == data
+    assert fresh.check(read_data=True) == []
+
+
 def test_backup_lands_while_victims_await_sweep(tmp_path):
     """After the mark phase (manifest written, grace running), backups
     proceed normally, never dedup into marked packs, and the deferred

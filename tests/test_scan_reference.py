@@ -255,22 +255,78 @@ def test_load_index_over_three_objects_gives_what_one_would(tmp_path,
     assert set(ids) == set(loaded["one"][0])
 
 
+def _index_object(repo, name: str, packs: dict) -> None:
+    """One index object, as ``_write_index_delta`` seals it."""
+    body = repo.box.seal(repo._zc.compress(
+        json.dumps({"packs": packs}).encode()))
+    repo.store.put(f"index/{name}", body)
+
+
+@pytest.mark.parametrize("first", ["parked-first", "parked-last"])
+def test_an_id_two_objects_list_ends_in_the_pack_that_stays(first):
+    """An id listed by two index objects, one of them under a pack that
+    ``pending-delete/`` names (a crashed pruner's old delta): whichever
+    object is read first, the load ends with the LAST listing in a pack
+    that stays, else the FIRST in a parked pack, and the id's row where
+    its first listing put it."""
+    # a store that lists by name: the order the objects are read in
+    repo = Repository.init(_store("mem:"), password=mover.PASSWORD)
+    ids = [f"{i:064x}" for i in range(1, 7)]
+
+    def entry(bid, offset):
+        return {"id": bid, "type": "data", "offset": offset, "length": 9,
+                "raw_length": 7}
+    parked = {"a" * 64: [entry(ids[0], 10), entry(ids[1], 11),
+                         entry(ids[4], 14)],
+              "b" * 64: [entry(ids[4], 24), entry(ids[5], 25)]}
+    stays = {"c" * 64: [entry(ids[0], 30), entry(ids[2], 32)],
+             "d" * 64: [entry(ids[0], 40), entry(ids[3], 43)]}
+    names = ("0" * 64, "1" * 64)
+    for name, packs in zip(names if first == "parked-first"
+                           else names[::-1], (parked, stays)):
+        _index_object(repo, name, packs)
+    repo.store.put("pending-delete/x", json.dumps(
+        {"packs": ["a" * 64, "b" * 64]}).encode())
+    reset_spans()
+    repo.load_index()
+    want = {ids[0]: ("d" * 64, 40),  # the last listing in a pack that stays
+            ids[1]: ("a" * 64, 11), ids[2]: ("c" * 64, 32),
+            ids[3]: ("d" * 64, 43),
+            ids[4]: ("a" * 64, 14),  # parked twice: the first listing
+            ids[5]: ("b" * 64, 25)}
+    got = {bid: (pack, offset)
+           for bid, (pack, _, offset, _, _) in repo._index.items()}
+    assert got == want
+    # by shard, then by first listing: every id here is of shard 0
+    order = [0, 1, 4, 5, 2, 3] if first == "parked-first" \
+        else [0, 2, 3, 1, 4, 5]
+    assert list(repo._index) == [ids[i] for i in order]
+    counts = counter_totals()
+    assert counts["repo.index_bulk_entries"] == 6 \
+        == counts["repo.index_entries"]
+    assert not repo.has_blobs([ids[1], ids[4]]).any()  # parked: not held
+    assert repo.has_blobs([ids[0], ids[2], ids[3]]).all()
+
+
 def test_the_loads_spans_nest_and_split_it(schedule):
     """``repo.index_fetch``, ``repo.index_decode`` and
     ``repo.index_insert`` close inside ``repo.load_index`` (its self
     time is what they leave), one decode and one insert an index
-    object; they keep totals and leave no event on the ring."""
+    object and one insert more for the load's placement, which puts
+    every entry in by the column (``repo.index_bulk_entries``); they
+    keep totals and leave no event on the ring."""
     reset_trace()
     reset_spans()
     with trace_context(sampled=True):
         repo = mover.open_repo(_env(schedule["repo"]))
-    objects = counter_totals()["repo.index_objects"]
+    counts = counter_totals()
+    objects = counts["repo.index_objects"]
     assert objects == 2 + SYNCS and len(repo.blob_ids()) \
-        == counter_totals()["repo.index_entries"]
+        == counts["repo.index_entries"] == counts["repo.index_bulk_entries"]
     parts = ("repo.index_fetch", "repo.index_decode", "repo.index_insert")
     totals, own = span_totals(), span_self_totals()
     assert totals["repo.index_decode"][0] == objects \
-        == totals["repo.index_insert"][0]
+        == totals["repo.index_insert"][0] - 1
     assert totals["repo.index_fetch"][0] == objects + 1  # the listings
     whole, inside = totals["repo.load_index"][1], sum(
         totals[name][1] for name in parts)

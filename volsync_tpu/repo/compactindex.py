@@ -15,6 +15,14 @@ once. ~10x less RAM than the dict, no per-entry Python objects, and a
 Deletions (prune) leave tombstones in the slot table and a dead mark in
 the entry arrays; ``vacuum()`` rebuilds both dense. The table rebuilds
 automatically when live+tombstone load crosses ~2/3.
+
+Two ways in: ``insert`` an entry (a writer's new blobs, the seal path)
+and ``insert_many`` a batch given as columns (``Repository.load_index``:
+a whole load's entries at once). The batch is resolved against what the
+index holds and against itself, appended a column at a time after
+ONE growth, and the slot table is placed once for the final live count by
+``place_slots``, the numpy placement every table rebuild uses. Both
+leave the same index behind.
 """
 
 from __future__ import annotations
@@ -55,6 +63,91 @@ def as_key_rows(keys) -> np.ndarray:
         raise ValueError("blob ids must each be 32 bytes hex")
     return (np.frombuffer(raw, dtype=">u8").astype(np.uint64)
             .reshape(-1, 4))
+
+
+def id_bytes(hex_ids: list) -> bytes:
+    """The raw digests of a list of hex blob ids, 32 bytes an id in the
+    list's order (what ``as_key_rows`` takes as an ``(N, 32)`` uint8
+    array); ``ValueError`` for an id that is not 32 bytes of hex."""
+    raw = bytes.fromhex("".join(hex_ids))
+    if len(raw) != 32 * len(hex_ids) or set(map(len, hex_ids)) - {64}:
+        raise ValueError("blob ids must each be 32 bytes hex")
+    return raw
+
+
+def place_slots(rows: np.ndarray, homes: np.ndarray,
+                size: int) -> np.ndarray:
+    """A linear-probing slot table of ``size`` slots holding ``rows``,
+    each at or after its home slot with no ``_EMPTY`` between (all a
+    lookup needs), placed by numpy: no loop over the entries.
+
+    With the homes sorted (stably, so keys of one home keep their
+    order), key ``i`` lands on the first slot that is both at or after
+    its home and after key ``i - 1``'s: ``max(h[i], pos[i - 1] + 1)``,
+    which unrolls to a running maximum of ``h - i``. The few whose slot
+    runs past the table's end wrap: everything from their home to the
+    end is taken, so they fill the first free slots from 0."""
+    table = np.full((size,), _EMPTY, dtype=np.int64)
+    n = int(rows.shape[0])
+    if n == 0:
+        return table
+    if n >= size:
+        raise ValueError(f"{n} rows do not fit a table of {size} slots")
+    order = np.argsort(homes, kind="stable")
+    ramp = np.arange(n, dtype=np.int64)
+    pos = np.maximum.accumulate(homes[order] - ramp) + ramp
+    fit = int(np.searchsorted(pos, size))  # pos is strictly rising
+    rows = rows[order]
+    table[pos[:fit]] = rows[:fit]
+    if fit < n:
+        table[np.flatnonzero(table == _EMPTY)[: n - fit]] = rows[fit:]
+    return table
+
+
+def _whole(values) -> np.ndarray:
+    """``values`` as an array of whole numbers: an integer array as it
+    is (a narrow one stays narrow)."""
+    values = np.asarray(values)
+    return values if values.dtype.kind in "iu" else values.astype(np.int64)
+
+
+def batch_columns(n: int, pack_codes, type_codes, offset, length,
+                  raw_length, replace) -> tuple:
+    """The columns of an ``insert_many`` batch of ``n`` entries as
+    arrays, the lengths as the entry arrays hold them (uint32);
+    ``ValueError`` for a length ``insert`` would refuse."""
+    lengths = [_whole(length), _whole(raw_length)]
+    if any(col.dtype != np.uint32 and (col.astype(np.int64) >> 32).any()
+           for col in lengths):
+        raise ValueError("blob larger than 4 GiB cannot be indexed")
+    cols = (_whole(pack_codes), _whole(type_codes),
+            np.asarray(offset, dtype=np.uint64),
+            *(col.astype(np.uint32, copy=False) for col in lengths),
+            np.broadcast_to(np.asarray(replace, dtype=bool), (n,)))
+    if any(col.shape != (n,) for col in cols):
+        raise ValueError(f"every column of the batch holds {n} entries")
+    return cols
+
+
+def _occurrences(k4: np.ndarray,
+                 replace: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """The distinct ids of a batch, in the order they first occur:
+    -> (the position of each one's first occurrence, the position of
+    its last occurrence that replaces or -1)."""
+    n = int(k4.shape[0])
+    by_word = np.sort(k4[:, 1])
+    if not (by_word[1:] == by_word[:-1]).any():  # no id twice
+        lead = np.arange(n, dtype=np.int64)
+        return lead, np.where(replace, lead, -1)
+    # stable, so the occurrences of one id stay in the batch's order
+    order = np.lexsort((k4[:, 3], k4[:, 2], k4[:, 1], k4[:, 0]))
+    ks = k4[order]
+    starts = np.flatnonzero(np.concatenate(
+        ([True], (ks[1:] != ks[:-1]).any(axis=1))))
+    lead = order[starts]
+    last = np.maximum.reduceat(np.where(replace[order], order, -1), starts)
+    by_first = np.argsort(lead)
+    return lead[by_first], last[by_first]
 
 
 class CompactIndex:
@@ -214,10 +307,13 @@ class CompactIndex:
         rows = np.nonzero(self._pack[: self._n] != _DEAD_PACK)[0]
         return self._keys[rows].copy()
 
-    def _grow_entries(self):
+    def _grow_entries(self, rows: int = 0):
+        """Double the entry block, until it holds ``rows``."""
         # max() guards the vacuumed-to-empty index: doubling a
         # zero-length entry block would stay zero-length forever
         cap = max(16, self._keys.shape[0] * 2)
+        while cap < rows:
+            cap *= 2
         for name in ("_keys", "_pack", "_type", "_off", "_len", "_raw"):
             old = getattr(self, name)
             shape = (cap,) + old.shape[1:]
@@ -231,17 +327,9 @@ class CompactIndex:
         while ts < want:
             ts *= 2
         mask = ts - 1
-        # Hot at million-entry scale: plain-list probing (~100ns/entry)
-        # instead of numpy scalar indexing (~2us/entry); one bulk
-        # conversion at each end.
-        table = [_EMPTY] * ts
         rows = np.nonzero(self._pack[: self._n] != _DEAD_PACK)[0]
-        slots = (self._keys[rows, 0] & np.uint64(mask)).astype(np.int64)
-        for j, i in zip(rows.tolist(), slots.tolist()):
-            while table[i] != _EMPTY:
-                i = (i + 1) & mask
-            table[i] = j
-        self._table = np.asarray(table, dtype=np.int64)
+        homes = (self._keys[rows, 0] & np.uint64(mask)).astype(np.int64)
+        self._table = place_slots(rows, homes, ts)
         self._mask = mask
         self._tombs = 0
 
@@ -298,6 +386,82 @@ class CompactIndex:
         if (self._live + self._tombs) * 3 > self._table.shape[0] * 2:
             self._rebuild_table()
         return True
+
+    def insert_many(self, k4: np.ndarray, pack_names: list,
+                    pack_codes: np.ndarray, type_names: list,
+                    type_codes: np.ndarray, offset, length, raw_length,
+                    replace=True) -> int:
+        """``insert`` for a batch given as columns, in the batch's
+        order: ``(N, 4)`` key rows, a code an entry into ``pack_names``
+        and into ``type_names``, the three number columns, and
+        ``replace`` an entry (or one for all). Returns the ids added;
+        ``ValueError``, with nothing changed, for a length ``insert``
+        would refuse.
+
+        Leaves what ``insert`` an entry, in that order, would leave:
+        an id listed several times ends at its LAST replacing
+        occurrence, else stays what the index held, else takes its
+        FIRST occurrence; a new id's row goes where its first
+        occurrence puts it; names are interned in the order their first
+        entry takes effect. But the entry arrays grow once, a column is
+        stored at once, a name is interned once, and the slot table is
+        placed once for the final live count."""
+        return self.insert_columns(
+            k4, pack_names, type_names, *batch_columns(
+                int(k4.shape[0]), pack_codes, type_codes, offset, length,
+                raw_length, replace))
+
+    def insert_columns(self, k4: np.ndarray, pack_names: list,
+                       type_names: list, pack_codes: np.ndarray,
+                       type_codes: np.ndarray, offset: np.ndarray,
+                       length: np.ndarray, raw_length: np.ndarray,
+                       replace: np.ndarray) -> int:
+        """``insert_many`` of columns ``batch_columns`` has passed (or
+        a part of them: ``ShardedBlobIndex.insert_many`` checks a batch
+        once and hands each shard its rows)."""
+        n = int(k4.shape[0])
+        if n == 0:
+            return 0
+        lead, last = _occurrences(k4, replace)
+        held = self.probe_rows(k4)[lead]
+        new = held < 0
+        # an id's values come from its last replacing occurrence, else
+        # (a new id) from its first
+        src = np.where(last >= 0, last, lead)
+        took = replace.copy()  # the entries that change the mapping
+        took[lead[new]] = True
+        packs = self._intern_codes(pack_names, pack_codes[took],
+                                   self._packs, self._pack_idx)
+        types = self._intern_codes(type_names, type_codes[took],
+                                   self._types, self._type_idx)
+        n0, added = self._n, int(new.sum())
+        if n0 + added > self._keys.shape[0]:
+            self._grow_entries(n0 + added)
+        rows = held
+        rows[new] = np.arange(n0, n0 + added)
+        wrote = new | (last >= 0)
+        rows, src = rows[wrote], src[wrote]
+        self._pack[rows] = packs[pack_codes[src]]
+        self._type[rows] = types[type_codes[src]]
+        self._off[rows] = offset[src]
+        self._len[rows] = length[src]
+        self._raw[rows] = raw_length[src]
+        if added:
+            self._keys[n0: n0 + added] = k4[lead[new]]
+            self._n += added
+            self._live += added
+            self._rebuild_table()
+        return added
+
+    def _intern_codes(self, names: list, codes: np.ndarray, values: list,
+                      index: dict) -> np.ndarray:
+        """Intern the ``names`` that ``codes`` uses, in the order it
+        first uses them; -> the interned number of each code."""
+        used, first = np.unique(codes, return_index=True)
+        out = np.zeros((len(names),), dtype=np.uint32)
+        for code in used[np.argsort(first)].tolist():
+            out[code] = self._intern(names[code], values, index)
+        return out
 
     def remove(self, hex_id: str) -> bool:
         slot, j = self._probe(self._key4(hex_id))

@@ -199,7 +199,9 @@ class RepackService:
 
         repo.flush()
         repo.load_index()
-        baseline_deltas = set(repo.store.list("index/"))
+        # what the load read, not a second listing (Repository.
+        # _prune_locked says why)
+        baseline_deltas = set(repo._loaded_deltas)
         own_mark = len(repo._published_deltas)
         now = datetime.now(timezone.utc)
         locks = repo._live_foreign_locks()

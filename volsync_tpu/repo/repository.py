@@ -41,14 +41,18 @@ import atexit
 import hashlib
 import json
 import logging
+import operator
 import threading
 import time as time_mod
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from datetime import datetime, timedelta, timezone
+from itertools import chain
 from typing import Iterable, Optional
 
 from concurrent.futures import Future, ThreadPoolExecutor
+
+import numpy as np
 
 from volsync_tpu import envflags
 from volsync_tpu.analysis import lockcheck
@@ -63,6 +67,7 @@ from volsync_tpu.obs import (
     span,
 )
 from volsync_tpu.repo import blobid, crypto
+from volsync_tpu.repo.compactindex import as_key_rows, id_bytes
 from volsync_tpu.repo.shardedindex import ShardedBlobIndex
 from volsync_tpu.repo.compress import (
     CompressError,
@@ -168,6 +173,71 @@ class _IndexReloadRace(RuntimeError):
     """A load_index pass raced a concurrent consolidation (delta
     deleted mid-scan) or a torn delta PUT; the whole pass restarts
     (classified retryable by the reload policy)."""
+
+
+_ENTRY_ID, _ENTRY_TYPE, _ENTRY_OFFSET, _ENTRY_LENGTH, _ENTRY_RAW = map(
+    operator.itemgetter, ("id", "type", "offset", "length", "raw_length"))
+
+
+class _IndexColumns:
+    """A load's index entries as the columns
+    ``ShardedBlobIndex.insert_many`` takes: ``gather`` an index object's
+    ``packs`` at a time (its dicts can die before the next object is
+    fetched), then ``place`` the lot in one call. What is held between
+    the two is 56 bytes an entry (the id's four words, two codes, the
+    offset, two lengths as the index holds them), a column's parts
+    dying as ``place`` joins them."""
+
+    def __init__(self):
+        self.packs: dict[str, int] = {}  # name -> code, in code order
+        self.types: dict[str, int] = {}
+        # key rows, pack codes, type codes, offset, length, raw_length:
+        # a list of arrays each, an array an index object
+        self.columns: tuple = tuple([] for _ in range(6))
+
+    def gather(self, by_pack: dict) -> None:
+        entries = list(chain.from_iterable(by_pack.values()))
+        if not entries:
+            return
+        codes = [self.packs.setdefault(pack_id, len(self.packs))
+                 for pack_id in by_pack]
+        n = len(entries)
+        kinds = list(map(_ENTRY_TYPE, entries))
+        for kind in set(kinds):
+            self.types.setdefault(kind, len(self.types))
+        try:  # a column a pass: half the cost of a tuple an entry
+            numbers = [np.fromiter(map(get, entries), dtype, n)
+                       for get, dtype in ((_ENTRY_OFFSET, np.uint64),
+                                          (_ENTRY_LENGTH, np.uint32),
+                                          (_ENTRY_RAW, np.uint32))]
+        except OverflowError as ex:  # negative, or past the dtype
+            raise ValueError(f"index entry out of range: {ex}") from ex
+        ids = np.frombuffer(id_bytes(list(map(_ENTRY_ID, entries))),
+                            dtype=np.uint8).reshape(-1, 32)
+        for column, part in zip(self.columns, (
+                as_key_rows(ids),
+                np.repeat(np.array(codes, dtype=np.int32),
+                          [len(listed) for listed in by_pack.values()]),
+                np.fromiter(map(self.types.__getitem__, kinds),
+                            np.int32, n),
+                *numbers)):
+            column.append(part)
+
+    def place(self, index: ShardedBlobIndex, pending: set) -> int:
+        """Insert everything gathered; entries of a pack in ``pending``
+        never replace. Returns the ids now in ``index`` by this call.
+        A load of no entries does no array work."""
+        if not self.columns[0]:
+            return 0
+        whole = []
+        for parts in self.columns:  # a column at a time: its parts die
+            whole.append(np.concatenate(parts))
+            parts.clear()
+        keys, codes, kinds, offset, length, raw_length = whole
+        names = list(self.packs)
+        replace = np.array([name not in pending for name in names])[codes]
+        return index.insert_many(keys, names, codes, list(self.types), kinds,
+                                 offset, length, raw_length, replace)
 
 
 # Shared worker pools for the pipelined write path — module-level
@@ -361,6 +431,10 @@ class Repository:
         #: entries pointing at them as ABSENT, so new backups re-store
         #: those blobs instead of extending a marked pack's life.
         self._pending_packs: set[str] = set()
+        # The index objects the last load_index read: what a prune or a
+        # repack may supersede (a second listing could name a delta a
+        # concurrent writer published after the load, never read).
+        self._loaded_deltas: frozenset = frozenset()
         #: index-delta keys this writer published (prune must know its
         #: own mid-run deltas to supersede them at consolidation)
         self._published_deltas: list[str] = []
@@ -796,12 +870,14 @@ class Repository:
                 # (retryable= is checked first) — store weather is the
                 # ResilientStore wrap's budget, not ours (VL602).
                 classify_fn=lambda exc: False)
-            fresh, pending, objects = reload_policy.call(
+            fresh, pending, deltas, bulk = reload_policy.call(
                 self._read_index_snapshot)
             self._index = fresh
+            self._loaded_deltas = frozenset(deltas)
             count("repo.index_loads")
-            count("repo.index_objects", objects)
+            count("repo.index_objects", len(deltas))
             count("repo.index_entries", len(fresh))
+            count("repo.index_bulk_entries", bulk)
             self._pending_packs = pending
             GLOBAL_METRICS.repo_pending_delete_packs.set(len(pending))
             self.generation = max(self.generation,
@@ -832,19 +908,29 @@ class Repository:
     def _decode_index_delta(self, raw: bytes) -> dict:
         return json.loads(self._zd.decompress(self.box.open(raw)))
 
-    def _read_index_snapshot(self) -> tuple[ShardedBlobIndex, set, int]:
+    def _read_index_snapshot(self) -> tuple[ShardedBlobIndex, set, list,
+                                            int]:
         """One full pass over ``index/`` + ``pending-delete/`` into a
         fresh index (load_index holds repo.state and swaps it in), with
-        the pending-delete packs and the index objects it read. Raises
-        _IndexReloadRace when the pass must restart.
+        the pending-delete packs, the keys of the index objects it read
+        and the ids the bulk placement put into the index. Raises _IndexReloadRace
+        when the pass must restart.
 
-        Three spans split the pass, one of each an index object and
-        all inside ``repo.load_index``: ``repo.index_fetch`` (the two
-        listings and the GETs), ``repo.index_decode`` (``box.open``,
-        zstd, ``json.loads``) and ``repo.index_insert`` (one
-        ``ShardedBlobIndex.insert`` an entry). They keep totals and
-        stay off the flight recorder's ring: a repository late in its
-        week holds a hundred small index objects, read twice a sync."""
+        The index is loaded by the column, not by the entry: each
+        decoded object's entries are gathered as columns
+        (``_IndexColumns``) and the whole load is placed by ONE
+        ``ShardedBlobIndex.insert_many``, so the numpy work's fixed cost
+        is paid once a load whatever the number of objects.
+
+        Three spans split the pass, all inside ``repo.load_index``:
+        ``repo.index_fetch`` (the two listings and the GETs),
+        ``repo.index_decode`` (``box.open``, zstd, ``json.loads``; one
+        an object) and ``repo.index_insert`` (one an object, its
+        columns gathered, and one more around the load's placement:
+        everything that is not fetch or decode).
+        They keep totals and stay off the flight recorder's ring: a
+        repository late in its week holds a hundred small index
+        objects, read twice a sync."""
         fresh = ShardedBlobIndex()
         # Pending set FIRST: a blob listed by several deltas (a crashed
         # pruner's old delta parks it in a marked pack, the consolidated
@@ -856,18 +942,17 @@ class Repository:
             for _key, man in self._load_pending_manifests():
                 pending.update(man.get("packs", ()))
             keys = list(self.store.list("index/"))
-        # Streaming: one index delta decoded at a time; entries land
-        # in the flat compact index, never in per-entry objects.
+        # Streaming: one index delta decoded at a time; its entries
+        # leave as flat columns, never as per-entry objects.
+        columns = _IndexColumns()
         for key in keys:
             payload = self._fetch_index_delta(key, quiet)
             with span("repo.index_insert", ctx=quiet):
-                for pack_id, entries in payload["packs"].items():
-                    replace = pack_id not in pending
-                    for e in entries:
-                        fresh.insert(e["id"], pack_id, e["type"],
-                                     e["offset"], e["length"],
-                                     e["raw_length"], replace=replace)
-        return fresh, pending, len(keys)
+                columns.gather(payload["packs"])
+            del payload
+        with span("repo.index_insert", ctx=quiet):
+            bulk = columns.place(fresh, pending)
+        return fresh, pending, keys, bulk
 
     def _fetch_index_delta(self, key: str, quiet) -> dict:
         """One index object, fetched and decoded. A torn body (the
@@ -1845,12 +1930,14 @@ class Repository:
         lockcheck.assert_held(self._lock, "prune (repo.state)")
         self.flush()
         self.load_index()
-        # Every index object visible NOW is superseded by the
+        # Every index object that load READ is superseded by the
         # consolidated shards written below; deltas concurrent writers
-        # publish AFTER this listing are preserved. Own deltas
-        # published mid-prune (the rewrite's add_blob calls can trip
-        # _persist_pending) are tracked via _published_deltas.
-        baseline_deltas = set(self.store.list("index/"))
+        # publish after the load's listing are preserved (a listing of
+        # its own here would name a delta published in between, whose
+        # entries the loaded index lacks: superseded unread, lost). Own
+        # deltas published mid-prune (the rewrite's add_blob calls can
+        # trip _persist_pending) are tracked via _published_deltas.
+        baseline_deltas = set(self._loaded_deltas)
         own_mark = len(self._published_deltas)
         reach = self._referenced_keys()
         now = datetime.now(timezone.utc)
@@ -2013,9 +2100,9 @@ class Repository:
         referenced_now = {p for p in self._index.live_packs() if p}
         sweep_packs -= referenced_now
         new_keys = self._write_consolidated_index()
-        # Step 4: drop superseded deltas — everything visible at entry
+        # Step 4: drop superseded deltas — everything the load read
         # plus own mid-prune deltas; deltas concurrent writers
-        # published since the baseline listing are preserved. Deletes
+        # published since the load's listing are preserved. Deletes
         # are idempotent, so a crash-retry re-runs this safely.
         superseded = (baseline_deltas
                       | set(self._published_deltas[own_mark:])) - new_keys

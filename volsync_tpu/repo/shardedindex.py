@@ -30,6 +30,19 @@ synchronizes the index:
   scalar probes under one lock a touched shard instead, and does not
   consult the prefilter.
 
+* **Loading.** ``insert`` takes an entry (a writer's new blobs, the
+  seal path: one shard lock, a scalar probe, the filter's
+  ``add_one``). ``insert_many`` takes a batch as columns and is what
+  ``Repository.load_index`` places a whole load with: one stable
+  argsort routes the batch, then a shard at a time (ascending, one
+  lock held) ``CompactIndex.insert_many`` resolves the ids the shard
+  holds and the ids the batch lists twice, grows the entry arrays
+  once, stores a column at once and places the slot table once by
+  numpy, and the shard's filter is built once at its final size.
+  Both leave the same index behind; a load is no longer one ``insert``
+  an entry, whatever its size: a load of no entries makes no array, any
+  other is one ``insert_many``.
+
 * **Prefilter.** A per-shard blocked-bloom filter answers "definitely
   absent" for the first-backup workload where nearly every query is a
   miss, skipping the probe entirely. It lives under the shard's lock
@@ -50,7 +63,12 @@ synchronizes the index:
   positives are its queries.
   The Prometheus counters (``volsync_index_queries_total``,
   ``volsync_index_prefilter_total``) move on the batched paths as
-  before.
+  before. The loads are counted by their caller, not here:
+  ``repo.index_loads``, ``repo.index_objects``, ``repo.index_entries``
+  and ``repo.index_bulk_entries`` (the ids ``insert_many`` put into a
+  load's fresh index: all of ``repo.index_entries`` when the load went
+  by the column) in ``Repository.load_index``; ``INDEX_COUNTERS`` stays
+  the membership questions' four.
 
 Lock order: ``repo.state`` -> ``repo.index.shard{i}``. The index never
 calls back into the repository or the object store, so no blocking
@@ -68,7 +86,11 @@ from volsync_tpu import envflags
 from volsync_tpu.analysis import lockcheck
 from volsync_tpu.metrics import GLOBAL as GLOBAL_METRICS
 from volsync_tpu.obs import count
-from volsync_tpu.repo.compactindex import CompactIndex, as_key_rows
+from volsync_tpu.repo.compactindex import (
+    CompactIndex,
+    as_key_rows,
+    batch_columns,
+)
 
 #: the membership counters this module keeps in ``obs.count`` (see the
 #: module's docstring); a reader of layer metrics asks for them by name
@@ -206,11 +228,14 @@ class ShardedBlobIndex:
     def _rebuild_filter(self, s: int):
         if not self._prefilter_on:
             return
+        self._refill_filter(s)
+        self._update_saturation()
+
+    def _refill_filter(self, s: int):
         rows = self._shards[s].live_key_rows()
         f = BloomPrefilter(capacity=max(4096, rows.shape[0] * 2))
         f.add_rows(rows)
         self._filters[s] = f
-        self._update_saturation()
 
     def _update_saturation(self):
         sats = [f.saturation() for f in self._filters if f is not None]
@@ -259,6 +284,54 @@ class ShardedBlobIndex:
                 if len(self._shards[s]) > f.capacity:
                     self._rebuild_filter(s)
             return changed
+
+    def insert_many(self, keys, pack_names: list, pack_codes,
+                    type_names: list, type_codes, offset, length,
+                    raw_length, replace=True) -> int:
+        """``insert`` for a batch given as columns, in the batch's
+        order (``CompactIndex.insert_many`` says what it leaves: what
+        ``insert`` an entry would): ``keys`` is anything ``as_key_rows``
+        takes, ``replace`` one bool an entry or one for all. Returns the
+        ids added. A bad id or a length past 4 GiB raises ``ValueError``
+        before any shard has changed.
+
+        One stable argsort routes the batch (a shard keeps the batch's
+        order); then a shard at a time, ascending, one lock held: the
+        shard places its part, and its filter takes the new keys, or is
+        built once at the shard's final size if that outgrew it (the
+        saturation gauge moves then, as after any filter rebuild)."""
+        k4 = as_key_rows(keys)
+        cols = batch_columns(int(k4.shape[0]), pack_codes, type_codes,
+                             offset, length, raw_length, replace)
+        sid = self._shard_ids(k4)
+        order = np.argsort(sid, kind="stable")
+        bounds = np.concatenate(([0], np.cumsum(np.bincount(
+            sid, minlength=self._nshards)))).tolist()
+        del sid
+        added, refilled = 0, False
+        for s in range(self._nshards):
+            # a shard's rows taken out as its turn comes: the batch is
+            # never held a second time in shard order
+            part = order[bounds[s]: bounds[s + 1]]
+            if not part.size:
+                continue
+            sh = self._shards[s]
+            with self._locks[s]:
+                n0 = sh._n
+                added += sh.insert_columns(
+                    k4[part], pack_names, type_names,
+                    *(col[part] for col in cols))
+                f = self._filters[s]
+                if f is None:
+                    continue
+                if len(sh) > f.capacity:
+                    self._refill_filter(s)
+                    refilled = True
+                else:
+                    f.add_rows(sh._keys[n0: sh._n])
+        if refilled:  # the gauge moves when a filter is rebuilt
+            self._update_saturation()
+        return added
 
     def remove(self, hex_id: str) -> bool:
         k4 = CompactIndex._key4(hex_id)
